@@ -98,7 +98,9 @@ def main(argv=None) -> int:
     # the soak runtime (jax + aiohttp) loads only past this point —
     # schedule compilation and validation stay import-light
     from dstack_tpu.loadgen.soak import SoakConfig, run_soak
+    from dstack_tpu.utils.backend import enable_compile_cache
 
+    enable_compile_cache()
     cfg = SoakConfig(
         replicas=args.replicas,
         model=args.model,
@@ -117,7 +119,8 @@ def main(argv=None) -> int:
         k: result[k]
         for k in (
             "metric", "value", "unit", "seed", "schedule_digest",
-            "events", "duration_s", "replicas", "backend", "note",
+            "events", "duration_s", "replicas", "device",
+            "replica_devices", "device_bytes_in_use",
             "failures", "client_5xx", "router",
         )
     }))

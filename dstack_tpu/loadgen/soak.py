@@ -122,6 +122,18 @@ class _Replica:
         self.killed = False
 
 
+def _replica_mesh(index: int):
+    """A one-device mesh over replica ``index``'s own device: on a
+    four-chip host each in-process replica holds its weights and KV
+    cache on its own chip instead of all of them sharing chip 0."""
+    import jax
+
+    from dstack_tpu.parallel.mesh import single_device_mesh
+
+    devices = jax.devices()
+    return single_device_mesh(devices[index % len(devices)])
+
+
 async def _start_replica(rid: str, engine, model: str, policy, boot=None):
     from aiohttp import web
 
@@ -365,6 +377,7 @@ async def _scale_up_replica(
         engine = InferenceEngine(
             config, fresh, max_batch=cfg.max_batch,
             max_seq=cfg.max_seq, prefill_chunk=cfg.prefill_chunk,
+            mesh=_replica_mesh(cfg.replicas),
         )
     engine.fault_ctx = {"replica": rid}
     replica = await _start_replica(
@@ -415,7 +428,7 @@ async def _soak_async(schedule: EventSchedule, cfg: SoakConfig) -> dict:
         ReplicaState,
     )
     from dstack_tpu.serve.engine import InferenceEngine
-    from dstack_tpu.utils.backend import backend_info
+    from dstack_tpu.utils.backend import device_bytes_in_use, device_info
 
     spec, seed = schedule.spec, schedule.seed
     if cfg.replicas < 2:
@@ -447,11 +460,14 @@ async def _soak_async(schedule: EventSchedule, cfg: SoakConfig) -> dict:
         )
     config = llama.CONFIGS[cfg.model]
     params = llama.init_params(config, jax.random.key(0))
-    # pin the random-init model to ASCII output (ban non-byte ids incl.
-    # eos): resumed streams splice delivered TEXT back into the prompt,
-    # so output must round-trip the byte tokenizer exactly, and banning
-    # eos keeps generations at their full token budget
-    ascii_bias = {str(i): -100 for i in range(128, config.vocab_size)}
+    # pin the random-init model to ASCII output (every other id, eos
+    # included, loses to a +100 bias): resumed streams splice delivered
+    # TEXT back into the prompt, so output must round-trip the byte
+    # tokenizer exactly, and never sampling eos keeps generations at
+    # their full token budget. Lifting the 128 wanted ids — not banning
+    # the rest — keeps the request body small at a 128k vocab (banning
+    # 128,128 ids is a 2 MB body the server's 1 MB limit answers 413).
+    ascii_bias = {str(i): 100 for i in range(128)}
     policy = qos.QoSPolicy(
         rps=cfg.qos_rps, burst=cfg.qos_burst,
         tenant_inflight=cfg.tenant_inflight,
@@ -472,6 +488,7 @@ async def _soak_async(schedule: EventSchedule, cfg: SoakConfig) -> dict:
             engine = InferenceEngine(
                 config, params, max_batch=cfg.max_batch,
                 max_seq=cfg.max_seq, prefill_chunk=cfg.prefill_chunk,
+                mesh=_replica_mesh(i),
             )
             # both engines share this process's fault plan: the replica
             # ctx lets a chaos rule target ONE of them (e.g. bounded
@@ -697,9 +714,8 @@ async def _soak_async(schedule: EventSchedule, cfg: SoakConfig) -> dict:
     # per-stage boot timeline from its private recorder, schedule-
     # relative spawn time, and the /health-shaped summary — read next
     # to the `scale_up` entry in the window analysis (goodput/tails
-    # around the join). Same backend/note labels as the whole
-    # artifact: on CPU fallback these stage durations are NOT TPU boot
-    # numbers.
+    # around the join). The artifact's `device` block says where these
+    # stage durations were taken.
     boot_block = None
     boot_rec = scale_state.get("recorder") if cfg.scale_up else None
     if boot_rec is not None:
@@ -716,7 +732,6 @@ async def _soak_async(schedule: EventSchedule, cfg: SoakConfig) -> dict:
                 if up_engine is not None else 0
             ),
         }
-    info = backend_info()
     result = {
         "metric": (
             f"loadgen_goodput_under_slo[{cfg.model},"
@@ -745,11 +760,15 @@ async def _soak_async(schedule: EventSchedule, cfg: SoakConfig) -> dict:
             if cfg.chaos
             else None
         ),
-        "backend": info["backend"],
-        "note": info["note"],
-        # engine-side observability over the timed soak (obs/flight.py;
-        # same backend label as the artifact — CPU-fallback honesty
-        # applies to memory/compile numbers too)
+        "device": device_info(),
+        # which device each replica's cache lives on and what every
+        # device holds (None where the backend reports no stats, i.e.
+        # CPU) — the proof that replicas do not share one chip
+        "replica_devices": {
+            r.rid: sorted(d.id for d in r.engine.devices) for r in replicas
+        },
+        "device_bytes_in_use": device_bytes_in_use(),
+        # engine-side observability over the timed soak (obs/flight.py)
         "flight": flight_block,
         # scale-up boot decomposition (None unless cfg.scale_up): the
         # TTFST baseline for ROADMAP item 4

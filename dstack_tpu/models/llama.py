@@ -24,7 +24,12 @@ from jax.sharding import Mesh
 
 from dstack_tpu.ops.attention import attention
 from dstack_tpu.parallel.ring_attention import ring_attention
-from dstack_tpu.parallel.sharding import ShardingRules, constrain, default_rules
+from dstack_tpu.parallel.sharding import (
+    ShardingRules,
+    constrain,
+    default_rules,
+    kernel_shard,
+)
 
 
 @dataclass(frozen=True)
@@ -1263,7 +1268,7 @@ def _attention_block(
         o = attention(
             q, k, v, causal=True, scale=scale, impl=attn_impl,
             window=window, softcap=c.attn_softcap, chunk=chunk,
-            sinks=sinks,
+            sinks=sinks, shard=kernel_shard(mesh, rules, b, k.shape[1]),
         )
     if c.mla and c.qk_head_dim > c.v_head_dim:
         o = o[..., : c.v_head_dim]  # drop the zero v padding

@@ -8,10 +8,12 @@ shape/platform dispatch and the XLA fallback used off-TPU (CPU tests,
 virtual meshes) and for non-tiling shapes (decode steps, tiny models).
 """
 
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from dstack_tpu.ops.flash import (  # re-exported public kernel API
     flash_attention,
@@ -110,6 +112,22 @@ def sink_postscale(
     return (o.astype(jnp.float32) * gate).astype(o.dtype)
 
 
+def _per_shard(kernel, shard, with_lse: bool = False):
+    """``kernel(q, k, v)`` as is, or — with ``shard=(mesh, spec)`` from
+    :func:`dstack_tpu.parallel.sharding.kernel_shard` — per shard under
+    ``shard_map``: GSPMD cannot partition a Mosaic call, and attention
+    needs no collective across batch rows or KV-head groups."""
+    if shard is None:
+        return kernel
+    mesh, spec = shard
+    # dtpu: noqa[DTPU012] spec comes from parallel/sharding.kernel_shard, which draws its axis names from the rule table (checked there) and drops axes that do not divide the operand
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=(spec, P(*spec[:3])) if with_lse else spec,
+        check_vma=False,
+    )
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -124,6 +142,7 @@ def attention(
     sinks: Optional[jax.Array] = None,  # [H] gpt-oss attention sinks
     impl: Optional[str] = None,  # None=auto | "flash" | "xla"
     sinks_forward_only: bool = False,  # caller never differentiates
+    shard: Optional[tuple] = None,  # (mesh, spec): kernel runs per shard
 ) -> jax.Array:
     """Dispatching attention entry point used by models.
 
@@ -132,6 +151,9 @@ def attention(
     chunks at unequal starts share one dispatch). The pallas kernel
     tiles exactly one static offset per call, so vector offsets always
     take the masked-einsum path (window/softcap/chunk/sinks included).
+
+    On a multi-device mesh pass ``shard`` (:func:`kernel_shard`): the
+    XLA path partitions on its own, the pallas kernel cannot.
     """
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if isinstance(q_offset, jax.Array) and q_offset.ndim > 0:
@@ -149,10 +171,10 @@ def attention(
             and not chunk
             and (impl == "flash" or (impl is None and flash_supported(q, k)))
         ):
-            o, lse = flash_attention_with_lse(
-                q, k, v, causal=causal, scale=scale, q_offset=q_offset,
-                window=window, softcap=softcap,
-            )
+            o, lse = _per_shard(partial(
+                flash_attention_with_lse, causal=causal, scale=scale,
+                q_offset=q_offset, window=window, softcap=softcap,
+            ), shard, with_lse=True)(q, k, v)
             return sink_postscale(o, lse, sinks)
         return _xla_attention(
             q, k, v, causal=causal, scale=scale, q_offset=q_offset,
@@ -172,10 +194,10 @@ def attention(
             window=window, softcap=softcap, chunk=chunk,
         )
     if impl == "flash" or (impl is None and flash_supported(q, k)):
-        return flash_attention(
-            q, k, v, causal=causal, scale=scale, q_offset=q_offset,
+        return _per_shard(partial(
+            flash_attention, causal=causal, scale=scale, q_offset=q_offset,
             window=window, softcap=softcap,
-        )
+        ), shard)(q, k, v)
     return _xla_attention(
         q, k, v, causal=causal, scale=scale, q_offset=q_offset,
         window=window, softcap=softcap,

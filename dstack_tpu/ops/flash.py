@@ -34,6 +34,11 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+# The backward kernels hold four [bq, bk] f32 temporaries (s, p, dp, ds)
+# where the forward holds two: at the default 1024×1024 blocks that is
+# 16 MiB, the whole default scoped VMEM, and the TPU compiler refused
+# them for every T ≥ 2048 (17.07 MiB asked). A v5e core has 128 MiB.
+_BWD_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def _pick_block(t: int, cap: int, unit: int = 128) -> int:
@@ -484,6 +489,7 @@ def _flash_bwd(
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -557,6 +563,7 @@ def _flash_bwd(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(q, k, v, do, lse, delta)

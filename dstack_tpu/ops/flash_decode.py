@@ -51,7 +51,7 @@ def _decode_kernel(
     q_ref,  # [1, 1, G, D]
     k_ref,  # [1, 1, BK, D] compute dtype or int8
     v_ref,
-    *rest,  # optional (ks_ref, vs_ref [1, 1, BK] f32), optional (sink_ref [1, G] f32), then o_ref + scratch
+    *rest,  # optional (ks_ref, vs_ref [1, 1, 1, BK] f32), optional (sink_ref [1, G, 1] f32), then o_ref + scratch
     scale: float,
     softcap: float,
     block_k: int,
@@ -101,8 +101,8 @@ def _decode_kernel(
         v = v_ref[0, 0]
         if quantized:
             # per-token scales broadcast over D; HBM read was int8
-            k = (k.astype(jnp.float32) * ks_ref[0, 0][:, None]).astype(q.dtype)
-            v = (v.astype(jnp.float32) * vs_ref[0, 0][:, None]).astype(q.dtype)
+            k = (k.astype(jnp.float32) * ks_ref[0, 0, 0][:, None]).astype(q.dtype)
+            v = (v.astype(jnp.float32) * vs_ref[0, 0, 0][:, None]).astype(q.dtype)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [G, BK] f32
@@ -147,7 +147,7 @@ def _decode_kernel(
         if sinks:
             # the sink joins the DENOMINATOR only (ops/attention.py::
             # sink_softmax): rescale running stats to max(m, sink)
-            snk = sink_ref[0][:, None].astype(jnp.float32)  # [G, 1]
+            snk = sink_ref[0]  # [G, 1] f32
             m_f = jnp.maximum(m, snk)
             alpha = jnp.where(
                 m <= NEG_INF / 2, jnp.zeros_like(m), jnp.exp(m - m_f)
@@ -227,18 +227,24 @@ def flash_decode(
         pl.BlockSpec((1, 1, bk, d), _kv_ix),
     ]
     args = [q, k, v]
+    # Mosaic wants a block's last two dims to be multiples of (8, 128)
+    # or to span the array: scales ride as [B, Hkv, 1, T] (a unit row
+    # over the token lanes), sinks as [Hkv, G, 1] (a column per head)
     if quantized:
-        sc_ix = lambda bi, h, ki, p, w: _kv_ix(bi, h, ki, p, w)[:3]
-        in_specs += [
-            pl.BlockSpec((1, 1, bk), sc_ix),
-            pl.BlockSpec((1, 1, bk), sc_ix),
+        def sc_ix(bi, h, ki, p, w):
+            bi, h, ki, _ = _kv_ix(bi, h, ki, p, w)
+            return (bi, h, 0, ki)
+
+        in_specs += [pl.BlockSpec((1, 1, 1, bk), sc_ix)] * 2
+        args += [
+            s.astype(jnp.float32).reshape(b, hkv, 1, t)
+            for s in (k_scale, v_scale)
         ]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     if sinks is not None:
         in_specs.append(
-            pl.BlockSpec((1, g), lambda bi, h, ki, p, w: (h, 0))
+            pl.BlockSpec((1, g, 1), lambda bi, h, ki, p, w: (h, 0, 0))
         )
-        args.append(sinks.astype(jnp.float32))
+        args.append(sinks.astype(jnp.float32).reshape(hkv, g, 1))
 
     kernel = functools.partial(
         _decode_kernel,

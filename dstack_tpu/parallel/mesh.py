@@ -109,8 +109,12 @@ def make_mesh(
     return Mesh(dev_array, AXES)
 
 
-def single_device_mesh() -> Mesh:
-    return make_mesh(MeshConfig(dp=1, fsdp=1, ep=1, sp=1, tp=1), devices=jax.devices()[:1])
+def single_device_mesh(device: Optional[jax.Device] = None) -> Mesh:
+    """A mesh over one device (default: the first)."""
+    return make_mesh(
+        MeshConfig(dp=1, fsdp=1, ep=1, sp=1, tp=1),
+        devices=[device or jax.devices()[0]],
+    )
 
 
 def mesh_shape(mesh: Mesh) -> dict[str, int]:

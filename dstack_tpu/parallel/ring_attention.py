@@ -36,7 +36,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dstack_tpu.ops.flash import _flash_bwd, _flash_fwd
@@ -483,10 +482,10 @@ def ring_attention(
         )
 
     spec = P(None, None, axis_name, None)  # seq sharded; heads follow outer
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)

@@ -97,3 +97,34 @@ def constrain(
         return x
     spec = filter_spec_for_mesh(rules.spec(tuple(logical_axes)), mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def kernel_shard(
+    mesh: Optional[Mesh],
+    rules: ShardingRules,
+    batch: int,
+    kv_heads: int,
+) -> Optional[tuple[Mesh, P]]:
+    """``(mesh, spec)`` for running a per-(batch, head) attention kernel
+    under ``shard_map`` — ``spec`` fits both the ``[B, H, T, D]`` and
+    the ``[B, Hkv, T, D]`` operands — or None on a one-device mesh.
+
+    GSPMD cannot partition a Mosaic (Pallas) call — on a real multi-chip
+    mesh the compiler refuses it — so the kernel has to run per shard.
+    Attention is independent per batch row and per KV-head group, so the
+    batch and head axes shard without collectives. A mesh axis whose
+    size does not divide its dim is left out (that dim replicates and
+    each shard computes all of it); q and kv heads share one axis, so
+    GQA groups never straddle a shard."""
+    if mesh is None or mesh.size == 1:
+        return None
+
+    def fit(logical: str, dim: int) -> MeshAxis:
+        entry = filter_spec_for_mesh(rules.spec((logical,)), mesh)[0]
+        axes = (entry,) if isinstance(entry, str) else (entry or ())
+        ways = 1
+        for a in axes:
+            ways *= mesh.shape[a]
+        return entry if dim % ways == 0 else None
+
+    return mesh, P(fit("batch", batch), fit("kv_heads", kv_heads), None, None)

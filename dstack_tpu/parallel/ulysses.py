@@ -34,7 +34,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dstack_tpu.ops.attention import attention
@@ -114,10 +113,10 @@ def ulysses_attention(
 
     spec = P(None, None, axis_name, None)
     kv_spec = P(None, None, axis_name, None)
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, kv_spec, kv_spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)

@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dstack_tpu.models import llama
 from dstack_tpu.parallel.sharding import ShardingRules, default_rules, tree_shardings
-from dstack_tpu.train.step import batch_sharding, cross_entropy_loss
+from dstack_tpu.train.step import batch_sharding, chunked_cross_entropy
 
 # logical out-axis of each adaptable projection (in-axis of A is the
 # module's input axis); mirrors llama.param_specs
@@ -208,7 +208,7 @@ def make_lora_train_step(
     repl = NamedSharding(mesh, P())
 
     def loss_fn(lora, params, batch):
-        logits = llama.forward(
+        hidden = llama.forward(
             params,
             batch["tokens"],
             config,
@@ -217,8 +217,21 @@ def make_lora_train_step(
             attn_impl=attn_impl,
             lora=lora,
             lora_scale=lora_config.scale,
+            return_hidden=True,
         )
-        loss, _ = cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
+        # the same logits as forward()'s tail, a sequence chunk at a
+        # time: the naive log_softmax over [B, T, V] f32 logits is two
+        # 7.8 GB tensors at the default batch 8 × seq 2048 on a 128k
+        # vocab — more than the chip the adapters were meant to fit on
+        # (the TPU compiler refused the step at 21.75 of 16 GB)
+        head = (
+            params["embed"].T if config.tie_embeddings else params["lm_head"]
+        ).astype(config.dtype)
+        loss, _ = chunked_cross_entropy(
+            hidden, head, batch["targets"], batch.get("mask"),
+            rules=rules, mesh=mesh, softcap=config.logit_softcap,
+            logit_scale=config.logit_scale,
+        )
         return loss
 
     def accum_grads(lora, params, batch):

@@ -79,6 +79,7 @@ def chunked_cross_entropy(
     rules: Optional[ShardingRules] = None,
     mesh: Optional[Mesh] = None,
     softcap: float = 0.0,  # Gemma2 final-logit tanh cap
+    logit_scale: float = 0.0,  # Cohere logit multiplier (0 = off)
 ) -> tuple[jax.Array, jax.Array]:
     """LM-head matmul fused into the loss, chunked over the sequence.
 
@@ -111,6 +112,8 @@ def chunked_cross_entropy(
         logits = jnp.einsum(
             "bth,hv->btv", xc, head, preferred_element_type=jnp.float32
         )
+        if logit_scale:
+            logits = logits * logit_scale
         if softcap:
             logits = softcap * jnp.tanh(logits / softcap)
         if rules is not None:
@@ -407,6 +410,28 @@ def flops_per_token(config: llama.LlamaConfig, seq_len: int) -> float:
     return 6.0 * n + attn
 
 
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+#: 819 GB/s HBM per chip). A device that is not listed is an error,
+#: not a default: MFU against an assumed peak is not a measurement.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def peak_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s of one chip of ``device_kind``; raises for a
+    device the table does not know."""
+    try:
+        return DEVICE_PEAKS[device_kind]["bf16_flops"]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); add it to "
+            "train/step.py DEVICE_PEAKS with its source"
+        ) from None
+
+
 # ---------------------------------------------------------------------------
 # step telemetry (obs registry hook)
 # ---------------------------------------------------------------------------
@@ -440,7 +465,7 @@ def make_step_callback(
     config: llama.LlamaConfig,
     tokens_per_step: int,
     seq_len: int,
-    peak_flops_per_chip: float = 197e12,  # v5e bf16
+    peak_flops_per_chip: Optional[float] = None,
     n_chips: int = 1,
     registry=None,
 ):
@@ -451,8 +476,10 @@ def make_step_callback(
     dispatch, so ``dt_seconds`` is the window-average step time and
     ``steps`` the window width). Each call observes step time and
     refreshes tokens/sec and MFU; an exporter (or the bench) reads the
-    registry. Returns the callback; the registry rides on it as
-    ``cb.registry``."""
+    registry. ``peak_flops_per_chip`` comes from :func:`peak_flops` for
+    the device the run is on; without one (a CPU run) MFU is neither
+    computed nor exported. Returns the callback; the registry rides on
+    it as ``cb.registry``."""
     reg = registry if registry is not None else new_train_registry()
     fpt = flops_per_token(config, seq_len)
     step_hist = reg.family("dtpu_train_step_seconds")
@@ -464,14 +491,16 @@ def make_step_callback(
     def cb(dt_seconds: float, steps: int = 1) -> dict:
         dt = max(float(dt_seconds), 1e-9)
         tps = tokens_per_step / dt
-        mfu = tps * fpt / (peak_flops_per_chip * max(n_chips, 1))
+        out = {"tokens_per_sec": tps, "step_time_s": dt}
         for _ in range(steps):
             step_hist.observe(dt)
         tps_gauge.set(round(tps, 3))
-        mfu_gauge.set(round(mfu, 6))
+        if peak_flops_per_chip:
+            out["mfu"] = tps * fpt / (peak_flops_per_chip * max(n_chips, 1))
+            mfu_gauge.set(round(out["mfu"], 6))
         steps_ctr.inc(steps)
         tokens_ctr.inc(tokens_per_step * steps)
-        return {"tokens_per_sec": tps, "mfu": mfu, "step_time_s": dt}
+        return out
 
     cb.registry = reg
     return cb

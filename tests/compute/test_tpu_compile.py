@@ -1,0 +1,429 @@
+"""The main path's kernels and jitted steps COMPILE for a TPU v5e —
+device-free, at published Llama-3.2-1B widths.
+
+libtpu is installed here without a chip; it compiles for a *described*
+topology (``v5e:2x2``), and the Pallas TPU lowering refuses there
+exactly what it would refuse on the chip: block shapes that do not tile
+(8, 128), slices the layout cannot express, programs that do not fit
+16 GB. Interpret-mode tests cannot see any of that (the int8-KV scale
+and sink blocks of ``flash_decode`` passed them for rounds and were
+refused by this compile). Nothing runs: a compile that passes is not a
+chip run — ``chip_smoke.py`` is. Shapes only, never arrays (there is no
+device to hold one).
+
+Code that asks ``jax.default_backend()`` still sees the CPU, so the
+kernel entry points are called directly (``impl="flash"`` /
+``interpret=False``), and the one engine step whose dispatch is a
+platform gate is steered in the test (``_as_tpu``).
+"""
+
+import dataclasses
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+# libtpu guards the CHIP with a /tmp lockfile, one process at a time;
+# nothing here touches a chip, and pytest-xdist workers must not skip
+# each other out of the topology
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from dstack_tpu.models import llama
+from dstack_tpu.ops.flash import flash_attention
+from dstack_tpu.ops.flash_decode import flash_decode
+from dstack_tpu.serve import engine as eng
+
+HBM_BYTES = 16e9  # one v5e chip
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu / topology not describable here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """A described-device executable is written to the persistent cache
+    but can never be read back without a chip (each later compile would
+    warn and recompile), so the cache is off around this module."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def _as_tpu(monkeypatch):
+    """Steer the platform gates (``flash_supported``, ``interp =``) the
+    way the chip would: they read ``jax.default_backend()``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *args, **jit_kw):
+    return jax.jit(fn, **jit_kw).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+def _fits(compiled) -> float:
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit 16 GB"
+    return total
+
+
+# ---------------------------------------------------------------------------
+# ops/flash.py — training attention and the serve prefill chunk
+# ---------------------------------------------------------------------------
+
+
+def _flash_fwd(sds):
+    q = sds((8, 32, 1024, 64), BF16)
+    kv = sds((8, 8, 1024, 64), BF16)
+    return lambda q, k, v: flash_attention(q, k, v, causal=True), (q, kv, kv)
+
+
+def _flash_bwd(t=1024, heads=32, d=64):
+    """Forward + both backward kernels. ``t=2048`` is ``finetune``'s
+    default ``--seq-len``: with 1024×1024 backward blocks the compiler
+    refused it for want of VMEM (found by the first chip run of the
+    LoRA path; every earlier capture trained at 1024)."""
+
+    def build(sds):
+        q = sds((8, heads, t, d), BF16)
+        kv = sds((8, 8, t, d), BF16)
+
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True)
+            return o.astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv)
+
+    return build
+
+
+def _prefill_chunk(q_offset, row):
+    def build(sds):
+        q = sds((1, 32, 256, 64), BF16)
+        kv = sds((1, 8, row, 64), BF16)
+        return (
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, q_offset=q_offset
+            ),
+            (q, kv, kv),
+        )
+
+    return build
+
+
+def _flash_window_softcap(sds):
+    q = sds((2, 16, 1024, 128), BF16)
+    kv = sds((2, 8, 1024, 128), BF16)
+    return (
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=512, softcap=50.0
+        ),
+        (q, kv, kv),
+    )
+
+
+# ---------------------------------------------------------------------------
+# ops/flash_decode.py — ragged decode/verify over the slot cache
+# ---------------------------------------------------------------------------
+
+_B, _HKV, _G, _T, _D = 16, 8, 4, 2048, 64
+
+
+def _decode(rows_per_slot=1, int8=False, sinks=False):
+    def build(sds):
+        rows = _G * rows_per_slot
+        args = [
+            sds((_B, _HKV, rows, _D), BF16),
+            sds((_B, _HKV, _T, _D), jnp.int8 if int8 else BF16),
+            sds((_B, _HKV, _T, _D), jnp.int8 if int8 else BF16),
+            sds((_B,), jnp.int32),
+        ]
+        names = []
+        if int8:
+            args += [sds((_B, _HKV, _T), jnp.float32)] * 2
+            names += ["k_scale", "v_scale"]
+        if sinks:
+            args.append(sds((_HKV, rows), jnp.float32))
+            names.append("sinks")
+
+        def fn(q, k, v, pos, *opt):
+            return flash_decode(
+                q, k, v, pos, scale=_D**-0.5, rows_per_slot=rows_per_slot,
+                **dict(zip(names, opt)),
+            )
+
+        return fn, tuple(args)
+
+    return build
+
+
+KERNELS = {
+    "flash_fwd": _flash_fwd,
+    "flash_bwd": _flash_bwd(),
+    "flash_bwd_seq2048": _flash_bwd(t=2048),
+    "flash_bwd_seq4096_d128": _flash_bwd(t=4096, heads=16, d=128),
+    "prefill_chunk_256_of_2048": _prefill_chunk(256, 2048),
+    "prefill_chunk_4096_of_8192": _prefill_chunk(4096, 8192),
+    "flash_window_softcap_d128": _flash_window_softcap,
+    "decode_bf16": _decode(),
+    "decode_verify_rows5": _decode(rows_per_slot=5),
+    "decode_int8_kv": _decode(int8=True),
+    "decode_sinks": _decode(sinks=True),
+    "decode_verify_int8_sinks": _decode(rows_per_slot=5, int8=True, sinks=True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles(topo, name):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = KERNELS[name](sds)
+    assert _has_kernel(_compile(fn, *args)), "no tpu_custom_call in program"
+
+
+# ---------------------------------------------------------------------------
+# the engine's jitted steps at serve shapes (max_batch 16, max_seq 2048)
+# ---------------------------------------------------------------------------
+
+
+def _abstract_engine_state(config, sharding, max_batch=16, max_seq=2048):
+    """(params, cache, sds) as shapes placed on ``sharding`` (None: the
+    caller places them)."""
+
+    def place(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+            tree,
+        )
+
+    params = jax.eval_shape(
+        lambda: llama.init_params(config, jax.random.key(0))
+    )
+    cache = jax.eval_shape(lambda: eng.init_cache(config, max_batch, max_seq))
+    return place(params), place(cache), lambda shape, dt: jax.ShapeDtypeStruct(
+        shape, dt, sharding=sharding
+    )
+
+
+def test_decode_step_llama_1b_fits(topo):
+    config = llama.LLAMA_32_1B
+    params, cache, sds = _abstract_engine_state(
+        config, SingleDeviceSharding(topo.devices[0])
+    )
+    compiled = _compile(
+        lambda p, c, t, pos, m: eng.decode_step(p, c, t, pos, config, m),
+        params, cache, sds((16,), jnp.int32), sds((16,), jnp.int32),
+        sds((16,), jnp.bool_), donate_argnums=(1,),
+    )
+    _fits(compiled)
+
+
+def test_decode_step_flash_kernel_llama_1b(topo, _as_tpu):
+    """The opt-in ragged decode (ROADMAP A3's A/B) inside the whole
+    step, int8 KV: the variant the block-spec repair unblocks."""
+    config = llama.LLAMA_32_1B
+    sharding = SingleDeviceSharding(topo.devices[0])
+    params, _, sds = _abstract_engine_state(config, sharding)
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(
+            lambda: eng.init_cache(config, 16, 2048, kv_quant="int8")
+        ),
+    )
+    compiled = _compile(
+        lambda p, c, t, pos, m: eng.decode_step(
+            p, c, t, pos, config, m, decode_kernel="flash"
+        ),
+        params, cache, sds((16,), jnp.int32), sds((16,), jnp.int32),
+        sds((16,), jnp.bool_), donate_argnums=(1,),
+    )
+    assert _has_kernel(compiled)
+    _fits(compiled)
+
+
+def test_prefill_packed_step_llama_1b_fits(topo):
+    config = llama.LLAMA_32_1B
+    params, cache, sds = _abstract_engine_state(
+        config, SingleDeviceSharding(topo.devices[0])
+    )
+    g, c = 4, 256  # the widest default bucket: prefill_pack × prefill_chunk
+    compiled = _compile(
+        lambda p, ca, t, s, st, li: eng.prefill_packed_step(
+            p, ca, t, s, st, li, config
+        ),
+        params, cache, sds((g, c), jnp.int32), sds((g,), jnp.int32),
+        sds((g,), jnp.int32), sds((g,), jnp.int32), donate_argnums=(1,),
+    )
+    _fits(compiled)
+
+
+def test_prefill_chunk_step_llama_1b_uses_kernel(topo, _as_tpu):
+    """The serial prefill path (every unpacked request): on ``tpu`` the
+    platform gate must land on the compiled Pallas kernel, not the XLA
+    reference and not interpret mode."""
+    config = llama.LLAMA_32_1B
+    params, cache, sds = _abstract_engine_state(
+        config, SingleDeviceSharding(topo.devices[0])
+    )
+    compiled = _compile(
+        lambda p, ca, t, s, li: eng.prefill_chunk_step(
+            p, ca, t, s, li, config, start=256
+        ),
+        params, cache, sds((1, 256), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32), donate_argnums=(1,),
+    )
+    assert _has_kernel(compiled)
+    _fits(compiled)
+
+
+def test_mla_moe_decode_step_deepseek_widths(topo):
+    """One dense + one expert layer at DeepSeek-V2-Lite widths through
+    ``_decode_step_mla`` + ``_mlp_out``'s MoE branch: ROADMAP B1's
+    cells live on this path and it had never met the TPU compiler."""
+    config = dataclasses.replace(llama.DEEPSEEK_V2_LITE, n_layers=2)
+    assert config.mla and config.first_k_dense == 1 and config.n_experts
+    params, cache, sds = _abstract_engine_state(
+        config, SingleDeviceSharding(topo.devices[0])
+    )
+    assert "w_router" in params["layers"]  # the expert layer is there
+    compiled = _compile(
+        lambda p, c, t, pos, m: eng.decode_step(p, c, t, pos, config, m),
+        params, cache, sds((16,), jnp.int32), sds((16,), jnp.int32),
+        sds((16,), jnp.bool_), donate_argnums=(1,),
+    )
+    _fits(compiled)
+
+
+# ---------------------------------------------------------------------------
+# four chips: GSPMD cannot partition a Mosaic call, so under a mesh the
+# flash kernel must run per shard (parallel/sharding.kernel_shard). On
+# virtual CPU devices the XLA path is taken and none of this is seen.
+# ---------------------------------------------------------------------------
+
+
+def _described_mesh(topo, **sizes):
+    from dstack_tpu.parallel.mesh import AXES
+
+    shape = tuple(sizes.get(a, 1) for a in AXES)
+    return Mesh(np.asarray(topo.devices).reshape(shape), AXES)
+
+
+def test_prefill_chunk_step_tp4_uses_kernel(topo, _as_tpu):
+    """``openai_server --tp 4``: the serial prefill chunk over a cache
+    sharded on KV heads."""
+    from dstack_tpu.parallel.sharding import default_rules, tree_shardings
+
+    config = llama.LLAMA_32_1B
+    mesh = _described_mesh(topo, tp=4)
+    params, cache, _ = _abstract_engine_state(config, None)
+    params = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        params,
+        tree_shardings(llama.param_specs(config), mesh, default_rules()),
+    )
+    kv_heads = NamedSharding(mesh, P(None, None, "tp", None, None))
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=kv_heads),
+        cache,
+    )
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(mesh, P())
+        )
+
+    compiled = _compile(
+        lambda p, ca, t, s, li: eng.prefill_chunk_step(
+            p, ca, t, s, li, config, start=256, mesh=mesh
+        ),
+        params, cache, sds((1, 256), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32), donate_argnums=(1,),
+    )
+    assert _has_kernel(compiled)
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("fsdp,tp", [(4, 1), (2, 2)])
+def test_sharded_forward_backward_uses_kernel(topo, _as_tpu, fsdp, tp):
+    """``finetune --fsdp 4`` / ``--fsdp 2 --tp 2``: the model's forward
+    and backward under the mesh at published widths (depth and sequence
+    cut — the Mosaic compile of long-sequence blocks is what takes
+    time), flash forward and both backward kernels per shard."""
+    from dstack_tpu.parallel.sharding import tree_shardings
+    from dstack_tpu.train.step import batch_sharding, rules_for_mesh
+
+    config = dataclasses.replace(llama.LLAMA_32_1B, n_layers=1)
+    mesh = _described_mesh(topo, fsdp=fsdp, tp=tp)
+    rules = rules_for_mesh(mesh, None)
+    params = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        jax.eval_shape(lambda: llama.init_params(config, jax.random.key(0))),
+        tree_shardings(llama.param_specs(config), mesh, rules),
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (8, 256), jnp.int32, sharding=batch_sharding(mesh, rules)
+    )
+
+    def loss(p, t):
+        hidden = llama.forward(
+            p, t, config, mesh=mesh, rules=rules, return_hidden=True
+        )
+        return hidden.astype(jnp.float32).mean()
+
+    compiled = _compile(jax.grad(loss), params, tokens)
+    # forward, recomputed forward (remat), dq and dk/dv
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    _fits(compiled)
+
+
+# ---------------------------------------------------------------------------
+# ring attention on a described four-chip mesh
+# ---------------------------------------------------------------------------
+
+
+def test_ring_attention_step_kernel_sp4(topo):
+    from dstack_tpu.parallel.ring_attention import ring_attention
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("sp",))
+    seq = NamedSharding(mesh, P(None, None, "sp", None))
+    q = jax.ShapeDtypeStruct((1, 32, 4096, 64), BF16, sharding=seq)
+    kv = jax.ShapeDtypeStruct((1, 8, 4096, 64), BF16, sharding=seq)
+    compiled = _compile(
+        lambda q, k, v: ring_attention(
+            q, k, v, mesh=mesh, causal=True, impl="pallas"
+        ),
+        q, kv, kv,
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the per-step flash kernel
+    assert "collective-permute" in text  # KV rotating around the ring

@@ -52,6 +52,41 @@ def test_warmup_compiles_and_leaves_engine_clean():
 
 
 class TestOpenAIServer:
+    async def test_health_names_the_device(self):
+        """A client — the chip smoke, the router's probe — must see
+        WHERE a replica runs: the devices its KV cache lives on, as
+        jax reports them."""
+        client = await _client()
+        try:
+            h = await (await client.get("/health")).json()
+            d = jax.devices()[0]
+            assert h["device"] == {
+                "platform": d.platform, "kind": d.device_kind, "count": 1,
+            }
+        finally:
+            await client.close()
+
+    async def test_health_device_count_follows_the_mesh(self):
+        """``--tp 2``: the block counts the replica's own devices, not
+        every device jax can see (8 virtual ones here)."""
+        from dstack_tpu.parallel.mesh import MeshConfig, make_mesh
+
+        config = llama.LLAMA_TINY
+        mesh = make_mesh(MeshConfig(dp=1, fsdp=1, tp=2))
+        engine = InferenceEngine(
+            config, llama.init_params(config, jax.random.key(0)),
+            max_batch=2, max_seq=128, mesh=mesh,
+        )
+        client = TestClient(TestServer(
+            build_app(engine, ByteTokenizer(), "llama-tiny")
+        ))
+        await client.start_server()
+        try:
+            h = await (await client.get("/health")).json()
+            assert h["device"]["count"] == 2 < len(jax.devices())
+        finally:
+            await client.close()
+
     async def test_health_and_models(self):
         client = await _client()
         try:

@@ -91,7 +91,7 @@ class TestDTPU012:
             "dstack_tpu/parallel/ring.py": """
                 import jax
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def _make_ring(sp, axis_name):
@@ -104,7 +104,7 @@ class TestDTPU012:
                     spec = P(None, None, axis_name, None)
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(spec,),
-                        out_specs=spec, check_rep=False,
+                        out_specs=spec, check_vma=False,
                     )(q)
             """,
         })
@@ -129,7 +129,7 @@ class TestDTPU012:
         root = _tree(tmp_path, {
             "dstack_tpu/parallel/ulysses.py": """
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def ulysses(q, *, mesh, axis_name: str = "zz"):
@@ -138,7 +138,7 @@ class TestDTPU012:
                     spec = P(None, None, axis_name, None)
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(spec,),
-                        out_specs=spec, check_rep=False,
+                        out_specs=spec, check_vma=False,
                     )(q)
             """,
         })
@@ -169,7 +169,7 @@ class TestDTPU012:
         root = _tree(tmp_path, {
             "dstack_tpu/parallel/pipe.py": """
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def apply(x, *, mesh):
@@ -177,7 +177,7 @@ class TestDTPU012:
                         return lax.psum(x, "pp")
                     return shard_map(
                         body, mesh=mesh, in_specs=(P("ppp"),),
-                        out_specs=P(), check_rep=False,
+                        out_specs=P(), check_vma=False,
                     )(x)
             """,
         })
@@ -208,7 +208,7 @@ class TestDTPU013:
             "dstack_tpu/parallel/ring.py": """
                 import jax
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def _helper(x):
@@ -220,7 +220,7 @@ class TestDTPU013:
                         return lax.psum(x * s, "sp")
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(P("sp"),),
-                        out_specs=P("sp"), check_rep=False,
+                        out_specs=P("sp"), check_vma=False,
                     )(q)
             """,
         })
@@ -231,7 +231,7 @@ class TestDTPU013:
         root = _tree(tmp_path, {
             "dstack_tpu/parallel/ring.py": """
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def ring(q, *, mesh):
@@ -241,7 +241,7 @@ class TestDTPU013:
                         return x
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(P("sp"),),
-                        out_specs=P("sp"), check_rep=False,
+                        out_specs=P("sp"), check_vma=False,
                     )(q)
             """,
         })
@@ -252,7 +252,7 @@ class TestDTPU013:
         root = _tree(tmp_path, {
             "dstack_tpu/parallel/ring.py": """
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def ring(q, *, mesh):
@@ -262,7 +262,7 @@ class TestDTPU013:
                         return lax.psum(x * 2, "sp")
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(P("sp"),),
-                        out_specs=P("sp"), check_rep=False,
+                        out_specs=P("sp"), check_vma=False,
                     )(q)
             """,
         })
@@ -295,7 +295,7 @@ class TestDTPU014:
         root = _tree(tmp_path, {
             "dstack_tpu/parallel/ring.py": """
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def _reduce_if_hot(x):
@@ -308,7 +308,7 @@ class TestDTPU014:
                         return _reduce_if_hot(x)
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(P("sp"),),
-                        out_specs=P("sp"), check_rep=False,
+                        out_specs=P("sp"), check_vma=False,
                     )(q)
             """,
         })
@@ -322,7 +322,7 @@ class TestDTPU014:
         root = _tree(tmp_path, {
             "dstack_tpu/parallel/ring.py": """
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def ring(q, *, mesh):
@@ -330,7 +330,7 @@ class TestDTPU014:
                         return lax.psum(x, "sp")
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(P("sp"),),
-                        out_specs=P("sp"), check_rep=False,
+                        out_specs=P("sp"), check_vma=False,
                     )(q)
             """,
         })
@@ -342,7 +342,7 @@ class TestDTPU014:
         root = _tree(tmp_path, {
             "dstack_tpu/parallel/ring.py": """
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def ring(q, *, mesh):
@@ -350,7 +350,7 @@ class TestDTPU014:
                         return lax.psum(x, "tp")
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(P("sp"),),
-                        out_specs=P("sp"), check_rep=False,
+                        out_specs=P("sp"), check_vma=False,
                     )(q)
             """,
         })
@@ -366,7 +366,7 @@ class TestDTPU014:
         root = _tree(tmp_path, {
             "dstack_tpu/parallel/ring.py": """
                 import jax.lax as lax
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def ring(q, *, mesh, axis_name: str = "sp"):
@@ -375,7 +375,7 @@ class TestDTPU014:
                     spec = P(axis_name)
                     return shard_map(
                         local_fn, mesh=mesh, in_specs=(spec,),
-                        out_specs=spec, check_rep=False,
+                        out_specs=spec, check_vma=False,
                     )(q)
             """,
         })

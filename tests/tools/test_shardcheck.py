@@ -127,7 +127,7 @@ class TestAbstractTrace:
         # (on a fleet this is a trace-time error on every host)
         def build(ctx):
             import jax
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             def body(x):
@@ -136,7 +136,7 @@ class TestAbstractTrace:
             def fn(x):
                 return shard_map(
                     body, mesh=ctx.mesh, in_specs=P("zz"), out_specs=P(),
-                    check_rep=False,
+                    check_vma=False,
                 )(x)
 
             return fn, (ctx.f32(8),), {}
@@ -176,17 +176,6 @@ class TestAbstractTrace:
         entry = Entry("drifted", "engine", real.build, bad_check)
         r = run_entry(entry, "tp2", tp2_ctx)
         assert r.status == "fail" and "drifted" in r.detail
-
-    def test_missing_jax_feature_skips_with_reason(self, tp2_ctx):
-        entry = Entry(
-            "future", "parallel",
-            lambda ctx: (_ for _ in ()).throw(RuntimeError("not reached")),
-            lambda ctx, out: None,
-            requires="definitely_not_a_jax_attr",
-        )
-        r = run_entry(entry, "tp2", tp2_ctx)
-        assert r.status == "skip"
-        assert "unavailable" in r.detail
 
     def test_main_single_entry_grid(self, capsys):
         assert main(["--grid", "tp2", "--entry", "sample"]) == 0

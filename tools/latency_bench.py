@@ -1,7 +1,7 @@
 """Serving latency under load: TTFT / inter-token latency vs concurrency.
 
-Round-3 verdict: 1408 tok/s aggregate decode said nothing about what a
-single request experiences when it arrives mid-macro-step. This harness
+An aggregate decode rate says nothing about what a single request
+experiences when it arrives mid-macro-step. This harness
 drives the FULL serving stack (OpenAI HTTP app → Scheduler → engine)
 with C concurrent streaming clients and reports per-request TTFT and
 inter-token gaps, for turbo K ∈ {1, 8, 32, 128} with the adaptive-K
@@ -14,8 +14,9 @@ Run on the target TPU for real numbers::
     python tools/latency_bench.py --model llama-3.2-1b --batch 16 \
         --concurrency 1 4 16 32 --turbo 1 8 32 128
 
-CPU runs (llama-tiny) are smoke tests of the harness itself.
-Prints one JSON line per (concurrency, turbo) cell.
+``--platform cpu`` runs (llama-tiny) are smoke tests of the harness
+itself; without it the harness needs the chip. Prints one JSON line per
+(concurrency, turbo) cell, each naming the device it ran on.
 """
 
 import argparse
@@ -131,12 +132,13 @@ async def bench_cell(
 async def main_async(args) -> int:
     import jax
 
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
-
     from dstack_tpu.models import llama
     from dstack_tpu.serve.engine import InferenceEngine
     from dstack_tpu.serve.tokenizer import ByteTokenizer
+    from dstack_tpu.utils.backend import enable_compile_cache, select_platform
+
+    device = select_platform(args.platform)
+    enable_compile_cache()
 
     config = llama.CONFIGS[args.model]
     params = llama.init_params(config, jax.random.key(0))
@@ -171,7 +173,7 @@ async def main_async(args) -> int:
                 adaptive=not args.no_adaptive,
             )
             cell["model"] = args.model
-            cell["backend"] = jax.default_backend()
+            cell["device"] = device
             print(json.dumps(cell), flush=True)
     return 0
 

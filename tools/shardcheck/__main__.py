@@ -13,7 +13,6 @@ import sys
 import traceback
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Optional
 
 _REPO = Path(__file__).resolve().parent.parent.parent
 if str(_REPO) not in sys.path:
@@ -33,27 +32,14 @@ from tools.shardcheck.manifest import (  # noqa: E402
 class Result:
     entry: str
     grid: str
-    status: str  # "pass" | "fail" | "skip"
+    status: str  # "pass" | "fail"
     detail: str = ""
-
-
-def _missing_requirement(entry: Entry) -> Optional[str]:
-    if entry.requires is None:
-        return None
-    import jax
-
-    if getattr(jax, entry.requires, None) is None:
-        return f"jax.{entry.requires} unavailable in jax {jax.__version__}"
-    return None
 
 
 def run_entry(entry: Entry, grid: str, ctx=None) -> Result:
     """Abstract-trace one entry over one grid (device-free)."""
     import jax
 
-    missing = _missing_requirement(entry)
-    if missing:
-        return Result(entry.name, grid, "skip", missing)
     ctx = make_ctx(grid) if ctx is None else ctx
     try:
         fn, args, kwargs = entry.build(ctx)
@@ -128,13 +114,12 @@ def main(argv=None) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     results = run_all(args.grid, args.entry, verbose=args.verbose)
     failed = [r for r in results if r.status == "fail"]
-    skipped = [r for r in results if r.status == "skip"]
     passed = [r for r in results if r.status == "pass"]
     for r in failed:
         print(f"\nFAIL {r.grid}/{r.entry}:\n{r.detail}", file=sys.stderr)
     print(
-        f"shardcheck: {len(passed)} passed, {len(failed)} failed, "
-        f"{len(skipped)} skipped across {len(args.grid or GRIDS)} grid(s)"
+        f"shardcheck: {len(passed)} passed, {len(failed)} failed "
+        f"across {len(args.grid or GRIDS)} grid(s)"
         + (f"; {len(problems)} coverage/validation problem(s)" if problems else "")
     )
     return 1 if (failed or problems) else 0

@@ -18,7 +18,7 @@ from __future__ import annotations
 import ast
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Callable, Optional
+from typing import Callable
 
 REPO = Path(__file__).resolve().parent.parent.parent
 ENGINE_PATH = REPO / "dstack_tpu" / "serve" / "engine.py"
@@ -57,21 +57,18 @@ class Entry:
     kind: str  # "engine" | "parallel"
     build: Callable
     check: Callable
-    #: getattr path on the jax module that must exist for this entry
-    #: to trace under the installed jax (None = always runnable)
-    requires: Optional[str] = None
     notes: str = ""
 
 
 MANIFEST: dict[str, Entry] = {}
 
 
-def register(name: str, kind: str, *, requires: str = None, notes: str = ""):
+def register(name: str, kind: str, *, notes: str = ""):
     def deco(build_and_check):
         build, check = build_and_check()
         if name in MANIFEST:
             raise ValueError(f"duplicate shardcheck entry {name!r}")
-        MANIFEST[name] = Entry(name, kind, build, check, requires, notes)
+        MANIFEST[name] = Entry(name, kind, build, check, notes)
         return build_and_check
 
     return deco
@@ -128,7 +125,8 @@ def make_ctx(grid: str) -> Ctx:
         intermediate_size=2 * HEADS * HEAD_DIM,
         max_seq_len=2 * T,
     )
-    mesh = AbstractMesh(GRIDS[grid])
+    names, sizes = zip(*GRIDS[grid])
+    mesh = AbstractMesh(sizes, names)
     params = jax.eval_shape(partial(llama.init_params, config), jax.random.key(0))
     cache = jax.eval_shape(lambda: eng.init_cache(config, B, T, mesh=mesh))
     return Ctx(grid, mesh, config, params, cache, _sds=jax.ShapeDtypeStruct)
@@ -501,9 +499,9 @@ def _ulysses():
 
 
 @register(
-    "pipeline_apply", "parallel", requires="shard_map",
-    notes="GPipe loop over tp as the stage axis; needs jax.shard_map "
-    "(partial-manual axis_names), absent from older jax — skipped there",
+    "pipeline_apply", "parallel",
+    notes="GPipe loop over tp as the stage axis (jax.shard_map with "
+    "partial-manual axis_names)",
 )
 def _pipeline():
     def build(ctx):
