@@ -20,7 +20,6 @@ from dstack_tpu.obs.metrics import (
     escape_label,
     LATENCY_BUCKETS_S,
     SHORT_LATENCY_BUCKETS_S,
-    THROUGHPUT_BUCKETS,
 )
 
 __all__ = [
@@ -31,5 +30,4 @@ __all__ = [
     "escape_label",
     "LATENCY_BUCKETS_S",
     "SHORT_LATENCY_BUCKETS_S",
-    "THROUGHPUT_BUCKETS",
 ]

@@ -52,7 +52,7 @@ def _fmt(v: float) -> str:
 
 # Log-spaced buckets (seconds). The wide set covers HTTP requests,
 # TTFT, and train steps (1ms .. 60s); the short set covers per-token
-# decode latencies (0.1ms .. 2.5s); the throughput set covers tokens/s.
+# decode latencies (0.1ms .. 2.5s).
 LATENCY_BUCKETS_S = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 25.0, 60.0,
@@ -60,10 +60,6 @@ LATENCY_BUCKETS_S = (
 SHORT_LATENCY_BUCKETS_S = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
-)
-THROUGHPUT_BUCKETS = (
-    1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
-    1000.0, 2500.0, 5000.0, 10000.0, 25000.0, 100000.0,
 )
 
 _TRUNCATED = "<truncated>"

@@ -27,6 +27,7 @@ import numpy as np
 from dstack_tpu import faults
 from dstack_tpu.obs import boot as obs_boot
 from dstack_tpu.obs import flight
+from dstack_tpu.obs import profiling
 from dstack_tpu.models import llama
 from dstack_tpu.models.llama import (
     LlamaConfig,
@@ -1908,44 +1909,39 @@ class InferenceEngine:
         # live request paid the trace. Host-side set bookkeeping only
         # (DTPU002: no device sync on the compile path).
         self._compile_manifest: set = set()
-        _watch = partial(
-            flight.watch_jit, registry=self.metrics,
-            warm=lambda: self._flight_warm,
-            on_compile=self._note_boot_compile,
-        )
-        self._watch_jit = _watch
-        self._decode = _watch(jax.jit(
+        _watch = self._watch_jit
+        self._decode = _watch(
             partial(
                 decode_step, config=config,
                 decode_kernel=self.decode_kernel, mesh=mesh,
             ),
-            donate_argnums=(1,),
-        ), "decode")
-        self._verify = _watch(jax.jit(
+            "decode", donate_argnums=(1,),
+        )
+        self._verify = _watch(
             partial(
                 verify_step, config=config,
                 decode_kernel=self.decode_kernel, mesh=mesh,
             ),
-            donate_argnums=(1,),
-        ), "verify")
-        self._sample = _watch(jax.jit(sample), "sample")
+            "verify", donate_argnums=(1,),
+        )
+        self._sample = _watch(sample, "sample")
         self._turbo_fns: dict = {}  # steps → jitted decode_loop
-        self._argmax = _watch(jax.jit(partial(jnp.argmax, axis=-1)), "argmax")
+        self._argmax = _watch(partial(jnp.argmax, axis=-1), "argmax")
         # per-step device mirror of the slot-state transition (shared
         # with decode_loop's scan body): _plain_step advances the cached
         # decode state on device instead of re-uploading five host
         # lists per sampled token
-        self._advance_state = _watch(jax.jit(
-            partial(advance_decode_state, max_seq=max_seq)
-        ), "advance_state")
-        self._logprobs = _watch(jax.jit(token_logprobs), "logprobs")
+        self._advance_state = _watch(
+            partial(advance_decode_state, max_seq=max_seq), "advance_state"
+        )
+        self._logprobs = _watch(token_logprobs, "logprobs")
         self._mark_seen = _watch(
-            jax.jit(_mark_seen, donate_argnums=(0, 1)), "mark_seen"
+            _mark_seen, "mark_seen", donate_argnums=(0, 1)
         )
         self._mark_prompt = _watch(
-            jax.jit(_mark_prompt, donate_argnums=(0, 1)), "mark_prompt"
+            _mark_prompt, "mark_prompt", donate_argnums=(0, 1)
         )
-        self._skip_key = _watch(jax.jit(skip_key_data), "skip_key")
+        self._skip_key = _watch(skip_key_data, "skip_key")
         # watchdog plumbing: the serve scheduler runs step() on a worker
         # thread and may give up on a wedged dispatch (abandon_step).
         # The abandoned thread checks the epoch after every pre-dispatch
@@ -1971,27 +1967,44 @@ class InferenceEngine:
             if not self.active[i] and i not in self._prefilling
         ]
 
+    def _watch_jit(self, fn, label: str, key=None, **jit_kw):
+        """THE engine jit site. Compiles ``fn`` under the name of the
+        function it wraps — a ``functools.partial`` has no name of its
+        own and XLA would call the program ``jit__unknown`` — so a
+        profiler capture names every program on the device's line
+        (``jit_decode_step``, ``jit_prefill_chunk_step``, ...), then
+        hands it to ``flight.watch_jit`` under ``label``, the ``fn``
+        label of ``dtpu_serve_compiles_total`` (PERF.md §3 has the
+        label ↔ program table)."""
+        if isinstance(fn, partial):
+            fn.__name__ = fn.func.__name__
+        return flight.watch_jit(
+            jax.jit(fn, **jit_kw), label, registry=self.metrics, key=key,
+            warm=lambda: self._flight_warm,
+            on_compile=self._note_boot_compile,
+        )
+
     def _chunk_fn(self, cl: int, start: int):
         key = (cl, start)
         if key not in self._chunk_fns:
             # dtpu: noqa[DTPU003] cl is power-of-2-bucketed and start chunk-aligned by prefill_step; grid ≤ log2(C) × (T/C)
-            self._chunk_fns[key] = self._watch_jit(jax.jit(
+            self._chunk_fns[key] = self._watch_jit(
                 partial(
                     prefill_chunk_step, config=self.config, start=start,
                     mesh=self._mesh,
                 ),
-                donate_argnames=("cache",),
-            ), "chunk", key=key)
+                "chunk", key=key, donate_argnames=("cache",),
+            )
         return self._chunk_fns[key]
 
     def _packed_fn(self, g: int, cl: int):
         key = (g, cl)
         if key not in self._packed_fns:
             # dtpu: noqa[DTPU003] prefill_wave buckets g and cl to powers of two; grid ≤ log2(G) × log2(C), pinned by the compile-cache accounting test
-            self._packed_fns[key] = self._watch_jit(jax.jit(
+            self._packed_fns[key] = self._watch_jit(
                 partial(prefill_packed_step, config=self.config),
-                donate_argnames=("cache",),
-            ), "packed", key=key)
+                "packed", key=key, donate_argnames=("cache",),
+            )
         return self._packed_fns[key]
 
     def _find_prefix_source(self, prompt: list) -> tuple[int, Optional[int]]:
@@ -2037,9 +2050,10 @@ class InferenceEngine:
         its variants can't drift from what start_request builds)."""
         if p not in self._copy_fns:
             # dtpu: noqa[DTPU003] p is chunk-aligned by _find_prefix_source (reuse // C * C), ≤ max_seq/prefill_chunk variants, warmup precompiles them
-            self._copy_fns[p] = self._watch_jit(jax.jit(
-                partial(copy_cache_prefix, p=p), donate_argnums=(0,)
-            ), "copy", key=p)
+            self._copy_fns[p] = self._watch_jit(
+                partial(copy_cache_prefix, p=p), "copy", key=p,
+                donate_argnums=(0,),
+            )
         return self._copy_fns[p]
 
     def _start_request_inner(self, prompt, gen, free, reuse_len, src) -> int:
@@ -2099,13 +2113,14 @@ class InferenceEngine:
         # logits index only matters on the final chunk
         last_ix = (tp - 1 - start) if final else (cl - 1)
         t0 = time.perf_counter()
-        logits, self.cache = self._chunk_fn(cl, start)(
-            self.params,
-            self.cache,
-            jnp.asarray([chunk], jnp.int32),
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(last_ix, jnp.int32),
-        )
+        with profiling.span("dtpu.engine.prefill", rows=1, cl=cl):
+            logits, self.cache = self._chunk_fn(cl, start)(
+                self.params,
+                self.cache,
+                jnp.asarray([chunk], jnp.int32),
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(last_ix, jnp.int32),
+            )
         self.metrics.family("dtpu_serve_prefill_dispatches_total").inc(1)
         self.metrics.family("dtpu_serve_prefill_pack_rows").observe(1)
         if flight.enabled():
@@ -2206,14 +2221,15 @@ class InferenceEngine:
             starts.append(0)
             last_ix.append(-1)
         t0 = time.perf_counter()
-        logits, self.cache = self._packed_fn(g, cl)(
-            self.params,
-            self.cache,
-            jnp.asarray(tok_rows, jnp.int32),
-            jnp.asarray(slot_ix, jnp.int32),
-            jnp.asarray(starts, jnp.int32),
-            jnp.asarray(last_ix, jnp.int32),
-        )
+        with profiling.span("dtpu.engine.prefill", rows=len(rows), cl=cl):
+            logits, self.cache = self._packed_fn(g, cl)(
+                self.params,
+                self.cache,
+                jnp.asarray(tok_rows, jnp.int32),
+                jnp.asarray(slot_ix, jnp.int32),
+                jnp.asarray(starts, jnp.int32),
+                jnp.asarray(last_ix, jnp.int32),
+            )
         self.metrics.family("dtpu_serve_prefill_dispatches_total").inc(1)
         self.metrics.family("dtpu_serve_prefill_pack_rows").observe(len(rows))
         if flight.enabled():
@@ -2416,9 +2432,9 @@ class InferenceEngine:
         with an n-gram draft take the speculative path and may emit
         several tokens per call; otherwise each list has one token.
 
-        Wraps the dispatch in the step-latency/TPOT/throughput
-        histograms — recorded here, at the engine, so the HTTP server
-        and the offline bench export identical numbers."""
+        Wraps the dispatch in the step-latency/TPOT histograms —
+        recorded here, at the engine, so the HTTP server and the
+        offline bench export identical numbers."""
         epoch = self._step_epoch
         t_all0 = time.perf_counter()
         # chaos hook (no-op calls when no plan is installed), fired once
@@ -2467,9 +2483,6 @@ class InferenceEngine:
                 m.family("dtpu_serve_tpot_seconds").observe(
                     dt / n_tokens, exemplar=ex,
                 )
-                m.family("dtpu_serve_decode_tokens_per_sec").observe(
-                    n_tokens / dt
-                )
             if flight.enabled():
                 # one flight record per emitting step — strictly
                 # host-side fields (slot lists, perf counters, the
@@ -2499,24 +2512,37 @@ class InferenceEngine:
         live = [i for i in range(self.max_batch) if self.active[i]]
         if not live:
             return {}
-        spec_ok = self.spec_draft > 0 and self._all_greedy(live)
-        if spec_ok:
+        phase, drafts = "decode", None
+        if self.spec_draft > 0 and self._all_greedy(live):
             drafts = {i: self._find_draft(i) for i in live}
             drafting = sum(1 for d in drafts.values() if d)
             # non-drafting slots pay ~(S×) decode compute for nothing —
             # speculate only when at least half the batch drafts
             if drafting and drafting * 2 >= len(live):
-                self._last_step_phase = "spec"
-                return self._spec_step(live, drafts)
+                phase = "spec"
         if (
-            self.turbo_steps > 1
+            phase == "decode"
+            and self.turbo_steps > 1
             and not self._prefilling  # don't starve queued prompt chunks
             and self._all_greedy(live)
         ):
-            self._last_step_phase = "turbo"
-            return self._turbo_step(live)
-        self._last_step_phase = "decode"
-        return {i: [tok] for i, tok in self._plain_step(live).items()}
+            phase = "turbo"
+        self._last_step_phase = phase
+        # on a capture's clock (obs/profiling.py): `seq` is the flight
+        # record this step writes next (/debug/flight carries the slots'
+        # trace ids, so a step in a capture leads to /debug/traces with
+        # no third identifier; a compile record may take the number
+        # first, then the step's is the one after)
+        rec = flight.get_recorder()
+        with profiling.span(
+            "dtpu.engine.step",
+            seq=rec.seq + 1 if rec is not None else 0, phase=phase,
+        ):
+            if phase == "spec":
+                return self._spec_step(live, drafts)
+            if phase == "turbo":
+                return self._turbo_step(live)
+            return {i: [tok] for i, tok in self._plain_step(live).items()}
 
     def _spec_step(self, live: list, drafts: dict) -> dict:
         """One verify_step call emits 1..spec_draft+1 tokens per slot."""
@@ -2594,14 +2620,14 @@ class InferenceEngine:
     def _turbo_fn(self, steps: int):
         if steps not in self._turbo_fns:
             # dtpu: noqa[DTPU003] _turbo_step buckets steps to powers of two capped at turbo_steps; ≤ log2(turbo_steps) variants
-            self._turbo_fns[steps] = self._watch_jit(jax.jit(
+            self._turbo_fns[steps] = self._watch_jit(
                 partial(
                     decode_loop, config=self.config, steps=steps,
                     max_seq=self.max_seq,
                     decode_kernel=self.decode_kernel, mesh=self._mesh,
                 ),
-                donate_argnums=(1,),
-            ), "turbo", key=steps)
+                "turbo", key=steps, donate_argnums=(1,),
+            )
         return self._turbo_fns[steps]
 
     def _invalidate_decode_cache(self) -> None:

@@ -3,7 +3,7 @@
 One construction point for every ``dtpu_serve_*`` series, used by:
 
 - :class:`dstack_tpu.serve.engine.InferenceEngine` — records TTFT,
-  per-step decode latency, TPOT, decode throughput, token counters,
+  per-step decode latency, TPOT, token counters,
   and prefix-cache counters at the source (the engine), so the HTTP
   server and the offline bench (``serve/bench.py``) read ONE set of
   numbers instead of keeping parallel stopwatches.
@@ -21,7 +21,6 @@ from dstack_tpu.obs import (
     LATENCY_BUCKETS_S,
     Registry,
     SHORT_LATENCY_BUCKETS_S,
-    THROUGHPUT_BUCKETS,
 )
 
 
@@ -62,10 +61,35 @@ def new_serve_registry() -> Registry:
         "Time per output token: step wall time / tokens emitted",
         buckets=SHORT_LATENCY_BUCKETS_S,
     )
+    # host phases of the serve loop, each timed by its caller around
+    # the interval that obs/profiling.span puts on a capture's clock
+    # (unlabelled: scrapers that sum label sets read them as they are)
     r.histogram(
-        "dtpu_serve_decode_tokens_per_sec",
-        "Per-step decode throughput across all active slots",
-        buckets=THROUGHPUT_BUCKETS,
+        "dtpu_serve_host_gap_seconds",
+        "Engine-idle gap: return of one engine call (step or prefill "
+        "wave) to the start of the next while requests hold slots — "
+        "the device has no work queued (parked-idle waits excluded)",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_tick_host_seconds",
+        "Scheduler tick's own host code: deadline sweep + admission "
+        "walk + token hand-over (engine calls excluded), one "
+        "observation per tick",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_detokenize_seconds",
+        "Streaming handler re-decode of a request's ids into emittable "
+        "text, one observation per token consumed (plus the final "
+        "flush)",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_stream_write_seconds",
+        "Streaming handler chunk build + json.dumps + socket write, "
+        "one observation per SSE chunk",
+        buckets=SHORT_LATENCY_BUCKETS_S,
     )
     # prefill dispatch accounting: the packed multi-slot prefill packs
     # up to prefill_pack concurrent prompt chunks into one forward —

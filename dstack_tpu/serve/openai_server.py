@@ -42,6 +42,7 @@ Request-lifecycle hardening (serving.md §9):
 
 import argparse
 import asyncio
+import contextlib
 import json
 import os
 import re
@@ -174,6 +175,11 @@ class Scheduler:
         self.by_slot: dict[int, _Request] = {}
         self.by_prefill: dict[int, _Request] = {}  # chunked prefills in flight
         self._task: Optional[asyncio.Task] = None
+        # host-phase accounting (PERF.md §3): when the last engine call
+        # returned (None while parked idle: a park is not a gap), and
+        # this tick's own host seconds so far
+        self._engine_returned: Optional[float] = None
+        self._tick_host_s = 0.0
         # serving metrics live in the ENGINE's obs registry (one source
         # of truth shared with serve/bench.py); /metrics renders the
         # registry for the shim relay → server prometheus plane.
@@ -311,6 +317,40 @@ class Scheduler:
         req.error_status = 504
         req.queue.put_nowait(None)
 
+    # ---- host-phase accounting ----
+
+    def _engine_call(self, fn):
+        """``fn`` (``engine.step`` / ``engine.prefill_wave``) on a worker
+        thread. The time since the previous engine call returned — the
+        device had no work queued while requests held slots — goes to
+        ``dtpu_serve_host_gap_seconds``; both clock reads are taken on
+        the worker thread, so the gap includes the thread hops."""
+
+        def run():
+            t0 = time.perf_counter()
+            if self._engine_returned is not None:
+                self.engine.metrics.family(
+                    "dtpu_serve_host_gap_seconds"
+                ).observe(t0 - self._engine_returned)
+            try:
+                return fn()
+            finally:
+                self._engine_returned = time.perf_counter()
+
+        return asyncio.to_thread(run)
+
+    @contextlib.contextmanager
+    def _host_code(self):
+        """The tick's OWN code (sweep, admission, token hand-over — not
+        the engine calls): span ``dtpu.tick.host`` in a capture, summed
+        into one ``dtpu_serve_tick_host_seconds`` observation a tick."""
+        t0 = time.perf_counter()
+        try:
+            with obs_profiling.span("dtpu.tick.host"):
+                yield
+        finally:
+            self._tick_host_s += time.perf_counter() - t0
+
     # ---- engine watchdog ----
 
     async def _guarded_step(self) -> Optional[dict]:
@@ -323,8 +363,8 @@ class Scheduler:
         stream. Returns None when the watchdog tripped (this tick
         produced no tokens); engine errors propagate as before."""
         if self.watchdog_seconds <= 0:
-            return await asyncio.to_thread(self.engine.step)
-        task = asyncio.ensure_future(asyncio.to_thread(self.engine.step))
+            return await self._engine_call(self.engine.step)
+        task = asyncio.ensure_future(self._engine_call(self.engine.step))
         done, _ = await asyncio.wait({task}, timeout=self.watchdog_seconds)
         if done:
             return task.result()
@@ -439,6 +479,11 @@ class Scheduler:
                     req.error = str(e)
                     req.queue.put_nowait(None)
                 self.by_slot.clear()
+            if self._tick_host_s:
+                self.engine.metrics.family(
+                    "dtpu_serve_tick_host_seconds"
+                ).observe(self._tick_host_s)
+                self._tick_host_s = 0.0
 
     def _handle_first_token(self, slot: int, req: _Request, first: int) -> bool:
         """Deliver a finished prefill's first token; True when the slot
@@ -510,6 +555,68 @@ class Scheduler:
             # the stale step rebuilt device mirrors from released slot
             # state — drop them before the next dispatch
             self.engine.finish_abandoned_step()
+        with self._host_code():
+            self._admit_pending()
+        # ONE prefill dispatch per tick — a packed wave advancing up to
+        # prefill_pack pending prompts a chunk each (engine.prefill_wave)
+        # — so decode steps for running slots interleave between chunk
+        # waves instead of stalling behind N serial per-prompt prefills
+        if self.by_prefill:
+            try:
+                firsts = await self._engine_call(self.engine.prefill_wave)
+            except Exception as e:  # noqa: BLE001 - reported per request
+                logger.exception("prefill failed: %s", e)
+                flight.post_mortem(
+                    "prefill_error",
+                    registry=self.engine.metrics,
+                    error=str(e)[:200],
+                    slots=list(self.engine.last_wave_slots),
+                    **self.engine.fault_ctx,
+                )
+                # fail exactly the rows that were in the failing
+                # dispatch (the engine publishes them before running);
+                # prompts beyond prefill_pack never ran and keep their
+                # place in the queue
+                for slot in self.engine.last_wave_slots:
+                    req = self.by_prefill.pop(slot, None)
+                    if req is None:
+                        continue
+                    self.engine.release(slot)
+                    self._count_error(req)
+                    self._refund_unstarted(req)
+                    req.phase.end("error")
+                    req.error = str(e)
+                    req.queue.put_nowait(None)
+                return
+            with self._host_code():
+                for slot, first in firsts.items():
+                    # prompt complete; first token sampled
+                    req = self.by_prefill.pop(slot, None)
+                    if req is None or req.cancelled:
+                        # cancel() landed while the wave ran on the
+                        # worker thread
+                        self.engine.release(slot)
+                    elif self._handle_first_token(slot, req, first):
+                        self.by_slot[slot] = req
+        if not self.by_slot:
+            if self.by_prefill:
+                return  # keep chunking without blocking
+            # idle: wait for work instead of spinning. With nothing in
+            # flight the tenant caps cannot defer anyone, so an empty
+            # by_slot/by_prefill here implies an empty queue — wait()
+            # parks until the next push (and a park is not a host gap).
+            self._engine_returned = None
+            await self.pending.wait()
+            return
+        out = await self._guarded_step()
+        if out is None:
+            return  # watchdog tripped: bookkeeping already done
+        with self._host_code():
+            self._hand_over(out)
+        await asyncio.sleep(0)
+
+    def _admit_pending(self) -> None:
+        """The tick's admission half, host bookkeeping only."""
         # deadline sweep FIRST: an expired slot frees its KV before the
         # admission pass below, so the reclaimed slot serves live work
         # in the same tick
@@ -582,64 +689,13 @@ class Scheduler:
             )
             self.by_prefill[slot] = req
 
-        # ONE prefill dispatch per tick — a packed wave advancing up to
-        # prefill_pack pending prompts a chunk each (engine.prefill_wave)
-        # — so decode steps for running slots interleave between chunk
-        # waves instead of stalling behind N serial per-prompt prefills
-        if self.by_prefill:
-            for slot in [
-                s for s, r in self.by_prefill.items() if r.cancelled
-            ]:
-                self.engine.release(slot)
-                del self.by_prefill[slot]
-        if self.by_prefill:
-            try:
-                firsts = await asyncio.to_thread(self.engine.prefill_wave)
-            except Exception as e:  # noqa: BLE001 - reported per request
-                logger.exception("prefill failed: %s", e)
-                flight.post_mortem(
-                    "prefill_error",
-                    registry=self.engine.metrics,
-                    error=str(e)[:200],
-                    slots=list(self.engine.last_wave_slots),
-                    **self.engine.fault_ctx,
-                )
-                # fail exactly the rows that were in the failing
-                # dispatch (the engine publishes them before running);
-                # prompts beyond prefill_pack never ran and keep their
-                # place in the queue
-                for slot in self.engine.last_wave_slots:
-                    req = self.by_prefill.pop(slot, None)
-                    if req is None:
-                        continue
-                    self.engine.release(slot)
-                    self._count_error(req)
-                    self._refund_unstarted(req)
-                    req.phase.end("error")
-                    req.error = str(e)
-                    req.queue.put_nowait(None)
-                return
-            for slot, first in firsts.items():
-                # prompt complete; first token sampled
-                req = self.by_prefill.pop(slot, None)
-                if req is None or req.cancelled:
-                    # cancel() landed while the wave ran on the worker
-                    # thread
-                    self.engine.release(slot)
-                elif self._handle_first_token(slot, req, first):
-                    self.by_slot[slot] = req
-        if not self.by_slot:
-            if self.by_prefill:
-                return  # keep chunking without blocking
-            # idle: wait for work instead of spinning. With nothing in
-            # flight the tenant caps cannot defer anyone, so an empty
-            # by_slot/by_prefill here implies an empty queue — wait()
-            # parks until the next push.
-            await self.pending.wait()
-            return
-        out = await self._guarded_step()
-        if out is None:
-            return  # watchdog tripped: bookkeeping already done
+        # cancelled mid-prefill: free the slot before the next wave
+        for slot in [s for s, r in self.by_prefill.items() if r.cancelled]:
+            self.engine.release(slot)
+            del self.by_prefill[slot]
+
+    def _hand_over(self, out: dict) -> None:
+        """One engine step's tokens → their requests' queues."""
         for slot, toks in out.items():
             req = self.by_slot.get(slot)
             if req is None:
@@ -677,7 +733,6 @@ class Scheduler:
                 )
                 req.queue.put_nowait(None)
                 del self.by_slot[slot]
-        await asyncio.sleep(0)
 
 
 def _truncate_stop(text: str, stop) -> str:
@@ -1513,51 +1568,85 @@ def build_app(
             lp_top = req.gen.logprobs or 0
             lp_emitted = 0
 
-            def emittable() -> str:
-                full = tokenizer.decode(ids)
-                while full.endswith("�"):
-                    full = full[:-1]
-                full = _truncate_stop(full, req.gen.stop)
-                return full[: len(full) - _stop_holdback(full, req.gen.stop)]
+            m_detok = engine.metrics.family("dtpu_serve_detokenize_seconds")
+            m_write = engine.metrics.family("dtpu_serve_stream_write_seconds")
+            # the per-token timings are only noted on the token path:
+            # the handlers run in the gap between two engine calls,
+            # with the device idle. The histograms take them when the
+            # handler parks for its next token, in the loop iteration
+            # AFTER the scheduler's own — while the next engine call
+            # is already on the device.
+            detok_s: list[float] = []
+            write_s: list[float] = []
+            loop = asyncio.get_running_loop()
+
+            def observe_noted() -> None:
+                for v in detok_s:
+                    m_detok.observe(v)
+                for v in write_s:
+                    m_write.observe(v)
+                detok_s.clear()
+                write_s.clear()
+
+            def detokenize(hold: bool) -> str:
+                """All ids so far → deliverable text (``hold``: minus a
+                tail that may still grow into a stop string)."""
+                t0 = time.perf_counter()
+                with obs_profiling.span("dtpu.stream.detokenize"):
+                    full = tokenizer.decode(ids)
+                    while full.endswith("�"):
+                        full = full[:-1]
+                    full = _truncate_stop(full, req.gen.stop)
+                    if hold:
+                        full = full[
+                            : len(full) - _stop_holdback(full, req.gen.stop)
+                        ]
+                detok_s.append(time.perf_counter() - t0)
+                return full
 
             async def emit(delta: str, tool_calls=None) -> None:
                 nonlocal lp_emitted
-                d = {"role": "assistant", "content": delta}
-                if tool_calls is not None:
-                    d["tool_calls"] = tool_calls
-                choice = {
-                    "index": 0,
-                    "delta": d,
-                    "finish_reason": None,
-                }
-                if req.gen.logprobs is not None:
-                    # entries for the tokens consumed since the last
-                    # chunk (delta boundaries are char-diffs, so the
-                    # token alignment is approximate at holdback edges)
-                    hi = len(req.logprob_entries)
-                    choice["logprobs"] = {
-                        "content": _chat_logprob_entries(
-                            req, tokenizer, lp_top, lp_emitted, hi
-                        )
+                t0 = time.perf_counter()
+                with obs_profiling.span("dtpu.stream.write"):
+                    d = {"role": "assistant", "content": delta}
+                    if tool_calls is not None:
+                        d["tool_calls"] = tool_calls
+                    choice = {
+                        "index": 0,
+                        "delta": d,
+                        "finish_reason": None,
                     }
-                    lp_emitted = hi
-                chunk = {
-                    "id": completion_id,
-                    "object": "chat.completion.chunk",
-                    "created": created,
-                    "model": model_name,
-                    "choices": [choice],
-                }
-                await resp.write(b"data: " + json.dumps(chunk).encode() + b"\n\n")
+                    if req.gen.logprobs is not None:
+                        # entries for the tokens consumed since the last
+                        # chunk (delta boundaries are char-diffs, so the
+                        # token alignment is approximate at holdback edges)
+                        hi = len(req.logprob_entries)
+                        choice["logprobs"] = {
+                            "content": _chat_logprob_entries(
+                                req, tokenizer, lp_top, lp_emitted, hi
+                            )
+                        }
+                        lp_emitted = hi
+                    chunk = {
+                        "id": completion_id,
+                        "object": "chat.completion.chunk",
+                        "created": created,
+                        "model": model_name,
+                        "choices": [choice],
+                    }
+                    await resp.write(b"data: " + json.dumps(chunk).encode() + b"\n\n")
+                write_s.append(time.perf_counter() - t0)
 
             stream_finish = None
             try:
                 while True:
+                    if detok_s and req.queue.empty():
+                        loop.call_soon(observe_noted)
                     tok = await req.queue.get()
                     if tok is None:
                         break
                     ids.append(tok)
-                    out = emittable()
+                    out = detokenize(hold=True)
                     if tools:
                         # stream prose up to the first point that could
                         # still become a tool call; only the candidate
@@ -1570,20 +1659,14 @@ def build_app(
                     await emit(delta)
                 # generation over: flush held-back text that never
                 # completed into a stop string (minus any true stop cut)
-                def final_text() -> str:
-                    full = tokenizer.decode(ids)
-                    while full.endswith("�"):
-                        full = full[:-1]
-                    return _truncate_stop(full, req.gen.stop)
-
                 if ids and not tools:
-                    tail = final_text()[len(sent):]
+                    tail = detokenize(hold=False)[len(sent):]
                     if tail:
                         await emit(tail)
                 elif ids and tools:
                     # parse only the HELD-BACK tail: any prose before it
                     # already streamed incrementally
-                    rest = final_text()[len(sent):]
+                    rest = detokenize(hold=False)[len(sent):]
                     content, tool_calls = (
                         _parse_tool_calls(rest) if rest else (None, None)
                     )
@@ -1597,6 +1680,7 @@ def build_app(
                         await emit(rest)
             finally:
                 sched.cancel(req)  # no-op when finished; frees the slot on disconnect
+                observe_noted()
             if req.error:
                 await resp.write(
                     b"data: " + json.dumps({"error": req.error}).encode() + b"\n\n"

@@ -584,7 +584,6 @@ class TestServeMetrics:
             assert t["dtpu_serve_tpot_seconds"] == "histogram"
             assert s["dtpu_serve_tpot_seconds_count"] >= 1
             assert s["dtpu_serve_decode_step_seconds_count"] >= 1
-            assert s["dtpu_serve_decode_tokens_per_sec_count"] >= 1
             # cumulative-bucket invariant: counts never decrease with le
             prefix = 'dtpu_serve_ttft_seconds_bucket{le="'
             buckets = sorted(
