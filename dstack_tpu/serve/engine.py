@@ -188,6 +188,117 @@ def _cfull(ckv, dtype):
     return ckv
 
 
+def _cstored(new, like):
+    """``new`` rows as the cache stores them: the array itself, or its
+    (int8, scale) pair where ``like`` is a quantized leaf."""
+    if isinstance(like, tuple):
+        q, s = kv_quantize(new)
+        return q, s.astype(like[1].dtype)
+    return new
+
+
+def _cwrite_rows(
+    ckv, layer, positions, write_mask, new, axis: int = 1, unroll: bool = False
+):
+    """Write ``S`` new tokens a slot into layers ``layer .. layer + N``
+    of a STACKED cache leaf, in place: ``ckv`` [L, B, *slot] with the
+    token axis at ``axis`` of the slot's shape ([Hkv, T, D] values: 1;
+    [T, R] latents: 0), ``new`` [N, B, *slot] with S in the place of T
+    (or [B, *slot]: one layer's; as stored, see :func:`_cstored`), for
+    the tokens at ``positions[b] + s``.
+
+    A one-token ``.at[].set`` touches one row of a tile on the token
+    axis, and the TPU compiler will not do that in place: it re-lays
+    the layer's slice (or the whole cache) out so that a token is a
+    whole tile, writes, and copies it back: two thirds of the decode
+    program's device time (PERF.md §6, PR 25). So each slot reads the
+    tile-aligned block around its position, selects the new rows in
+    and writes the WHOLE block back: a ``dynamic_update_slice`` of
+    whole tiles at a visibly aligned offset updates the donated buffer
+    where it lies, and nothing the size of a layer's cache moves
+    (``test_decode_program_holds_no_second_cache`` compiles it for the
+    chip). Rows with ``write_mask`` false, and positions past the end,
+    put the block back as read: their bytes stay.
+
+    The slots go through a ``fori_loop`` (one small body: unrolled, the
+    16 slots doubled the programs' size and cost every boot seconds of
+    lowering and loading); ``unroll`` is for the latent, which the
+    compiler re-lays out ``{3,2,1,0}`` under a nested loop and copies
+    whole at every call (device-free: ``temp`` 0.33 → 2.0 GB)."""
+    if isinstance(ckv, tuple):
+        return tuple(
+            _cwrite_rows(c, layer, positions, write_mask, n, axis, unroll)
+            for c, n in zip(ckv, new)
+        )
+    if new.ndim < ckv.ndim:  # one layer's rows
+        new = new[None]
+    t_ax = 2 + axis
+    tmax, s = ckv.shape[t_ax], new.shape[t_ax]
+    # tokens a tile holds: 128 lanes where the token axis is minor (the
+    # scales), else 8 sublanes × the dtype's packing (bf16 16, int8 32)
+    tile = 128 if t_ax == ckv.ndim - 1 else 8 * (4 // ckv.dtype.itemsize)
+    w = min(tmax, tile * (1 + -(-(s - 1) // tile)))  # S rows span ≤ this
+    base = (positions // tile) * tile  # [B]; a multiple of the tile, visibly
+    start = jnp.clip(base, 0, tmax - w)  # what dynamic_slice clamps it to
+    # unsigned: lax.dynamic_slice wraps every signed index (lt, add,
+    # select), thrice the operations of the write itself
+    base, layer = base.astype(jnp.uint32), jnp.asarray(layer, jnp.uint32)
+    zero = jnp.zeros((), jnp.uint32)
+    off = start[:, None] + jnp.arange(w)[None, :] - positions[:, None]
+    wide = [1] * new.ndim
+    wide[1], wide[t_ax] = new.shape[1], w
+    hit = (write_mask[:, None] & (off >= 0) & (off < s)).reshape(wide)
+    # the new row each block row would take, [N, B, *slot with W for T].
+    # One token broadcasts: a gathered copy of it, W rows tall in the
+    # scan's ys layout, talks XLA into re-laying the whole cache out
+    src = new if s == 1 else jnp.take_along_axis(
+        new, jnp.clip(off, 0, s - 1).reshape(wide), axis=t_ax
+    )
+    sizes = (new.shape[0], 1) + ckv.shape[2:t_ax] + (w,) + ckv.shape[t_ax + 1:]
+
+    def one_slot(b, ckv):
+        at = [layer, jnp.asarray(b, jnp.uint32)] + [zero] * (ckv.ndim - 2)
+        at[t_ax] = base[b]
+        mine = lambda a: jax.lax.dynamic_slice_in_dim(a, b, 1, 1)
+        blk = jnp.where(mine(hit), mine(src), jax.lax.dynamic_slice(ckv, at, sizes))
+        return jax.lax.dynamic_update_slice(ckv, blk, at)
+
+    if unroll:
+        for b in range(new.shape[1]):
+            ckv = one_slot(b, ckv)
+        return ckv
+    return jax.lax.fori_loop(0, new.shape[1], one_slot, ckv)
+
+
+def _cwith_row(ckv, positions, write_mask, new):
+    """One layer's slice [B, Hkv, T, D] (or its (int8, scale) pair)
+    with each slot's new token selected in at its position: what the
+    slice will hold once the row is written, without writing it. The
+    select fuses into the attention's operand reads like the int8
+    dequant does."""
+    if isinstance(ckv, tuple):
+        return tuple(
+            _cwith_row(c, positions, write_mask, n) for c, n in zip(ckv, new)
+        )
+    hit = write_mask[:, None] & (
+        jnp.arange(ckv.shape[2])[None, :] == positions[:, None]
+    )  # [B, T]
+    return jnp.where(
+        hit.reshape(hit.shape[0], 1, -1, *[1] * (ckv.ndim - 3)), new, ckv
+    )
+
+
+def _clayer(ckv, layer):
+    """Layer ``layer``'s slice of a stacked cache leaf as stored (array
+    or (int8, scale) pair). Read by an attention einsum, the
+    ``dynamic_slice`` fuses into the operand read like the int8 dequant:
+    a dense layer's slice is never materialized."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        ckv,
+    )
+
+
 def init_cache(
     config: LlamaConfig,
     max_batch: int,
@@ -461,7 +572,9 @@ def _mla_scan(params: dict, rows: jax.Array, x: jax.Array, one_layer):
     """Drive ``one_layer(x, layer, row) -> (x, row)`` over the DeepSeek
     layer layout: the ``first_k_dense`` prelude layers run unrolled
     (K ≤ 3 on every real config), the main stack runs as one
-    ``lax.scan``; returns (x, updated [L, ...] cache rows)."""
+    ``lax.scan``; returns (x, updated [L, ...] cache rows). The prefill
+    programs' form: the cache travels xs → ys, so the program holds a
+    second one (the decode programs: :func:`_mla_layers_inplace`)."""
     k_dense = rows.shape[0] - params["layers"]["attn_norm"].shape[0]
     out_pre = []
     for j in range(k_dense):
@@ -478,6 +591,28 @@ def _mla_scan(params: dict, rows: jax.Array, x: jax.Array, one_layer):
     if k_dense:
         main = jnp.concatenate([jnp.stack(out_pre), main], axis=0)
     return x, main
+
+
+def _mla_layers_inplace(params: dict, ckv: jax.Array, x: jax.Array, one_layer):
+    """Drive ``one_layer(x, layer, ckv, li) -> (x, ckv)`` over the
+    DeepSeek layer layout with the STACKED latent cache as the carry of
+    the prelude and of the main scan alike (the decode programs: layer
+    ``li`` writes its new rows into the donated buffer in place and
+    reads its slice from it) → (x, ckv)."""
+    n_main = params["layers"]["attn_norm"].shape[0]
+    k_dense = ckv.shape[0] - n_main
+    for j in range(k_dense):
+        lyr = jax.tree.map(lambda a: a[j], params["dense_layers"])
+        x, ckv = one_layer(x, lyr, ckv, j)
+
+    def scan_fn(carry, layer_and_ix):
+        (xx, ckv), (layer, li) = carry, layer_and_ix
+        return one_layer(xx, layer, ckv, li), None
+
+    (x, ckv), _ = jax.lax.scan(
+        scan_fn, (x, ckv), (params["layers"], k_dense + jnp.arange(n_main))
+    )
+    return x, ckv
 
 
 def _prefill_chunk_mla(
@@ -560,14 +695,12 @@ def _decode_step_mla(
 
     b = tokens.shape[0]
     tmax = cache["ckv"].shape[2]
-    write_pos = jnp.where(write_mask, positions, tmax)
     x = _embed_lookup(params, tokens, c)[:, None, :]
     (cos, sin), _ = dual_rope_freqs(c, positions)  # [B, rope/2]
-    batch_ix = jnp.arange(b)
     scale = c.attention_scale
 
-    def one_layer(x, layer, row):
-        # row [B, Tmax, R]
+    def one_layer(x, layer, lat, li):
+        # lat [L, B, Tmax, R]: the stacked latent cache
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
         q = _mla_q(h, layer, c)  # [B, H, 1, qk_head_dim]
         q_nope = q[..., : c.qk_nope_head_dim]
@@ -578,8 +711,11 @@ def _decode_step_mla(
         k_pe = _apply_rope_batch(
             k_pe[:, :, None], cos, sin, interleaved=True
         )[:, 0, 0]  # [B, rope]
-        new_row = jnp.concatenate([ckv[:, 0], k_pe], axis=-1)  # [B, R]
-        row = row.at[batch_ix, write_pos].set(new_row, mode="drop")
+        new_row = jnp.concatenate([ckv, k_pe[:, None]], axis=-1)  # [B, 1, R]
+        lat = _cwrite_rows(
+            lat, li, positions, write_mask, new_row, axis=0, unroll=True
+        )
+        row = _clayer(lat, li)  # [B, Tmax, R]
         w_kb_nope, w_kb_v = _mla_kb(layer, c)
         q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, :, 0], w_kb_nope)
         q_abs = jnp.concatenate([q_lat, q_pe[:, :, 0]], axis=-1)  # [B,H,R]
@@ -594,11 +730,11 @@ def _decode_step_mla(
         )
         o = jnp.einsum("bhr,rhv->bhv", o_lat, w_kb_v).reshape(b, 1, c.o_dim)
         ao = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-        return _mlp(x + ao, layer, c), row
+        return _mlp(x + ao, layer, c), lat
 
-    x, rows = _mla_scan(params, cache["ckv"], x, one_layer)
+    x, lat = _mla_layers_inplace(params, cache["ckv"], x, one_layer)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
-    return _head_logits(params, x[:, 0], c), {"ckv": rows}
+    return _head_logits(params, x[:, 0], c), {"ckv": lat}
 
 
 def _verify_step_mla(
@@ -620,14 +756,12 @@ def _verify_step_mla(
         lambda a: a.reshape(b, sdraft, c.qk_rope_head_dim // 2),
         dual_rope_freqs(c, pos_grid.reshape(-1)),
     )
-    batch_ix = jnp.arange(b)
     scale = c.attention_scale
-    write_pos = jnp.where(write_mask[:, None], pos_grid, tmax)  # [B, S]
 
     def rope_rows(t):  # MLA rope is always interleaved
         return _rope_rows(t, cos, sin, interleaved=True)
 
-    def one_layer(x, layer, row):
+    def one_layer(x, layer, lat, li):
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
         q = _mla_q(h, layer, c)  # [B, H, S, qk_head_dim]
         q_nope = q[..., : c.qk_nope_head_dim]
@@ -635,7 +769,10 @@ def _verify_step_mla(
         ckv, k_pe = _mla_latents(h, layer, c)  # [B,S,rank], [B,S,rope]
         k_pe = rope_rows(k_pe[:, None])[:, 0]  # [B, S, rope]
         new_rows = jnp.concatenate([ckv, k_pe], axis=-1)  # [B, S, R]
-        row = row.at[batch_ix[:, None], write_pos].set(new_rows, mode="drop")
+        lat = _cwrite_rows(
+            lat, li, positions, write_mask, new_rows, axis=0, unroll=True
+        )
+        row = _clayer(lat, li)  # [B, Tmax, R]
         w_kb_nope, w_kb_v = _mla_kb(layer, c)
         q_lat = jnp.einsum("bhsn,rhn->bhsr", q_nope, w_kb_nope)
         q_abs = jnp.concatenate([q_lat, q_pe], axis=-1)  # [B, H, S, R]
@@ -653,11 +790,11 @@ def _verify_step_mla(
             b, sdraft, c.o_dim
         )
         ao = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-        return _mlp(x + ao, layer, c), row
+        return _mlp(x + ao, layer, c), lat
 
-    x, rows = _mla_scan(params, cache["ckv"], x, one_layer)
+    x, lat = _mla_layers_inplace(params, cache["ckv"], x, one_layer)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
-    return _head_logits(params, x, c, eq="bse,ev->bsv"), {"ckv": rows}
+    return _head_logits(params, x, c, eq="bse,ev->bsv"), {"ckv": lat}
 
 
 def prefill(
@@ -1128,11 +1265,8 @@ def decode_step(
         return _decode_step_mla(
             params, cache, tokens, positions, c, write_mask
         )
-    # out-of-range scatter indices drop the write (mode="drop")
-    write_pos = jnp.where(write_mask, positions, cache["k"].shape[3])
     x = _embed_lookup(params, tokens, c)[:, None, :]
     (cos, sin), (cos_l, sin_l) = dual_rope_freqs(c, positions)  # [B, D/2]
-    batch_ix = jnp.arange(b)
     scale = c.attention_scale
     # decode attention is a masked einsum, so *traced* per-layer window
     # and NoPE flags can ride the scan — no grouped unrolling needed
@@ -1143,8 +1277,10 @@ def decode_step(
         attn_temp_scales(positions, c) if c.attn_temp_scale else None
     )  # [B]
 
-    def layer_fn(x, layer_and_cache):
-        layer, ck, cv, window, nope = layer_and_cache  # ck/cv [B,Hkv,Tmax,D]
+    ck, cv = _cache_pack(cache)  # the stacked [L,B,Hkv,Tmax,D] buffers
+
+    def layer_fn(x, layer_and_flags):
+        layer, window, nope, li = layer_and_flags
         # Gemma3 dual rope rides the traced window too: sliding layers
         # (window > 0) rotate with the local-theta pair
         cs, sn = (
@@ -1174,12 +1310,16 @@ def decode_step(
             k = jnp.where(nope, k, k_ro)
         else:
             q, k = q_ro, k_ro
-        # write this token's K/V at each slot's position (masked rows
-        # get an out-of-range index → dropped)
-        ck = _cwrite_at(ck, batch_ix, write_pos, k[:, :, 0, :])
-        cv = _cwrite_at(cv, batch_ix, write_pos, v[:, :, 0, :])
-        ckf = _cfull(ck, k.dtype)  # int8 caches dequant INSIDE the dot
-        cvf = _cfull(cv, v.dtype)
+        # the scan only READS the stacked cache: this token's K/V is
+        # selected into the layer's slice on its way into the attention
+        # (masked rows keep theirs) and goes out as ys; all layers' rows
+        # are written after the scan, in place, 2 × B small blocks a
+        # step where writing inside the scan costs that a layer
+        k_new, v_new = _cstored(k, ck), _cstored(v, cv)
+        ckl = _cwith_row(_clayer(ck, li), positions, write_mask, k_new)
+        cvl = _cwith_row(_clayer(cv, li), positions, write_mask, v_new)
+        ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
+        cvf = _cfull(cvl, v.dtype)
         # attend over the cache prefix (mask: j <= position, and within
         # the layer's sliding window when set). Grouped-query einsum:
         # q regrouped [B, Hkv, G, D] against the [B, Hkv, T, D] cache —
@@ -1193,7 +1333,7 @@ def decode_step(
             # DMA-elided (caller gated out MLA/chunked-attention/shape
             # misfits via flash_decode_supported)
             o = _flash_attend(
-                qg, ck, cv, positions, window,
+                qg, ckl, cvl, positions, window,
                 config=c, scale=scale, grp=grp, rows_per_slot=1,
                 sinks_leaf=layer.get("sinks"), mesh=mesh,
             )
@@ -1237,15 +1377,18 @@ def decode_step(
         if c.residual_multiplier:  # Granite scales the sublayer output
             ao = ao * jnp.asarray(c.residual_multiplier, ao.dtype)
         if c.parallel_block:  # Cohere: joint residual add
-            return x + ao + _mlp_out(x, layer, c), (ck, cv)
+            return x + ao + _mlp_out(x, layer, c), (k_new, v_new)
         x = x + ao
-        return _mlp(x, layer, c), (ck, cv)
+        return _mlp(x, layer, c), (k_new, v_new)
 
-    ck_p, cv_p = _cache_pack(cache)
-    x, (ks, vs) = jax.lax.scan(
-        layer_fn, x, (params["layers"], ck_p, cv_p, windows, nopes)
+    x, (k_rows, v_rows) = jax.lax.scan(
+        layer_fn, x,
+        (params["layers"], windows, nopes, jnp.arange(c.n_layers)),
     )
-    cache = _cache_unpack(ks, vs)
+    cache = _cache_unpack(
+        _cwrite_rows(ck, 0, positions, write_mask, k_rows),
+        _cwrite_rows(cv, 0, positions, write_mask, v_rows),
+    )
     x = model_norm(x, params["final_norm"], c)
     return _head_logits(params, x[:, 0], c), cache
 
@@ -1370,7 +1513,6 @@ def verify_step(
         lambda a: a.reshape(b, sdraft, inv_shape),
         dual_rope_freqs(c, pos_grid.reshape(-1)),
     )
-    batch_ix = jnp.arange(b)
     scale = c.attention_scale
     windows = jnp.asarray(layer_windows(c), jnp.int32)
     nopes = jnp.asarray(layer_nope(c), bool)
@@ -1380,13 +1522,13 @@ def verify_step(
         if c.attn_temp_scale else None
     )  # [B, S]
     tmax = cache["k"].shape[3]
-    write_pos = jnp.where(write_mask[:, None], pos_grid, tmax)  # [B, S]
 
     def rope_rows(t, cos, sin):  # t [B, Hh, S, D]
         return _rope_rows(t, cos, sin, interleaved=c.rope_interleaved)
 
-    def layer_fn(x, layer_and_cache):
-        layer, ck, cv, window, nope = layer_and_cache
+    def layer_fn(carry, layer_and_flags):
+        x, ck, cv = carry  # ck/cv: the stacked buffers, as in decode_step
+        layer, window, nope, li = layer_and_flags
         cs, sn = (
             (jnp.where(window > 0, cos_l, cos), jnp.where(window > 0, sin_l, sin))
             if c.rope_local_theta else (cos, sin)
@@ -1414,11 +1556,12 @@ def verify_step(
             k = jnp.where(nope, k, k_ro)
         else:
             q, k = q_ro, k_ro
-        # scatter the S tokens' K/V at their per-row positions
-        ck = _cwrite_at(ck, batch_ix, write_pos, k.transpose(0, 2, 1, 3))
-        cv = _cwrite_at(cv, batch_ix, write_pos, v.transpose(0, 2, 1, 3))
-        ckf = _cfull(ck, k.dtype)  # int8 caches dequant INSIDE the dot
-        cvf = _cfull(cv, v.dtype)
+        # write the S tokens' K/V at their per-row positions, in place
+        ck = _cwrite_rows(ck, li, positions, write_mask, _cstored(k, ck))
+        cv = _cwrite_rows(cv, li, positions, write_mask, _cstored(v, cv))
+        ckl, cvl = _clayer(ck, li), _clayer(cv, li)
+        ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
+        cvf = _cfull(cvl, v.dtype)
         # grouped-query attention against the KV-width cache (see
         # decode_step): q [B, Hkv, G, S, D] · cache [B, Hkv, T, D]
         grp = c.n_heads // c.n_kv_heads
@@ -1429,7 +1572,7 @@ def verify_step(
             # SAME dispatch — sink column included — as decode)
             qr = qg.reshape(b, c.n_kv_heads, grp * sdraft, c.head_dim)
             o = _flash_attend(
-                qr, ck, cv, positions, window,
+                qr, ckl, cvl, positions, window,
                 config=c, scale=scale, grp=grp, rows_per_slot=sdraft,
                 sinks_leaf=layer.get("sinks"), mesh=mesh,
             ).reshape(b, c.n_kv_heads, grp, sdraft, c.head_dim)
@@ -1473,13 +1616,13 @@ def verify_step(
         if c.residual_multiplier:  # Granite scales the sublayer output
             ao = ao * jnp.asarray(c.residual_multiplier, ao.dtype)
         if c.parallel_block:  # Cohere: joint residual add
-            return x + ao + _mlp_out(x, layer, c), (ck, cv)
+            return (x + ao + _mlp_out(x, layer, c), ck, cv), None
         x = x + ao
-        return _mlp(x, layer, c), (ck, cv)
+        return (_mlp(x, layer, c), ck, cv), None
 
-    ck_p, cv_p = _cache_pack(cache)
-    x, (ks, vs) = jax.lax.scan(
-        layer_fn, x, (params["layers"], ck_p, cv_p, windows, nopes)
+    (x, ks, vs), _ = jax.lax.scan(
+        layer_fn, (x, *_cache_pack(cache)),
+        (params["layers"], windows, nopes, jnp.arange(c.n_layers)),
     )
     cache = _cache_unpack(ks, vs)
     x = model_norm(x, params["final_norm"], c)

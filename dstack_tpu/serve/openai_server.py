@@ -2244,6 +2244,20 @@ def _warmup_engine(engine) -> None:
             s //= 2
         # sampled path: _decode + the full-batch [B, V] sampler
         run(full[:5], GenParams(max_new_tokens=2, temperature=0.7, seed=0))
+        # an all-greedy batch beside a prompt that is still prefilling
+        # takes the per-token path with the [B, V] argmax (the macro-
+        # step waits for the prefill): the smaller the live batch, the
+        # likelier, and a run with short decode steps hit it cold
+        slot, _ = engine.add_request(full[:5], GenParams(max_new_tokens=3))
+        late = engine.start_request(list(full), GenParams(max_new_tokens=2))
+        engine.step()
+        while late not in engine.prefill_wave():
+            pass
+        while engine.active[slot] or engine.active[late]:
+            engine.step()
+        engine.release(slot)
+        engine.release(late)
+        runs += 2
         if engine.prefill_pack > 1:
             # packed prefill variants: every power-of-2 G bucket at the
             # full chunk width (the shapes concurrent bursts hit;
@@ -2267,9 +2281,18 @@ def _warmup_engine(engine) -> None:
                 g *= 2
         engine.spec_draft = spec
         if spec:
-            # repetitive prompt → drafts fire → verify_step compiles
-            rep = (full[:4] * (engine.prefill_chunk // 4 + 1))[: engine.prefill_chunk]
-            run(rep, GenParams(max_new_tokens=spec + 2))
+            # the verify step. Verification is lossless whatever the
+            # draft, so hand it one: a repetitive prompt drafts only if
+            # the model's continuation happens to repeat, and where it
+            # did not, the first live draft paid this compile
+            slot, _ = engine.add_request(
+                full[:5], GenParams(max_new_tokens=spec + 2)
+            )
+            engine._spec_step([slot], {slot: [0] * spec})
+            while engine.active[slot]:
+                engine.step()
+            engine.release(slot)
+            runs += 1
         # warmup prompts aren't real: none may linger as prefix-reuse
         # candidates (a production prompt sharing their byte pattern
         # would silently reuse warmup KV rows)

@@ -52,6 +52,13 @@ async def _watchdog_client(watchdog_seconds=0.3):
     config = llama.LLAMA_TINY
     params = llama.init_params(config, jax.random.key(0))
     engine = InferenceEngine(config, params, max_batch=4, max_seq=128)
+    if watchdog_seconds:
+        # as `openai_server.main` does before it serves: a 0.3 s
+        # watchdog over a cold engine times the sandbox's compiler, not
+        # a wedged slot (see tests/chaos/test_chaos_serve.py)
+        from dstack_tpu.serve.openai_server import _warmup_engine
+
+        _warmup_engine(engine)
     app = build_app(
         engine, ByteTokenizer(), "llama-tiny",
         watchdog_seconds=watchdog_seconds,

@@ -72,6 +72,14 @@ async def _client_with(watchdog_seconds=0.0, qos_policy=None, max_batch=4):
     config = llama.LLAMA_TINY
     params = llama.init_params(config, jax.random.key(0))
     engine = InferenceEngine(config, params, max_batch=max_batch, max_seq=128)
+    if watchdog_seconds:
+        # as `openai_server.main` does before it serves: a watchdog of
+        # 0.3 s armed over a cold engine times the sandbox's compiler
+        # (trace + compile of one tiny step is 0.3-0.4 s here, more
+        # under xdist load), not a wedged slot
+        from dstack_tpu.serve.openai_server import _warmup_engine
+
+        _warmup_engine(engine)
     app = build_app(
         engine, ByteTokenizer(), "llama-tiny",
         qos_policy=qos_policy, watchdog_seconds=watchdog_seconds,

@@ -19,6 +19,7 @@ platform gate is steered in the test (``_as_tpu``).
 
 import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 # libtpu guards the CHIP with a /tmp lockfile, one process at a time;
@@ -320,6 +321,129 @@ def test_mla_moe_decode_step_deepseek_widths(topo):
         lambda p, c, t, pos, m: eng.decode_step(p, c, t, pos, config, m),
         params, cache, sds((16,), jnp.int32), sds((16,), jnp.int32),
         sds((16,), jnp.bool_), donate_argnums=(1,),
+    )
+    _fits(compiled)
+
+
+# ---------------------------------------------------------------------------
+# the decode programs update the donated cache in place: no second cache,
+# no layer's slice copied out and back (PERF.md §6 PR 25). Two thirds of
+# the decode program's device time was such copies, and no CPU test can
+# see them: they are the TPU compiler's answer to a one-token write into
+# a tiled buffer, and to a cache that travels through ``lax.scan`` as
+# xs → ys.
+# ---------------------------------------------------------------------------
+
+_INSTR = re.compile(
+    r"\s*(?:ROOT )?%(?P<name>[\w.\-]+) = \w+\[(?P<dims>[\d,]*)\]\S* "
+    r"(?P<op>[\w\-]+)\("
+)
+# what may carry a cache leaf's shape: the buffer on its way through the
+# loops, and writes into it where it lies
+_PASSES = {"parameter", "get-tuple-element", "bitcast", "dynamic-update-slice"}
+_COPIES = {"copy", "pad", "concatenate", "slice"}
+
+
+def _cache_sized_moves(hlo: str, stacked: set, layer: set) -> list:
+    """Instructions of the optimized program that copy, allocate, pad,
+    concatenate or slice something with a stacked cache leaf's shape,
+    and instructions outside any fusion that materialize one layer's
+    slice of it (``layer`` shapes). A fusion counts by its body: an
+    in-place ``dynamic-update-slice`` fusion passes unless a copy of a
+    slice rides in it (the parent's ``copy_dynamic-update-slice_fusion``
+    into the scan's ``ys``)."""
+    bodies: dict = {}  # computation → [(name, shape, op, line)]
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        m = _INSTR.match(line)
+        if head:
+            body = bodies.setdefault(head.group(1), [])
+        elif m and line.startswith("  "):
+            shape = tuple(int(d) for d in m["dims"].split(",") if d)
+            body.append((m["name"], shape, m["op"], line))
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+
+    def writes_in_place(fusion_line):
+        inner = bodies[re.search(r"calls=%([\w.\-]+)", fusion_line).group(1)]
+        return any(op == "dynamic-update-slice" for _, _, op, _ in inner) and not any(
+            op in _COPIES and shape in stacked | layer for _, shape, op, _ in inner
+        )
+
+    found = []
+    for comp, body in bodies.items():
+        if comp in fused:
+            continue
+        for name, shape, op, line in body:
+            if op in _PASSES or shape not in stacked | layer:
+                continue
+            if shape in layer:
+                found.append(f"{comp}: {name} = {op}: a layer's slice")
+            elif op != "fusion" or not writes_in_place(line):
+                found.append(f"{comp}: {name} = {op}: a whole cache leaf")
+    return found
+
+
+def _decode_program(case, sds, place):
+    """(fn, args, cache) of one decode program at a cell's shapes."""
+    b = 16
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    mask = sds((b,), jnp.bool_)
+    if case == "decode_loop-deepseek_v2_lite_9l":
+        config = dataclasses.replace(llama.DEEPSEEK_V2_LITE, n_layers=9)
+        max_seq, kv_quant = 8192, None
+    elif case == "decode_step-int8kv-llama_1b":
+        config, max_seq, kv_quant = llama.LLAMA_32_1B, 2048, "int8"
+    else:
+        config, max_seq, kv_quant = llama.MINITRON_4B, 1536, None
+    params = place(jax.eval_shape(
+        lambda: llama.init_params(config, jax.random.key(0))
+    ))
+    cache = place(jax.eval_shape(
+        lambda: eng.init_cache(config, b, max_seq, kv_quant=kv_quant)
+    ))
+    if case.startswith("decode_loop"):
+        fn = lambda p, c, t, pos, rem, act, eos: eng.decode_loop(
+            p, c, t, pos, rem, act, eos, config, steps=8, max_seq=max_seq
+        )
+        return fn, (params, cache, i32(b), i32(b), i32(b), mask, i32(b)), cache
+    if case.startswith("verify_step"):
+        fn = lambda p, c, t, pos, m: eng.verify_step(p, c, t, pos, config, m)
+        return fn, (params, cache, i32(b, 5), i32(b), mask), cache
+    fn = lambda p, c, t, pos, m: eng.decode_step(p, c, t, pos, config, m)
+    return fn, (params, cache, i32(b), i32(b), mask), cache
+
+
+@pytest.mark.parametrize("case", [
+    "decode_step-minitron_4b",  # the chat cell: 16 × 1536, cache 3.22 GB
+    "decode_loop-deepseek_v2_lite_9l",  # reasoning: 16 × 8192, latent 1.36 GB
+    "verify_step-minitron_4b",  # S = 5 rows a slot on the chat cell's shapes
+    "decode_step-int8kv-llama_1b",  # (int8, scale) leaves, head_dim 64
+])
+def test_decode_program_holds_no_second_cache(topo, case):
+    """Compiled the way the engine jits them (cache donated). Parent
+    readings, ``temp`` / cache: decode_step 3.32 / 3.22 GB, decode_loop
+    3.06 / 1.36 GB, verify_step 3.26 / 3.22 GB, int8 0.79 / 0.57 GB."""
+    sharding = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    fn, args, cache = _decode_program(case, sds, place)
+    compiled = _compile(fn, *args, donate_argnums=(1,))
+    stacked = {leaf.shape for leaf in jax.tree.leaves(cache)}
+    # a dense layer's slice of K, V or their scales, as the scan's xs
+    # handed it over; the latent's per-layer slice is still copied once
+    # a layer (151 MB at these shapes: the two latent einsums will not
+    # take a fused slice)
+    layer = {
+        sh for name, leaf in cache.items() if name != "ckv"
+        for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])
+    }
+    assert not _cache_sized_moves(compiled.as_text(), stacked, layer)
+    cache_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache)
+    )
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.25 * cache_bytes, (
+        f"temp {temp / 1e9:.2f} GB beside a cache of {cache_bytes / 1e9:.2f} GB"
     )
     _fits(compiled)
 

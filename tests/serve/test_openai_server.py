@@ -51,6 +51,39 @@ def test_warmup_compiles_and_leaves_engine_clean():
     assert len(out) == 4
 
 
+def test_warmup_covers_greedy_step_beside_pending_prefill():
+    """An all-greedy live batch while another prompt is mid-prefill
+    takes the per-token path with the [B, V] argmax (the macro-step
+    waits for the prefill). The warm-up never drove that state, and a
+    chip run with short decode steps (small live batches, so "all
+    greedy" happens) paid the compile inside its window (PERF.md §6,
+    PR 25)."""
+    from dstack_tpu.serve.engine import GenParams
+    from dstack_tpu.serve.openai_server import _warmup_engine
+
+    config = llama.LLAMA_TINY
+    params = llama.init_params(config, jax.random.key(0))
+    engine = InferenceEngine(
+        config, params, max_batch=4, max_seq=128, spec_draft=0, turbo_steps=4
+    )
+    _warmup_engine(engine)
+    assert engine.free_slots() == [0, 1, 2, 3]
+    compiles = engine.metrics.family("dtpu_serve_compiles_total")
+    before = dict(compiles.items())
+    slot, _ = engine.add_request([5, 6, 7], GenParams(max_new_tokens=4))
+    late = engine.start_request(
+        [(i % 251) + 1 for i in range(engine.prefill_chunk)],
+        GenParams(max_new_tokens=2),
+    )
+    engine.step()  # greedy, one live slot, `late` still prefilling
+    assert engine._last_step_phase == "decode"
+    while late not in engine.prefill_wave():
+        pass
+    while engine.active[slot] or engine.active[late]:
+        engine.step()
+    assert dict(compiles.items()) == before, "the warm-up left a variant out"
+
+
 class TestOpenAIServer:
     async def test_health_names_the_device(self):
         """A client — the chip smoke, the router's probe — must see
