@@ -74,10 +74,11 @@ def build_llama_config(llama_config: dict):
     kw["dtype"] = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
         kw.get("dtype", "bfloat16")
     ]
-    for k, v in kw.items():
-        if isinstance(v, list):
-            kw[k] = tuple(v)
-    return llama.LlamaConfig(**kw)
+
+    def frozen(v):  # a per-layer pattern of pairs is a list of lists in JSON
+        return tuple(frozen(x) for x in v) if isinstance(v, list) else v
+
+    return llama.LlamaConfig(**{k: frozen(v) for k, v in kw.items()})
 
 
 def _pow2_at_least(n: int) -> int:
@@ -190,7 +191,7 @@ def serve(args) -> int:
 
     def seeded_params(config, key):
         took_weights.append(True)
-        return weights.make_params(cfg["llama_config"], args.seed)
+        return weights.make_params(cfg, args.seed)
 
     llama.init_params = seeded_params
     program_warmup = openai_server._warmup_engine

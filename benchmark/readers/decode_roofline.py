@@ -2,7 +2,10 @@
 window, in %.
 
 Least time = steps × max(FLOPs / peak FLOP/s, bytes / peak bytes/s) of
-one decode step at the live batch and context (``benchmark/costs``),
+one decode step at the live batch and context (``benchmark/costs``:
+the module the configuration file names under ``costs``, else
+``decode``; the contract is ``decode_step(llama_config, batch, context)
+-> {"flops", "bytes", "weight_bytes", "cache_bytes"}``),
 over the device's busy time in the trace: all of it, whatever the
 programs are called, so prefill and the sampler sit in the denominator
 and the share is a floor of the decode program's own. Naming or
@@ -12,8 +15,9 @@ the engine's decode modes: a token that arrived while ``n`` requests
 were decoding is 1/n of a step.
 """
 
+import importlib
+
 from benchmark import costs
-from benchmark.costs import decode
 
 
 def live_decode_stats(records, t0, t1):
@@ -52,7 +56,10 @@ def read(ctx):
     steps, batch, context = live_decode_stats(ctx["records"], t0, t1)
     if not steps:
         return None
-    step = decode.decode_step(ctx["config"]["llama_config"], batch, context)
+    step_costs = importlib.import_module(
+        f"benchmark.costs.{ctx['config'].get('costs', 'decode')}"
+    )
+    step = step_costs.decode_step(ctx["config"]["llama_config"], batch, context)
     roof = costs.roofline_seconds(step["flops"], step["bytes"], ctx["device"]["kind"])
     print(
         f"decode_roofline steps={steps:.1f} live_batch={batch:.2f} context={context:.0f} "

@@ -95,7 +95,7 @@ def run(cfg: dict, seed: int, requests: list, control=None) -> dict:
 
     t0 = time.monotonic()
     ref = importlib.import_module(f"benchmark.reference.{cfg['reference']}")
-    params = weights.make_params(cfg["llama_config"], seed)
+    params = weights.make_params(cfg, seed)
     jax.block_until_ready(params)
     t1 = time.monotonic()
     modes = [None, control] if control else [None]

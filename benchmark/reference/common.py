@@ -19,6 +19,10 @@ import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
+#: std of a seeded normal leaf in every ``leaf_shapes``; a projection back
+#: into the residual stream takes ``STD / sqrt(2 * n_layers)``
+STD = 0.02
+
 
 def _q8(x, axis):
     scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0

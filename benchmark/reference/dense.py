@@ -7,15 +7,43 @@ that slice), an ungated MLP ``down(relu(up(x))**2)``, untied output
 head. One sequence at a time, layer by layer, float32.
 
 ``cfg`` is the configuration file (HF keys); ``params`` the weight tree
-as ``benchmark/weights.py`` lays it out.
+that ``leaf_shapes`` states and ``benchmark/weights.py`` draws.
 """
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from . import common as C
+
+
+def leaf_shapes(c: dict) -> dict:
+    """The weight tree this architecture reads, as the program's
+    ``init_params`` lays it out: ``{path: (shape, scale)}``, nested;
+    scale None = a norm leaf (identity init), else the std of the normal
+    draw. ``c`` is the configuration file's ``llama_config`` group."""
+    H, V, L = c["hidden_size"], c["vocab_size"], c["n_layers"]
+    F = c["intermediate_size"]
+    q = c["n_heads"] * c["head_dim"]
+    kv = c["n_kv_heads"] * c["head_dim"]
+    down = C.STD / math.sqrt(2 * L)
+
+    def norm(*lead):  # LayerNorm1P: (scale-1, bias)
+        return (lead + (2, H), None)
+
+    return {
+        "embed": ((V, H), C.STD),
+        "layers": {
+            "attn_norm": norm(L), "mlp_norm": norm(L),
+            "wq": ((L, H, q), C.STD), "wk": ((L, H, kv), C.STD),
+            "wv": ((L, H, kv), C.STD), "wo": ((L, q, H), down),
+            "w_up": ((L, H, F), C.STD), "w_down": ((L, F, H), down),
+        },
+        "final_norm": norm(),
+        "lm_head": ((H, V), C.STD),
+    }
 
 
 def rope_tables(cfg, t):

@@ -11,7 +11,7 @@ experts as one always-on SwiGLU. One sequence at a time, layer by
 layer, float32; the experts are visited one after the other.
 
 ``cfg`` is the configuration file (HF keys); ``params`` the weight tree
-as ``benchmark/weights.py`` lays it out.
+that ``leaf_shapes`` states and ``benchmark/weights.py`` draws.
 """
 
 import math
@@ -21,6 +21,61 @@ import jax
 import jax.numpy as jnp
 
 from . import common as C
+
+
+def leaf_shapes(c: dict) -> dict:
+    """The weight tree this architecture reads, as the program's
+    ``init_params`` lays it out: ``{path: (shape, scale)}``, nested;
+    scale None = a norm leaf (identity init), else the std of the normal
+    draw. ``c`` is the configuration file's ``llama_config`` group. The
+    expert layers are one stacked group, the dense layers before them
+    (``first_k_dense``) another."""
+    H, V = c["hidden_size"], c["vocab_size"]
+    n_layers, k_dense = c["n_layers"], c.get("first_k_dense", 0)
+    L = n_layers - k_dense
+    down = C.STD / math.sqrt(2 * n_layers)
+
+    def norm(*lead):
+        return (lead + (H,), None)
+
+    def attn(n):
+        r, rope = c["kv_lora_rank"], c["qk_rope_head_dim"]
+        nope, vd, nh = c["qk_nope_head_dim"], c["v_head_dim"], c["n_heads"]
+        return {
+            "wq": ((n, H, nh * (nope + rope)), C.STD),
+            "wkv_a": ((n, H, r + rope), C.STD),
+            "kv_a_norm": ((n, r), None),
+            "wkv_b": ((n, r, nh * (nope + vd)), C.STD),
+            "wo": ((n, nh * vd, H), down),
+        }
+
+    F, E = c["intermediate_size"], c["n_experts"]
+    FS = c.get("moe_shared_intermediate") or F
+    tree = {
+        "embed": ((V, H), C.STD),
+        "layers": {
+            "attn_norm": norm(L), "mlp_norm": norm(L), **attn(L),
+            "w_router": ((L, H, E), C.STD),
+            "w_gate": ((L, E, H, F), C.STD),
+            "w_up": ((L, E, H, F), C.STD),
+            "w_down": ((L, E, F, H), down),
+            "w_shared_gate": ((L, H, FS), C.STD),
+            "w_shared_up": ((L, H, FS), C.STD),
+            "w_shared_down": ((L, FS, H), down),
+        },
+        "final_norm": norm(),
+        "lm_head": ((H, V), C.STD),
+    }
+    if k_dense:
+        FD = c.get("dense_intermediate") or F
+        tree["dense_layers"] = {
+            "attn_norm": norm(k_dense), "mlp_norm": norm(k_dense),
+            **attn(k_dense),
+            "w_gate": ((k_dense, H, FD), C.STD),
+            "w_up": ((k_dense, H, FD), C.STD),
+            "w_down": ((k_dense, FD, H), down),
+        }
+    return tree
 
 
 def yarn_inv_freq(cfg):

@@ -72,6 +72,14 @@ def validate(root: str) -> list:
         _line(c["why"], f"config {c['name']} why", faults)
         if not under_paths(c["file"]) or not os.path.isfile(os.path.join(root, c["file"])):
             faults.append(f"config {c['name']}: file {c['file']} not under paths or missing")
+        else:
+            # the modules the harness looks up by the names in the file
+            with open(os.path.join(root, c["file"])) as f:
+                cf = json.load(f)
+            for group, module in (("reference", cf.get("reference")),
+                                  ("costs", cf.get("costs", "decode"))):
+                if not os.path.isfile(os.path.join(root, "benchmark", group, f"{module}.py")):
+                    faults.append(f"config {c['name']}: no benchmark/{group}/{module}.py")
         if c["file"] in files:
             faults.append(f"config file {c['file']} used twice")
         files.add(c["file"])

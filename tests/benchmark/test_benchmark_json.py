@@ -67,6 +67,21 @@ def test_catalog_config_keeps_every_published_number(root):
     assert checked >= 1  # DeepSeek-V2-Lite is in the catalog
 
 
+@pytest.mark.parametrize("key", ["reference", "costs"])
+def test_a_module_a_configuration_names_has_to_be_there(root, tmp_path, key):
+    copy = tmp_path / "copy"
+    shutil.copytree(os.path.join(root, "benchmark"), copy / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (copy / "tests" / "benchmark").mkdir(parents=True)
+    shutil.copy(os.path.join(root, "BENCHMARK.json"), copy / "BENCHMARK.json")
+    path = copy / "benchmark/configs/minitron-4b.json"
+    with open(path) as f:
+        cfg = json.load(f)
+    cfg[key] = "nowhere"
+    path.write_text(json.dumps(cfg))
+    assert validate.validate(str(copy)) == [f"config minitron-4b: no benchmark/{key}/nowhere.py"]
+
+
 @pytest.mark.parametrize("what", ["validator", "harness"])
 def test_dummy_config_cell_and_metric_are_new_files_plus_one_entry(root, tmp_path, what):
     """Copy the benchmark, add one of each as NEW files and entries, and

@@ -10,14 +10,15 @@ from benchmark import costs, weights
 from benchmark.costs import decode
 
 
-def _lc(root, name):
+def _cfg(root, name):
     with open(os.path.join(root, "benchmark", "configs", f"{name}.json")) as f:
-        return json.load(f)["llama_config"]
+        return json.load(f)
 
 
 def test_minitron_decode_step(root):
-    c = _lc(root, "minitron-4b")
-    assert weights.num_params(c) == pytest.approx(4.19e9, rel=0.01)
+    cfg = _cfg(root, "minitron-4b")
+    c = cfg["llama_config"]
+    assert weights.num_params(cfg) == pytest.approx(4.19e9, rel=0.01)
     assert decode.cache_bytes_per_token(c) == 32 * 8 * 128 * 2 * 2  # 131 KB
     d = decode.decode_step(c, batch=16, context=2048)
     # all weights but the embedding table (a gather): 8.4 - 1.57 GB
@@ -29,8 +30,9 @@ def test_minitron_decode_step(root):
 
 
 def test_deepseek_decode_step(root):
-    c = _lc(root, "deepseek-v2-lite-9l")
-    assert weights.num_params(c) == pytest.approx(5.18e9, rel=0.01)
+    cfg = _cfg(root, "deepseek-v2-lite-9l")
+    c = cfg["llama_config"]
+    assert weights.num_params(cfg) == pytest.approx(5.18e9, rel=0.01)
     assert decode.cache_bytes_per_token(c) == 576 * 2 * 9  # 10.4 KB
     assert decode.expected_distinct_experts(64, 6, 16) == pytest.approx(50.8, abs=0.2)
     assert decode.expected_distinct_experts(64, 6, 1) == pytest.approx(6.0)
