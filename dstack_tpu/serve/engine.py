@@ -111,12 +111,6 @@ def kv_dequant(q: jax.Array, s: jax.Array, dtype) -> jax.Array:
 # traffic stays int8.
 
 
-def _tree_stack(lst):
-    """Stack a list of same-structure pytrees leaf-wise (plain arrays
-    AND (int8, scale) cache tuples)."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *lst)
-
-
 def _cache_pack(cache: dict) -> tuple:
     """dict → (ck, cv) where each is an array or an (int8, scale) pair."""
     if "k_s" in cache:
@@ -130,55 +124,72 @@ def _cache_unpack(ck, cv) -> dict:
     return {"k": ck, "v": cv}
 
 
-def _cwrite_chunk(ckv, new, slot, start: int):
-    """Write a prefill chunk [B, H, C, D] at (slot, start)."""
+def _cwrite_chunk(ckv, layer, slot, start: int, new, axis: int = 1):
+    """Write one slot's prefill chunk into layer ``layer`` of a STACKED
+    cache leaf at the static token offset ``start``, in place: ``new``
+    [1, *slot] with C in the place of T, as stored (:func:`_cstored`);
+    ``axis`` as in :func:`_cwrite_rows`. The engine keeps ``start`` a
+    multiple of the chunk and a chunk is whole tiles, so the
+    ``dynamic_update_slice`` lands where the donated buffer lies. (One
+    shape is left to the compiler: a short prompt's 16-row bucket on a
+    bf16 leaf with its tokens on the lanes is an eighth of a tile, and
+    the leaf is re-laid out around the layer loop; block forms made it
+    worse, PERF.md §6 PR 29.)"""
     if isinstance(ckv, tuple):
-        q, s = kv_quantize(new)
-        s = s.astype(ckv[1].dtype)
-        return (
-            jax.lax.dynamic_update_slice(ckv[0], q, (slot, 0, start, 0)),
-            jax.lax.dynamic_update_slice(ckv[1], s, (slot, 0, start)),
+        return tuple(
+            _cwrite_chunk(c, layer, slot, start, n, axis) for c, n in zip(ckv, new)
         )
-    return jax.lax.dynamic_update_slice(ckv, new, (slot, 0, start, 0))
+    at = [layer, slot] + [0] * (ckv.ndim - 2)
+    at[2 + axis] = start
+    return jax.lax.dynamic_update_slice(ckv, new[None], at)
 
 
-def _cread_row(ckv, slot, dtype):
-    """One slot's row [1, H, Tmax, D] in compute dtype."""
+def _cread_rows(ckv, layer, slots, dtype):
+    """``slots``' rows [G, *slot] of layer ``layer`` of a stacked cache
+    leaf, in compute dtype: what a prefill chunk attends over (serial:
+    G = 1; packed: the wave's rows). One ``dynamic_slice`` a row, so
+    neither the layer's slice nor the leaf is copied out (one gather of
+    the G rows made the compiler copy whole leaves: device-free,
+    ``temp`` 1.8 GB on the dense packed wave)."""
+
+    def rows(a):
+        zeros, sizes = (0,) * (a.ndim - 2), (1, 1) + a.shape[2:]
+        return jnp.concatenate([
+            jax.lax.dynamic_slice(a, (layer, s) + zeros, sizes)[0] for s in slots
+        ])
+
     if isinstance(ckv, tuple):
-        rq = jax.lax.dynamic_slice_in_dim(ckv[0], slot, 1, 0)
-        rs = jax.lax.dynamic_slice_in_dim(ckv[1], slot, 1, 0)
-        return kv_dequant(rq, rs, dtype)
-    return jax.lax.dynamic_slice_in_dim(ckv, slot, 1, 0)
+        return kv_dequant(rows(ckv[0]), rows(ckv[1]), dtype)
+    return rows(ckv)
 
 
-def _cread_rows(ckv, slots, dtype):
-    """Gather ``slots``' rows [G, H, Tmax, D] in compute dtype (packed
-    prefill: G concurrent prompt chunks attend over their own rows)."""
-    if isinstance(ckv, tuple):
-        rq = jnp.take(ckv[0], slots, axis=0)
-        rs = jnp.take(ckv[1], slots, axis=0)
-        return kv_dequant(rq, rs, dtype)
-    return jnp.take(ckv, slots, axis=0)
+def _tokens_on_lanes(width: int) -> bool:
+    """Whether a cache leaf [..., T, width] lies in device memory with
+    its TOKENS on the 128 lanes: the TPU compiler's own choice for a
+    minor axis that does not fill them (head_dim 64, the latent's 576,
+    a window latent's 1088), since it wastes no lane. What holds in
+    place on such a leaf is the other form of what holds on a leaf
+    with its width on the lanes (head_dim 128), in two places below."""
+    return width % 128 != 0
 
 
-def _cwrite_at(ckv, batch_ix, write_pos, new):
-    """Scatter per-slot tokens: new [B, H, D] at [B] positions, or
-    [B, S, H, D] at [B, S] positions (speculative verify)."""
-    if isinstance(ckv, tuple):
-        q, s = kv_quantize(new)
-        s = s.astype(ckv[1].dtype)
-        if new.ndim == 3:  # [B, H, D] single token
-            return (
-                ckv[0].at[batch_ix, :, write_pos].set(q, mode="drop"),
-                ckv[1].at[batch_ix, :, write_pos].set(s, mode="drop"),
-            )
-        return (  # [B, S, H, D] at [B, S]
-            ckv[0].at[batch_ix[:, None], :, write_pos].set(q, mode="drop"),
-            ckv[1].at[batch_ix[:, None], :, write_pos].set(s, mode="drop"),
-        )
-    if new.ndim == 3:
-        return ckv.at[batch_ix, :, write_pos].set(new, mode="drop")
-    return ckv.at[batch_ix[:, None], :, write_pos].set(new, mode="drop")
+def _own_rows(rows):
+    """``rows`` [..., T, width], read out of a leaf with its tokens on
+    the lanes, for the flash kernel, which wants the width there → the
+    same values in a buffer of their own. A ``dynamic_slice`` hands the
+    kernel's layout on to what it slices, so the compiler re-lays the
+    WHOLE leaf out before the layer loop and back after it
+    (device-free: ``temp`` 1.5 GB beside V2-Lite's 1.36 GB latent, 2.1
+    beside Llama-3.2-1B's 1.07 GB cache). A product with the identity is
+    exact (one and zeros, summed in float32) and is the one operation
+    whose operand's layout the compiler leaves alone: the transposition
+    then costs one row a layer."""
+    if not _tokens_on_lanes(rows.shape[-1]):
+        return rows
+    return jnp.einsum(
+        "...td,de->...te", rows, jnp.eye(rows.shape[-1], dtype=rows.dtype),
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 def _cfull(ckv, dtype):
@@ -199,14 +210,18 @@ def _cstored(new, like):
 
 
 def _cwrite_rows(
-    ckv, layer, positions, write_mask, new, axis: int = 1, unroll: bool = False
+    ckv, layer, positions, write_mask, new, axis: int = 1, unroll: bool = False,
+    slots=None, counts=None,
 ):
     """Write ``S`` new tokens a slot into layers ``layer .. layer + N``
     of a STACKED cache leaf, in place: ``ckv`` [L, B, *slot] with the
     token axis at ``axis`` of the slot's shape ([Hkv, T, D] values: 1;
     [T, R] latents: 0), ``new`` [N, B, *slot] with S in the place of T
     (or [B, *slot]: one layer's; as stored, see :func:`_cstored`), for
-    the tokens at ``positions[b] + s``.
+    the tokens at ``positions[b] + s``. Row ``b`` of ``new`` is slot
+    ``b``'s (a decode or verify step) or slot ``slots[b]``'s (a packed
+    prefill wave's G rows), and only its first ``counts[b]`` tokens are
+    written where given (a padded last chunk; 0: a pad row).
 
     A one-token ``.at[].set`` touches one row of a tile on the token
     axis, and the TPU compiler will not do that in place: it re-lays
@@ -228,7 +243,9 @@ def _cwrite_rows(
     whole at every call (device-free: ``temp`` 0.33 → 2.0 GB)."""
     if isinstance(ckv, tuple):
         return tuple(
-            _cwrite_rows(c, layer, positions, write_mask, n, axis, unroll)
+            _cwrite_rows(
+                c, layer, positions, write_mask, n, axis, unroll, slots, counts
+            )
             for c, n in zip(ckv, new)
         )
     if new.ndim < ckv.ndim:  # one layer's rows
@@ -248,7 +265,8 @@ def _cwrite_rows(
     off = start[:, None] + jnp.arange(w)[None, :] - positions[:, None]
     wide = [1] * new.ndim
     wide[1], wide[t_ax] = new.shape[1], w
-    hit = (write_mask[:, None] & (off >= 0) & (off < s)).reshape(wide)
+    most = s if counts is None else counts[:, None]
+    hit = (write_mask[:, None] & (off >= 0) & (off < most)).reshape(wide)
     # the new row each block row would take, [N, B, *slot with W for T].
     # One token broadcasts: a gathered copy of it, W rows tall in the
     # scan's ys layout, talks XLA into re-laying the whole cache out
@@ -258,7 +276,8 @@ def _cwrite_rows(
     sizes = (new.shape[0], 1) + ckv.shape[2:t_ax] + (w,) + ckv.shape[t_ax + 1:]
 
     def one_slot(b, ckv):
-        at = [layer, jnp.asarray(b, jnp.uint32)] + [zero] * (ckv.ndim - 2)
+        slot = b if slots is None else slots[b]
+        at = [layer, jnp.asarray(slot, jnp.uint32)] + [zero] * (ckv.ndim - 2)
         at[t_ax] = base[b]
         mine = lambda a: jax.lax.dynamic_slice_in_dim(a, b, 1, 1)
         blk = jnp.where(mine(hit), mine(src), jax.lax.dynamic_slice(ckv, at, sizes))
@@ -654,17 +673,19 @@ def _mlp_out(
     return mo if valid is None else (mo, picks)
 
 
-def _mlp_counted(xc: tuple, ao: jax.Array, layer: dict, c: LlamaConfig, valid):
-    """``(x, stats)`` → the same after attention output ``ao`` and the
-    MLP sublayer. ``stats`` is None for a model that holds every expert
-    (nothing is counted, nothing is traced), else the [2] int32 routing
-    counts of :func:`_mlp_out`, which this layer's are added to."""
-    x, stats = xc
-    if stats is None:
-        return _mlp(x + ao, layer, c), None
+def _mlp_counted(
+    x: jax.Array, cache: dict, ao: jax.Array, layer: dict, c: LlamaConfig, valid
+):
+    """``(x, cache)`` → the same after attention output ``ao`` and the
+    MLP sublayer. A model that holds every expert has no ``moe_stats``
+    in its cache (nothing is counted, nothing is traced); else this
+    layer's [2] int32 routing counts of :func:`_mlp_out` are added to
+    the cache's."""
+    if "moe_stats" not in cache:
+        return _mlp(x + ao, layer, c), cache
     x = x + ao
     mo, picks = _mlp_out(x, layer, c, valid=valid)
-    return x + mo, stats + picks
+    return x + mo, {**cache, "moe_stats": cache["moe_stats"] + picks}
 
 
 def _qkv(h: jax.Array, layer: dict, c: LlamaConfig) -> tuple:
@@ -749,72 +770,18 @@ def _head_logits(
     return logits
 
 
-def _mla_scan(params: dict, cache: dict, xc, one_layer, c: LlamaConfig):
-    """Drive ``one_layer(xc, layer, rows, run) -> (xc, rows)`` over the
-    model's layer runs (``llama.layer_runs``: the ``first_k_dense``
-    prelude, then runs of consecutive layers of one group; a DeepSeek
-    model is the prelude and one run). ``rows`` maps each cache buffer
-    the run's group owns to that layer's slice of it. The prelude runs
-    unrolled (K ≤ 3 on every real config), every other run as one
-    ``lax.scan`` over its slice of its group's stack; returns (xc, the
-    updated buffers). The prefill programs' form: the cache travels
-    xs → ys, so the program holds a second one (the decode programs:
-    :func:`_mla_layers_inplace`)."""
-    k_dense = c.first_k_dense
-    out = {n: [] for n in cache if n != "moe_stats"}
-    for run in llama.layer_runs(c):
-        names = _run_names(cache, run)
-        if run.key == "dense_layers":
-            pre = {n: [] for n in names}
-            for j in range(run.lo, run.hi):
-                lyr = jax.tree.map(lambda a: a[j], params["dense_layers"])
-                xc, r = one_layer(xc, lyr, {n: cache[n][j] for n in names}, run)
-                for n in names:
-                    pre[n].append(r[n])
-            for n in names:
-                out[n].append(pre[n])  # stacked below, after the scans
-            continue
-        base = 0 if run.window else k_dense
-
-        def scan_fn(xx, layer_and_rows, run=run):
-            layer, rows = layer_and_rows
-            return one_layer(xx, layer, rows, run)
-
-        xc, ys = jax.lax.scan(
-            scan_fn, xc, (
-                llama.run_slice(params[run.key], run),
-                {n: _rows_of(cache[n], base + run.lo, base + run.hi) for n in names},
-            ),
-        )
-        for n in names:
-            out[n].append(ys[n])
-    pieces = {
-        n: [jnp.stack(p) if isinstance(p, list) else p for p in v]
-        for n, v in out.items()
-    }
-    return xc, {
-        n: v[0] if len(v) == 1 else jnp.concatenate(v, axis=0)
-        for n, v in pieces.items()
-    }
-
-
-def _run_names(cache: dict, run) -> tuple:
-    """The cache buffers a run's layers own."""
-    if run.window:
-        return ("win",)
-    return ("ckv", "idx") if "idx" in cache else ("ckv",)
-
-
-def _rows_of(buf: jax.Array, lo: int, hi: int) -> jax.Array:
-    return buf if (lo, hi) == (0, buf.shape[0]) else buf[lo:hi]
-
-
 def _mla_layers_inplace(params: dict, cache: dict, x: jax.Array, one_layer, c):
     """Drive ``one_layer(x, layer, cache, li, run) -> (x, cache)`` over
-    the model's layer runs with the STACKED cache buffers as the carry
-    of the prelude and of every scan alike (the decode programs: layer
+    the model's layer runs (``llama.layer_runs``: the ``first_k_dense``
+    prelude, unrolled, then runs of consecutive layers of one group,
+    each one ``lax.scan`` over its slice of its group's stack; a
+    DeepSeek model is the prelude and one run) with the STACKED cache
+    buffers as the carry of the prelude and of every scan alike: layer
     ``li`` of its buffers writes its new rows into the donated buffers
-    in place and reads its slice from them) → (x, cache)."""
+    in place and reads its rows from them → (x, cache). Every serving
+    program's form, prefill and decode: handed through a scan as
+    xs → ys the cache is held twice and copied whole (PERF.md §6, PR 25
+    and PR 29)."""
     k_dense = c.first_k_dense
     for run in llama.layer_runs(c):
         if run.key == "dense_layers":
@@ -867,15 +834,12 @@ def _prefill_chunk_mla(
     chunk_pos = start + jnp.arange(cl)
     (cos, sin), _ = dual_rope_freqs(c, chunk_pos)
     si = slot.astype(jnp.int32)
-    stats = valid = None
-    if "moe_stats" in cache:  # a chip's share of the experts: count its picks
-        stats = jnp.zeros((2,), jnp.int32)
-        valid = (jnp.arange(cl) <= last_ix)[None]  # [1, C]: the real tokens
+    # a chip's share of the experts counts its picks over the real tokens
+    valid = (jnp.arange(cl) <= last_ix)[None] if "moe_stats" in cache else None
 
-    def one_layer(xc, layer, rows, run):
-        # rows["ckv"] [B_pool, Tmax, rank+rope] — this layer's latents
+    def one_layer(x, layer, cache, li, run):
+        # cache["ckv"] [Lf, B_pool, Tmax, rank+rope]: this layer is row li
         gc = run.config
-        x = xc[0]
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
         q, _ = _mla_q(h, layer, gc)  # [B, H, C, qk_head_dim]
         q_nope = q[..., : gc.qk_nope_head_dim]
@@ -885,10 +849,11 @@ def _prefill_chunk_mla(
         ckv, k_pe = _mla_latents(h, layer, gc)
         k_pe = apply_rope(k_pe[:, None], cos, sin, interleaved=True)[:, 0]
         new_rows = jnp.concatenate([ckv, k_pe], axis=-1)  # [B, C, R]
-        row_cache = jax.lax.dynamic_update_slice(
-            rows["ckv"], new_rows, (si, start, 0)
-        )
-        row = jax.lax.dynamic_slice_in_dim(row_cache, si, 1, 0)  # [1,Tmax,R]
+        cache = {
+            **cache,
+            "ckv": _cwrite_chunk(cache["ckv"], li, si, start, new_rows, axis=0),
+        }
+        row = _own_rows(_cread_rows(cache["ckv"], li, si[None], c.dtype))  # [1, Tmax, R]
         w_kb_nope, w_kb_v = _mla_kb(layer, gc)
         q_lat = jnp.einsum("bhcn,rhn->bhcr", q_nope, w_kb_nope)
         q_abs = jnp.concatenate([q_lat, q_pe], axis=-1)  # [B, H, C, R]
@@ -905,32 +870,52 @@ def _prefill_chunk_mla(
         o = llama.head_gate(o, h, layer, gc, "bch,bchv->bchv")
         o = o.reshape(b, cl, gc.o_dim)
         ao = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-        return _mlp_counted(xc, ao, layer, c, valid), {"ckv": row_cache}
+        return _mlp_counted(x, cache, ao, layer, c, valid)
 
-    (x, stats), bufs = _mla_scan(params, cache, (x, stats), one_layer, c)
-    if stats is not None:
-        bufs["moe_stats"] = cache["moe_stats"] + stats
+    x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     last = jnp.take_along_axis(
         x, last_ix[None, None, None].astype(jnp.int32), axis=1
     )[:, 0]
-    return _head_logits(params, last, c), bufs
+    return _head_logits(params, last, c), cache
 
 
-def _stacked_write(cache: dict, name: str, li, positions, write_mask, new):
+def _stacked_write(
+    cache: dict, name: str, li, positions, write_mask, new, slots=None, counts=None
+):
     """``new`` [B, S, width] written in place into layer ``li`` of the
-    stacked buffer ``name`` at each slot's ``positions[b] + s``; in the
-    window ring modulo its rows, a token at a time (a block write does
-    not wrap) → the cache with that buffer replaced."""
+    stacked buffer ``name`` at each row's ``positions[b] + s`` (rows,
+    ``slots`` and ``counts`` as in :func:`_cwrite_rows`); in the window
+    ring modulo its rows → the cache with that buffer replaced. A block
+    write does not wrap, so a step's few tokens go into the ring one at
+    a time, and a prefill chunk (``counts`` given) in two pieces: the
+    rows that fit before the ring's end, then the rest from row 0."""
     buf = cache[name]
+    put = partial(
+        _cwrite_rows, layer=li, write_mask=write_mask, axis=0, unroll=True,
+        slots=slots,
+    )
     if name != "win":
-        buf = _cwrite_rows(buf, li, positions, write_mask, new, axis=0, unroll=True)
-    else:
+        buf = put(buf, positions=positions, new=new, counts=counts)
+    elif counts is None:
         for j in range(new.shape[1]):
-            buf = _cwrite_rows(
-                buf, li, jnp.mod(positions + j, buf.shape[2]), write_mask,
-                new[:, j : j + 1], axis=0, unroll=True,
+            buf = put(
+                buf, positions=jnp.mod(positions + j, buf.shape[2]),
+                new=new[:, j : j + 1],
             )
+    else:
+        s = new.shape[1]
+        first = jnp.mod(positions, buf.shape[2])
+        fit = buf.shape[2] - first  # rows up to the ring's end; past it they drop
+        buf = put(buf, positions=first, new=new, counts=counts)
+        rest = jnp.take_along_axis(
+            new, jnp.clip(fit[:, None] + jnp.arange(s)[None, :], 0, s - 1)[..., None],
+            axis=1,
+        )
+        buf = put(
+            buf, positions=jnp.zeros_like(first), new=rest,
+            counts=jnp.clip(counts - fit, 0, s),
+        )
     return {**cache, name: buf}
 
 
@@ -1001,10 +986,7 @@ def _decode_step_mla(
             o = llama.head_gate(o, h, layer, gc, "bth,bhv->bhv")
             o = o.reshape(b, 1, gc.o_dim)
         ao = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-        x, stats = _mlp_counted(
-            (x, cache.get("moe_stats")), ao, layer, c, valid
-        )
-        return x, (cache if stats is None else {**cache, "moe_stats": stats})
+        return _mlp_counted(x, cache, ao, layer, c, valid)
 
     x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
@@ -1086,10 +1068,7 @@ def _verify_step_mla(
             o = llama.head_gate(o, h, layer, gc, "bsh,bshv->bshv")
             o = o.reshape(b, sdraft, gc.o_dim)
         ao = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-        x, stats = _mlp_counted(
-            (x, cache.get("moe_stats")), ao, layer, c, valid
-        )
-        return x, (cache if stats is None else {**cache, "moe_stats": stats})
+        return _mlp_counted(x, cache, ao, layer, c, valid)
 
     x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
@@ -1117,9 +1096,12 @@ def prefill(
 
 
 def _scan_layers_kv(params: dict, cache: dict, x: jax.Array, one_layer, c):
-    """Drive ``one_layer(x, layer, ck, cv, window, nope) -> (x, ck, cv)``
-    over the grouped scan layout (static per-layer windows / NoPE flags
-    ride the unrolled group; see :func:`llama.grouped_scan_layout`) →
+    """Drive ``one_layer(x, ck, cv, layer, li, window, nope) -> (x, ck,
+    cv)`` over the grouped scan layout (static per-layer windows / NoPE
+    flags ride the unrolled group; see :func:`llama.grouped_scan_layout`)
+    with the STACKED cache buffers ``ck`` / ``cv`` as the carry of the
+    scan and of its unrolled tail alike: layer ``li`` writes its chunk
+    into the donated buffers in place and reads its rows from them →
     (final hidden, updated cache). ONE copy of the scan/tail plumbing
     shared by the chunked and packed prefill forms, so a layout change
     cannot silently diverge them."""
@@ -1129,50 +1111,30 @@ def _scan_layers_kv(params: dict, cache: dict, x: jax.Array, one_layer, c):
         sublayer,
     )
 
-    ck_p, cv_p = _cache_pack(cache)
-    g, windows, xs_main, xs_tail = grouped_scan_layout(
-        c, {"layer": params["layers"], "ck": ck_p, "cv": cv_p}
-    )
+    g, windows, xs_main, xs_tail = grouped_scan_layout(c, params["layers"])
     nopes = layer_nope(c)
+    n_main = c.n_layers - (c.n_layers % g if g > 1 else 0)
 
-    def group_fn(x, group):
-        cks, cvs = [], []
+    def group_fn(carry, group_and_ix):
+        group, gi = group_and_ix
         for i in range(g):
-            sub = sublayer(group, i, g)
-            x, ck, cv = one_layer(
-                x, sub["layer"], sub["ck"], sub["cv"], windows[i], nopes[i]
+            carry = one_layer(
+                *carry, sublayer(group, i, g), gi * g + i, windows[i], nopes[i]
             )
-            cks.append(ck)
-            cvs.append(cv)
-        if g == 1:
-            return x, (cks[0], cvs[0])
-        return x, (_tree_stack(cks), _tree_stack(cvs))
+        return carry, None
 
-    x, (ks, vs) = jax.lax.scan(group_fn, x, xs_main)
-    r = c.n_layers % g if g > 1 else 0
-    unflat = lambda t: jax.tree.map(
-        lambda a: a.reshape((c.n_layers - r,) + a.shape[2:]), t
+    carry, _ = jax.lax.scan(
+        group_fn, (x, *_cache_pack(cache)), (xs_main, jnp.arange(n_main // g))
     )
-    if g > 1:  # [L'/g, g, ...] → [L', ...]
-        ks, vs = unflat(ks), unflat(vs)
-    if xs_tail is not None:
-        # pattern doesn't divide the layer count (Gemma3): unroll the
-        # last r layers after the scan and append their cache rows
-        tks, tvs = [], []
-        for j in range(r):
-            sub = jax.tree.map(lambda a: a[j], xs_tail)
-            x, ck, cv = one_layer(
-                x, sub["layer"], sub["ck"], sub["cv"],
-                windows[c.n_layers - r + j], nopes[c.n_layers - r + j],
-            )
-            tks.append(ck)
-            tvs.append(cv)
-        cat = lambda a, t: jax.tree.map(
-            lambda x1, x2: jnp.concatenate([x1, x2], axis=0), a, t
+    # pattern doesn't divide the layer count (Gemma3): unroll the last
+    # layers after the scan
+    for j in range(n_main, c.n_layers):
+        carry = one_layer(
+            *carry, jax.tree.map(lambda a: a[j - n_main], xs_tail), j,
+            windows[j], nopes[j],
         )
-        ks = cat(ks, _tree_stack(tks))
-        vs = cat(vs, _tree_stack(tvs))
-    return x, _cache_unpack(ks, vs)
+    x, ck, cv = carry
+    return x, _cache_unpack(ck, cv)
 
 
 def _prefill_one_layer(
@@ -1181,7 +1143,7 @@ def _prefill_one_layer(
     *,
     rope_apply,  # (t [B, Hh, C, D], cos, sin) → roped t
     temp_apply,  # (q) → NoPE-temperature-scaled q (Llama4)
-    kv_update,  # (ck, cv, k, v [B, Hkv, C, D]) → (ck, cv, row_k, row_v)
+    kv_update,  # (ck, cv, li, k, v [B, Hkv, C, D]) → (ck, cv, row_k, row_v)
     q_offset,  # static int (serial chunk) or [B] vector (packed)
     mesh=None,  # tp mesh: the flash kernel runs per KV-head shard
 ):
@@ -1197,8 +1159,8 @@ def _prefill_one_layer(
 
     scale = c.attention_scale
 
-    def one_layer(x, layer, ck, cv, window, nope):
-        # ck/cv [B_pool, Hkv, Tmax, D] — this layer's cache
+    def one_layer(x, ck, cv, layer, li, window, nope):
+        # ck/cv [L, B_pool, Hkv, Tmax, D]: the stacked cache, this layer li
         b, cl = x.shape[0], x.shape[1]
         cos, sin = layer_rope(ropes, c, window)
         h = (
@@ -1222,7 +1184,7 @@ def _prefill_one_layer(
         # write the chunk K/V into the slot rows, then attend over the
         # whole rows: positions past each causal frontier are masked,
         # so stale data beyond the prompts is never read
-        ck, cv, row_k, row_v = kv_update(ck, cv, k, v)
+        ck, cv, row_k, row_v = kv_update(ck, cv, li, k, v)
         o = attention(
             q, row_k, row_v, causal=True, scale=scale, q_offset=q_offset,
             window=window, softcap=c.attn_softcap,
@@ -1287,10 +1249,14 @@ def prefill_chunk_step(
     chunk_pos = start + jnp.arange(tokens.shape[1])
     si = slot.astype(jnp.int32)
 
-    def kv_update(ck, cv, k, v):
-        ck = _cwrite_chunk(ck, k, si, start)
-        cv = _cwrite_chunk(cv, v, si, start)
-        return ck, cv, _cread_row(ck, si, k.dtype), _cread_row(cv, si, v.dtype)
+    def kv_update(ck, cv, li, k, v):
+        ck = _cwrite_chunk(ck, li, si, start, _cstored(k, ck))
+        cv = _cwrite_chunk(cv, li, si, start, _cstored(v, cv))
+        return (
+            ck, cv,
+            _own_rows(_cread_rows(ck, li, si[None], k.dtype)),
+            _own_rows(_cread_rows(cv, li, si[None], v.dtype)),
+        )
 
     one_layer = _prefill_one_layer(
         c, dual_rope_freqs(c, chunk_pos),
@@ -1322,8 +1288,9 @@ def _prefill_packed_mla(
     c: LlamaConfig,
 ) -> tuple[jax.Array, dict]:
     """MLA packed prefill: G concurrent prompt chunks write their
-    latents into their own ``ckv`` rows (masked scatter) and attend in
-    the absorbed MQA form with per-row causal frontiers."""
+    latents into their own ``ckv`` rows (in-place block writes of the
+    real tokens) and attend in the absorbed MQA form with per-row
+    causal frontiers."""
     from dstack_tpu.models.llama import dual_rope_freqs
     from dstack_tpu.ops.attention import attention
 
@@ -1335,25 +1302,23 @@ def _prefill_packed_mla(
         dual_rope_freqs(c, pos_grid.reshape(-1)),
     )
     si = slots.astype(jnp.int32)
-    # positions past each row's real tokens (padding, pad rows) scatter
-    # out of range and drop — the masked-future invariant
+    # positions past each row's real tokens (padding, pad rows) keep
+    # their bytes — the masked-future invariant
     valid = jnp.arange(cl)[None, :] <= last_ix[:, None]  # [G, C]
-    write_at = {"ckv": jnp.where(valid, pos_grid, cache["ckv"].shape[2])}
-    if "win" in cache:  # a window ring takes position p at row p % rows
-        ring = cache["win"].shape[2]
-        write_at["win"] = jnp.where(valid, jnp.mod(pos_grid, ring), ring)
-    stats = jnp.zeros((2,), jnp.int32) if "moe_stats" in cache else None
+    write = partial(
+        _stacked_write, positions=starts, write_mask=last_ix >= 0, slots=si,
+        counts=last_ix + 1,
+    )
 
-    def one_layer(xc, layer, rows, run):
-        # rows: this layer's slice [B_pool, T, width] of each buffer of
-        # its group (latents, and index keys where an indexer bites)
+    def one_layer(x, layer, cache, li, run):
+        # cache: the stacked buffers; this layer is row li of its group's
+        # (latents, and index keys where an indexer bites)
         gc = run.config
         cos, sin = llama.layer_rope(ropes, c, run.window)
         # MLA rope is always interleaved
         rope_rows = lambda t: _rope_rows(t, cos, sin, interleaved=True)
         name = "win" if run.window else "ckv"
-        tmax, write_pos = rows[name].shape[1], write_at[name]
-        x = xc[0]
+        tmax = cache[name].shape[2]
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
         q, qa = _mla_q(h, layer, gc)  # [G, H, C, qk_head_dim]
         q_nope = q[..., : gc.qk_nope_head_dim]
@@ -1361,11 +1326,8 @@ def _prefill_packed_mla(
         ckv, k_pe = _mla_latents(h, layer, gc)  # [G,C,rank], [G,C,rope]
         k_pe = rope_rows(k_pe[:, None])[:, 0]  # [G, C, rope]
         new_rows = jnp.concatenate([ckv, k_pe], axis=-1)  # [G, C, R]
-        row_cache = rows[name].at[si[:, None], write_pos].set(
-            new_rows, mode="drop"
-        )
-        out = {name: row_cache}
-        row = jnp.take(row_cache, si, axis=0)  # [G, Tmax, R]
+        cache = write(cache, name, li, new=new_rows)
+        row = _cread_rows(cache[name], li, si, c.dtype)  # [G, Tmax, R]
         w_kb_nope, w_kb_v = _mla_kb(layer, gc)
         q_lat = jnp.einsum("bhcn,rhn->bhcr", q_nope, w_kb_nope)
         q_abs = jnp.concatenate([q_lat, q_pe], axis=-1)  # [G, H, C, R]
@@ -1374,13 +1336,11 @@ def _prefill_packed_mla(
             mask = _ring_mask(
                 pos_grid, starts + jnp.maximum(last_ix, 0), tmax, run.window
             )
-        elif "idx" in rows:
+        elif "idx" in cache:
             q_i, k_i, w_i = llama.index_qkw(h, qa, layer, gc, rope_rows)
-            out["idx"] = rows["idx"].at[si[:, None], write_pos].set(
-                k_i, mode="drop"
-            )
+            cache = write(cache, "idx", li, new=k_i)
             mask = llama.index_select(
-                q_i, w_i, jnp.take(out["idx"], si, axis=0),
+                q_i, w_i, _cread_rows(cache["idx"], li, si, c.dtype),
                 jnp.arange(tmax)[None, None, :] <= pos_grid[:, :, None],
                 gc.index_topk,
             )
@@ -1403,16 +1363,14 @@ def _prefill_packed_mla(
             o = llama.head_gate(o, h, layer, gc, "bch,bchv->bchv")
             o = o.reshape(g, cl, gc.o_dim)
         ao = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-        return _mlp_counted(xc, ao, layer, c, valid), out
+        return _mlp_counted(x, cache, ao, layer, c, valid)
 
-    (x, stats), bufs = _mla_scan(params, cache, (x, stats), one_layer, c)
-    if stats is not None:
-        bufs["moe_stats"] = cache["moe_stats"] + stats
+    x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     last = jnp.take_along_axis(
         x, jnp.maximum(last_ix, 0)[:, None, None].astype(jnp.int32), axis=1
     )[:, 0]
-    return _head_logits(params, last, c), bufs
+    return _head_logits(params, last, c), cache
 
 
 def prefill_packed_step(
@@ -1432,9 +1390,10 @@ def prefill_packed_step(
     form :func:`verify_step` uses at decode width S, here at prefill
     width C): a burst of N arrivals costs ceil(N/G) dispatches per
     chunk wave instead of N batch-1 passes that underfill the MXU.
-    Per-row rope angles come from the position grid, cache writes use
-    the ``mode="drop"`` scatter so short rows and inactive pad rows
-    (``last_ix = -1``) mask out, and attention gets per-row causal
+    Per-row rope angles come from the position grid, cache writes are
+    in-place block writes (:func:`_cwrite_rows`) that leave out what
+    short rows and inactive pad rows (``last_ix = -1``) have past their
+    real tokens, and attention gets per-row causal
     frontiers via the vector ``q_offset`` (masked-einsum path — the
     pallas kernel can't tile per-row offsets). Because ``starts`` is
     traced, ONE compile per (G, C) shape serves every start
@@ -1457,22 +1416,26 @@ def prefill_packed_step(
         dual_rope_freqs(c, pos_grid.reshape(-1)),
     )
     si = slots.astype(jnp.int32)
-    tmax = cache["k"].shape[3]
-    # positions past each row's real tokens (padding, pad rows) scatter
-    # out of range and drop — the masked-future invariant
-    valid = jnp.arange(cl)[None, :] <= last_ix[:, None]  # [G, C]
-    write_pos = jnp.where(valid, pos_grid, tmax)
     temp = (
         attn_temp_scales(pos_grid.reshape(-1), c).reshape(g, cl)
         if c.attn_temp_scale else None
     )
 
-    def kv_update(ck, cv, k, v):
-        # scatter each row's chunk K/V at its own positions, then
-        # gather the packed rows for attention
-        ck = _cwrite_at(ck, si, write_pos, k.transpose(0, 2, 1, 3))
-        cv = _cwrite_at(cv, si, write_pos, v.transpose(0, 2, 1, 3))
-        return ck, cv, _cread_rows(ck, si, k.dtype), _cread_rows(cv, si, v.dtype)
+    def kv_update(ck, cv, li, k, v):
+        # each row's real tokens go in at its own start; positions past
+        # them (padding, pad rows) and past the cache's end keep their
+        # bytes — the masked-future invariant
+        # (a leaf with its tokens on the lanes is re-laid out whole a
+        # layer under the rows' loop, like the latent: its rows go unrolled)
+        put = partial(
+            _cwrite_rows, layer=li, positions=starts, write_mask=last_ix >= 0,
+            slots=si, counts=last_ix + 1, unroll=_tokens_on_lanes(c.head_dim),
+        )
+        ck, cv = put(ck, new=_cstored(k, ck)), put(cv, new=_cstored(v, cv))
+        return (
+            ck, cv,
+            _cread_rows(ck, li, si, k.dtype), _cread_rows(cv, li, si, v.dtype),
+        )
 
     one_layer = _prefill_one_layer(
         c, ropes,

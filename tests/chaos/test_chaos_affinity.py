@@ -20,6 +20,7 @@ Three invariants, per the issue's acceptance bar:
 """
 
 import asyncio
+import dataclasses
 import json
 import time
 
@@ -142,11 +143,11 @@ class _Router:
 
 
 async def _serving_stack(
-    n=2, max_batch=4, max_seq=1024, prefill_chunk=32
+    n=2, max_batch=4, max_seq=1024, prefill_chunk=32, n_layers=2
 ):
     """n REAL replicas (same tiny model + params) behind a logging
     router → (client, servers, engines, router)."""
-    config = llama.LLAMA_TINY
+    config = dataclasses.replace(llama.LLAMA_TINY, n_layers=n_layers)
     params = llama.init_params(config, jax.random.key(0))
     servers, engines = [], []
     for _ in range(n):
@@ -215,8 +216,12 @@ def _turn_text(i: int, t: int) -> str:
 class TestSessionStickinessAndWarmTTFT:
     async def test_warm_turns_stick_and_beat_the_control(self):
         """Acceptance (1): same-session turns land on one replica and
-        warm-turn TTFT p50 beats affinity-off by ≥ 1.3×."""
-        client, servers, engines, router = await _serving_stack()
+        warm-turn TTFT p50 beats affinity-off by ≥ 1.3×. Eight layers,
+        so that a prompt chunk costs something beside a request's fixed
+        ~50 ms on the CPU: since the prefill programs stopped copying
+        the whole cache a chunk (PR 29), two layers' chunks cost a third
+        of what they did and reuse saved 12 ms of 70, not 38 of 100."""
+        client, servers, engines, router = await _serving_stack(n_layers=8)
         pool = router.pool
         sessions, turns = 3, 3
         try:

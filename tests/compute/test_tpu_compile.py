@@ -395,7 +395,7 @@ def test_window_cache_argument_bytes_do_not_follow_max_seq(topo):
 
 
 # ---------------------------------------------------------------------------
-# the decode programs update the donated cache in place: no second cache,
+# the serving programs update the donated cache in place: no second cache,
 # no layer's slice copied out and back (PERF.md §6 PR 25). Two thirds of
 # the decode program's device time was such copies, and no CPU test can
 # see them: they are the TPU compiler's answer to a one-token write into
@@ -452,34 +452,84 @@ def _cache_sized_moves(hlo: str, stacked: set, layer: set) -> list:
     return found
 
 
-def _decode_program(case, sds, place):
-    """(fn, args, cache) of one decode program at a cell's shapes."""
+def _serving_program(case, sds, place):
+    """(fn, args, cache) of one serving program at a cell's shapes:
+    ``<program>[@<start | G>]-<model>``."""
     b = 16
     i32 = lambda *shape: sds(shape, jnp.int32)
     mask = sds((b,), jnp.bool_)
-    if case == "decode_loop-deepseek_v2_lite_9l":
-        config = dataclasses.replace(llama.DEEPSEEK_V2_LITE, n_layers=9)
-        max_seq, kv_quant = 8192, None
-    elif case == "decode_step-int8kv-llama_1b":
-        config, max_seq, kv_quant = llama.LLAMA_32_1B, 2048, "int8"
-    else:
-        config, max_seq, kv_quant = llama.MINITRON_4B, 1536, None
+    program, model = case.split("-", 1)
+    program, _, at = program.partition("@")
+    config, max_seq, kv_quant = {
+        "minitron_4b": (llama.MINITRON_4B, 1536, None),
+        "deepseek_v2_lite_9l": (
+            dataclasses.replace(llama.DEEPSEEK_V2_LITE, n_layers=9), 8192, None
+        ),
+        "int8kv-llama_1b": (llama.LLAMA_32_1B, 2048, "int8"),
+        "llama_1b": (llama.LLAMA_32_1B, 2048, None),
+        "layer_groups": (None, 8192, None),
+    }[model]
+    config = config or _layer_groups_config()
     params = place(jax.eval_shape(
         lambda: llama.init_params(config, jax.random.key(0))
     ))
     cache = place(jax.eval_shape(
         lambda: eng.init_cache(config, b, max_seq, kv_quant=kv_quant)
     ))
-    if case.startswith("decode_loop"):
+    if program == "decode_loop":
         fn = lambda p, c, t, pos, rem, act, eos: eng.decode_loop(
             p, c, t, pos, rem, act, eos, config, steps=8, max_seq=max_seq
         )
         return fn, (params, cache, i32(b), i32(b), i32(b), mask, i32(b)), cache
-    if case.startswith("verify_step"):
+    if program == "verify_step":
         fn = lambda p, c, t, pos, m: eng.verify_step(p, c, t, pos, config, m)
         return fn, (params, cache, i32(b, 5), i32(b), mask), cache
+    if program == "prefill_chunk_step":  # a 256-token chunk, or a short prompt's 16
+        cl, start = (16, 0) if at == "short" else (256, int(at))
+        fn = lambda p, c, t, s, li: eng.prefill_chunk_step(
+            p, c, t, s, li, config, start=start
+        )
+        return fn, (params, cache, i32(1, cl), i32(), i32()), cache
+    if program == "prefill_packed_step":
+        g = int(at)
+        fn = lambda p, c, t, s, st, li: eng.prefill_packed_step(
+            p, c, t, s, st, li, config
+        )
+        return fn, (params, cache, i32(g, 256), i32(g), i32(g), i32(g)), cache
     fn = lambda p, c, t, pos, m: eng.decode_step(p, c, t, pos, config, m)
     return fn, (params, cache, i32(b), i32(b), mask), cache
+
+
+def _holds_no_second_cache(
+    topo, case, layer_of=lambda name: True, room: float = 0.0, known: int = 0
+):
+    """Compile ``case`` the way the engine jits it (cache donated) and
+    assert that no operation copies, pads, concatenates or slices a
+    stacked cache leaf (but ``known`` whole-leaf copies, where the
+    compiler still makes them), that no layer's slice of a leaf that
+    ``layer_of`` names is materialized outside a fusion, and that
+    ``temp`` stays under a quarter of the cache plus ``room`` bytes of
+    attention scores."""
+    sharding = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    fn, args, cache = _serving_program(case, sds, place)
+    compiled = _compile(fn, *args, donate_argnums=(1,))
+    stacked = {leaf.shape for leaf in jax.tree.leaves(cache) if leaf.ndim > 1}
+    layer = {
+        sh for name, leaf in cache.items() if leaf.ndim > 1 and layer_of(name)
+        for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])
+    }
+    moves = _cache_sized_moves(compiled.as_text(), stacked, layer)
+    assert len(moves) <= known and not any("slice" in m for m in moves), moves
+    cache_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache)
+    )
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.25 * cache_bytes + room, (
+        f"temp {temp / 1e9:.2f} GB beside a cache of {cache_bytes / 1e9:.2f} GB"
+    )
+    _fits(compiled)
 
 
 @pytest.mark.parametrize("case", [
@@ -489,32 +539,64 @@ def _decode_program(case, sds, place):
     "decode_step-int8kv-llama_1b",  # (int8, scale) leaves, head_dim 64
 ])
 def test_decode_program_holds_no_second_cache(topo, case):
-    """Compiled the way the engine jits them (cache donated). Parent
-    readings, ``temp`` / cache: decode_step 3.32 / 3.22 GB, decode_loop
-    3.06 / 1.36 GB, verify_step 3.26 / 3.22 GB, int8 0.79 / 0.57 GB."""
-    sharding = SingleDeviceSharding(topo.devices[0])
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-    fn, args, cache = _decode_program(case, sds, place)
-    compiled = _compile(fn, *args, donate_argnums=(1,))
-    stacked = {leaf.shape for leaf in jax.tree.leaves(cache)}
-    # a dense layer's slice of K, V or their scales, as the scan's xs
-    # handed it over; the latent's per-layer slice is still copied once
-    # a layer (151 MB at these shapes: the two latent einsums will not
-    # take a fused slice)
-    layer = {
-        sh for name, leaf in cache.items() if name != "ckv"
-        for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])
-    }
-    assert not _cache_sized_moves(compiled.as_text(), stacked, layer)
-    cache_bytes = sum(
-        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache)
-    )
-    temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 0.25 * cache_bytes, (
-        f"temp {temp / 1e9:.2f} GB beside a cache of {cache_bytes / 1e9:.2f} GB"
-    )
-    _fits(compiled)
+    """Parent readings (PR 24's tree), ``temp`` / cache: decode_step
+    3.32 / 3.22 GB, decode_loop 3.06 / 1.36 GB, verify_step 3.26 / 3.22
+    GB, int8 0.79 / 0.57 GB. The latent's per-layer slice is still
+    copied once a layer (151 MB at these shapes: the two latent einsums
+    will not take a fused slice)."""
+    _holds_no_second_cache(topo, case, layer_of=lambda name: name != "ckv")
+
+
+def _scores(g, heads, max_seq, c=256):
+    """Bytes of one float32 score a query a key: what a packed wave's
+    masked-einsum attention holds beside the cache (twice on the latent:
+    scores and probabilities)."""
+    return 4.0 * g * heads * c * max_seq
+
+
+# case → (room for attention scores, whole-leaf copies the compiler still makes)
+_PREFILL = {
+    # the chat cell: 16 × 1536, cache 3.22 GB
+    "prefill_chunk_step@0-minitron_4b": (0, 0),
+    "prefill_chunk_step@256-minitron_4b": (0, 0),
+    "prefill_packed_step@2-minitron_4b": (0, 0),
+    "prefill_packed_step@4-minitron_4b": (0, 0),
+    # reasoning: 16 × 8192, latent 1.36 GB, its tokens on the lanes
+    "prefill_chunk_step@0-deepseek_v2_lite_9l": (0, 0),
+    "prefill_chunk_step@256-deepseek_v2_lite_9l": (0, 0),
+    "prefill_packed_step@2-deepseek_v2_lite_9l": (2 * _scores(2, 16, 8192), 0),
+    "prefill_packed_step@4-deepseek_v2_lite_9l": (2 * _scores(4, 16, 8192), 0),
+    # longdoc: ckv, idx and the ring, 0.45 GB; a row's index scores are
+    # 64 heads × 256 × 8192. A wave of ONE row still has its ckv and win
+    # leaves re-laid out around the layers (parent: 18 moves, temp 0.90 GB)
+    "prefill_packed_step@1-layer_groups": (_scores(1, 64, 8192), 6),
+    "prefill_packed_step@4-layer_groups": (_scores(1, 64, 8192), 0),
+    # (int8, scale) leaves, head_dim 64; a short prompt's 16 rows are
+    # half an int8 tile
+    "prefill_chunk_step@short-int8kv-llama_1b": (0, 0),
+    "prefill_chunk_step@256-int8kv-llama_1b": (0, 0),
+    # head_dim 64 in bf16: tokens on the lanes (rows own their buffer,
+    # a wave's rows go unrolled)
+    "prefill_chunk_step@256-llama_1b": (0, 0),
+    "prefill_packed_step@4-llama_1b": (_scores(4, 32, 2048), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PREFILL))
+def test_prefill_program_holds_no_second_cache(topo, _as_tpu, case):
+    """The four prefill programs on the chip's own paths (the flash
+    kernel under a serial chunk). Parent readings (PR 27's tree),
+    ``temp`` / cache in GB and cache-sized moves: Minitron-4B chunk
+    3.23 / 3.22 (8), packed G 4 3.73 / 3.22 (10); V2-Lite 9 layers
+    chunk 2.87 / 1.36 (9), packed 4.04 / 1.36 (7); layer groups G 1
+    0.90 / 0.45 (18), G 4 1.12 / 0.45 (13); int8 Llama-3.2-1B chunk
+    0.58 / 0.57 (22); bf16 Llama-3.2-1B chunk 1.22 / 1.07 (13), packed
+    1.75 / 1.07 (4). Since PR 29: chunk steps 0.4-5 MB, packed waves
+    their scores. Left: a short prompt's 16-row bucket on a bf16
+    head_dim-64 cache (16 of a tile's 128 lanes) is still re-laid out
+    whole around the loop (temp 2.15 / 1.07 GB; not a case here)."""
+    room, known = _PREFILL[case]
+    _holds_no_second_cache(topo, case, room=room, known=known)
 
 
 # ---------------------------------------------------------------------------

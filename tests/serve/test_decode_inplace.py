@@ -6,8 +6,9 @@ tile-aligned block (``engine._cwrite_rows``) instead of handing a
 layer's slice through ``lax.scan`` as xs → ys. What the TPU compiler
 makes of that is ``tests/compute/test_tpu_compile.py``'s to check; here,
 on the CPU at small widths: the logits are the full forward's, the
-cache holds what the scatter form (``engine._cwrite_at``, the parent's
-write) would have put there, and nothing else moved.
+cache holds what the scatter form (``.at[].set(mode="drop")``, the
+write of the tree before PR 25) would have put there, and nothing else
+moved.
 """
 
 import dataclasses
@@ -240,8 +241,8 @@ def test_verify_step_leaves_the_rows_of_s_decode_steps(model):
 @pytest.mark.parametrize("leaf", ["values", "int8-pair", "latent"])
 @pytest.mark.parametrize("span", ["one-layer", "all-layers"])
 def test_block_write_is_the_scatter_write(span, leaf, rows):
-    """``_cwrite_rows`` on the stacked buffer against the parent's
-    per-layer ``_cwrite_at`` scatter (out-of-range index → dropped), bit
+    """``_cwrite_rows`` on the stacked buffer against the per-layer
+    scatter it replaced (out-of-range index → dropped), bit
     for bit, for one layer inside a scan (verify, the latent) and for
     all layers after it (decode): every position class in one batch (a
     tile's first and last row, the clamped last block, the end of the
@@ -273,9 +274,14 @@ def test_block_write_is_the_scatter_write(span, leaf, rows):
                 jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
                 jnp.asarray(rng.uniform(0.01, 1.0, shape[:-1]), jnp.float32),
             )
-        scatter = lambda layer, rows_: eng._cwrite_at(
-            layer, batch_ix, write_pos, rows_.transpose(0, 2, 1, 3)
-        )
+        def scatter(layer, rows_):  # leaf-wise: values, or the (int8, scale) pair
+            return jax.tree.map(
+                lambda a, n: a.at[batch_ix[:, None], :, write_pos].set(
+                    jnp.moveaxis(n, 2, 1), mode="drop"
+                ),
+                layer, eng._cstored(rows_, layer),
+            )
+
         axis = 1
     got = eng._cwrite_rows(
         buf, first, jnp.asarray(pos), jnp.asarray(mask),

@@ -19,15 +19,21 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-#: taken on the tree of PR 26 (before issue 27 moved a line)
+#: the three decode programs: taken on the tree of PR 26 (before issue 27
+#: moved a line) and untouched since, which is the proof that PR 29 left
+#: the decode programs alone. The four prefill programs: taken again on
+#: the tree of PR 29, which moved their cache from the layer scan's
+#: xs → ys into its carry on purpose (the sameness tests of
+#: ``test_prefill_inplace.py`` passed first; PR 28, refused for a claim
+#: and not for its code, had taken the same four digests)
 PINS = {
     "decode_step": "b04eb128c0bfb96c3389b5cded7a0831fa4ba5700abb8e9788173e7be03fe4cd",
     "decode_loop": "719adef2066bfc901d7883b59313e6b3a3f6ea3ecc737452f5581eaaa7a1d789",
     "verify_step": "56ce65d4985c8d8a942b813a4931f5675c2781f9f269a6a146a51d9618960d38",
-    "prefill_chunk_step@0": "e5d23923902a9c0614a8e42b7a29e6b57586b14c599658ae379b0a56fbbfbea7",
-    "prefill_chunk_step@256": "fc32793b3df648bd37f321b3b594717a7da87d67145803d109db7fa0550ba98c",
-    "prefill_packed_step@2": "f3273f59041fe02b51e2c8ad9064e69bfa2e9240839ce8b8c593e0014bdd52ed",
-    "prefill_packed_step@4": "c9b09add9c2176e7e593ee2507783aa656d14147b23e767129557b4c127f3b8f",
+    "prefill_chunk_step@0": "288d0834c3cce37700920d780da73acb7143ca300bd0939f209fe17830e6226e",
+    "prefill_chunk_step@256": "69a3129b1016f7d17ad1a984fce1a54d0253457d1fb07c92cfef418748ea8638",
+    "prefill_packed_step@2": "ec960d7ac9100619ad9f9353a2603d8ed7b0379497310d36c539bad7ff5bedcd",
+    "prefill_packed_step@4": "12148c36bc0cf3d7115f858f3c7990cb1a1d049d7f6a349e1b7b82afcc89d730",
 }
 
 
