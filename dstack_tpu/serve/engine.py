@@ -1884,18 +1884,26 @@ def sample(
     gen_counts: jax.Array,  # [B, V] int32: occurrences in GENERATED text
     logit_bias=None,  # [B, V] f32 additive bias (None = off)
     min_p=None,  # [B] f32: drop tokens with p < min_p·p_max (None = off)
+    live=None,  # [B] bool: rows whose token is used (None = all)
 ) -> tuple[jax.Array, jax.Array]:
     """→ (tokens [B], advanced key_data). Greedy when temperature == 0,
-    else penalized temperature/top-k/top-p sampling — all branches
-    computed, selected per slot (static shapes). Per-slot keys make a
-    request's stream deterministic under its ``seed`` regardless of
-    which other slots are active.
+    else penalized temperature/top-k/top-p/min-p sampling, selected per
+    slot (static shapes). The filters (:func:`_filter_logits`: min-p's
+    softmax, the [B, V] sort, the sorted softmax and cumsum) run only
+    on a call where some ``live`` row set top-k, top-p or min-p —
+    one ``lax.cond`` on what the call's own inputs say; otherwise the
+    draw is from the temperature-scaled logits as they stand, which is
+    what the filters leave of them when every row has them off. A dead
+    row keeps its last request's values, so it is masked out of the
+    decision (its token is never read). Per-slot keys make a request's
+    stream deterministic under its ``seed`` regardless of which other
+    slots are active, and the key evolution is the same on both
+    branches.
 
     Penalty scopes follow their ecosystems: the HF-style multiplicative
     repetition penalty sees prompt + generated tokens, while OpenAI's
     additive presence/frequency penalties count only SAMPLED tokens
     (a long prompt must not pre-ban its own vocabulary)."""
-    v = logits.shape[-1]
     if logit_bias is not None:
         logits = logits + logit_bias  # OpenAI bias: pre-everything
     seen = counts > 0
@@ -1909,6 +1917,29 @@ def sample(
     logits = logits - freq_pen[:, None] * gen_counts.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1)
     scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    asked = (top_k > 0) | (top_p < 1.0)
+    if min_p is not None:
+        asked |= min_p > 0.0
+    if live is not None:
+        asked &= live
+    masked = jax.lax.cond(
+        jnp.any(asked),
+        lambda s: _filter_logits(s, top_p, top_k, min_p),
+        lambda s: s,
+        scaled,
+    )
+    keys = jax.vmap(jax.random.wrap_key_data)(key_data)
+    splits = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # [B, 2]
+    sampled = jax.vmap(jax.random.categorical)(splits[:, 1], masked)
+    tokens = jnp.where(temperature <= 0.0, greedy, sampled)
+    return tokens, jax.vmap(jax.random.key_data)(splits[:, 0])
+
+
+def _filter_logits(scaled, top_p, top_k, min_p):
+    """Temperature-scaled logits [B, V] → the same with every token a
+    row's min-p / top-k / top-p excludes at NEG_INF; a row with all
+    three off comes back as it went in. All rows pay the sort."""
+    v = scaled.shape[-1]
     if min_p is not None:
         # min-p (applied before top-k/top-p): relative-probability floor
         probs_mp = jax.nn.softmax(scaled, axis=-1)
@@ -1916,8 +1947,9 @@ def sample(
         scaled = jnp.where(
             (min_p[:, None] <= 0.0) | (probs_mp >= floor), scaled, NEG_INF
         )
-    # ONE [B, V] descending sort serves both filters — at a 128k vocab
-    # the sort dominates per-token sampling cost
+    # ONE [B, V] descending sort serves top-k and top-p — at a 128k
+    # vocab it dominates per-token sampling cost, which is why
+    # :func:`sample` calls this only when a live row asked for a filter
     sorted_full = jnp.sort(scaled, axis=-1)[:, ::-1]
     # top-k: drop everything below the k-th largest logit (ties at the
     # k-th value survive, HF TopKLogitsWarper semantics)
@@ -1943,12 +1975,7 @@ def sample(
     cutoff_ix = jnp.argmax(cumulative >= top_p[:, None], axis=-1)
     cutoff = jnp.take_along_axis(sorted_logits, cutoff_ix[:, None], axis=-1)
     masked = jnp.where(scaled >= cutoff, scaled, NEG_INF)
-    masked = jnp.where(top_p[:, None] >= 1.0, scaled, masked)
-    keys = jax.vmap(jax.random.wrap_key_data)(key_data)
-    splits = jax.vmap(lambda k: jax.random.split(k, 2))(keys)  # [B, 2]
-    sampled = jax.vmap(jax.random.categorical)(splits[:, 1], masked)
-    tokens = jnp.where(temperature <= 0.0, greedy, sampled)
-    return tokens, jax.vmap(jax.random.key_data)(splits[:, 0])
+    return jnp.where(top_p[:, None] >= 1.0, scaled, masked)
 
 
 def skip_key_data(kd: jax.Array, n) -> jax.Array:
@@ -2755,6 +2782,7 @@ class InferenceEngine:
         sp = self._sampling_params()
         temps, top_ps, top_ks, rep_pens, pres_pens, freq_pens, min_ps = sp
         row = slice(slot, slot + 1)
+        self._count_sample([slot])
         toks, kd = self._sample(
             logits,
             self._key_data[row],
@@ -3232,6 +3260,19 @@ class InferenceEngine:
             for i in live
         )
 
+    def _count_sample(self, rows: list) -> None:
+        """One :func:`sample` call over slots ``rows``: the same
+        decision the program takes on the device (does any of them set
+        top-k, top-p or min-p, so that the call sorts the vocabulary),
+        from the host lists."""
+        m = self.metrics
+        m.family("dtpu_serve_sample_calls_total").inc(1)
+        if any(
+            self.top_ks[i] > 0 or self.top_ps[i] < 1.0 or self.min_ps[i] > 0.0
+            for i in rows
+        ):
+            m.family("dtpu_serve_sample_filtered_calls_total").inc(1)
+
     def _plain_step(self, live: list) -> dict[int, int]:
         # device-resident decode state: tokens/positions/active come
         # from the cached mirror (rebuilt only after a host-side slot
@@ -3243,8 +3284,8 @@ class InferenceEngine:
         )
         if self._all_greedy(live):
             # all-greedy batch: argmax only — the general sampler's
-            # full [B, V] descending sort (the dominant per-token cost
-            # at a 128k vocab) buys nothing here
+            # penalty passes over [B, V], its noise draw and the count
+            # update buy nothing here
             sampled_dev = self._argmax(logits)
             adv = self._advance_state(
                 tok_d, pos_d, rem_d, act_d, eos_d, sampled_dev
@@ -3259,6 +3300,7 @@ class InferenceEngine:
             return out
         sp = self._sampling_params()
         temps, top_ps, top_ks, rep_pens, pres_pens, freq_pens, min_ps = sp
+        self._count_sample(live)
         sampled_dev, self._key_data = self._sample(
             logits,
             self._key_data,
@@ -3272,6 +3314,7 @@ class InferenceEngine:
             self._gen_counts,
             self._logit_bias,
             min_ps,
+            act_d,  # a released slot keeps its last request's filters
         )
         self._seen, self._gen_counts = self._mark_seen(
             self._seen, self._gen_counts, self._slot_iota, sampled_dev

@@ -231,6 +231,21 @@ def new_serve_registry() -> Registry:
         "dtpu_serve_prefix_tokens_reused_total",
         "Prompt tokens skipped via prefix-cache reuse",
     )
+    # the sampler: how often a live slot's top-k / top-p / min-p made a
+    # call pay the full-vocabulary sort (engine.sample skips the
+    # filters on every other call). inc(0): the series exist from boot,
+    # so a scrape reads 0 and the share of the two is defined
+    r.counter(
+        "dtpu_serve_sample_calls_total",
+        "Calls of the sampler (a decode step of a batch that is not "
+        "all plain-greedy, and every request's first token)",
+    ).inc(0)
+    r.counter(
+        "dtpu_serve_sample_filtered_calls_total",
+        "Of those, the calls on which a live slot had set top_k, "
+        "top_p or min_p, so that the sampler sorted the vocabulary "
+        "(host-side, from the slots' parameters)",
+    ).inc(0)
     # layer groups: the sparse indexer and a chip's share of the experts
     # (series stay 0 for a model that has neither)
     r.counter(
