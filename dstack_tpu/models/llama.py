@@ -186,17 +186,20 @@ class LlamaConfig:
     # params["dense_layers"] stack scanned before the main layers
     first_k_dense: int = 0
     dense_intermediate: int = 0
-    # --- layer groups: two attention shapes in one MLA model ---
+    # --- layer groups: two attention shapes in one model ---
     # per-layer kind over all n_layers, "full" | "window" (() = every
     # layer full; the first_k_dense prelude layers must be full). A
-    # window layer is MLA of ANOTHER shape (the swa_* sizes), sees key j
-    # from query i iff 0 <= i - j < sliding_window, rotates with
-    # rope_local_theta, and its weights are a stack of their own,
-    # params["window_layers"] (full layers: "dense_layers" + "layers").
-    # :func:`layer_runs` is the one place that turns this into the
-    # order the stacks are walked in.
+    # window layer is attention of ANOTHER shape (the swa_* sizes: of
+    # an MLA model the latent's, of a grouped-query model the query
+    # head count over the same KV heads), sees key j from query i iff
+    # 0 <= i - j < sliding_window, rotates with rope_local_theta (no
+    # scaling) over swa_partial_rotary of its head, and its weights are
+    # a stack of their own, params["window_layers"] (full layers:
+    # "dense_layers" + "layers"). :func:`layer_runs` is the one place
+    # that turns this into the order the stacks are walked in.
     layer_types: tuple = ()
     swa_n_heads: int = 0
+    swa_partial_rotary: float = 0.0  # 0 = partial_rotary
     swa_q_lora_rank: int = 0
     swa_kv_lora_rank: int = 0
     swa_qk_nope_head_dim: int = 0
@@ -224,14 +227,17 @@ class LlamaConfig:
             len(self.layer_types) != self.n_layers
             or not kinds <= {"full", "window"}
             or "window" in self.layer_types[: self.first_k_dense]
-            or not self.mla
+            or self.sliding_pattern or self.nope_pattern
         ):
             raise ValueError(
-                "layer_types: one of 'full' | 'window' a layer of an MLA "
-                "model, the first_k_dense prelude all 'full'"
+                "layer_types: one of 'full' | 'window' a layer (in place "
+                "of sliding_pattern / nope_pattern), the first_k_dense "
+                "prelude all 'full'"
             )
         if "window" in kinds and not (
-            self.sliding_window and self.swa_n_heads and self.swa_kv_lora_rank
+            self.sliding_window and self.swa_n_heads
+            and (self.swa_kv_lora_rank or not self.mla)
+            and self.swa_n_heads % (1 if self.mla else self.n_kv_heads) == 0
         ):
             raise ValueError(
                 "window layers need sliding_window and the swa_* sizes"
@@ -251,8 +257,17 @@ class LlamaConfig:
     @property
     def window_config(self) -> "LlamaConfig":
         """The window layers' attention shape as a config of its own:
-        everything that reads an MLA shape off a config (projections,
-        absorbed forms, caches) takes this one for a window layer."""
+        everything that reads an attention shape off a config
+        (projections, absorbed forms, caches) takes this one for a
+        window layer."""
+        if not self.mla:
+            return dataclasses.replace(
+                self, n_heads=self.swa_n_heads,
+                partial_rotary=self.swa_partial_rotary or self.partial_rotary,
+                rope_theta=self.rope_local_theta or self.rope_theta,
+                rope_scaling=None if self.rope_local_theta else self.rope_scaling,
+                layer_types=(),
+            )
         return dataclasses.replace(
             self, n_heads=self.swa_n_heads,
             q_lora_rank=self.swa_q_lora_rank,
@@ -280,6 +295,14 @@ class LlamaConfig:
         if self.mla:
             return self.qk_rope_head_dim
         return int(self.head_dim * self.partial_rotary)
+
+    @property
+    def rope_dim_local(self) -> int:
+        """:attr:`rope_dim` of the layers that rotate with the local
+        rope (``swa_partial_rotary`` of a grouped-query head)."""
+        if self.mla or not self.swa_partial_rotary:
+            return self.rope_dim
+        return int(self.head_dim * self.swa_partial_rotary)
 
     @property
     def q_dim(self) -> int:
@@ -328,6 +351,7 @@ class LlamaConfig:
             h * self.q_dim + 2 * h * self.kv_dim + self.q_dim * h
             + (self.q_dim + 2 * self.kv_dim if self.qkv_bias else 0)
             + (h if self.proj_bias else 0)  # bo
+            + (h * self.n_heads if self.attn_gate else 0)
         )
 
     def _shared_expert_params(self) -> int:
@@ -649,6 +673,8 @@ def param_specs(config: LlamaConfig) -> dict:
             "wv": L + ("embed_fsdp", "kv_heads"),
             "wo": L + ("heads", "embed_fsdp"),
         }
+        if config.attn_gate:
+            attn["w_og"] = L + ("embed_fsdp", "heads")
     N = (
         (None, None)
         if config.norm_type in ("layernorm1p", "layernorm_bias")
@@ -796,6 +822,10 @@ def _init_attn(
         attn["bq"] = jnp.zeros((L, c.q_dim), dt)
         attn["bk"] = jnp.zeros((L, c.kv_dim), dt)
         attn["bv"] = jnp.zeros((L, c.kv_dim), dt)
+    if c.attn_gate:
+        attn["w_og"] = normal(
+            jax.random.fold_in(key, 22), (L, c.hidden_size, c.n_heads)
+        )
     return attn
 
 
@@ -1132,6 +1162,46 @@ def run_slice(stack: dict, run: LayerRun) -> dict:
     return jax.tree.map(lambda a: a[run.lo : run.hi], stack)
 
 
+class LayerPeriods(NamedTuple):
+    """:func:`layer_runs` folded where the runs repeat: ``head`` (the
+    prelude) walked once, then ``count`` times the runs of ``period``
+    (given as the FIRST period's; period ``i``'s run lies ``i *
+    per[run.key]`` layers further along its stack), then ``tail``."""
+
+    head: list
+    period: list
+    count: int
+    per: dict  # stack key → layers of it in one period
+    tail: list
+
+
+def layer_periods(config: "LlamaConfig") -> LayerPeriods:
+    """The runs after the prelude as the shortest pattern of (stack,
+    length) that repeats over most of them: a program that scans over
+    the periods, its body one period, does not grow with depth (13
+    layers of full·dense, then window × 3 + full three times, are a
+    prelude and three periods of two runs, not seven runs). Where
+    nothing repeats ``count`` is 0 and every run is in ``tail``."""
+    runs = layer_runs(config)
+    head = [r for r in runs if r.key == "dense_layers"]
+    rest = runs[len(head):]
+    shape = lambda r: (r.key, r.hi - r.lo)
+    p_best, n_best = 0, 0
+    for p in range(1, len(rest) // 2 + 1):
+        n = 1
+        while (n + 1) * p <= len(rest) and all(
+            shape(rest[n * p + j]) == shape(rest[j]) for j in range(p)
+        ):
+            n += 1
+        if n > 1 and n * p > n_best * p_best:
+            p_best, n_best = p, n
+    period = rest[:p_best]
+    per: dict = {}
+    for r in period:
+        per[r.key] = per.get(r.key, 0) + r.hi - r.lo
+    return LayerPeriods(head, period, n_best, per, rest[p_best * n_best:])
+
+
 def l2_norm(x: jax.Array, eps: float) -> jax.Array:
     """Weightless rms normalization in f32 (Llama4 qk norm)."""
     x32 = x.astype(jnp.float32)
@@ -1224,7 +1294,9 @@ def dual_rope_freqs(
     )
     if not config.rope_local_theta:
         return g, g
-    return g, rope_freqs(positions, config.rope_dim, config.rope_local_theta)
+    return g, rope_freqs(
+        positions, config.rope_dim_local, config.rope_local_theta
+    )
 
 
 def layer_rope(ropes: tuple[tuple, tuple], config: "LlamaConfig", window: int):

@@ -286,6 +286,7 @@ def flash_decode_supported(config, max_seq: int) -> bool:
     per-call when ``interpret`` isn't wanted off-TPU)."""
     return (
         not config.mla
+        and not config.layer_types  # a window layer's cache is a ring
         and not config.attention_chunk_size
         and config.head_dim % 64 == 0
         and max_seq % 128 == 0

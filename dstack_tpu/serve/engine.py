@@ -15,6 +15,7 @@ user containers, reference examples use vLLM/TGI); this module makes
 command on any slice the orchestrator provisions.
 """
 
+import contextlib
 import math
 import time
 from dataclasses import dataclass
@@ -211,7 +212,7 @@ def _cstored(new, like):
 
 def _cwrite_rows(
     ckv, layer, positions, write_mask, new, axis: int = 1, unroll: bool = False,
-    slots=None, counts=None,
+    slots=None, counts=None, opaque_loop: bool = False,
 ):
     """Write ``S`` new tokens a slot into layers ``layer .. layer + N``
     of a STACKED cache leaf, in place: ``ckv`` [L, B, *slot] with the
@@ -240,7 +241,13 @@ def _cwrite_rows(
     16 slots doubled the programs' size and cost every boot seconds of
     lowering and loading); ``unroll`` is for the latent, which the
     compiler re-lays out ``{3,2,1,0}`` under a nested loop and copies
-    whole at every call (device-free: ``temp`` 0.33 → 2.0 GB)."""
+    whole at every call (device-free: ``temp`` 0.33 → 2.0 GB).
+    ``opaque_loop`` is for a wave of ONE row: a loop of one trip is
+    inlined, the block write then stands bare in the layer scan, and
+    the compiler re-lays every K/V leaf out whole around it
+    (device-free at 16 × 8192 over two caches: 12 whole-leaf copies,
+    ``temp`` 0.64 → 4.2 GB, past the chip); a trip count it cannot read
+    (``min(1, 1 + slots[0])``, which is 1) keeps the loop."""
     if isinstance(ckv, tuple):
         return tuple(
             _cwrite_rows(
@@ -287,7 +294,10 @@ def _cwrite_rows(
         for b in range(new.shape[1]):
             ckv = one_slot(b, ckv)
         return ckv
-    return jax.lax.fori_loop(0, new.shape[1], one_slot, ckv)
+    n = new.shape[1]
+    if n == 1 and slots is not None and opaque_loop:
+        n = jnp.minimum(1, 1 + slots[0])
+    return jax.lax.fori_loop(0, n, one_slot, ckv)
 
 
 def _cwith_row(ckv, positions, write_mask, new):
@@ -330,7 +340,7 @@ def _masked(c: LlamaConfig, tmax: int) -> bool:
     """Whether some layer attends under an explicit mask (a window
     ring's row order, an indexer's selection): then no static-offset
     kernel applies, and prefill has one form, the packed one."""
-    return c.mla and ("window" in c.layer_types or _indexed(c, tmax))
+    return "window" in c.layer_types or _indexed(c, tmax)
 
 
 def ring_rows(c: LlamaConfig, max_seq: int, chunk: int) -> int:
@@ -343,31 +353,55 @@ def ring_rows(c: LlamaConfig, max_seq: int, chunk: int) -> int:
     return min(max_seq, -(-(c.sliding_window - 1 + chunk) // 16) * 16)
 
 
-def _mla_cache_shapes(
-    c: LlamaConfig, max_batch: int, max_seq: int, chunk: int
+def _cache_shapes(
+    c: LlamaConfig, max_batch: int, max_seq: int, chunk: int, kv_quant=None
 ) -> dict:
-    """Buffer name → shape of the latent cache, one buffer a kind of
-    state: ``ckv`` [Lf, B, Tmax, rank+rope] the full-attention layers'
-    latent rows (the dense prelude first, then ``layers``); ``idx``
-    [Lf, B, Tmax, index_head_dim] their indexer keys; ``win`` [Lw, B,
-    W, swa_rank+rope] the window layers' rows, a ring of
-    :func:`ring_rows`; ``moe_stats`` [2] int32, the routing counts of a
-    chip's share of the experts (picks held, token-layers routed),
-    carried with the cache so that they need no fetch of their own. A
-    model of one kind of layer has ``ckv`` alone."""
+    """Buffer name → shape of the cache, one buffer a kind of state; the
+    one place that says what rows a kind of layer holds, for both
+    families. Full-attention layers (the dense prelude first, then
+    ``layers``) hold ``max_seq`` rows a slot: the latent family's
+    ``ckv`` [Lf, B, Tmax, rank+rope] and, where an indexer can bite,
+    ``idx`` [Lf, B, Tmax, index_head_dim]; the grouped-query family's
+    ``k`` / ``v`` [Lf, B, Hkv, Tmax, D] (with ``kv_quant`` their
+    per-vector scales ``k_s`` / ``v_s`` [Lf, B, Hkv, Tmax]). Window
+    layers hold a ring of :func:`ring_rows`: ``win`` [Lw, B, W,
+    swa_rank+rope], or ``win_k`` / ``win_v`` [Lw, B, Hkv, W, D].
+    ``moe_stats`` [2] int32 are the routing counts of a chip's share of
+    the experts (picks held, token-layers routed), carried with the
+    cache so that they need no fetch of their own. A model of one kind
+    of layer has ``ckv``, or ``k`` and ``v``, alone."""
     n_win = c.layer_types.count("window")
-    rope = c.qk_rope_head_dim
-    shapes = {"ckv": (c.n_layers - n_win, max_batch, max_seq, c.kv_lora_rank + rope)}
-    if _indexed(c, max_seq):
-        shapes["idx"] = shapes["ckv"][:3] + (c.index_head_dim,)
-    if n_win:
-        shapes["win"] = (
-            n_win, max_batch, ring_rows(c, max_seq, chunk),
-            c.window_config.kv_lora_rank + rope,
-        )
+    n_full = c.n_layers - n_win
+    ring = ring_rows(c, max_seq, chunk) if n_win else 0
+    if c.mla:
+        rope = c.qk_rope_head_dim
+        shapes = {"ckv": (n_full, max_batch, max_seq, c.kv_lora_rank + rope)}
+        if _indexed(c, max_seq):
+            shapes["idx"] = shapes["ckv"][:3] + (c.index_head_dim,)
+        if n_win:
+            shapes["win"] = (
+                n_win, max_batch, ring, c.window_config.kv_lora_rank + rope
+            )
+    else:
+        kv = lambda n, t: (n, max_batch, c.n_kv_heads, t, c.head_dim)
+        shapes = {"k": kv(n_full, max_seq), "v": kv(n_full, max_seq)}
+        if kv_quant:
+            shapes["k_s"] = shapes["v_s"] = shapes["k"][:-1]
+        if n_win:
+            shapes["win_k"] = shapes["win_v"] = kv(n_win, ring)
     if c.experts_held:
         shapes["moe_stats"] = (2,)
     return shapes
+
+
+#: the name ``tests/benchmark`` knows it by (the benchmark's files are
+#: not a program PR's to edit)
+_mla_cache_shapes = _cache_shapes
+
+
+def _is_ring(name: str) -> bool:
+    """Whether cache buffer ``name`` is a window layers' ring."""
+    return name.startswith("win")
 
 
 def _ring_mask(qpos: jax.Array, newest: jax.Array, rows: int, window: int):
@@ -511,64 +545,47 @@ def init_cache(
     128 heads × 2 × 192/128 wide) that is a ~50-100× smaller cache and
     proportionally less HBM traffic per decoded token — the reason MLA
     exists. Replicated over ``tp`` (it has no head dim; the q heads
-    shard instead). A model of layer GROUPS holds a buffer a kind of
-    state (:func:`_mla_cache_shapes`): ``ckv`` for the full-attention
-    layers, ``idx`` for their indexer keys, ``win`` for the window
-    layers, whose rows are set by the window and not by ``max_seq``.
+    shard instead). A model of layer GROUPS, of either family, holds a
+    buffer a kind of state (:func:`_cache_shapes`): ``ckv`` or ``k`` /
+    ``v`` for the full-attention layers, ``idx`` for their indexer
+    keys, ``win`` or ``win_k`` / ``win_v`` for the window layers, whose
+    rows are set by the window and not by ``max_seq``.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    if config.mla:
-        if kv_quant:
-            raise ValueError(
-                "kv_quant does not combine with MLA (the latent cache "
-                "is already the compression)"
-            )
-        shapes = _mla_cache_shapes(config, max_batch, max_seq, chunk)
-
-        def buf(n: str):
-            dt = jnp.int32 if n == "moe_stats" else config.dtype
-            if mesh is None:
-                return jnp.zeros(shapes[n], dt)
-            sh = NamedSharding(mesh, P(*([None] * len(shapes[n]))))
-            # dtpu: noqa[DTPU003] loop over the fixed latent-cache buffer names at engine construction — bounded and once
-            return jax.jit(partial(jnp.zeros, shapes[n], dt), out_shardings=sh)()
-
-        return {n: buf(n) for n in shapes}
+    if kv_quant and (config.mla or config.layer_types):
+        raise ValueError(
+            "kv_quant combines with neither MLA (the latent cache is "
+            "already the compression) nor layer groups"
+        )
     if kv_quant not in (None, "int8"):
         raise ValueError(f"unknown kv_quant {kv_quant!r}")
-    shape = (
-        config.n_layers,
-        max_batch,
-        config.n_kv_heads,
-        max_seq,
-        config.head_dim,
-    )
-    dt = jnp.int8 if kv_quant else config.dtype
-    names = {"k": shape, "v": shape}
-    if kv_quant:
+    shapes = _cache_shapes(config, max_batch, max_seq, chunk, kv_quant)
+
+    def buf(n: str):
+        s = shapes[n]
         # per-(token, head) scales stored in FLOAT32: the quantizer
         # computes f32 absmax scales, and rounding them to bf16 would
         # stack up to ~0.4% multiplicative error on every dequantized
         # vector on top of the int8 error, for ~1.5% byte savings
-        names["k_s"] = shape[:-1]
-        names["v_s"] = shape[:-1]
-
-    def buf_dtype(n: str):
-        return jnp.float32 if n.endswith("_s") else dt
-
-    if mesh is None:
-        return {n: jnp.zeros(s, buf_dtype(n)) for n, s in names.items()}
-    # allocate directly sharded: a host-side zeros + device_put would
-    # materialize the full cache on one chip first
-    out = {}
-    for n, s in names.items():
-        sh = NamedSharding(mesh, P(*([None, None, "tp"] + [None] * (len(s) - 3))))
-        # dtpu: noqa[DTPU003] loop over the fixed cache buffer names (k/v[/scales]) at engine construction — bounded and once
-        out[n] = jax.jit(
-            partial(jnp.zeros, s, buf_dtype(n)), out_shardings=sh
+        dt = (
+            jnp.int32 if n == "moe_stats" else jnp.float32 if n.endswith("_s")
+            else jnp.int8 if kv_quant else config.dtype
+        )
+        if mesh is None:
+            return jnp.zeros(s, dt)
+        # allocate directly sharded: a host-side zeros + device_put would
+        # materialize the full cache on one chip first. K/V shard over
+        # their heads; a latent has none (the q heads shard instead)
+        spec = [None] * len(s)
+        if not config.mla and len(s) > 2:
+            spec[2] = "tp"
+        # dtpu: noqa[DTPU003] loop over the fixed cache buffer names at engine construction — bounded and once
+        return jax.jit(
+            partial(jnp.zeros, s, dt), out_shardings=NamedSharding(mesh, P(*spec))
         )()
-    return out
+
+    return {n: buf(n) for n in shapes}
 
 
 # ---------------------------------------------------------------------------
@@ -732,10 +749,22 @@ def _dense_in(x: jax.Array, layer: dict, c: LlamaConfig, rope, nope, temp) -> tu
     return jnp.where(nope, q_no, q_ro), jnp.where(nope, k, k_ro), v
 
 
-def _dense_out(x: jax.Array, o: jax.Array, layer: dict, c: LlamaConfig) -> jax.Array:
+def _dense_out(
+    x: jax.Array, o: jax.Array, layer: dict, c: LlamaConfig, stats=None, valid=None
+):
     """A dense layer's way out, the one copy: attention output ``o``
-    [B, S, q_dim] through ``wo`` (its bias, post norm and multiplier),
-    the residual, the MLP sublayer → x."""
+    [B, S, q_dim] (heads in query-head order) under the head-wise gate
+    (``attn_gate``), through ``wo`` (its bias, post norm and
+    multiplier), the residual, the MLP sublayer → x. With ``stats``
+    (the [2] int32 routing counts a chip's share of the experts keeps,
+    ``cache["moe_stats"]``) → (x, stats plus this layer's counts of
+    :func:`_mlp_out` over the ``valid`` tokens)."""
+    if c.attn_gate:
+        b, s = o.shape[:2]
+        h = model_norm(x, layer["attn_norm"], c) if c.pre_norm else x
+        o = llama.head_gate(
+            o.reshape(b, s, c.n_heads, c.head_dim), h, layer, c, "bsh,bshd->bshd"
+        ).reshape(o.shape)
     ao = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
     if c.proj_bias:
         ao = ao + layer["bo"]
@@ -743,28 +772,39 @@ def _dense_out(x: jax.Array, o: jax.Array, layer: dict, c: LlamaConfig) -> jax.A
         ao = model_norm(ao, layer["attn_post_norm"], c)
     if c.residual_multiplier:  # Granite scales the sublayer output
         ao = ao * jnp.asarray(c.residual_multiplier, ao.dtype)
+    if stats is not None:
+        mo, picks = _mlp_out(x + ao, layer, c, valid=valid)
+        return x + ao + mo, stats + picks
     if c.parallel_block:  # Cohere: joint residual add
         return x + ao + _mlp_out(x, layer, c)
     return _mlp(x + ao, layer, c)
 
 
-def _dense_probs(s, qpos, window, nope, layer: dict, c: LlamaConfig):
+def _dense_probs(s, qpos, window, nope, layer: dict, c: LlamaConfig, ring=None):
     """Scaled scores ``s`` [B, Hkv, G, (S,) T] of the queries at ``qpos``
     [B(, S)] against a slot's whole cache row → probabilities, the one
     copy decode and verify share: Gemma2's softcap, the causal frontier,
     the layer's (traced) sliding ``window``, Llama4's chunks on rope
-    layers (``nope`` traced), gpt-oss's sink column."""
+    layers (``nope`` traced), gpt-oss's sink column. ``ring`` [B] (a
+    window layer of a model of groups): the row is a ring whose newest
+    position is ``ring[b]``, and what a query sees is :func:`_ring_mask`'s
+    (``window`` static there)."""
     if c.attn_softcap:
         s = c.attn_softcap * jnp.tanh(s / c.attn_softcap)
-    kj = jnp.arange(s.shape[-1])[(None,) * (s.ndim - 1)]
-    # [B, 1, 1, (S,) 1]: heads and groups broadcast, keys last
-    qpos = qpos[(slice(None), None, None) + (slice(None),) * (qpos.ndim - 1) + (None,)]
-    mask = kj <= qpos
-    mask = jnp.logical_and(mask, jnp.logical_or(window == 0, qpos - kj < window))
-    if c.attention_chunk_size:
-        # Llama4: rope layers attend within their chunk only
-        start = (qpos // c.attention_chunk_size) * c.attention_chunk_size
-        mask = jnp.logical_and(mask, jnp.logical_or(nope, kj >= start))
+    if ring is not None:
+        mask = _ring_mask(
+            qpos.reshape(qpos.shape[0], -1), ring, s.shape[-1], window
+        ).reshape((qpos.shape[0], 1, 1) + qpos.shape[1:] + s.shape[-1:])
+    else:
+        kj = jnp.arange(s.shape[-1])[(None,) * (s.ndim - 1)]
+        # [B, 1, 1, (S,) 1]: heads and groups broadcast, keys last
+        qpos = qpos[(slice(None), None, None) + (slice(None),) * (qpos.ndim - 1) + (None,)]
+        mask = kj <= qpos
+        mask = jnp.logical_and(mask, jnp.logical_or(window == 0, qpos - kj < window))
+        if c.attention_chunk_size:
+            # Llama4: rope layers attend within their chunk only
+            start = (qpos // c.attention_chunk_size) * c.attention_chunk_size
+            mask = jnp.logical_and(mask, jnp.logical_or(nope, kj >= start))
     s = jnp.where(mask, s, NEG_INF)
     if not c.attn_sinks:
         return jax.nn.softmax(s, axis=-1)
@@ -1012,42 +1052,56 @@ def _prefill_chunk_mla(
     return _head_logits(params, last, c), cache
 
 
+def _cwrite_ring(
+    buf, li, positions, write_mask, new, axis: int = 0, unroll: bool = True,
+    slots=None, counts=None, **kw,
+):
+    """:func:`_cwrite_rows` into a window layers' ring: ``new``
+    [B, *slot] with S in the place of T goes to rows ``(positions[b] +
+    s) %`` the ring's. A block write does not wrap, so a step's few
+    tokens go in one at a time, and a prefill chunk (``counts`` given)
+    in two pieces: the rows that fit before the ring's end, then the
+    rest from row 0."""
+    put = partial(
+        _cwrite_rows, layer=li, write_mask=write_mask, axis=axis, unroll=unroll,
+        slots=slots, **kw,
+    )
+    t_ax = 1 + axis  # of ``new``
+    rows, s = buf.shape[1 + t_ax], new.shape[t_ax]
+    if counts is None:
+        for j in range(s):
+            buf = put(
+                buf, positions=jnp.mod(positions + j, rows),
+                new=new[(slice(None),) * t_ax + (slice(j, j + 1),)],
+            )
+        return buf
+    first = jnp.mod(positions, rows)
+    fit = rows - first  # rows up to the ring's end; past it they drop
+    buf = put(buf, positions=first, new=new, counts=counts)
+    rest = jnp.take_along_axis(
+        new,
+        jnp.expand_dims(
+            jnp.clip(fit[:, None] + jnp.arange(s)[None, :], 0, s - 1),
+            [a for a in range(1, new.ndim) if a != t_ax],
+        ),
+        axis=t_ax,
+    )
+    return put(
+        buf, positions=jnp.zeros_like(first), new=rest,
+        counts=jnp.clip(counts - fit, 0, s),
+    )
+
+
 def _stacked_write(
     cache: dict, name: str, li, positions, write_mask, new, slots=None, counts=None
 ):
     """``new`` [B, S, width] written in place into layer ``li`` of the
-    stacked buffer ``name`` at each row's ``positions[b] + s`` (rows,
+    latent buffer ``name`` at each row's ``positions[b] + s`` (rows,
     ``slots`` and ``counts`` as in :func:`_cwrite_rows`); in the window
-    ring modulo its rows → the cache with that buffer replaced. A block
-    write does not wrap, so a step's few tokens go into the ring one at
-    a time, and a prefill chunk (``counts`` given) in two pieces: the
-    rows that fit before the ring's end, then the rest from row 0."""
-    buf = cache[name]
-    put = partial(
-        _cwrite_rows, layer=li, write_mask=write_mask, axis=0, unroll=True,
-        slots=slots,
-    )
-    if name != "win":
-        buf = put(buf, positions=positions, new=new, counts=counts)
-    elif counts is None:
-        for j in range(new.shape[1]):
-            buf = put(
-                buf, positions=jnp.mod(positions + j, buf.shape[2]),
-                new=new[:, j : j + 1],
-            )
-    else:
-        s = new.shape[1]
-        first = jnp.mod(positions, buf.shape[2])
-        fit = buf.shape[2] - first  # rows up to the ring's end; past it they drop
-        buf = put(buf, positions=first, new=new, counts=counts)
-        rest = jnp.take_along_axis(
-            new, jnp.clip(fit[:, None] + jnp.arange(s)[None, :], 0, s - 1)[..., None],
-            axis=1,
-        )
-        buf = put(
-            buf, positions=jnp.zeros_like(first), new=rest,
-            counts=jnp.clip(counts - fit, 0, s),
-        )
+    ring modulo its rows (:func:`_cwrite_ring`) → the cache with that
+    buffer replaced."""
+    write = _cwrite_ring if _is_ring(name) else partial(_cwrite_rows, axis=0, unroll=True)
+    buf = write(cache[name], li, positions, write_mask, new, slots=slots, counts=counts)
     return {**cache, name: buf}
 
 
@@ -1246,6 +1300,222 @@ def _scan_layers_kv(params: dict, cache: dict, x: jax.Array, one_layer, c):
     return x, _cache_unpack(ck, cv)
 
 
+def _walk_layer_groups(params: dict, carry, one_layer, c: LlamaConfig):
+    """Drive ``one_layer(carry, layer, li, run) -> (carry, y)`` over a
+    grouped-query model of layer GROUPS, in the order of
+    ``llama.layer_periods``: the prelude, then ONE ``lax.scan`` over the
+    periods whose body is one period (each of its runs a scan over its
+    share of its group's stack), then what is left over, so that the
+    program holds a layer body a run of the period and does not grow
+    with depth. ``li`` is the layer's row in its kind's cache buffers
+    (full layers: the prelude first). → (carry, [(run, first row, ys
+    stacked in row order)] a run of the prelude and of the tail and a
+    stack of the period: what a program that only READS the cache in
+    its scans writes after them, one block write a buffer)."""
+    plan = llama.layer_periods(c)
+    k_dense = c.first_k_dense
+
+    def row(run):  # the run's first layer → its row in its kind's buffers
+        return run.lo + (k_dense if run.key == "layers" else 0)
+
+    def scan_run(carry, run, ahead=0):
+        # the layers are indexed out of the whole stack (what lax.scan
+        # does with its xs): handed down as a period's share through an
+        # outer scan's xs, a period's weights were copied out a period
+        # (device-free: 2.4 GB of ``temp``, 3 layers x 32 experts)
+        def layer_fn(carry, j):
+            layer = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, run.lo + ahead + j, 0, keepdims=False
+                ),
+                params[run.key],
+            )
+            return one_layer(carry, layer, row(run) + ahead + j, run)
+
+        return jax.lax.scan(layer_fn, carry, jnp.arange(run.hi - run.lo))
+
+    out = []
+    for run in plan.head:
+        carry, ys = scan_run(carry, run)
+        out.append((run, row(run), ys))
+    if plan.count:
+
+        def period_fn(carry, i):
+            ys = {key: [] for key in plan.per}
+            for run in plan.period:
+                carry, y = scan_run(carry, run, i * plan.per[run.key])
+                ys[run.key].append(y)
+            return carry, {
+                key: y[0] if len(y) == 1 else jax.tree.map(
+                    lambda *a: jnp.concatenate(a), *y
+                )
+                for key, y in ys.items()
+            }
+
+        carry, ys = jax.lax.scan(period_fn, carry, jnp.arange(plan.count))
+        seen = set()
+        for run in plan.period:
+            if run.key not in seen:  # once a stack: its first run's row
+                seen.add(run.key)
+                out.append((run, row(run), jax.tree.map(
+                    lambda a: a.reshape((-1,) + a.shape[2:]), ys[run.key]
+                )))
+    for run in plan.tail:
+        carry, ys = scan_run(carry, run)
+        out.append((run, row(run), ys))
+    return carry, out
+
+
+def _group_kv(run) -> tuple[str, str]:
+    """The cache buffers a run of a grouped-query model of groups keeps
+    its keys and values in."""
+    return ("win_k", "win_v") if run.window else ("k", "v")
+
+
+def _attend_rows(
+    q: jax.Array,  # [B, H, S, D]
+    k: jax.Array,  # [B, Hkv, T, D] each row's cached keys
+    v: jax.Array,
+    mask: jax.Array,  # [B, S, T] bool
+    c: LlamaConfig,
+    n_keys,  # [B]: rows of the T that can be visible
+) -> jax.Array:
+    """Grouped-query attention of a prefill chunk under an explicit mask
+    (a window ring's row order, a full row's causal frontier at a traced
+    start) → [B, S, q_dim]: the cache is read at KV width, the group's
+    query heads ride one einsum. A short row (a ring) is scored at
+    once; a long one goes by a sequence at a time, its keys in blocks
+    under a running softmax, as many blocks as hold its ``n_keys``: the
+    work follows the context a row has, not ``max_seq`` (as
+    :func:`_attend_masked` does for the latent family)."""
+    b, nh, s, d = q.shape
+    hkv, t = k.shape[1], k.shape[2]
+    scale, cap = c.attention_scale, c.attn_softcap
+    q = q.reshape(b, hkv, nh // hkv, s, d)
+
+    def scores(q, k, m):  # q [Hkv, G, S, D], k [Hkv, T', D], m [S, T']
+        sc = jnp.einsum(
+            "hgsd,htd->hgst", q, k, preferred_element_type=jnp.float32
+        ) * scale
+        if cap:
+            sc = cap * jnp.tanh(sc / cap)
+        return jnp.where(m[None, None], sc, NEG_INF)
+
+    def attend(q, k, v, m):
+        pr = jax.nn.softmax(scores(q, k, m), axis=-1)
+        return jnp.einsum("hgst,htd->hgsd", pr.astype(v.dtype), v)
+
+    kb = math.gcd(t, 512)  # keys a block
+
+    def attend_blocks(args):
+        q, k, v, m, blocks = args
+
+        def block(i, carry):
+            top, den, acc = carry  # running max, sum [Hkv,G,S], values [Hkv,G,S,D]
+            sc = scores(
+                q, jax.lax.dynamic_slice_in_dim(k, i * kb, kb, 1),
+                jax.lax.dynamic_slice_in_dim(m, i * kb, kb, 1),
+            )
+            new_top = jnp.maximum(top, sc.max(-1))
+            # (a query with no visible key so far: see _attend_masked)
+            keep = jnp.exp(top - new_top)
+            pr = jnp.exp(sc - new_top[..., None])
+            acc = acc * keep[..., None] + jnp.einsum(
+                "hgst,htd->hgsd", pr.astype(v.dtype),
+                jax.lax.dynamic_slice_in_dim(v, i * kb, kb, 1),
+                preferred_element_type=jnp.float32,
+            )
+            return new_top, den * keep + pr.sum(-1), acc
+
+        lead = q.shape[:-1]
+        _, den, acc = jax.lax.fori_loop(
+            0, blocks, block,
+            (
+                jnp.full(lead, NEG_INF, jnp.float32),
+                jnp.zeros(lead, jnp.float32),
+                jnp.zeros(lead + (d,), jnp.float32),
+            ),
+        )
+        return (acc / den[..., None]).astype(v.dtype)
+
+    if t <= 2 * kb and b * nh * s * t * 4 <= _SCORE_BYTES:
+        o = jax.vmap(attend)(q, k, v, mask)
+    else:
+        blocks = jnp.clip(-(-n_keys // kb), 1, t // kb).astype(jnp.int32)
+        o = jax.lax.map(attend_blocks, (q, k, v, mask, blocks))
+    # [B, Hkv, G, S, D] → query-head order a token
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, s, nh * d)
+
+
+def _prefill_packed_groups(
+    params: dict,
+    cache: dict,
+    tokens: jax.Array,  # [G, C]
+    slots: jax.Array,  # [G]
+    starts: jax.Array,  # [G] traced per-row start positions
+    last_ix: jax.Array,  # [G]; -1 marks an inactive pad row
+    c: LlamaConfig,
+) -> tuple[jax.Array, dict]:
+    """Packed prefill of a grouped-query model of layer GROUPS, its one
+    prefill form (:func:`_masked`; a serial chunk is a wave of one row):
+    each row's real tokens go into its slot's rows of the run's buffers
+    in place (a window layer's into its ring), and the chunk attends
+    over them under the run's mask."""
+    from dstack_tpu.models.llama import dual_rope_freqs
+
+    g, cl = tokens.shape
+    x = _embed_lookup(params, tokens, c)
+    pos_grid = starts[:, None] + jnp.arange(cl)[None, :]  # [G, C]
+    ropes = jax.tree.map(
+        lambda a: a.reshape(g, cl, a.shape[-1]),
+        dual_rope_freqs(c, pos_grid.reshape(-1)),
+    )
+    si = slots.astype(jnp.int32)
+    # positions past each row's real tokens (padding, pad rows) keep
+    # their bytes — the masked-future invariant
+    valid = jnp.arange(cl)[None, :] <= last_ix[:, None]  # [G, C]
+    newest = starts + jnp.maximum(last_ix, 0)  # [G] the last position written
+    put = dict(
+        positions=starts, write_mask=last_ix >= 0, slots=si, counts=last_ix + 1,
+        axis=1, unroll=_tokens_on_lanes(c.head_dim), opaque_loop=True,
+    )
+
+    def one_layer(carry, layer, li, run):
+        x, cache = carry
+        gc = run.config
+        cos, sin = llama.layer_rope(ropes, c, run.window)
+        q, k, v = _dense_in(
+            x, layer, gc,
+            lambda t: _rope_rows(t, cos, sin, interleaved=c.rope_interleaved),
+            False, None,
+        )
+        write = _cwrite_ring if run.window else _cwrite_rows
+        rows = []  # the wave's rows of the run's buffers, [G, Hkv, T, D] each
+        for name, new in zip(_group_kv(run), (k, v)):
+            buf = write(cache[name], li, new=new, **put)
+            cache = {**cache, name: buf}
+            rows.append(_cread_rows(buf, li, si, new.dtype))
+        t = rows[0].shape[2]
+        with _attn_scope(run.window):
+            if run.window:
+                mask = _ring_mask(pos_grid, newest, t, run.window)
+            else:
+                mask = jnp.arange(t)[None, None, :] <= pos_grid[:, :, None]
+            o = _attend_rows(q, *rows, mask, gc, jnp.minimum(newest + 1, t))
+        stats = cache.get("moe_stats")
+        if stats is None:
+            return (_dense_out(x, o, layer, gc), cache), None
+        x, stats = _dense_out(x, o, layer, gc, stats, valid)
+        return (x, {**cache, "moe_stats": stats}), None
+
+    (x, cache), _ = _walk_layer_groups(params, (x, cache), one_layer, c)
+    x = model_norm(x, params["final_norm"], c)
+    last = jnp.take_along_axis(
+        x, jnp.maximum(last_ix, 0)[:, None, None].astype(jnp.int32), axis=1
+    )[:, 0]
+    return _head_logits(params, last, c), cache
+
+
 def _prefill_one_layer(
     c: LlamaConfig,
     ropes: tuple,
@@ -1328,6 +1598,11 @@ def prefill_chunk_step(
     if c.mla:
         return _prefill_chunk_mla(
             params, cache, tokens, slot, last_ix, c, start=start
+        )
+    if c.layer_types:  # layer groups: a wave of one row
+        return _prefill_packed_groups(
+            params, cache, tokens, slot[None],
+            jnp.full((1,), start, jnp.int32), last_ix[None], c,
         )
     x = _embed_lookup(params, tokens, c)
     chunk_pos = start + jnp.arange(tokens.shape[1])
@@ -1460,6 +1735,10 @@ def prefill_packed_step(
     c = config
     if c.mla:
         return _prefill_packed_mla(
+            params, cache, tokens, slots, starts, last_ix, c
+        )
+    if c.layer_types:
+        return _prefill_packed_groups(
             params, cache, tokens, slots, starts, last_ix, c
         )
     g, cl = tokens.shape
@@ -1614,9 +1893,12 @@ def decode_step(
         return _decode_step_mla(
             params, cache, tokens, positions, c, write_mask
         )
+    if c.layer_types:
+        return _decode_step_groups(
+            params, cache, tokens, positions, c, write_mask
+        )
     x = _embed_lookup(params, tokens, c)[:, None, :]
     (cos, sin), (cos_l, sin_l) = dual_rope_freqs(c, positions)  # [B, D/2]
-    scale = c.attention_scale
     # decode attention is a masked einsum, so *traced* per-layer window
     # and NoPE flags can ride the scan — no grouped unrolling needed
     windows = jnp.asarray(layer_windows(c), jnp.int32)
@@ -1636,49 +1918,14 @@ def decode_step(
             (jnp.where(window > 0, cos_l, cos), jnp.where(window > 0, sin_l, sin))
             if c.rope_local_theta else (cos, sin)
         )
-        q, k, v = _dense_in(
-            x, layer, c,
+        return _decode_layer(
+            x, layer, li, c, ck, cv, positions, write_mask,
             lambda t: _apply_rope_batch(t, cs, sn, interleaved=c.rope_interleaved),
             # Llama4 NoPE layers keep the unrotated q/k: a traced flag
             nope if has_nope else False,
             lambda q: q * temp[:, None, None, None].astype(q.dtype),
+            window, decode_kernel=decode_kernel, mesh=mesh,
         )
-        # the scan only READS the stacked cache: this token's K/V is
-        # selected into the layer's slice on its way into the attention
-        # (masked rows keep theirs) and goes out as ys; all layers' rows
-        # are written after the scan, in place, 2 × B small blocks a
-        # step where writing inside the scan costs that a layer
-        k_new, v_new = _cstored(k, ck), _cstored(v, cv)
-        ckl = _cwith_row(_clayer(ck, li), positions, write_mask, k_new)
-        cvl = _cwith_row(_clayer(cv, li), positions, write_mask, v_new)
-        ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
-        cvf = _cfull(cvl, v.dtype)
-        # attend over the cache prefix (mask: j <= position, and within
-        # the layer's sliding window when set). Grouped-query einsum:
-        # q regrouped [B, Hkv, G, D] against the [B, Hkv, T, D] cache —
-        # decode is HBM-bandwidth-bound on the KV read, so the cache is
-        # streamed ONCE at KV width instead of materializing a G×-wider
-        # repeat (4× read amplification for 32q/8kv models).
-        grp = c.n_heads // c.n_kv_heads
-        qg = q[:, :, 0, :].reshape(b, c.n_kv_heads, grp, c.head_dim)
-        if decode_kernel == "flash":
-            # ragged pallas read: blocks past each slot's position are
-            # DMA-elided (caller gated out MLA/chunked-attention/shape
-            # misfits via flash_decode_supported)
-            o = _flash_attend(
-                qg, ckl, cvl, positions, window,
-                config=c, scale=scale, grp=grp, rows_per_slot=1,
-                sinks_leaf=layer.get("sinks"), mesh=mesh,
-            )
-        else:
-            s = jnp.einsum(
-                "bhgd,bhkd->bhgk", qg, ckf, preferred_element_type=jnp.float32
-            ) * scale
-            p = _dense_probs(s, positions, window, nope, layer, c)
-            o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(cvf.dtype), cvf)
-        # [B, Hkv, G, D] row-major flatten == query-head order
-        o = o.reshape(b, 1, c.q_dim)
-        return _dense_out(x, o, layer, c), (k_new, v_new)
 
     x, (k_rows, v_rows) = jax.lax.scan(
         layer_fn, x,
@@ -1688,6 +1935,120 @@ def decode_step(
         _cwrite_rows(ck, 0, positions, write_mask, k_rows),
         _cwrite_rows(cv, 0, positions, write_mask, v_rows),
     )
+    x = model_norm(x, params["final_norm"], c)
+    return _head_logits(params, x[:, 0], c), cache
+
+
+def _decode_layer(
+    x, layer: dict, li, c: LlamaConfig, ck, cv, positions, write_mask,
+    rope, nope, temp, window, ring=None, stats=None,
+    decode_kernel: str = "einsum", mesh=None,
+):
+    """One dense layer of a decode step, the one copy → (x, this token's
+    (k, v) rows as stored[, stats]). ``c``: the layer's attention shape
+    (its run's, in a model of groups); ``ck`` / ``cv``: the stacked
+    buffers its kind of layer keeps, of which this layer is row ``li``;
+    ``rope``, ``nope``, ``temp`` as :func:`_dense_in` takes them;
+    ``window`` traced where the layers ride one scan, static in a model
+    of groups, whose window layers' buffers are rings (``ring`` = the
+    positions, and the new row lies at ``positions % rows``); ``stats``
+    as :func:`_dense_out` takes them (the live slots are the valid
+    tokens)."""
+    b = x.shape[0]
+    q, k, v = _dense_in(x, layer, c, rope, nope, temp)
+    # the scan only READS the stacked cache: this token's K/V is
+    # selected into the layer's slice on its way into the attention
+    # (masked rows keep theirs) and goes out as ys; all layers' rows
+    # are written after the scan, in place, 2 × B small blocks a
+    # step where writing inside the scan costs that a layer
+    at = positions if ring is None else _ring_row(positions, ck)
+    k_new, v_new = _cstored(k, ck), _cstored(v, cv)
+    ckl = _cwith_row(_clayer(ck, li), at, write_mask, k_new)
+    cvl = _cwith_row(_clayer(cv, li), at, write_mask, v_new)
+    ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
+    cvf = _cfull(cvl, v.dtype)
+    # attend over the cache prefix (mask: j <= position, and within
+    # the layer's sliding window when set). Grouped-query einsum:
+    # q regrouped [B, Hkv, G, D] against the [B, Hkv, T, D] cache —
+    # decode is HBM-bandwidth-bound on the KV read, so the cache is
+    # streamed ONCE at KV width instead of materializing a G×-wider
+    # repeat (4× read amplification for 32q/8kv models).
+    grp = c.n_heads // c.n_kv_heads
+    qg = q[:, :, 0, :].reshape(b, c.n_kv_heads, grp, c.head_dim)
+    if decode_kernel == "flash":
+        # ragged pallas read: blocks past each slot's position are
+        # DMA-elided (caller gated out MLA/chunked-attention/shape
+        # misfits via flash_decode_supported)
+        o = _flash_attend(
+            qg, ckl, cvl, positions, window,
+            config=c, scale=c.attention_scale, grp=grp, rows_per_slot=1,
+            sinks_leaf=layer.get("sinks"), mesh=mesh,
+        )
+    else:
+        with _attn_scope(window):
+            s = jnp.einsum(
+                "bhgd,bhkd->bhgk", qg, ckf, preferred_element_type=jnp.float32
+            ) * c.attention_scale
+            p = _dense_probs(s, positions, window, nope, layer, c, ring)
+            o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(cvf.dtype), cvf)
+    # [B, Hkv, G, D] row-major flatten == query-head order
+    o = o.reshape(b, 1, c.q_dim)
+    if stats is None:
+        return _dense_out(x, o, layer, c), (k_new, v_new)
+    x, stats = _dense_out(x, o, layer, c, stats, write_mask[:, None])
+    return (x, stats), (k_new, v_new)
+
+
+def _attn_scope(window):
+    """The named scope a capture finds a layer's attention under, where
+    its kind is static (a model of layer groups): ``dtpu.attn_window`` |
+    ``dtpu.attn_full``; none where the window rides a scan as data."""
+    if not isinstance(window, int):
+        return contextlib.nullcontext()
+    return jax.named_scope("dtpu.attn_window" if window else "dtpu.attn_full")
+
+
+def _ring_row(positions, buf):
+    """The row of a window ring ``buf`` [L, B, Hkv, W, D] that holds
+    position ``positions``."""
+    return jnp.mod(positions, buf.shape[3])
+
+
+def _decode_step_groups(
+    params: dict, cache: dict, tokens, positions, c: LlamaConfig, write_mask
+) -> tuple[jax.Array, dict]:
+    """:func:`decode_step` of a grouped-query model of layer GROUPS: the
+    same layer (:func:`_decode_layer`) at each run's attention shape
+    against its kind's buffers, a window layer's a ring; every scan
+    reads the cache, and each buffer takes its runs' new rows in one
+    block write a run after them (PR 25's form)."""
+    from dstack_tpu.models.llama import dual_rope_freqs
+
+    x = _embed_lookup(params, tokens, c)[:, None, :]
+    ropes = dual_rope_freqs(c, positions)  # ([B, D/2] each) full, window
+
+    def one_layer(carry, layer, li, run):
+        x, stats = carry
+        cos, sin = llama.layer_rope(ropes, c, run.window)
+        nk, nv = _group_kv(run)
+        out, rows = _decode_layer(
+            x, layer, li, run.config, cache[nk], cache[nv], positions,
+            write_mask,
+            lambda t: _apply_rope_batch(t, cos, sin, interleaved=c.rope_interleaved),
+            False, None, run.window, positions if run.window else None, stats,
+        )
+        return (out if stats is not None else (out, None)), rows
+
+    (x, stats), written = _walk_layer_groups(
+        params, (x, cache.get("moe_stats")), one_layer, c
+    )
+    cache = dict(cache)
+    if stats is not None:
+        cache["moe_stats"] = stats
+    for run, first, rows in written:
+        for name, new in zip(_group_kv(run), rows):
+            at = _ring_row(positions, cache[name]) if run.window else positions
+            cache[name] = _cwrite_rows(cache[name], first, at, write_mask, new)
     x = model_norm(x, params["final_norm"], c)
     return _head_logits(params, x[:, 0], c), cache
 
@@ -1801,6 +2162,10 @@ def verify_step(
         return _verify_step_mla(
             params, cache, tokens, positions, c, write_mask
         )
+    if c.layer_types:
+        return _verify_step_groups(
+            params, cache, tokens, positions, c, write_mask
+        )
     b, sdraft = tokens.shape
     x = _embed_lookup(params, tokens, c)  # [B, S, H]
     # per-row positions: row i covers [pos_i, pos_i + S)
@@ -1811,7 +2176,6 @@ def verify_step(
         lambda a: a.reshape(b, sdraft, inv_shape),
         dual_rope_freqs(c, pos_grid.reshape(-1)),
     )
-    scale = c.attention_scale
     windows = jnp.asarray(layer_windows(c), jnp.int32)
     nopes = jnp.asarray(layer_nope(c), bool)
     has_nope = any(layer_nope(c))
@@ -1827,46 +2191,105 @@ def verify_step(
             (jnp.where(window > 0, cos_l, cos), jnp.where(window > 0, sin_l, sin))
             if c.rope_local_theta else (cos, sin)
         )
-        q, k, v = _dense_in(
-            x, layer, c,
+        return _verify_layer(
+            x, layer, li, c, ck, cv, positions, pos_grid, write_mask,
             lambda t: _rope_rows(t, cs, sn, interleaved=c.rope_interleaved),
             nope if has_nope else False,
             lambda q: q * temp[:, None, :, None].astype(q.dtype),
-        )
-        # write the S tokens' K/V at their per-row positions, in place
-        ck = _cwrite_rows(ck, li, positions, write_mask, _cstored(k, ck))
-        cv = _cwrite_rows(cv, li, positions, write_mask, _cstored(v, cv))
-        ckl, cvl = _clayer(ck, li), _clayer(cv, li)
-        ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
-        cvf = _cfull(cvl, v.dtype)
-        # grouped-query attention against the KV-width cache (see
-        # decode_step): q [B, Hkv, G, S, D] · cache [B, Hkv, T, D]
-        grp = c.n_heads // c.n_kv_heads
-        qg = q.reshape(b, c.n_kv_heads, grp, sdraft, c.head_dim)
-        if decode_kernel == "flash":
-            # ragged verify: rows flatten [G, S] row-major; row g*S+s
-            # attends keys <= pos+s inside the kernel (verify rides the
-            # SAME dispatch — sink column included — as decode)
-            qr = qg.reshape(b, c.n_kv_heads, grp * sdraft, c.head_dim)
-            o = _flash_attend(
-                qr, ckl, cvl, positions, window,
-                config=c, scale=scale, grp=grp, rows_per_slot=sdraft,
-                sinks_leaf=layer.get("sinks"), mesh=mesh,
-            ).reshape(b, c.n_kv_heads, grp, sdraft, c.head_dim)
-        else:
-            s = jnp.einsum(
-                "bhgsd,bhkd->bhgsk", qg, ckf, preferred_element_type=jnp.float32
-            ) * scale
-            p = _dense_probs(s, pos_grid, window, nope, layer, c)
-            o = jnp.einsum("bhgsk,bhkd->bhgsd", p.astype(cvf.dtype), cvf)
-        o = o.transpose(0, 3, 1, 2, 4).reshape(b, sdraft, c.q_dim)
-        return (_dense_out(x, o, layer, c), ck, cv), None
+            window, decode_kernel=decode_kernel, mesh=mesh,
+        ), None
 
     (x, ks, vs), _ = jax.lax.scan(
         layer_fn, (x, *_cache_pack(cache)),
         (params["layers"], windows, nopes, jnp.arange(c.n_layers)),
     )
     cache = _cache_unpack(ks, vs)
+    x = model_norm(x, params["final_norm"], c)
+    return _head_logits(params, x, c, eq="bse,ev->bsv"), cache
+
+
+def _verify_layer(
+    x, layer: dict, li, c: LlamaConfig, ck, cv, positions, pos_grid, write_mask,
+    rope, nope, temp, window, ring=None, stats=None,
+    decode_kernel: str = "einsum", mesh=None,
+):
+    """One dense layer of a verify step, the one copy → (x, ck, cv[,
+    stats]): the S tokens' K/V written at their per-row positions into
+    the stacked buffers ``ck`` / ``cv`` in place (a ring's one token at
+    a time, :func:`_cwrite_ring`), then attended over. Arguments as
+    :func:`_decode_layer` takes them."""
+    b, sdraft = pos_grid.shape
+    q, k, v = _dense_in(x, layer, c, rope, nope, temp)
+    if ring is None:
+        ck = _cwrite_rows(ck, li, positions, write_mask, _cstored(k, ck))
+        cv = _cwrite_rows(cv, li, positions, write_mask, _cstored(v, cv))
+    else:
+        ck = _cwrite_ring(ck, li, positions, write_mask, k, axis=1, unroll=False)
+        cv = _cwrite_ring(cv, li, positions, write_mask, v, axis=1, unroll=False)
+    ckl, cvl = _clayer(ck, li), _clayer(cv, li)
+    ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
+    cvf = _cfull(cvl, v.dtype)
+    # grouped-query attention against the KV-width cache (see
+    # decode_step): q [B, Hkv, G, S, D] · cache [B, Hkv, T, D]
+    grp = c.n_heads // c.n_kv_heads
+    qg = q.reshape(b, c.n_kv_heads, grp, sdraft, c.head_dim)
+    if decode_kernel == "flash":
+        # ragged verify: rows flatten [G, S] row-major; row g*S+s
+        # attends keys <= pos+s inside the kernel (verify rides the
+        # SAME dispatch — sink column included — as decode)
+        qr = qg.reshape(b, c.n_kv_heads, grp * sdraft, c.head_dim)
+        o = _flash_attend(
+            qr, ckl, cvl, positions, window,
+            config=c, scale=c.attention_scale, grp=grp, rows_per_slot=sdraft,
+            sinks_leaf=layer.get("sinks"), mesh=mesh,
+        ).reshape(b, c.n_kv_heads, grp, sdraft, c.head_dim)
+    else:
+        with _attn_scope(window):
+            s = jnp.einsum(
+                "bhgsd,bhkd->bhgsk", qg, ckf, preferred_element_type=jnp.float32
+            ) * c.attention_scale
+            p = _dense_probs(s, pos_grid, window, nope, layer, c, ring)
+            o = jnp.einsum("bhgsk,bhkd->bhgsd", p.astype(cvf.dtype), cvf)
+    o = o.transpose(0, 3, 1, 2, 4).reshape(b, sdraft, c.q_dim)
+    if stats is None:
+        return _dense_out(x, o, layer, c), ck, cv
+    valid = jnp.broadcast_to(write_mask[:, None], (b, sdraft))
+    return _dense_out(x, o, layer, c, stats, valid) + (ck, cv)
+
+
+def _verify_step_groups(
+    params: dict, cache: dict, tokens, positions, c: LlamaConfig, write_mask
+) -> tuple[jax.Array, dict]:
+    """:func:`verify_step` of a grouped-query model of layer GROUPS:
+    :func:`_verify_layer` at each run's attention shape, the buffers the
+    carry of every scan."""
+    from dstack_tpu.models.llama import dual_rope_freqs
+
+    b, sdraft = tokens.shape
+    x = _embed_lookup(params, tokens, c)
+    pos_grid = positions[:, None] + jnp.arange(sdraft)[None, :]  # [B, S]
+    ropes = jax.tree.map(
+        lambda a: a.reshape(b, sdraft, a.shape[-1]),
+        dual_rope_freqs(c, pos_grid.reshape(-1)),
+    )
+
+    def one_layer(carry, layer, li, run):
+        x, cache = carry
+        cos, sin = llama.layer_rope(ropes, c, run.window)
+        nk, nv = _group_kv(run)
+        stats = cache.get("moe_stats")
+        x, *rest = _verify_layer(
+            x, layer, li, run.config, cache[nk], cache[nv], positions,
+            pos_grid, write_mask,
+            lambda t: _rope_rows(t, cos, sin, interleaved=c.rope_interleaved),
+            False, None, run.window,
+            positions + (sdraft - 1) if run.window else None, stats,
+        )
+        if stats is not None:
+            cache = {**cache, "moe_stats": rest.pop(0)}
+        return (x, {**cache, nk: rest[0], nv: rest[1]}), None
+
+    (x, cache), _ = _walk_layer_groups(params, (x, cache), one_layer, c)
     x = model_norm(x, params["final_norm"], c)
     return _head_logits(params, x, c, eq="bse,ev->bsv"), cache
 
@@ -2051,7 +2474,7 @@ def copy_cache_prefix(cache: dict, src, dst, *, p: int) -> dict:
     traced scalars so one compile serves every slot pair."""
     # token axis per cache tensor: MLA latent and index keys [L,B,T,R]
     # → 2; k/v [L,B,H,T,D] → 3; int8 scales k_s/v_s [L,B,H,T] → 3 (last)
-    t_axis = {"ckv": 2, "idx": 2, "win": 2, "k": 3, "v": 3, "k_s": 3, "v_s": 3}
+    t_axis = {"ckv": 2, "idx": 2, "k": 3, "v": 3, "k_s": 3, "v_s": 3}
     out = {}
     for name, a in cache.items():
         if name == "moe_stats":  # counts, not a slot's state
@@ -2061,7 +2484,7 @@ def copy_cache_prefix(cache: dict, src, dst, *, p: int) -> dict:
         # a window ring is copied whole: its rows are not in position
         # order (the engine only offers a source whose ring still holds
         # the window before ``p``, InferenceEngine._find_prefix_source)
-        if name != "win":
+        if not _is_ring(name):
             rows = jax.lax.slice_in_dim(rows, 0, p, axis=t_axis[name])
         idx = [jnp.asarray(0, jnp.int32)] * a.ndim
         idx[1] = dst
@@ -2179,6 +2602,21 @@ class InferenceEngine:
         self._indexer_layers = (
             config.n_layers - config.layer_types.count("window")
             if config.mla and _indexed(config, max_seq) else 0
+        )
+        # window layers, of either family: the rows of their ring (0:
+        # none), the share of the cache's bytes they take, and host-side
+        # counters of the keys their mask lets a decoded token see
+        rings = [a for n, a in self.cache.items() if _is_ring(n)]
+        self._ring_rows = rings[0].shape[-2] if rings else 0
+        self._window_layers = config.layer_types.count("window")
+        size = {
+            n: a.size * a.dtype.itemsize
+            for n, a in self.cache.items() if n != "moe_stats"
+        }
+        total = sum(size.values())
+        self.metrics.family("dtpu_serve_kv_cache_bytes").set(total)
+        self.metrics.family("dtpu_serve_kv_window_pool_percent").set(
+            100.0 * sum(b for n, b in size.items() if _is_ring(n)) / total
         )
         self._auto_seed = seed
         # per-slot host state
@@ -2449,7 +2887,7 @@ class InferenceEngine:
         best_len, best_src = 0, None
         # a window ring keeps a slot's newest rows only: a source serves
         # a prefix only while the window before its end is still there
-        ring = self.cache["win"].shape[2] if "win" in self.cache else 0
+        ring = self._ring_rows
         for s, cached in self._prefix_registry.items():
             common = _common_prefix_len(cached, prompt)
             # at least one real tail token must prefill (it produces
@@ -2925,7 +3363,17 @@ class InferenceEngine:
             m.family("dtpu_serve_decode_step_seconds").observe(dt)
             m.family("dtpu_serve_tokens_generated_total").inc(n_tokens)
             if self._indexer_layers:
-                self._count_indexed(out)
+                self._count_keys(
+                    out, self.config.index_topk, self._indexer_layers,
+                    "dtpu_serve_indexer_keys_selected_total",
+                    "dtpu_serve_indexer_keys_in_context_total",
+                )
+            if self._window_layers:
+                self._count_keys(
+                    out, self.config.sliding_window, self._window_layers,
+                    "dtpu_serve_window_keys_visible_total",
+                    "dtpu_serve_window_keys_in_context_total",
+                )
             if n_tokens and dt > 0:
                 # TPOT covers the whole batch: exemplar from the slot
                 # that yielded the most tokens this dispatch (ties by
@@ -3225,25 +3673,21 @@ class InferenceEngine:
         self.metrics.family("dtpu_serve_moe_tokens_routed_total").inc(routed)
         return x
 
-    def _count_indexed(self, out: dict) -> None:
-        """Host-side, from positions alone: the keys the sparse indexer
-        let this step's tokens attend to, and the keys in their
-        contexts, over the full-attention layers."""
-        topk = self.config.index_topk
-        picked = seen = 0
+    def _count_keys(self, out: dict, cap: int, layers: int, kept: str, total: str) -> None:
+        """Host-side, from positions alone: the keys a mask that keeps at
+        most ``cap`` of a context (the sparse indexer's selection over
+        the full layers, the window layers' window) let this step's
+        tokens attend to → counter ``kept``, and the causal keys in
+        their contexts → ``total``, over the ``layers`` it acts on."""
+        let = seen = 0
         for slot, toks in out.items():
             # the slot's last token was decoded with lengths[slot] keys
             # in its context, the one before with one fewer
             for ctx in range(self.lengths[slot] - len(toks) + 1, self.lengths[slot] + 1):
-                picked += min(ctx, topk)
+                let += min(ctx, cap)
                 seen += ctx
-        m = self.metrics
-        m.family("dtpu_serve_indexer_keys_selected_total").inc(
-            picked * self._indexer_layers
-        )
-        m.family("dtpu_serve_indexer_keys_in_context_total").inc(
-            seen * self._indexer_layers
-        )
+        self.metrics.family(kept).inc(let * layers)
+        self.metrics.family(total).inc(seen * layers)
 
     def _all_greedy(self, live: list) -> bool:
         """True when every live slot is plain-greedy with no penalties

@@ -270,4 +270,29 @@ def new_serve_registry() -> Registry:
         "Token x expert-layer routings behind those picks (a model "
         "holding every expert counts none)",
     )
+    # window layers beside full ones (either family): what their mask
+    # lets a decoded token see of its context, and what their ring
+    # takes of the cache
+    r.counter(
+        "dtpu_serve_window_keys_visible_total",
+        "Keys the window layers' mask let decoded tokens see, summed "
+        "over window layers: min(context, sliding_window) a token a "
+        "layer (host-side, from positions)",
+    )
+    r.counter(
+        "dtpu_serve_window_keys_in_context_total",
+        "Causal keys in the context of those tokens, summed the same "
+        "way: the denominator of the visible share",
+    )
+    r.gauge(
+        "dtpu_serve_kv_cache_bytes",
+        "Bytes of the K/V (or latent) cache buffers allocated at "
+        "engine construction, all layer kinds",
+    )
+    r.gauge(
+        "dtpu_serve_kv_window_pool_percent",
+        "Of those bytes, the share held by the window layers' rings "
+        "(sized by the window, not by max_seq); 0 for a model of one "
+        "kind of layer",
+    )
     return r

@@ -59,15 +59,21 @@ def topo():
 def _no_compile_cache():
     """A described-device executable is written to the persistent cache
     but can never be read back without a chip (each later compile would
-    warn and recompile), so the cache is off around this module."""
+    warn and recompile), so the cache is off around this module — and
+    stays off for the rest of the process. Twice in three whole runs of
+    PR 33's tree (and once in PR 31's) the xdist worker that had
+    compiled this module's programs for the described TPU died with a
+    segmentation fault inside ``compilation_cache.
+    get_executable_and_time`` when a later test of another module read
+    a CPU executable back (``test_prefix_registry``'s ``decode_loop``;
+    the entry was sound: other processes loaded it before and after;
+    not reproduced in one process). A process that has loaded libtpu
+    for a described topology compiles what it needs itself."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
 
 
 @pytest.fixture
@@ -325,16 +331,18 @@ def test_mla_moe_decode_step_deepseek_widths(topo):
     _fits(compiled)
 
 
-def _layer_groups_config():
-    """The benchmark's configuration of layer groups at its published
-    widths (two latent shapes, an indexer, a window ring, 32 of 256
-    experts held): ``benchmark/configs/dots3-note-prev-5l-ep8.json``."""
+def _layer_groups_config(name="dots3-note-prev-5l-ep8"):
+    """A benchmark configuration of layer groups at its published
+    widths: ``benchmark/configs/dots3-note-prev-5l-ep8.json`` (two
+    latent shapes, an indexer, a window ring, 32 of 256 experts held),
+    or ``laguna-s-2.1-13l-ep8`` (grouped-query layers of 48 | 72 query
+    heads, K/V at ``max_seq`` beside K/V rings, 13 layers)."""
     import json
 
     from benchmark import launch
 
     root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    with open(os.path.join(root, "benchmark", "configs", "dots3-note-prev-5l-ep8.json")) as f:
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
         return launch.build_llama_config(json.load(f)["llama_config"])
 
 
@@ -392,6 +400,41 @@ def test_window_cache_argument_bytes_do_not_follow_max_seq(topo):
     assert ring_a == ring_b == (3, 16, 768, 1088)
     # two full layers x 16 slots x 8192 more rows x (576 latent + 128 index) x bf16
     assert large - small == 2 * 16 * 8192 * (576 + 128) * 2
+
+
+def test_grouped_query_rings_are_the_condition_for_fitting(topo):
+    """``laguna-s-2.1-13l-ep8`` at 16 x 8192: the decode step's
+    arguments are the weights (9.36 GB) and a cache of 2.60 GB, the 9
+    window layers' K/V in rings of 768 rows; doubling ``--max-seq``
+    grows the 4 full layers' buffers alone; with every layer at
+    ``max_seq`` the cache would be 6.98 GB and the arguments 16.34 GB:
+    past what ``_fits`` allows a program before any ``temp`` (with the
+    decode step's 0.63 GB, past the chip's 15.75 GiB = 16.91 GB too)."""
+    config = _layer_groups_config("laguna-s-2.1-13l-ep8")
+    sizes = {}
+    for max_seq in (8192, 16384):
+        params, cache, sds = _abstract_engine_state(
+            config, SingleDeviceSharding(topo.devices[0]), max_seq=max_seq
+        )
+        lowered = jax.jit(
+            lambda p, c, t, pos, m: eng.decode_step(p, c, t, pos, config, m)
+        ).lower(
+            params, cache, sds((16,), jnp.int32), sds((16,), jnp.int32),
+            sds((16,), jnp.bool_),
+        )
+        args = jax.tree.leaves(lowered.in_avals)
+        sizes[max_seq] = sum(a.size * a.dtype.itemsize for a in args)
+        assert cache["win_k"].shape == cache["win_v"].shape == (9, 16, 8, 768, 128)
+        assert cache["k"].shape == (4, 16, 8, max_seq, 128)
+    row = 2 * 16 * 8 * 128 * 2  # K and V, slots, KV heads, head_dim, bf16: a layer's bytes a row
+    assert sizes[16384] - sizes[8192] == 4 * 8192 * row
+    weights = 2 * config.num_params()
+    assert weights == 2 * 4_681_933_824
+    cache_bytes = 4 * 8192 * row + 9 * 768 * row
+    assert 2.59e9 < cache_bytes < 2.61e9
+    assert abs(sizes[8192] - weights - cache_bytes) < 1e6  # + tokens, positions, counts
+    assert weights + 13 * 8192 * row > HBM_BYTES > sizes[8192] + 1e9
+    assert weights + 13 * 8192 * row + 0.63e9 > 15.75 * 2**30
 
 
 # ---------------------------------------------------------------------------
@@ -468,6 +511,7 @@ def _serving_program(case, sds, place):
         "int8kv-llama_1b": (llama.LLAMA_32_1B, 2048, "int8"),
         "llama_1b": (llama.LLAMA_32_1B, 2048, None),
         "layer_groups": (None, 8192, None),
+        "gqa_groups": (_layer_groups_config("laguna-s-2.1-13l-ep8"), 8192, None),
     }[model]
     config = config or _layer_groups_config()
     params = place(jax.eval_shape(
@@ -532,19 +576,39 @@ def _holds_no_second_cache(
     _fits(compiled)
 
 
-@pytest.mark.parametrize("case", [
-    "decode_step-minitron_4b",  # the chat cell: 16 × 1536, cache 3.22 GB
-    "decode_loop-deepseek_v2_lite_9l",  # reasoning: 16 × 8192, latent 1.36 GB
-    "verify_step-minitron_4b",  # S = 5 rows a slot on the chat cell's shapes
-    "decode_step-int8kv-llama_1b",  # (int8, scale) leaves, head_dim 64
-])
+#: what the compiler holds beside the mixed cell's cache that is no
+#: cache: the window layers' stacked ``wq`` and ``wk`` [9, 3072, 9216 |
+#: 1024], transposed whole once a call (a layer's projection weights are
+#: transposed a layer in every dense model's decode scan; of this stack
+#: the compiler hoists it out of the loops: PERF.md §7)
+_WINDOW_QK = 2.0 * 9 * 3072 * (9216 + 1024)
+
+# case → room beside a quarter of the cache
+_DECODE = {
+    "decode_step-minitron_4b": 0,  # the chat cell: 16 × 1536, cache 3.22 GB
+    "decode_loop-deepseek_v2_lite_9l": 0,  # reasoning: 16 × 8192, latent 1.36 GB
+    "verify_step-minitron_4b": 0,  # S = 5 rows a slot on the chat cell's shapes
+    "decode_step-int8kv-llama_1b": 0,  # (int8, scale) leaves, head_dim 64
+    # the mixed cell: 16 × 8192, K/V of 4 full layers 2.15 GB + 9 rings 0.45 GB
+    "decode_step-gqa_groups": _WINDOW_QK,
+    "decode_loop-gqa_groups": _WINDOW_QK,
+    "verify_step-gqa_groups": _WINDOW_QK,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DECODE))
 def test_decode_program_holds_no_second_cache(topo, case):
     """Parent readings (PR 24's tree), ``temp`` / cache: decode_step
     3.32 / 3.22 GB, decode_loop 3.06 / 1.36 GB, verify_step 3.26 / 3.22
     GB, int8 0.79 / 0.57 GB. The latent's per-layer slice is still
     copied once a layer (151 MB at these shapes: the two latent einsums
-    will not take a fused slice)."""
-    _holds_no_second_cache(topo, case, layer_of=lambda name: name != "ckv")
+    will not take a fused slice). The grouped-query layer groups (PR 33,
+    no parent: the programs are new), ``temp`` beside a cache of 2.60
+    GB and arguments of 11.96: decode_step 0.63, decode_loop 0.76,
+    verify_step 0.96 GB, no cache-sized move in any."""
+    _holds_no_second_cache(
+        topo, case, layer_of=lambda name: name != "ckv", room=_DECODE[case]
+    )
 
 
 def _scores(g, heads, max_seq, c=256):
@@ -571,6 +635,12 @@ _PREFILL = {
     # leaves re-laid out around the layers (parent: 18 moves, temp 0.90 GB)
     "prefill_packed_step@1-layer_groups": (_scores(1, 64, 8192), 6),
     "prefill_packed_step@4-layer_groups": (_scores(1, 64, 8192), 0),
+    # the mixed cell: K/V at max_seq beside K/V rings, 2.60 GB; a serial
+    # chunk is a wave of one row; a row's scores go by in blocks of 512
+    # keys (72 heads × 256 × 512 × f32)
+    "prefill_chunk_step@256-gqa_groups": (_WINDOW_QK, 0),
+    "prefill_packed_step@1-gqa_groups": (_WINDOW_QK, 0),
+    "prefill_packed_step@4-gqa_groups": (_WINDOW_QK + _scores(4, 72, 512), 0),
     # (int8, scale) leaves, head_dim 64; a short prompt's 16 rows are
     # half an int8 tile
     "prefill_chunk_step@short-int8kv-llama_1b": (0, 0),
@@ -592,7 +662,8 @@ def test_prefill_program_holds_no_second_cache(topo, _as_tpu, case):
     0.90 / 0.45 (18), G 4 1.12 / 0.45 (13); int8 Llama-3.2-1B chunk
     0.58 / 0.57 (22); bf16 Llama-3.2-1B chunk 1.22 / 1.07 (13), packed
     1.75 / 1.07 (4). Since PR 29: chunk steps 0.4-5 MB, packed waves
-    their scores. Left: a short prompt's 16-row bucket on a bf16
+    their scores. The grouped-query layer groups (PR 33, new programs):
+    chunk and G 1 0.64, G 4 0.99 GB beside 2.60, no move. Left: a short prompt's 16-row bucket on a bf16
     head_dim-64 cache (16 of a tile's 128 lanes) is still re-laid out
     whole around the loop (temp 2.15 / 1.07 GB; not a case here)."""
     room, known = _PREFILL[case]

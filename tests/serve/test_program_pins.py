@@ -51,12 +51,17 @@ NAMES = (
 #: whose digests these are) moved a line of the layer bodies: that they
 #: stand is the proof that every cell's programs are the ones the
 #: ledger's PR 29 lines measured
+_GROUPS = (
+    "decode_step", "decode_loop", "verify_step", "prefill_packed_step@1",
+    "prefill_packed_step@2", "prefill_packed_step@4",
+)
 CELLS = {
     "minitron-4b": (16, 1536, NAMES),
-    "dots3-note-prev-5l-ep8": (16, 8192, (
-        "decode_step", "decode_loop", "verify_step", "prefill_packed_step@1",
-        "prefill_packed_step@2", "prefill_packed_step@4",
-    )),
+    "dots3-note-prev-5l-ep8": (16, 8192, _GROUPS),
+    # taken on the tree of PR 33, which added the configuration and the
+    # dense family's walk over layer groups it runs through (two K/V
+    # caches, a period a scan step); the 68 pins above and below stood
+    "laguna-s-2.1-13l-ep8": (16, 8192, _GROUPS),
 }
 CELL_PINS = {
     "dots3-note-prev-5l-ep8": {
@@ -66,6 +71,14 @@ CELL_PINS = {
         "prefill_packed_step@1": "c8e31b1ccbf992ebe38cbe3d1ed75234adc4f0ba60c94f8fe21cd4dca0f5f732",
         "prefill_packed_step@2": "56aa8daefa334e623a9d7bb94f70f737cde1c5b54415d9b6d35a4ef268dd5f90",
         "prefill_packed_step@4": "281b1e89798de56f54659ceea9d0358a4011e22f800b9aa119b4dd997e0a9f83",
+    },
+    "laguna-s-2.1-13l-ep8": {
+        "decode_step": "b7fe76e64ae0c5ae8207ec16d1c04f345772307399cdf741bd8bd4c1417f6f75",
+        "decode_loop": "fd6d9bb6bf356ce00ebd0eb98ce1a762e93a029549285d6d3e62630b63f27558",
+        "verify_step": "863527a7810f3a1645c42b1f109abd981264446af76713aaf469792dec601e3a",
+        "prefill_packed_step@1": "8f39df1f55e4e644ecdbe712df1b9d94fa5457841e4fea9cee7a1ffe6e593e91",
+        "prefill_packed_step@2": "92f3dd51ee82bf69d5329288db95545904b8962629cbbf7f274b12da7dd18e7f",
+        "prefill_packed_step@4": "7da6acf1d559bbc0f69fc6b3a820752e83e8011a5a643ac343b82b14bcfaa4e2",
     },
     "minitron-4b": {
         "decode_step": "31cd7802fdfa5729183b1aa6346316af5f0a7b1d5a845041033888da8aa84ab7",
@@ -136,6 +149,18 @@ FAMILIES = {
         moe_act="oai_glu",
         rope_scaling=("yarn", 32.0, 32.0, 1.0, 64.0, 1.3465735902799727, False),
     ),
+    # grouped-query layer GROUPS (PR 33): window layers of another query
+    # head count in rings, yarn on half a head beside a plain local rope,
+    # per-head gates, a dense first layer, half of the experts held
+    "gqa-groups": dict(
+        n_layers=6, layer_types=("full", "window", "window", "full", "window", "window"),
+        sliding_window=8, swa_n_heads=6, attn_gate=True, partial_rotary=0.5,
+        swa_partial_rotary=1.0, rope_local_theta=10000.0,
+        rope_scaling=("yarn", 8.0, 32.0, 1.0, 16.0, 1.2), n_experts=4,
+        experts_per_token=2, experts_held=(2, 2), capacity_factor=2.0,
+        router_score="sigmoid", router_renorm=True, routed_scale=2.5,
+        moe_shared_expert=True, first_k_dense=1,
+    ),
 }
 TINY = tuple(sorted(FAMILIES)) + ("mla-tiny", "moe-tiny")
 TINY_NAMES = (
@@ -202,6 +227,12 @@ TINY_PINS = {
         "verify_step": "c0fd3d53be170bc18cf0ef868145eb59f1f621a88a7c98eb0b28d51892656d2e",
         "prefill_chunk_step@16": "3c95c42101623311b2bdfe7e627984666160fec5fdef1df42928cb741aa1e935",
         "prefill_packed_step@2": "7b42bbab4e82ee37d5d454b1bdfae798046ee7d0a48557ff405556a4dbf5eec6",
+    },
+    "gqa-groups": {
+        "decode_step": "4307a3ce009b56e58b3183ab5b3782476ce42a06640294c2ab84828ce814261e",
+        "verify_step": "9340551dfa7b86181c76874c2c026fad9dd1af0b2a0b808ae44b31a4b529c2c6",
+        "prefill_chunk_step@16": "acb6666f1c34ac16d28593cea0eb8bd76b6762fa256c1ef05964bc7f01ab1aeb",
+        "prefill_packed_step@2": "7c73724b9c2abe5dbe9edb6d8eabe5d41bf215d77796b6cf9c9528cc4b15f303",
     },
     "mla-tiny": {
         "decode_step": "63b1cdb1a9569580a8e0395583ed4a4ff1e84aa36232416294ccf00211131e1c",
