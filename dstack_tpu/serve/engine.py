@@ -336,6 +336,15 @@ def _indexed(c: LlamaConfig, tmax: int) -> bool:
     return bool(c.index_topk) and tmax > c.index_topk
 
 
+def _attends_masked(cache: dict, run) -> bool:
+    """Whether this run's layers of a latent model attend under an
+    explicit mask in decode and verify (a window layer's over its ring, a
+    full layer's the indexer's selection, which has its keys in the
+    cache where it can bite): else under the causal mask alone, over as
+    many key blocks as the live contexts hold (:func:`_attend_live`)."""
+    return bool(run.window) or "idx" in cache
+
+
 def _masked(c: LlamaConfig, tmax: int) -> bool:
     """Whether some layer attends under an explicit mask (a window
     ring's row order, an indexer's selection): then no static-offset
@@ -417,6 +426,10 @@ def _ring_mask(qpos: jax.Array, newest: jax.Array, rows: int, window: int):
     return (held[:, None, :] >= 0) & (age >= 0) & (age < window)
 
 
+#: keys a block of an attention that goes by its keys in blocks under a
+#: running softmax (a row whose length it does not divide: their gcd)
+_KEY_BLOCK = 512
+
 #: most bytes of f32 scores a masked latent attention holds at once (a
 #: verify step of 16 slots x 5 tokens against 8192 keys is 0.34 GB)
 _SCORE_BYTES = 1 << 29
@@ -453,7 +466,7 @@ def _attend_masked(
         pr = jax.nn.softmax(jnp.where(m[None], sc, NEG_INF), axis=-1)
         return jnp.einsum("hst,tr->hsr", pr.astype(r.dtype), r[:, :rank])
 
-    kb = math.gcd(t, 512)  # keys a block
+    kb = math.gcd(t, _KEY_BLOCK)  # keys a block
 
     def attend_blocks(args):
         q, r, m, blocks = args
@@ -498,6 +511,75 @@ def _attend_masked(
                 blocks = jnp.clip(-(-n_keys // kb), 1, t // kb).astype(jnp.int32)
             o_lat = jax.lax.map(attend_blocks, (q_abs, rows, mask, blocks))
         return _latent_values(o_lat, _mla_kb(layer, gc)[1], h, layer, gc)
+
+
+def _live_key_blocks(positions, write_mask, s: int, tmax: int):
+    """Key blocks of :data:`_KEY_BLOCK` (as many as divide ``tmax``)
+    that hold the longest context among a step's LIVE slots, its ``s``
+    new tokens included → (int32 scalar ≥ 1, keys a block). A dead
+    slot's position is stale and does not count."""
+    kb = math.gcd(tmax, _KEY_BLOCK)
+    n_keys = jnp.max(jnp.where(write_mask, positions + s, 0))
+    return jnp.clip(-(-n_keys // kb), 1, tmax // kb).astype(jnp.int32), kb
+
+
+def _attend_live(
+    q_abs: jax.Array,  # [B, H, S, R] absorbed queries
+    ckv: jax.Array,  # [L, B, T, R] the stacked latents, this step's written
+    li,  # the layer's row of ``ckv``
+    positions: jax.Array,  # [B] position of each slot's first query
+    write_mask: jax.Array,  # [B] bool: the step's live slots
+    gc: LlamaConfig,
+) -> jax.Array:
+    """Absorbed latent attention of a decode (S = 1) or verify step under
+    the causal mask alone → [B, H, S, rank]. The layer's keys go by in
+    blocks under a running softmax, as many blocks as hold the longest
+    context among the LIVE slots: a server reserves ``max_seq`` rows a
+    slot for its longest request and serves mostly short ones, and the
+    work follows what the slots hold (at a context near ``max_seq`` it
+    is the whole row, in ``T / 512`` blocks). Each block is one
+    ``dynamic_slice`` of the stacked leaf, so no layer's slice is
+    materialized (whole, it was a copy of 151 MB a layer a token at
+    16 × 8192: PERF.md §6, PR 35). A dead slot's row attends over the
+    same blocks under its stale position: finite, and discarded."""
+    b, nh, s, width = q_abs.shape
+    rank, scale = gc.kv_lora_rank, gc.attention_scale
+    blocks, kb = _live_key_blocks(positions, write_mask, s, ckv.shape[2])
+    q_abs = q_abs.astype(ckv.dtype)
+    qpos = (positions[:, None] + jnp.arange(s)[None, :])[:, None, :, None]
+    li = jnp.asarray(li, jnp.int32)
+
+    def block(i, carry):
+        top, den, acc = carry  # running max [B,H,S], sum [B,H,S], values [B,H,S,rank]
+        rk = jax.lax.dynamic_slice(
+            ckv, (li, 0, i * kb, 0), (1, b, kb, width)
+        )[0]  # [B, kb, R]
+        sc = jnp.einsum(
+            "bhsr,btr->bhst", q_abs, rk, preferred_element_type=jnp.float32
+        ) * scale
+        kj = i * kb + jnp.arange(kb)
+        sc = jnp.where(kj[None, None, None, :] <= qpos, sc, NEG_INF)
+        # key 0 is visible to every query, so after the first block no
+        # running maximum is NEG_INF
+        new_top = jnp.maximum(top, sc.max(-1))
+        keep = jnp.exp(top - new_top)
+        pr = jnp.exp(sc - new_top[..., None])
+        acc = acc * keep[..., None] + jnp.einsum(
+            "bhst,btr->bhsr", pr.astype(rk.dtype), rk[..., :rank],
+            preferred_element_type=jnp.float32,
+        )
+        return new_top, den * keep + pr.sum(-1), acc
+
+    with jax.named_scope("dtpu.attn_full"):
+        _, den, acc = jax.lax.fori_loop(
+            0, blocks, block,
+            (
+                jnp.full((b, nh, s), NEG_INF, jnp.float32),
+                jnp.zeros((b, nh, s), jnp.float32),
+                jnp.zeros((b, nh, s, rank), jnp.float32),
+            ),
+        )
+        return (acc / den[..., None]).astype(ckv.dtype)
 
 
 def _attend_causal(q_abs, rows, q_offset, gc: LlamaConfig, c: LlamaConfig):
@@ -1121,7 +1203,6 @@ def _decode_step_mla(
     attends over its ring."""
     from dstack_tpu.models.llama import dual_rope_freqs
 
-    b = tokens.shape[0]
     x = _embed_lookup(params, tokens, c)[:, None, :]
     ropes = dual_rope_freqs(c, positions)  # [B, rope/2] each
     valid = write_mask[:, None] if "moe_stats" in cache else None
@@ -1138,7 +1219,8 @@ def _decode_step_mla(
             rope_key=lambda t: rope(t[:, :, None])[:, 0, 0][:, None],
         )
         cache = _stacked_write(cache, name, li, positions, write_mask, new_row)
-        row = _clayer(cache[name], li)  # [B, Tmax, R]
+        if _attends_masked(cache, run):
+            row = _clayer(cache[name], li)  # [B, Tmax, R]
         w_kb_nope, w_kb_v = _mla_kb(layer, gc)
         q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, :, 0], w_kb_nope)
         q_abs = jnp.concatenate([q_lat, q_pe[:, :, 0]], axis=-1)  # [B,H,R]
@@ -1156,18 +1238,10 @@ def _decode_step_mla(
         if mask is not None:
             o = _attend_masked(q_abs[:, :, None], row, mask, h, layer, gc, run.window)
         else:
-            s = jnp.einsum(
-                "bhr,btr->bht", q_abs, row, preferred_element_type=jnp.float32
-            ) * gc.attention_scale
-            kj = jnp.arange(tmax)[None, None, :]
-            s = jnp.where(kj <= positions[:, None, None], s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o_lat = jnp.einsum(
-                "bht,btr->bhr", p.astype(row.dtype), row[..., : gc.kv_lora_rank]
+            o_lat = _attend_live(
+                q_abs[:, :, None], cache[name], li, positions, write_mask, gc
             )
-            o = jnp.einsum("bhr,rhv->bhv", o_lat, w_kb_v)
-            o = llama.head_gate(o, h, layer, gc, "bth,bhv->bhv")
-            o = o.reshape(b, 1, gc.o_dim)
+            o = _latent_values(o_lat, w_kb_v, h, layer, gc)
         return _latent_out(x, cache, o, layer, c, valid)
 
     x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
@@ -1205,10 +1279,10 @@ def _verify_step_mla(
         # MLA rope is always interleaved
         rope_rows = lambda t: _rope_rows(t, cos, sin, interleaved=True)
         name = "win" if run.window else "ckv"
-        tmax = cache[name].shape[2]
         h, qa, q_nope, q_pe, new_rows = _latent_in(x, layer, gc, c, rope_rows)
         cache = write(cache, name, li, new=new_rows)
-        row = _clayer(cache[name], li)  # [B, Tmax, R]
+        if _attends_masked(cache, run):
+            row = _clayer(cache[name], li)  # [B, Tmax, R]
         q_abs, w_kb_v = _latent_absorb(q_nope, q_pe, layer, gc)
         cache, mask = _latent_mask(
             cache, li, run, h, qa, layer, rope_rows, write, _clayer,
@@ -1220,15 +1294,8 @@ def _verify_step_mla(
                 n_keys=positions + sdraft,
             )
         else:
-            s = jnp.einsum(
-                "bhsr,btr->bhst", q_abs, row, preferred_element_type=jnp.float32
-            ) * gc.attention_scale
-            kj = jnp.arange(tmax)[None, None, None, :]  # [1,1,1,T]
-            qpos = pos_grid[:, None, :, None]  # [B,1,S,1]
-            s = jnp.where(kj <= qpos, s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o_lat = jnp.einsum(
-                "bhst,btr->bhsr", p.astype(row.dtype), row[..., : gc.kv_lora_rank]
+            o_lat = _attend_live(
+                q_abs, cache[name], li, positions, write_mask, gc
             )
             o = _latent_values(o_lat, w_kb_v, h, layer, gc)
         return _latent_out(x, cache, o, layer, c, valid)
@@ -1405,7 +1472,7 @@ def _attend_rows(
         pr = jax.nn.softmax(scores(q, k, m), axis=-1)
         return jnp.einsum("hgst,htd->hgsd", pr.astype(v.dtype), v)
 
-    kb = math.gcd(t, 512)  # keys a block
+    kb = math.gcd(t, _KEY_BLOCK)  # keys a block
 
     def attend_blocks(args):
         q, k, v, m, blocks = args
@@ -2603,6 +2670,15 @@ class InferenceEngine:
             config.n_layers - config.layer_types.count("window")
             if config.mla and _indexed(config, max_seq) else 0
         )
+        # full-attention layers, which reserve max_seq rows a slot, and
+        # the keys a block of their decode attention where it reads only
+        # the blocks the live contexts hold (the latent family under the
+        # causal mask alone, :func:`_attend_live`; 0: whole rows)
+        self._full_layers = config.n_layers - config.layer_types.count("window")
+        self._key_block = (
+            math.gcd(max_seq, _KEY_BLOCK)
+            if config.mla and not _indexed(config, max_seq) else 0
+        )
         # window layers, of either family: the rows of their ring (0:
         # none), the share of the cache's bytes they take, and host-side
         # counters of the keys their mask lets a decoded token see
@@ -3362,6 +3438,7 @@ class InferenceEngine:
             m.family("dtpu_serve_decode_steps_total").inc(1)
             m.family("dtpu_serve_decode_step_seconds").observe(dt)
             m.family("dtpu_serve_tokens_generated_total").inc(n_tokens)
+            self._count_decode_keys(out)
             if self._indexer_layers:
                 self._count_keys(
                     out, self.config.index_topk, self._indexer_layers,
@@ -3688,6 +3765,35 @@ class InferenceEngine:
                 seen += ctx
         self.metrics.family(kept).inc(let * layers)
         self.metrics.family(total).inc(seen * layers)
+
+    def _count_decode_keys(self, out: dict) -> None:
+        """Host-side, from positions alone, what the program took from
+        ``positions`` and ``write_mask`` on the device: the key rows the
+        full layers' decode attention read in this call's token steps →
+        ``dtpu_serve_decode_keys_read_total``, beside the rows the
+        slots reserve → ``dtpu_serve_decode_keys_reserved_total``. A
+        program that reads whole rows counts read = reserved."""
+        if self._last_step_phase == "spec":  # one call: S rows a slot
+            longest = [
+                max(self.lengths[i] - len(t) for i, t in out.items())
+                + self.spec_draft + 1
+            ]
+        else:  # token step k: the slots that emitted a k-th token
+            longest = [
+                max(
+                    self.lengths[i] - len(t) + k + 1
+                    for i, t in out.items() if len(t) > k
+                )
+                for k in range(max(len(t) for t in out.values()))
+            ]
+        kb = self._key_block or self.max_seq  # whole rows: one block
+        rows = sum(kb * min(-(-n // kb), self.max_seq // kb) for n in longest)
+        per = self.max_batch * self._full_layers
+        m = self.metrics
+        m.family("dtpu_serve_decode_keys_read_total").inc(rows * per)
+        m.family("dtpu_serve_decode_keys_reserved_total").inc(
+            self.max_seq * len(longest) * per
+        )
 
     def _all_greedy(self, live: list) -> bool:
         """True when every live slot is plain-greedy with no penalties

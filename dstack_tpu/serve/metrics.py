@@ -246,6 +246,22 @@ def new_serve_registry() -> Registry:
         "top_p or min_p, so that the sampler sorted the vocabulary "
         "(host-side, from the slots' parameters)",
     ).inc(0)
+    # the full layers' decode attention: the key rows it read beside the
+    # rows the slots reserve (series exist from boot; equal for a program
+    # that reads whole rows)
+    r.counter(
+        "dtpu_serve_decode_keys_read_total",
+        "Key rows the full-attention layers' decode attention read: "
+        "slots x key blocks up to the longest live context x layers a "
+        "token step where the program follows the contexts, slots x "
+        "max_seq x layers where it reads whole rows (host-side, from "
+        "positions)",
+    ).inc(0)
+    r.counter(
+        "dtpu_serve_decode_keys_reserved_total",
+        "Key rows those layers reserve: slots x max_seq x layers a "
+        "token step, the denominator of the read share",
+    ).inc(0)
     # layer groups: the sparse indexer and a chip's share of the experts
     # (series stay 0 for a model that has neither)
     r.counter(
