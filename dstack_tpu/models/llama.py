@@ -220,6 +220,22 @@ class LlamaConfig:
     # are computed, and the layer passes their partial sum on (what
     # expert parallelism holds on one chip); () = all of them
     experts_held: tuple = ()
+    # --- expert layers with a shortcut across several sublayers ---
+    # a layer is `sublayers` pairs of (attention, dense FFN of width
+    # dense_intermediate), each behind its own residual, with weights
+    # and a cache row of its own: sub-trees "sub0", "sub1", ... of the
+    # layer stack, each a plain dense layer's leaves [L, ...] (stacked
+    # [L, sublayers, ...] the layer scan's slice has a consumer a
+    # sublayer, and the TPU compiler copies every such weight out a
+    # layer: PERF.md §6, PR 37). The layer's experts read the
+    # normed hidden after the FIRST attention, and their sum joins the
+    # residual after the LAST dense FFN: the branch runs beside the
+    # sublayers between (arXiv:2509.01322). 1 = attention, then one MLP
+    sublayers: int = 1
+    # router outputs past n_experts that stand for no weights: a pick of
+    # one returns the expert's input times its gate ("zero-computation"
+    # identity experts); the router is n_experts + zero_experts wide
+    zero_experts: int = 0
 
     def __post_init__(self):
         kinds = set(self.layer_types)
@@ -242,6 +258,20 @@ class LlamaConfig:
             raise ValueError(
                 "window layers need sliding_window and the swa_* sizes"
             )
+        if self.sublayers > 1 and not (
+            self.mla and self.n_experts and self.dense_intermediate
+            and self.pre_norm and not self.layer_types
+            and not self.first_k_dense and not self.parallel_block
+            and not self.post_norms and not self.proj_bias
+            and not self.moe_shared_expert and not self.moe_bias
+        ):
+            raise ValueError(
+                "sublayers > 1: latent attention, experts and "
+                "dense_intermediate, one kind of plainly pre-normed "
+                "layer without biases or a shared expert"
+            )
+        if self.zero_experts and not self.n_experts:
+            raise ValueError("zero_experts are outputs of an expert router")
         if self.qk_norm and self.qk_norm_flat:
             raise ValueError(
                 "qk_norm (per-head, Qwen3) and qk_norm_flat (full "
@@ -364,7 +394,10 @@ class LlamaConfig:
         """Attention parameters over all layers (a window layer has
         its own shape)."""
         n_win = self.layer_types.count("window")
-        total = (self.n_layers - n_win) * self._attn_params_per_layer()
+        total = (
+            (self.n_layers - n_win) * self.sublayers
+            * self._attn_params_per_layer()
+        )
         if n_win:
             total += n_win * self.window_config._attn_params_per_layer()
         return total
@@ -388,15 +421,18 @@ class LlamaConfig:
         )
         sink = self.n_heads if self.attn_sinks and small else 0
         moe_layers = self.n_layers - self.first_k_dense
+        router = self.n_experts + self.zero_experts  # its width
         per_moe = (
-            extras
+            self.sublayers * extras
             + max(1, experts) * mats * h * self.intermediate_size
             + mlp_bias
             + self._shared_expert_params()
-            + (h * self.n_experts if self.n_experts else 0)
-            + (self.n_experts if self.router_bias else 0)
+            + (h * router if self.n_experts else 0)
+            + (router if self.router_bias else 0)
             + moe_bias + sink
         )
+        if self.sublayers > 1:  # a dense FFN a sublayer beside the experts
+            per_moe += self.sublayers * mats * h * self.dense_intermediate
         per_dense = (
             extras
             + mats * h * (self.dense_intermediate or self.intermediate_size)
@@ -593,6 +629,16 @@ MLA_TINY = LlamaConfig(  # for tests / virtual meshes
     moe_shared_expert=True, moe_shared_intermediate=64,
     first_k_dense=1, dense_intermediate=192,
 )
+SCMOE_TINY = LlamaConfig(  # for tests: layers of two sublayers, the experts across
+    vocab_size=512, hidden_size=128, n_layers=2, n_heads=4, n_kv_heads=4,
+    head_dim=16, intermediate_size=64, max_seq_len=256, dtype=jnp.float32,
+    remat=False,
+    q_lora_rank=48, kv_lora_rank=64, qk_nope_head_dim=32,
+    qk_rope_head_dim=16, v_head_dim=24, mla_lora_rescale=True,
+    sublayers=2, dense_intermediate=192,
+    n_experts=8, zero_experts=4, experts_per_token=3, capacity_factor=4.0,
+    router_bias=True, routed_scale=6.0,
+)
 
 _GPT_OSS_COMMON = dict(
     vocab_size=201088, hidden_size=2880, n_heads=64, n_kv_heads=8,
@@ -631,6 +677,7 @@ CONFIGS = {
     "deepseek-v2-lite": DEEPSEEK_V2_LITE,
     "deepseek-v3": DEEPSEEK_V3,
     "mla-tiny": MLA_TINY,
+    "scmoe-tiny": SCMOE_TINY,
     "glm-4-9b": GLM_4_9B,
     "olmo-2-7b": OLMO2_7B,
     "command-r-35b": COMMAND_R_35B,
@@ -639,6 +686,12 @@ CONFIGS = {
     "gpt-oss-20b": GPT_OSS_20B,
     "gpt-oss-120b": GPT_OSS_120B,
 }
+
+
+#: the leaves of a layer of several sublayers (``sublayers`` > 1) that
+#: are the layer's own, one a layer: its router and its experts; every
+#: other leaf is a sublayer's, in its sub-tree ``sub<i>``
+EXPERT_LEAVES = ("w_router", "router_bias", "w_gate", "w_up", "w_down")
 
 
 def param_specs(config: LlamaConfig) -> dict:
@@ -738,6 +791,17 @@ def param_specs(config: LlamaConfig) -> dict:
     if config.post_norms:
         layer["attn_post_norm"] = L + (None,)
         layer["mlp_post_norm"] = L + (None,)
+    if config.sublayers > 1:
+        # the router and the experts are the layer's; every other leaf
+        # is a sublayer's, beside its dense FFN under the plain names
+        sub = {
+            **{k: v for k, v in layer.items() if k not in EXPERT_LEAVES},
+            **dense_mlp,
+        }
+        layer = {
+            **{k: v for k, v in layer.items() if k in EXPERT_LEAVES},
+            **{f"sub{i}": dict(sub) for i in range(config.sublayers)},
+        }
     specs = {
         "embed": ("vocab", "embed_fsdp"),
         "layers": layer,
@@ -855,7 +919,7 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
 
     L = c.n_layers - c.first_k_dense - n_win
     if c.n_experts:
-        E, EH = c.n_experts, c.n_experts_held
+        E, EH = c.n_experts + c.zero_experts, c.n_experts_held
         mlp = {
             "mlp_norm": norm_init((L, c.hidden_size)),
             "w_router": normal(
@@ -890,7 +954,9 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
                 k[5], (L, c.hidden_size, c.intermediate_size)
             )
     if c.n_experts and c.router_bias:
-        mlp["router_bias"] = jnp.zeros((L, c.n_experts), jnp.float32)
+        mlp["router_bias"] = jnp.zeros(
+            (L, c.n_experts + c.zero_experts), jnp.float32
+        )
     if c.n_experts and c.moe_bias:
         mlp["b_router"] = jnp.zeros((L, c.n_experts), jnp.float32)
         mlp["b_gate"] = jnp.zeros((L, c.n_experts, c.intermediate_size), dt)
@@ -932,6 +998,29 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
     if c.post_norms:
         params["layers"]["attn_post_norm"] = norm_init((L, c.hidden_size))
         params["layers"]["mlp_post_norm"] = norm_init((L, c.hidden_size))
+    if c.sublayers > 1:
+        # the router and the experts stay the layer's; each sublayer is
+        # a plain dense layer's leaves (attention, norms, a dense FFN)
+        # in a sub-tree of its own, drawn from a key of its own
+        FD = c.dense_intermediate
+
+        def sublayer(i):
+            ks = jax.random.split(jax.random.fold_in(key, 31 + i), 4)
+            return {
+                "attn_norm": norm_init((L, c.hidden_size)),
+                **_init_attn(c, ks[0], L, std, depth),
+                "mlp_norm": norm_init((L, c.hidden_size)),
+                "w_gate": normal(ks[1], (L, c.hidden_size, FD)),
+                "w_up": normal(ks[2], (L, c.hidden_size, FD)),
+                "w_down": normal(
+                    ks[3], (L, FD, c.hidden_size), std / math.sqrt(2 * depth)
+                ),
+            }
+
+        params["layers"] = {
+            **{k: v for k, v in params["layers"].items() if k in EXPERT_LEAVES},
+            **{f"sub{i}": sublayer(i) for i in range(c.sublayers)},
+        }
     if c.first_k_dense:
         # DeepSeek dense prelude: same attention, plain-MLP FFN
         K, F = c.first_k_dense, c.dense_intermediate or c.intermediate_size
@@ -1672,6 +1761,7 @@ def _mlp_block(
             act=config.moe_act,
             act_limit=config.act_limit,
             held=config.experts_held,
+            zero=config.zero_experts,
         )
         aux_loss = (
             config.router_balance_coef * aux["balance"]
@@ -1701,6 +1791,32 @@ def _mlp_block(
     if config.residual_multiplier:  # Granite scales the sublayer output
         o = o * jnp.asarray(config.residual_multiplier, o.dtype)
     return constrain(o, rules, "batch", "seq", None, mesh=mesh), jnp.zeros((), jnp.float32)
+
+
+def expert_branch_of(layer: dict) -> dict:
+    """The experts of a layer of several sublayers as an expert layer's
+    leaves, under the MLP norm of the first sublayer, whose hidden they
+    read."""
+    return {
+        **{k: v for k, v in layer.items() if k in EXPERT_LEAVES},
+        "mlp_norm": layer["sub0"]["mlp_norm"],
+    }
+
+
+def _shortcut_layer(x, layer: dict, n: int, attend, mlp):
+    """A layer of ``n`` (attention, dense FFN) pairs with the
+    expert branch across them → (x, the branch's aux loss): the experts
+    read the hidden that the first dense FFN reads and are added after
+    the last one. ``attend(x, sub)`` and ``mlp(x, leaves) -> (out,
+    aux)`` are the caller's attention and MLP sublayers."""
+    for i in range(n):
+        sub = layer[f"sub{i}"]
+        x = x + attend(x, sub)
+        if i == 0:
+            with jax.named_scope("dtpu.scmoe"):
+                branch, aux = mlp(x, expert_branch_of(layer))
+        x = x + mlp(x, sub)[0]
+    return x + branch, aux
 
 
 def _embed_tokens(
@@ -1826,10 +1942,21 @@ def forward(
                     jax.tree.map(lambda a: a[i], group) if stacked else group
                 )
                 cos, sin = layer_rope(ropes, c, w)
-                ao = _attention_block(
-                    x, layer, ac, cos, sin, mesh, rules, attn_impl,
-                    window=w, nope=np_, positions=pos,
+                attend = functools.partial(
+                    _attention_block, config=ac, cos=cos, sin=sin, mesh=mesh,
+                    rules=rules, attn_impl=attn_impl, window=w, nope=np_,
+                    positions=pos,
                 )
+                if c.sublayers > 1:
+                    x, aux_i = _shortcut_layer(
+                        x, layer, c.sublayers, attend,
+                        functools.partial(
+                            _mlp_block, config=c, mesh=mesh, rules=rules
+                        ),
+                    )
+                    aux = aux + aux_i
+                    continue
+                ao = attend(x, layer)
                 if c.parallel_block:
                     # Cohere: attention and MLP read the SAME input,
                     # outputs add jointly (mlp_norm aliases attn_norm)
@@ -1873,6 +2000,8 @@ def forward(
     # scans in groups of `g` sublayers so every window/rope choice is
     # static — the flash kernel stays usable (a traced window would
     # force the masked XLA path)
+    if lora is not None and c.sublayers > 1:
+        raise NotImplementedError("LoRA over layers of several sublayers")
     xs = _merge_lora(params["layers"], lora, lora_scale, c)
     g, windows, xs_main, xs_tail = grouped_scan_layout(c, xs)
     nopes = layer_nope(c)

@@ -77,6 +77,7 @@ def router(
     topk_softmax: bool = False,  # gpt-oss: gates = softmax over top-k logits
     held: tuple = (),  # (first, count): the experts whose weights are here
     valid: Optional[jax.Array] = None,  # [B, T] bool: real tokens (held_picks)
+    zero: int = 0,  # the router's last outputs that are identity experts
 ) -> tuple[jax.Array, jax.Array, dict]:
     """Top-k routing → (dispatch [B,T,E,C] one-hot, combine [B,T,E,C], aux).
 
@@ -86,6 +87,14 @@ def router(
     that fell on an absent expert takes no slot and adds nothing: its
     part of the sum is another chip's. ``aux["held_picks"]`` counts the
     picks that landed here (of the ``valid`` tokens, where given).
+
+    ``zero``: the last ``zero`` of the router's outputs are experts
+    without weights that return their input (``w_router`` is
+    ``n_experts + zero`` wide). They are scored, biased, selected and
+    gated with the others; a pick of one takes no capacity slot, and
+    ``aux["zero_gate"]`` [B, T] f32 is the sum of a token's gates that
+    fell on them (the caller adds that times the token), ``aux
+    ["zero_picks"]`` their count.
 
     Each batch row is a routing group: capacity slots are assigned in
     sequence order per expert (cumsum positions), tokens overflowing an
@@ -137,10 +146,12 @@ def router(
     # Build per-choice one-hot assignments and capacity positions.
     # Choice order gives earlier (higher-gate) choices slot priority.
     e_here = logits.shape[-1]
-    if held:
+    # the experts dispatched to: the held ones, else all that have weights
+    here = held or ((0, e_here - zero) if zero else ())
+    if here:
         # an index outside [0, count) one-hots to a row of zeros
-        e_here = held[1]
-        expert_idx = expert_idx - held[0]
+        e_here = here[1]
+        expert_idx = expert_idx - here[0]
     slots = (*logits.shape[:-1], e_here)
     dispatch = jnp.zeros((*slots, capacity), x.dtype)  # [B,T,E,C]
     combine = jnp.zeros((*slots, capacity), x.dtype)
@@ -162,8 +173,8 @@ def router(
 
     # Switch aux losses (f32): load balance + router z-loss
     e = logits.shape[-1]
-    if held:
-        expert_idx = expert_idx + held[0]
+    if here:
+        expert_idx = expert_idx + here[0]
     top1 = jax.nn.one_hot(expert_idx[..., 0], e, dtype=jnp.float32)
     frac_tokens = top1.mean(axis=(0, 1))  # fraction routed (top-1) per expert
     frac_probs = probs.mean(axis=(0, 1))
@@ -171,10 +182,16 @@ def router(
     z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     aux = {"balance": balance, "z": z}
     if held:
-        here = (expert_idx >= held[0]) & (expert_idx < held[0] + held[1])
+        landed = (expert_idx >= held[0]) & (expert_idx < held[0] + held[1])
         if valid is not None:
-            here = here & valid[..., None]
-        aux["held_picks"] = jnp.sum(here).astype(jnp.int32)
+            landed = landed & valid[..., None]
+        aux["held_picks"] = jnp.sum(landed).astype(jnp.int32)
+    if zero:
+        is_zero = expert_idx >= e - zero
+        aux["zero_gate"] = jnp.sum(jnp.where(is_zero, gate_vals, 0.0), axis=-1)
+        if valid is not None:
+            is_zero = is_zero & valid[..., None]
+        aux["zero_picks"] = jnp.sum(is_zero).astype(jnp.int32)
     return dispatch, combine, aux
 
 
@@ -196,6 +213,7 @@ def moe_mlp(
     act_limit: float = 7.0,
     held: tuple = (),  # (first, count): the experts this chip holds
     valid: Optional[jax.Array] = None,  # [B, T] bool: real tokens (aux only)
+    zero: int = 0,  # identity experts among the router's outputs
 ) -> tuple[jax.Array, dict]:
     """Sparse SwiGLU FFN → (output [B,T,H], aux losses).
 
@@ -208,6 +226,9 @@ def moe_mlp(
     expert leaves of ``layer`` are [count, ...], only those experts are
     computed, and the output is their partial sum plus the shared
     expert: nothing stands in for the absent ones.
+
+    ``zero`` (see :func:`router`): a pick of an identity expert adds
+    its gate times ``x``, at no weights and no capacity slot.
     """
     def qw(name):
         """Expert weight, resolving the int8 form: returns (w, scale or
@@ -225,7 +246,7 @@ def moe_mlp(
         renorm=renorm, sigmoid=sigmoid_input, score=score, groups=groups,
         bias=layer.get("router_bias"), routed_scale=routed_scale,
         pre_bias=layer.get("b_router"), topk_softmax=topk_softmax,
-        held=held, valid=valid,
+        held=held, valid=valid, zero=zero,
     )
     if sigmoid_input:
         # move the gate onto the dispatch side: expert input is g·x,
@@ -267,6 +288,11 @@ def moe_mlp(
         if rules is not None:
             y = constrain(y, rules, "experts", "batch_noexp", None, None, mesh=mesh)
         out = jnp.einsum("btec,ebch->bth", combine, y)
+    if zero:
+        with jax.named_scope("dtpu.moe_zero"):
+            out = out + (
+                aux["zero_gate"][..., None] * x.astype(jnp.float32)
+            ).astype(x.dtype)
     if "w_shared_gate" in layer or "w_shared_gate_q" in layer:
         # Llama4/DeepSeek dense shared expert: plain 2D matmuls, so
         # llama._proj resolves the int8 form (and any LoRA bypass)

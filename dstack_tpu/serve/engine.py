@@ -376,15 +376,22 @@ def _cache_shapes(
     layers hold a ring of :func:`ring_rows`: ``win`` [Lw, B, W,
     swa_rank+rope], or ``win_k`` / ``win_v`` [Lw, B, Hkv, W, D].
     ``moe_stats`` [2] int32 are the routing counts of a chip's share of
-    the experts (picks held, token-layers routed), carried with the
-    cache so that they need no fetch of their own. A model of one kind
-    of layer has ``ckv``, or ``k`` and ``v``, alone."""
+    the experts (picks held, token-layers routed; a third where the
+    router has identity experts: the picks that fell on those), carried
+    with the cache so that they need no fetch of their own. A model of
+    one kind of layer has ``ckv``, or ``k`` and ``v``, alone; a latent
+    layer of several attention sublayers has a ``ckv`` row a sublayer,
+    layer ``l``'s sublayer ``i`` at row ``l * sublayers + i``."""
     n_win = c.layer_types.count("window")
     n_full = c.n_layers - n_win
     ring = ring_rows(c, max_seq, chunk) if n_win else 0
     if c.mla:
         rope = c.qk_rope_head_dim
-        shapes = {"ckv": (n_full, max_batch, max_seq, c.kv_lora_rank + rope)}
+        shapes = {
+            "ckv": (
+                n_full * c.sublayers, max_batch, max_seq, c.kv_lora_rank + rope
+            )
+        }
         if _indexed(c, max_seq):
             shapes["idx"] = shapes["ckv"][:3] + (c.index_head_dim,)
         if n_win:
@@ -399,7 +406,7 @@ def _cache_shapes(
         if n_win:
             shapes["win_k"] = shapes["win_v"] = kv(n_win, ring)
     if c.experts_held:
-        shapes["moe_stats"] = (2,)
+        shapes["moe_stats"] = (3 if c.zero_experts else 2,)
     return shapes
 
 
@@ -737,7 +744,8 @@ def _mlp_out(
     next to the attention output instead of sequentially). With
     ``valid`` ([B, T] bool, the real tokens) → (output, [2] int32: the
     router picks that landed on an expert held here, the tokens
-    routed), for a chip's share of the experts (``experts_held``)."""
+    routed; [3] with the picks of identity experts where the router has
+    them), for a chip's share of the experts (``experts_held``)."""
     from dstack_tpu.models.llama import act_fn
 
     m = (
@@ -758,11 +766,12 @@ def _mlp_out(
             routed_scale=c.routed_scale,
             topk_softmax=c.router_topk_softmax,
             act=c.moe_act, act_limit=c.act_limit,
-            held=c.experts_held, valid=valid,
+            held=c.experts_held, valid=valid, zero=c.zero_experts,
         )
         if valid is not None:
             picks = jnp.stack(
                 [aux["held_picks"], jnp.sum(valid).astype(jnp.int32)]
+                + ([aux["zero_picks"]] if c.zero_experts else [])
             )
     else:
         picks = None if valid is None else jnp.zeros((2,), jnp.int32)
@@ -1005,18 +1014,68 @@ def _latent_values(o_lat, w_kb_v, h, layer: dict, gc: LlamaConfig) -> jax.Array:
     return o.reshape(o.shape[0], o.shape[1], gc.o_dim)
 
 
-def _latent_out(x, cache: dict, o, layer: dict, c: LlamaConfig, valid):
-    """A latent layer's way out, the one copy: attention output ``o``
-    [B, S, o_dim] through ``wo``, the residual, the MLP sublayer →
-    (x, cache). A model that holds every expert has no ``moe_stats`` in
-    its cache (nothing is counted, nothing is traced); else this layer's
-    [2] int32 routing counts of :func:`_mlp_out` over the ``valid``
-    tokens are added to the cache's."""
-    x = x + _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
+def _latent_wo(x, o, layer: dict):
+    """A latent attention's output ``o`` [B, S, o_dim] through ``wo``
+    onto the residual."""
+    return x + _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
+
+
+def _mlp_picks(x, cache: dict, layer: dict, c: LlamaConfig, valid):
+    """:func:`_mlp_out` of ``layer`` → (output, its int32 routing counts
+    over the ``valid`` tokens). A model that holds every expert has no
+    ``moe_stats`` in its cache: nothing is counted, nothing is traced,
+    and the counts are None."""
     if "moe_stats" not in cache:
-        return _mlp(x, layer, c), cache
-    mo, picks = _mlp_out(x, layer, c, valid=valid)
-    return x + mo, {**cache, "moe_stats": cache["moe_stats"] + picks}
+        return _mlp_out(x, layer, c), None
+    return _mlp_out(x, layer, c, valid=valid)
+
+
+def _count_picks(cache: dict, picks) -> dict:
+    """``cache`` with a layer's routing counts added to its own."""
+    if picks is None:
+        return cache
+    return {**cache, "moe_stats": cache["moe_stats"] + picks}
+
+
+def _latent_out(x, cache: dict, o, layer: dict, c: LlamaConfig, valid):
+    """The common tail of a latent layer in every program: the output
+    through ``wo``, the residual, the MLP sublayer → (x, cache)."""
+    x = _latent_wo(x, o, layer)
+    mo, picks = _mlp_picks(x, cache, layer, c, valid)
+    return x + mo, _count_picks(cache, picks)
+
+
+def _latent_layer(attend, c: LlamaConfig, valid):
+    """A latent program's attention, ``attend(x, layer, cache, row, run)
+    -> (o [B, S, o_dim], cache)`` over row ``row`` of its cache buffers,
+    → the ``one_layer(x, layer, cache, li, run)`` that
+    :func:`_mla_layers_inplace` drives, the one copy the four programs
+    share: the plain layer (:func:`_latent_out`), or, for a model whose
+    layer holds several sublayers (``sublayers`` > 1), each sublayer's
+    attention over its own cache row and its dense FFN, with the
+    layer's experts read after the first attention and their sum
+    carried to behind the last FFN (``llama._shortcut_layer`` is the
+    training path's)."""
+    n = c.sublayers
+
+    def one_layer(x, layer, cache, li, run):
+        if n == 1:
+            o, cache = attend(x, layer, cache, li, run)
+            return _latent_out(x, cache, o, layer, c, valid)
+        for i in range(n):
+            sub = layer[f"sub{i}"]
+            o, cache = attend(x, sub, cache, n * li + i, run)
+            x = _latent_wo(x, o, sub)
+            if i == 0:
+                with jax.named_scope("dtpu.scmoe"):
+                    branch, picks = _mlp_picks(
+                        x, cache, llama.expert_branch_of(layer), c, valid
+                    )
+                cache = _count_picks(cache, picks)
+            x = x + _mlp_out(x, sub, c)
+        return x + branch, cache
+
+    return one_layer
 
 
 def _embed_lookup(params: dict, tokens: jax.Array, c: LlamaConfig) -> jax.Array:
@@ -1110,7 +1169,7 @@ def _prefill_chunk_mla(
     # a chip's share of the experts counts its picks over the real tokens
     valid = (jnp.arange(cl) <= last_ix)[None] if "moe_stats" in cache else None
 
-    def one_layer(x, layer, cache, li, run):
+    def attend(x, layer, cache, li, run):
         # cache["ckv"] [Lf, B_pool, Tmax, rank+rope]: this layer is row li
         gc = run.config
         h, _, q_nope, q_pe, new_rows = _latent_in(
@@ -1123,10 +1182,11 @@ def _prefill_chunk_mla(
         row = _own_rows(_cread_rows(cache["ckv"], li, si[None], c.dtype))  # [1, Tmax, R]
         q_abs, w_kb_v = _latent_absorb(q_nope, q_pe, layer, gc)
         o = _attend_causal(q_abs, row, start, gc, c)
-        o = _latent_values(o, w_kb_v, h, layer, gc)
-        return _latent_out(x, cache, o, layer, c, valid)
+        return _latent_values(o, w_kb_v, h, layer, gc), cache
 
-    x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
+    x, cache = _mla_layers_inplace(
+        params, cache, x, _latent_layer(attend, c, valid), c
+    )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     last = jnp.take_along_axis(
         x, last_ix[None, None, None].astype(jnp.int32), axis=1
@@ -1207,7 +1267,7 @@ def _decode_step_mla(
     ropes = dual_rope_freqs(c, positions)  # [B, rope/2] each
     valid = write_mask[:, None] if "moe_stats" in cache else None
 
-    def one_layer(x, layer, cache, li, run):
+    def attend(x, layer, cache, li, run):
         # cache: the stacked buffers; this layer is row li of its group's
         gc = run.config
         cos, sin = llama.layer_rope(ropes, c, run.window)
@@ -1242,9 +1302,11 @@ def _decode_step_mla(
                 q_abs[:, :, None], cache[name], li, positions, write_mask, gc
             )
             o = _latent_values(o_lat, w_kb_v, h, layer, gc)
-        return _latent_out(x, cache, o, layer, c, valid)
+        return o, cache
 
-    x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
+    x, cache = _mla_layers_inplace(
+        params, cache, x, _latent_layer(attend, c, valid), c
+    )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     return _head_logits(params, x[:, 0], c), cache
 
@@ -1273,7 +1335,7 @@ def _verify_step_mla(
     )
     write = partial(_stacked_write, positions=positions, write_mask=write_mask)
 
-    def one_layer(x, layer, cache, li, run):
+    def attend(x, layer, cache, li, run):
         gc = run.config
         cos, sin = llama.layer_rope(ropes, c, run.window)
         # MLA rope is always interleaved
@@ -1298,9 +1360,11 @@ def _verify_step_mla(
                 q_abs, cache[name], li, positions, write_mask, gc
             )
             o = _latent_values(o_lat, w_kb_v, h, layer, gc)
-        return _latent_out(x, cache, o, layer, c, valid)
+        return o, cache
 
-    x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
+    x, cache = _mla_layers_inplace(
+        params, cache, x, _latent_layer(attend, c, valid), c
+    )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     return _head_logits(params, x, c, eq="bse,ev->bsv"), cache
 
@@ -1736,7 +1800,7 @@ def _prefill_packed_mla(
     )
     read = lambda leaf, li: _cread_rows(leaf, li, si, c.dtype)
 
-    def one_layer(x, layer, cache, li, run):
+    def attend(x, layer, cache, li, run):
         # cache: the stacked buffers; this layer is row li of its group's
         # (latents, and index keys where an indexer bites)
         gc = run.config
@@ -1752,6 +1816,12 @@ def _prefill_packed_mla(
             cache, li, run, h, qa, layer, rope_rows, write, read, pos_grid,
             starts + jnp.maximum(last_ix, 0) if run.window else None,
         )
+        if mask is None and g * gc.n_heads * cl * row.shape[1] * 4 > _SCORE_BYTES:
+            # the causal mask alone, and a wave whose f32 scores over
+            # whole rows would not fit half a GB (64 heads x 256 x 8192
+            # a row): the masked form's rows in key blocks, as many as
+            # hold each row's context
+            mask = jnp.arange(row.shape[1])[None, None, :] <= pos_grid[:, :, None]
         if mask is not None:
             o = _attend_masked(
                 q_abs, row, mask, h, layer, gc, run.window,
@@ -1760,9 +1830,11 @@ def _prefill_packed_mla(
         else:
             o = _attend_causal(q_abs, row, starts, gc, c)  # [G, H, C, rank]
             o = _latent_values(o, w_kb_v, h, layer, gc)
-        return _latent_out(x, cache, o, layer, c, valid)
+        return o, cache
 
-    x, cache = _mla_layers_inplace(params, cache, x, one_layer, c)
+    x, cache = _mla_layers_inplace(
+        params, cache, x, _latent_layer(attend, c, valid), c
+    )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     last = jnp.take_along_axis(
         x, jnp.maximum(last_ix, 0)[:, None, None].astype(jnp.int32), axis=1
@@ -2663,7 +2735,9 @@ class InferenceEngine:
         self._packed_only = bool(_masked(config, max_seq))
         # a chip's share of the experts counts its routing on the device
         # (cache["moe_stats"]); the last reading, to publish differences
-        self._moe_stats_seen = np.zeros((2,), np.int64)
+        self._moe_stats_seen = np.zeros(
+            (3 if config.zero_experts else 2,), np.int64
+        )
         # full-attention layers with an indexer that can bite (host-side
         # counters of what it selects, from positions alone)
         self._indexer_layers = (
@@ -2674,7 +2748,10 @@ class InferenceEngine:
         # the keys a block of their decode attention where it reads only
         # the blocks the live contexts hold (the latent family under the
         # causal mask alone, :func:`_attend_live`; 0: whole rows)
-        self._full_layers = config.n_layers - config.layer_types.count("window")
+        # (a latent layer of several attention sublayers: a row each)
+        self._full_layers = config.sublayers * (
+            config.n_layers - config.layer_types.count("window")
+        )
         self._key_block = (
             math.gcd(max_seq, _KEY_BLOCK)
             if config.mla and not _indexed(config, max_seq) else 0
@@ -3737,17 +3814,23 @@ class InferenceEngine:
 
     def _routed(self, got):
         """The fetched ``x`` of :meth:`_and_stats`; what the routing
-        counts grew by since the last fetch goes to the two
-        ``dtpu_serve_moe_*`` counters."""
+        counts grew by since the last fetch goes to the
+        ``dtpu_serve_moe_*`` counters (the picks of identity experts,
+        and all picks beside them, for a router that has such)."""
         if "moe_stats" not in self.cache:
             return got
         x, now = got
         now = now.astype(np.int64)
         # int32 on the device: it wraps, the difference does not
-        picks, routed = ((now - self._moe_stats_seen) % (1 << 32)).tolist()
+        picks, routed, *zero = ((now - self._moe_stats_seen) % (1 << 32)).tolist()
         self._moe_stats_seen = now
         self.metrics.family("dtpu_serve_moe_picks_held_total").inc(picks)
         self.metrics.family("dtpu_serve_moe_tokens_routed_total").inc(routed)
+        if zero:
+            self.metrics.family("dtpu_serve_moe_picks_zero_total").inc(zero[0])
+            self.metrics.family("dtpu_serve_moe_picks_total").inc(
+                routed * self.config.experts_per_token
+            )
         return x
 
     def _count_keys(self, out: dict, cap: int, layers: int, kept: str, total: str) -> None:

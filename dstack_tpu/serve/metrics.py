@@ -286,6 +286,21 @@ def new_serve_registry() -> Registry:
         "Token x expert-layer routings behind those picks (a model "
         "holding every expert counts none)",
     )
+    # a router with identity ("zero-computation") experts among its
+    # outputs: how much of the routing costs no weights
+    r.counter(
+        "dtpu_serve_moe_picks_zero_total",
+        "Router picks that fell on an identity expert (zero_experts: "
+        "the token itself times its gate, no weights), over real "
+        "tokens of prefill and decode, summed on the device with the "
+        "held picks",
+    ).inc(0)
+    r.counter(
+        "dtpu_serve_moe_picks_total",
+        "All router picks of those tokens (routings x experts_per_token)"
+        ": the denominator of the zero picks' share; 0 for a router "
+        "without identity experts",
+    ).inc(0)
     # window layers beside full ones (either family): what their mask
     # lets a decoded token see of its context, and what their ring
     # takes of the cache

@@ -512,6 +512,7 @@ def _serving_program(case, sds, place):
         "llama_1b": (llama.LLAMA_32_1B, 2048, None),
         "layer_groups": (None, 8192, None),
         "gqa_groups": (_layer_groups_config("laguna-s-2.1-13l-ep8"), 8192, None),
+        "scmoe_zero": (_layer_groups_config("longcat-flash-chat-4l-ep32"), 8192, None),
     }[model]
     config = config or _layer_groups_config()
     params = place(jax.eval_shape(
@@ -580,6 +581,13 @@ def _holds_no_second_cache(topo, case, room: float = 0.0, known: int = 0):
 #: the compiler hoists it out of the loops: PERF.md §7)
 _WINDOW_QK = 2.0 * 9 * 3072 * (9216 + 1024)
 
+#: what the compiler holds beside the agent cell's cache that is no
+#: cache: each sublayer's stacked ``wq_b`` [4, 1536, 12288] and ``wkv_b``
+#: [4, 512, 16384], transposed whole once a macro-step and parked for
+#: the token loop (151 + 67 MB a sublayer; the one-token programs
+#: transpose a layer's at a time and hold 5-11 MB)
+_SCMOE_QB = 2.0 * 2 * 4 * (1536 * 12288 + 512 * 16384)
+
 # case → room beside a quarter of the cache
 _DECODE = {
     "decode_step-minitron_4b": 0,  # the chat cell: 16 × 1536, cache 3.22 GB
@@ -591,6 +599,10 @@ _DECODE = {
     "decode_step-gqa_groups": _WINDOW_QK,
     "decode_loop-gqa_groups": _WINDOW_QK,
     "verify_step-gqa_groups": _WINDOW_QK,
+    # the agent cell: 16 × 8192, a latent row a SUBLAYER, 8 rows 1.21 GB
+    "decode_step-scmoe_zero": 0,
+    "decode_loop-scmoe_zero": _SCMOE_QB,
+    "verify_step-scmoe_zero": 0,
 }
 
 
@@ -607,7 +619,15 @@ def test_decode_program_holds_no_second_cache(topo, case):
     verify_step 0.005 GB. The grouped-query layer groups (PR 33,
     no parent: the programs are new), ``temp`` beside a cache of 2.60
     GB and arguments of 11.96: decode_step 0.63, decode_loop 0.76,
-    verify_step 0.96 GB, no cache-sized move in any."""
+    verify_step 0.96 GB, no cache-sized move in any. The latent layers
+    of two sublayers (PR 37, new programs), ``temp`` beside a cache of
+    1.21 GB and arguments of 11.55: decode_step 0.005, decode_loop
+    0.505, verify_step 0.011 GB, no move. With the sublayers' leaves
+    stacked [L, 2, ...] in one scanned leaf (this PR's first layout)
+    the layer scan's slice had a consumer a sublayer and the compiler
+    copied every such weight out a layer, 1.2 GB of copies a layer a
+    token: decode_step 0.307, decode_loop 0.806, verify_step 0.314 GB;
+    a sub-tree a sublayer ([L, ...] leaves) has none."""
     _holds_no_second_cache(topo, case, room=_DECODE[case])
 
 
@@ -641,6 +661,15 @@ _PREFILL = {
     "prefill_chunk_step@256-gqa_groups": (_WINDOW_QK, 0),
     "prefill_packed_step@1-gqa_groups": (_WINDOW_QK, 0),
     "prefill_packed_step@4-gqa_groups": (_WINDOW_QK + _scores(4, 72, 512), 0),
+    # the agent cell: 8 latent rows, 1.21 GB; a lone row goes by the
+    # serial chunk at its static start (prompts to 2048: starts to
+    # 1792), a wave's rows one after the other with their keys in blocks
+    # of 512 (64 heads × 256 × 512 × f32: at once a row's scores are
+    # 1.07 GB, ``temp`` 2.31 and 4.47 GB for G 2 and 4)
+    "prefill_chunk_step@0-scmoe_zero": (0, 0),
+    "prefill_chunk_step@1792-scmoe_zero": (0, 0),
+    "prefill_packed_step@2-scmoe_zero": (_scores(2, 64, 512), 0),
+    "prefill_packed_step@4-scmoe_zero": (_scores(4, 64, 512), 0),
     # (int8, scale) leaves, head_dim 64; a short prompt's 16 rows are
     # half an int8 tile
     "prefill_chunk_step@short-int8kv-llama_1b": (0, 0),
@@ -663,7 +692,10 @@ def test_prefill_program_holds_no_second_cache(topo, _as_tpu, case):
     0.58 / 0.57 (22); bf16 Llama-3.2-1B chunk 1.22 / 1.07 (13), packed
     1.75 / 1.07 (4). Since PR 29: chunk steps 0.4-5 MB, packed waves
     their scores. The grouped-query layer groups (PR 33, new programs):
-    chunk and G 1 0.64, G 4 0.99 GB beside 2.60, no move. Left: a short prompt's 16-row bucket on a bf16
+    chunk and G 1 0.64, G 4 0.99 GB beside 2.60, no move. The latent
+    layers of two sublayers (PR 37, new programs) beside 1.21 GB: chunk
+    0.016, G 2 0.156, G 4 0.299 GB, no move (G 1, which no aligned
+    prompt reaches, still copies two whole leaves: not a case). Left: a short prompt's 16-row bucket on a bf16
     head_dim-64 cache (16 of a tile's 128 lanes) is still re-laid out
     whole around the loop (temp 2.15 / 1.07 GB; not a case here)."""
     room, known = _PREFILL[case]

@@ -37,7 +37,25 @@ import pytest  # noqa: E402
 if _use_compile_cache:
     from dstack_tpu.utils.backend import enable_compile_cache  # noqa: E402
 
-    enable_compile_cache()
+    # One directory a xdist WORKER. JAX writes a cache entry with a
+    # plain ``write_bytes`` and reads it back without a lock unless the
+    # cache has a size limit (``jax/_src/lru_cache.py``), so a worker
+    # that finds an entry another worker is still writing reads a
+    # truncated executable: the segmentation faults inside
+    # ``compilation_cache.get_executable_and_time`` of PR 33 (both "while
+    # reading a sound entry", one on a fresh cache) and the one test that
+    # flipped in the driver's run of PR 35
+    # (``test_prefix_registry.py::...::test_slot_overwrite_drops_stale_entry``,
+    # the first of its file to load llama-tiny's ``decode_loop``, which
+    # tests of other files on other workers compile at the same moment).
+    # A worker's own entries are written before it reads them.
+    _worker = os.environ.get("PYTEST_XDIST_WORKER")
+    if _worker and not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        from dstack_tpu.utils.backend import compile_cache_dir  # noqa: E402
+
+        enable_compile_cache(os.path.join(compile_cache_dir(), _worker))
+    else:
+        enable_compile_cache()
 
 
 # ---- quick tier ----

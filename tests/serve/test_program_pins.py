@@ -68,6 +68,12 @@ CELLS = {
     # dense family's walk over layer groups it runs through (two K/V
     # caches, a period a scan step); the 68 pins above and below stood
     "laguna-s-2.1-13l-ep8": (16, 8192, _GROUPS),
+    # taken on the tree of PR 37, which added the configuration and the
+    # latent family's body for a layer of two attention sublayers with
+    # the expert branch across them (``engine._latent_layer``); the 78
+    # pins above and below stood. An unmasked latent model: a lone row
+    # prefills by the serial chunk, so the seven programs of ``NAMES``
+    "longcat-flash-chat-4l-ep32": (16, 8192, NAMES),
 }
 CELL_PINS = {
     "dots3-note-prev-5l-ep8": {
@@ -85,6 +91,15 @@ CELL_PINS = {
         "prefill_packed_step@1": "8f39df1f55e4e644ecdbe712df1b9d94fa5457841e4fea9cee7a1ffe6e593e91",
         "prefill_packed_step@2": "92f3dd51ee82bf69d5329288db95545904b8962629cbbf7f274b12da7dd18e7f",
         "prefill_packed_step@4": "7da6acf1d559bbc0f69fc6b3a820752e83e8011a5a643ac343b82b14bcfaa4e2",
+    },
+    "longcat-flash-chat-4l-ep32": {
+        "decode_step": "a6da6b098f2fa0e586d78ec10dda8b8fee4ce2df6955f990413461d02f62ffe5",
+        "decode_loop": "208f147b14a0c6b4b2538516db006659ded95a92984baac7813146e9136448e5",
+        "verify_step": "a2314f1ff9acd74625223693b3038bd5da80c65ddea8e8931ac471d1107a47c6",
+        "prefill_chunk_step@0": "57bf9f94af64788bd729462e65c92aa8c198d5422513f6b6499924a24e49d7c9",
+        "prefill_chunk_step@256": "15c03e399fe7a8fea15f14859af994ba2c0703c30ae2604c508db17290d454d4",
+        "prefill_packed_step@2": "5cdb2dfee612621eadb0d1de8435dce51edd71119120f70c67f3626da974e362",
+        "prefill_packed_step@4": "ca2817590b8a6bf31679fa8cfbfd41feaa015dc5fed561d6159d00139485405f",
     },
     "minitron-4b": {
         "decode_step": "31cd7802fdfa5729183b1aa6346316af5f0a7b1d5a845041033888da8aa84ab7",
@@ -168,7 +183,10 @@ FAMILIES = {
         moe_shared_expert=True, first_k_dense=1,
     ),
 }
-TINY = tuple(sorted(FAMILIES)) + ("mla-tiny", "moe-tiny")
+#: ``scmoe-tiny`` (PR 37): a latent layer of two attention sublayers and
+#: two dense FFNs, the expert branch across them, identity experts among
+#: the router's outputs, every real expert held (no counts in the cache)
+TINY = tuple(sorted(FAMILIES)) + ("mla-tiny", "moe-tiny", "scmoe-tiny")
 TINY_NAMES = (
     "decode_step", "verify_step", "prefill_chunk_step@16", "prefill_packed_step@2",
 )
@@ -245,6 +263,12 @@ TINY_PINS = {
         "verify_step": "f70fa58eed2dbfc39b01c77c71a26d8048ad40a49a204f1c5ba9e4c93d3c39c5",
         "prefill_chunk_step@16": "39a09faca0a1cd213c1cd45111cc1df775fa8184ae6a6f987b3dd53f0fe3939f",
         "prefill_packed_step@2": "600d47229f1fce5a68d0d4719b94780c6e6c152aeb80022daa3084af7b680c9d",
+    },
+    "scmoe-tiny": {
+        "decode_step": "d2e810aa2f7cb1be4848b93830d341d9e21395d3292de5a836af2de2279f2a14",
+        "verify_step": "22caee88aef102fc7884f239b90ff19e4deb987c3218cf1a4b060a1776631905",
+        "prefill_chunk_step@16": "8297db34a0663aaa48b4ab0c26a0716f947a6ac0aa34c8e0dae6685ce5a035a7",
+        "prefill_packed_step@2": "70e7b0ca3f571e66510cce28dc91707c976da446fbe0139e4b0e435aba622c75",
     },
     "moe-tiny": {
         "decode_step": "a38072b4a98bfd0c163d6cec124ee7e5712705874e59bee3e100c69fdb1838bf",
