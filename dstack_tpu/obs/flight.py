@@ -14,8 +14,10 @@ work in PAPERS.md):
   with strictly host-side data (no device syncs — DTPU002-clean):
   step seq, phase (``prefill``/``prefill_packed``/``decode``/``spec``/
   ``turbo``), batch composition (live slots, G/C bucket, packed rows),
-  host-side vs dispatch wall time, tokens emitted, KV/prefix
-  occupancy, and the trace ids riding the step.
+  host-side vs dispatch wall time (``host_s`` / ``dispatch_s``; a
+  step's ``dispatch_s`` includes ``wait_s``, its time parked in the
+  blocking device→host fetches), tokens emitted, KV/prefix occupancy,
+  and the trace ids riding the step.
 - **Compile accounting.** :func:`watch_jit` wraps every engine
   ``jax.jit`` site so first-trace/compile events are counted and timed
   per function with the causing bucket key

@@ -2997,6 +2997,12 @@ class InferenceEngine:
         # path the current step() took for its flight record.
         self._flight_warm = False
         self._last_step_phase = "decode"
+        # host-phase accounting of the engine call now running on the
+        # worker thread (step / prefill_wave reset them at entry, _fetch
+        # adds to them): seconds inside the blocking fetches so far, and
+        # when the last of them returned
+        self._wait_s = 0.0
+        self._fetched_at = 0.0
         # boot-compile manifest (obs/boot.py helpers): every compile
         # BEFORE mark_flight_warm() records its per-fn key here; a
         # compile AFTER of a key absent from the manifest is a
@@ -3216,6 +3222,8 @@ class InferenceEngine:
         # logits index only matters on the final chunk
         last_ix = (tp - 1 - start) if final else (cl - 1)
         t0 = time.perf_counter()
+        # the span holds the activation too: its dtpu.engine.wait (the
+        # first token's fetch) lies inside it
         with profiling.span("dtpu.engine.prefill", rows=1, cl=cl):
             logits, self.cache = self._chunk_fn(cl, start)(
                 self.params,
@@ -3224,28 +3232,28 @@ class InferenceEngine:
                 jnp.asarray(slot, jnp.int32),
                 jnp.asarray(last_ix, jnp.int32),
             )
-        self.metrics.family("dtpu_serve_prefill_dispatches_total").inc(1)
-        self.metrics.family("dtpu_serve_prefill_pack_rows").observe(1)
-        if flight.enabled():
-            # host-side data only (the DTPU002 contract): serial chunk
-            # at its static (C, start) bucket, one row
-            flight.record(
-                phase="prefill", slots=[slot], rows=1, g=1, cl=cl,
-                start=start, final=final,
-                dispatch_s=round(time.perf_counter() - t0, 6),
-                traces=(
-                    {slot: st["gen"].trace_id} if st["gen"].trace_id
-                    else None
-                ),
-                **self.fault_ctx,
-            )
-        if not final:
-            st["next"] = start + cl
-            return None
-        gen = st["gen"]
-        if self._prefilling.pop(slot, None) is None:
-            return None  # released while the final chunk ran
-        return self._activate(slot, st["prompt"], tp, gen, logits)
+            self.metrics.family("dtpu_serve_prefill_dispatches_total").inc(1)
+            self.metrics.family("dtpu_serve_prefill_pack_rows").observe(1)
+            if flight.enabled():
+                # host-side data only (the DTPU002 contract): serial chunk
+                # at its static (C, start) bucket, one row
+                flight.record(
+                    phase="prefill", slots=[slot], rows=1, g=1, cl=cl,
+                    start=start, final=final,
+                    dispatch_s=round(time.perf_counter() - t0, 6),
+                    traces=(
+                        {slot: st["gen"].trace_id} if st["gen"].trace_id
+                        else None
+                    ),
+                    **self.fault_ctx,
+                )
+            if not final:
+                st["next"] = start + cl
+                return None
+            gen = st["gen"]
+            if self._prefilling.pop(slot, None) is None:
+                return None  # released while the final chunk ran
+            return self._activate(slot, st["prompt"], tp, gen, logits)
 
     def prefill_wave(self) -> dict[int, int]:
         """ONE prefill dispatch advancing up to ``prefill_pack`` pending
@@ -3269,6 +3277,8 @@ class InferenceEngine:
         # cancelled row's chunk still dispatches harmlessly (its slot
         # can't be reassigned until the next scheduler tick) and is
         # skipped at activation below.
+        t0 = time.perf_counter()
+        self._wait_s = 0.0
         states = {}
         for s in list(self._prefilling):
             st = self._prefilling.get(s)
@@ -3287,13 +3297,21 @@ class InferenceEngine:
             slot = pending[0]
             self.last_wave_slots = [slot]
             tok = self.prefill_step(slot)
-            return {} if tok is None else {slot: tok}
-        rows = pending[: max(1, self.prefill_pack)]
-        # published BEFORE dispatch: on an engine error the caller fails
-        # exactly the rows that were in the failing dispatch, not every
-        # queued prefill (slots beyond prefill_pack never ran)
-        self.last_wave_slots = list(rows)
-        return self._packed_wave(rows, states)
+            out = {} if tok is None else {slot: tok}
+        else:
+            rows = pending[: max(1, self.prefill_pack)]
+            # published BEFORE dispatch: on an engine error the caller
+            # fails exactly the rows that were in the failing dispatch,
+            # not every queued prefill (slots beyond prefill_pack never
+            # ran)
+            self.last_wave_slots = list(rows)
+            out = self._packed_wave(rows, states)
+        # the call's host time: a non-final chunk does not sync, so all
+        # of it is enqueue; a final one waits in _activate's fetches
+        self.metrics.family("dtpu_serve_prefill_host_seconds").observe(
+            time.perf_counter() - t0 - self._wait_s
+        )
+        return out
 
     def _packed_wave(self, rows: list, states: dict) -> dict[int, int]:
         """One :func:`prefill_packed_step` dispatch over ``rows`` (slots
@@ -3334,6 +3352,7 @@ class InferenceEngine:
             starts.append(0)
             last_ix.append(-1)
         t0 = time.perf_counter()
+        # the span holds the activations too (see prefill_step)
         with profiling.span("dtpu.engine.prefill", rows=len(rows), cl=cl):
             logits, self.cache = self._packed_fn(g, cl)(
                 self.params,
@@ -3343,35 +3362,35 @@ class InferenceEngine:
                 jnp.asarray(starts, jnp.int32),
                 jnp.asarray(last_ix, jnp.int32),
             )
-        self.metrics.family("dtpu_serve_prefill_dispatches_total").inc(1)
-        self.metrics.family("dtpu_serve_prefill_pack_rows").observe(len(rows))
-        if flight.enabled():
-            # batch composition straight from the wave's host lists:
-            # the (G, C) bucket, real rows packed, per-row starts
-            flight.record(
-                phase="prefill_packed", g=g, cl=cl, rows=len(rows),
-                slots=list(rows), starts=starts[: len(rows)],
-                dispatch_s=round(time.perf_counter() - t0, 6),
-                traces={
-                    s: states[s]["gen"].trace_id
-                    for s in rows
-                    if states[s]["gen"].trace_id
-                } or None,
-                **self.fault_ctx,
-            )
-        out: dict[int, int] = {}
-        for i, s in enumerate(rows):
-            st = self._prefilling.get(s)
-            if st is None:
-                continue  # released while the wave ran
-            if not final[s]:
-                st["next"] += cl
-                continue
-            self._prefilling.pop(s, None)
-            out[s] = self._activate(
-                s, st["prompt"], st["tp"], st["gen"], logits[i : i + 1]
-            )
-        return out
+            self.metrics.family("dtpu_serve_prefill_dispatches_total").inc(1)
+            self.metrics.family("dtpu_serve_prefill_pack_rows").observe(len(rows))
+            if flight.enabled():
+                # batch composition straight from the wave's host lists:
+                # the (G, C) bucket, real rows packed, per-row starts
+                flight.record(
+                    phase="prefill_packed", g=g, cl=cl, rows=len(rows),
+                    slots=list(rows), starts=starts[: len(rows)],
+                    dispatch_s=round(time.perf_counter() - t0, 6),
+                    traces={
+                        s: states[s]["gen"].trace_id
+                        for s in rows
+                        if states[s]["gen"].trace_id
+                    } or None,
+                    **self.fault_ctx,
+                )
+            out: dict[int, int] = {}
+            for i, s in enumerate(rows):
+                st = self._prefilling.get(s)
+                if st is None:
+                    continue  # released while the wave ran
+                if not final[s]:
+                    st["next"] += cl
+                    continue
+                self._prefilling.pop(s, None)
+                out[s] = self._activate(
+                    s, st["prompt"], st["tp"], st["gen"], logits[i : i + 1]
+                )
+            return out
 
     def add_request(
         self, prompt: list[int], gen: GenParams
@@ -3457,7 +3476,7 @@ class InferenceEngine:
             self._logit_bias[row],
             min_ps[row],
         )
-        tok = int(toks[0])
+        tok = self._fetch(toks).tolist()[0]
         self._key_data = self._key_data.at[slot].set(kd[0])
         self._seen, self._gen_counts = self._mark_seen(
             self._seen, self._gen_counts, self._slot_iota[row], toks
@@ -3466,7 +3485,7 @@ class InferenceEngine:
         if gen.logprobs is not None:
             lp, tids, tlps = (
                 a.tolist()
-                for a in jax.device_get(self._logprobs(logits, toks))
+                for a in self._fetch(self._logprobs(logits, toks))
             )
             # tolist() above already yields python floats/ints
             self._last_logprobs[slot] = (
@@ -3550,6 +3569,7 @@ class InferenceEngine:
         recorded here, at the engine, which /metrics renders."""
         epoch = self._step_epoch
         t_all0 = time.perf_counter()
+        self._wait_s = 0.0
         # chaos hook (no-op calls when no plan is installed), fired once
         # per live slot with ctx slot=<i>: a raise provokes mid-decode
         # engine death (the scheduler loop must fail only the inflight
@@ -3578,61 +3598,78 @@ class InferenceEngine:
         # the engine until this thread returns, then calls
         # :meth:`finish_abandoned_step` before dispatching again.
         if out:
-            dt = time.perf_counter() - t0
-            n_tokens = sum(len(v) for v in out.values())
+            with profiling.span("dtpu.engine.finish"):
+                self._account_step(out, time.perf_counter() - t0, t_all0)
+            # the call by phase, from shared clock reads: enqueue is
+            # what is left of the call's wall time, so the three add up
+            # to it exactly (every emitting path fetched at least once)
+            t1 = time.perf_counter()
+            finish = t1 - self._fetched_at
             m = self.metrics
-            m.family("dtpu_serve_decode_steps_total").inc(1)
-            m.family("dtpu_serve_decode_step_seconds").observe(dt)
-            m.family("dtpu_serve_tokens_generated_total").inc(n_tokens)
-            self._count_decode_keys(out)
-            if self._indexer_layers:
-                self._count_keys(
-                    out, self.config.index_topk, self._indexer_layers,
-                    "dtpu_serve_indexer_keys_selected_total",
-                    "dtpu_serve_indexer_keys_in_context_total",
-                )
-            if self._window_layers:
-                self._count_keys(
-                    out, self.config.sliding_window, self._window_layers,
-                    "dtpu_serve_window_keys_visible_total",
-                    "dtpu_serve_window_keys_in_context_total",
-                )
-            if n_tokens and dt > 0:
-                # TPOT covers the whole batch: exemplar from the slot
-                # that yielded the most tokens this dispatch (ties by
-                # slot order) — any live trace explains the step
-                ex = None
-                for s in sorted(out, key=lambda s: -len(out[s])):
-                    ex = self._trace_ids.get(s)
-                    if ex is not None:
-                        break
-                m.family("dtpu_serve_tpot_seconds").observe(
-                    dt / n_tokens, exemplar=ex,
-                )
-            if flight.enabled():
-                # one flight record per emitting step — strictly
-                # host-side fields (slot lists, perf counters, the
-                # prefix-registry snapshot; DTPU002-clean), with the
-                # trace ids riding the step for post-mortem stitching
-                flight.record(
-                    phase=self._last_step_phase,
-                    slots=list(out),
-                    tokens=n_tokens,
-                    dispatch_s=round(dt, 6),
-                    host_s=round(
-                        max(0.0, time.perf_counter() - t_all0 - dt), 6
-                    ),
-                    kv_util=round(self.kv_cache_utilization(), 4),
-                    prefix_slots=len(self._prefix_registry),
-                    traces={
-                        s: self._trace_ids[s]
-                        for s in out
-                        if s in self._trace_ids
-                    } or None,
-                    **self.fault_ctx,
-                )
-                flight.maybe_poll_memory(self.metrics)
+            m.family("dtpu_serve_step_enqueue_seconds").observe(
+                t1 - t_all0 - self._wait_s - finish
+            )
+            m.family("dtpu_serve_step_wait_seconds").observe(self._wait_s)
+            m.family("dtpu_serve_step_finish_seconds").observe(finish)
         return out
+
+    def _account_step(self, out: dict, dt: float, t_all0: float) -> None:
+        """An emitting step's counters, histograms and flight record
+        (``dt``: the dispatch's wall time, ``t_all0``: step()'s entry)."""
+        n_tokens = sum(len(v) for v in out.values())
+        m = self.metrics
+        m.family("dtpu_serve_decode_steps_total").inc(1)
+        m.family("dtpu_serve_decode_step_seconds").observe(dt)
+        m.family("dtpu_serve_tokens_generated_total").inc(n_tokens)
+        self._count_decode_keys(out)
+        if self._indexer_layers:
+            self._count_keys(
+                out, self.config.index_topk, self._indexer_layers,
+                "dtpu_serve_indexer_keys_selected_total",
+                "dtpu_serve_indexer_keys_in_context_total",
+            )
+        if self._window_layers:
+            self._count_keys(
+                out, self.config.sliding_window, self._window_layers,
+                "dtpu_serve_window_keys_visible_total",
+                "dtpu_serve_window_keys_in_context_total",
+            )
+        if n_tokens and dt > 0:
+            # TPOT covers the whole batch: exemplar from the slot
+            # that yielded the most tokens this dispatch (ties by
+            # slot order) — any live trace explains the step
+            ex = None
+            for s in sorted(out, key=lambda s: -len(out[s])):
+                ex = self._trace_ids.get(s)
+                if ex is not None:
+                    break
+            m.family("dtpu_serve_tpot_seconds").observe(
+                dt / n_tokens, exemplar=ex,
+            )
+        if flight.enabled():
+            # one flight record per emitting step — strictly
+            # host-side fields (slot lists, perf counters, the
+            # prefix-registry snapshot; DTPU002-clean), with the
+            # trace ids riding the step for post-mortem stitching
+            flight.record(
+                phase=self._last_step_phase,
+                slots=list(out),
+                tokens=n_tokens,
+                dispatch_s=round(dt, 6),
+                wait_s=round(self._wait_s, 6),
+                host_s=round(
+                    max(0.0, time.perf_counter() - t_all0 - dt), 6
+                ),
+                kv_util=round(self.kv_cache_utilization(), 4),
+                prefix_slots=len(self._prefix_registry),
+                traces={
+                    s: self._trace_ids[s]
+                    for s in out
+                    if s in self._trace_ids
+                } or None,
+                **self.fault_ctx,
+            )
+            flight.maybe_poll_memory(self.metrics)
 
     def _step_dispatch(self) -> dict:
         live = [i for i in range(self.max_batch) if self.active[i]]
@@ -3690,9 +3727,15 @@ class InferenceEngine:
         # the shared jitted argmax (an op-by-op jnp.argmax here paid
         # uncompiled dispatch overhead every speculative step); ONE
         # fetch + tolist() so the accept loop compares plain ints
-        preds = self._routed(
-            jax.device_get(self._and_stats(self._argmax(logits)))
-        ).tolist()  # [B, S]
+        got = self._fetch(self._and_stats(self._argmax(logits)))
+        with profiling.span("dtpu.engine.finish"):
+            return self._accept_drafts(
+                live, drafts, self._routed(got).tolist()  # [B, S]
+            )
+
+    def _accept_drafts(self, live: list, drafts: dict, preds: list) -> dict:
+        """The host half of :meth:`_spec_step`: per slot, the verified
+        prefix of its draft plus one token."""
         out: dict = {}
         for i in live:
             draft = drafts.get(i, [])
@@ -3852,25 +3895,42 @@ class InferenceEngine:
             )
             segs.append(toks_dev)
         self._turbo_state = (tok_d, pos_d, rem_d, act_d, eos_d)
-        # ONE blocking fetch for every in-flight segment ([depth*steps, B])
-        # dtpu: noqa[DTPU002] the designed single device_get per macro-step — K×depth tokens amortize this one round trip
-        got = jax.device_get(self._and_stats(segs))
-        toks = np.concatenate(self._routed(got), axis=0).tolist()
-        out: dict = {}
-        for i in live:
-            emitted: list = []
-            for k in range(depth * steps):
-                tok = toks[k][i]  # plain int: the fetch tolist()'d once
-                if tok < 0:  # row deactivated on an earlier step
-                    break
-                emitted.append(tok)
-                if not self._advance_slot(i, tok):
-                    break
-            if emitted:
-                out[i] = emitted
-            # _seen is not updated here — turbo is gated to slots with
-            # no penalties, where the counts can't affect sampling
-        return out
+        # ONE blocking fetch for every in-flight segment ([depth*steps, B]):
+        # K×depth tokens amortize this one round trip
+        got = self._fetch(self._and_stats(segs))
+        with profiling.span("dtpu.engine.finish"):
+            toks = np.concatenate(self._routed(got), axis=0).tolist()
+            out: dict = {}
+            for i in live:
+                emitted: list = []
+                for k in range(depth * steps):
+                    tok = toks[k][i]  # plain int: the fetch tolist()'d once
+                    if tok < 0:  # row deactivated on an earlier step
+                        break
+                    emitted.append(tok)
+                    if not self._advance_slot(i, tok):
+                        break
+                if emitted:
+                    out[i] = emitted
+                # _seen is not updated here — turbo is gated to slots with
+                # no penalties, where the counts can't affect sampling
+            return out
+
+    def _fetch(self, x):
+        """THE blocking device→host fetch of the engine calls (``step``'s
+        three paths, the first token and logprobs of a prefill): ``x`` is
+        already built, so what produced it counts as enqueue. The time
+        parked here (the device runs, or the transfer does) is the
+        running call's ``dtpu_serve_step_wait_seconds`` and is taken off
+        its ``dtpu_serve_prefill_host_seconds``; span ``dtpu.engine.wait``
+        in a capture."""
+        t0 = time.perf_counter()
+        with profiling.span("dtpu.engine.wait"):
+            # dtpu: noqa[DTPU002] the engine's one fetch site: a step's tokens (one round trip a macro-step), an activation's first token, logprobs where asked
+            got = jax.device_get(x)
+        self._fetched_at = time.perf_counter()
+        self._wait_s += self._fetched_at - t0
+        return got
 
     def _and_stats(self, x):
         """What a step is about to fetch and, for the same transfer,
@@ -3989,67 +4049,62 @@ class InferenceEngine:
         logits, self.cache = self._decode(
             self.params, self.cache, tok_d, pos_d, write_mask=act_d,
         )
+        sp = None
         if self._all_greedy(live):
             # all-greedy batch: argmax only — the general sampler's
             # penalty passes over [B, V], its noise draw and the count
             # update buy nothing here
             sampled_dev = self._argmax(logits)
-            adv = self._advance_state(
-                tok_d, pos_d, rem_d, act_d, eos_d, sampled_dev
+        else:
+            sp = self._sampling_params()
+            temps, top_ps, top_ks, rep_pens, pres_pens, freq_pens, min_ps = sp
+            self._count_sample(live)
+            sampled_dev, self._key_data = self._sample(
+                logits,
+                self._key_data,
+                temps,
+                top_ps,
+                top_ks,
+                rep_pens,
+                self._seen,
+                pres_pens,
+                freq_pens,
+                self._gen_counts,
+                self._logit_bias,
+                min_ps,
+                act_d,  # a released slot keeps its last request's filters
             )
-            out = self._emit(
-                live, self._routed(jax.device_get(self._and_stats(sampled_dev)))
+            self._seen, self._gen_counts = self._mark_seen(
+                self._seen, self._gen_counts, self._slot_iota, sampled_dev
             )
+            if any(self.want_logprobs[i] for i in live):
+                lp, tids, tlps = (
+                    a.tolist()
+                    for a in self._fetch(self._logprobs(logits, sampled_dev))
+                )
+                for i in live:
+                    if self.want_logprobs[i]:
+                        # tolist() above already yields python floats/ints
+                        self._last_logprobs[i] = (
+                            lp[i],
+                            list(zip(tids[i], tlps[i])),
+                        )
+        adv = self._advance_state(
+            tok_d, pos_d, rem_d, act_d, eos_d, sampled_dev
+        )
+        got = self._fetch(self._and_stats(sampled_dev))
+        with profiling.span("dtpu.engine.finish"):
+            out = self._emit(live, self._routed(got))
             # _emit invalidated the mirror; the host replay applied the
             # SAME transition advance_decode_state just did on device,
             # so the advanced arrays are the valid next-step inputs
             self._turbo_state = (*adv, eos_d)
+            # _emit's invalidation also dropped the sampling-params
+            # mirror, but the per-token advance never touches those
+            # lists — restore so the next sampled token reuses the same
+            # device arrays
+            self._sampling_state = sp
             return out
-        sp = self._sampling_params()
-        temps, top_ps, top_ks, rep_pens, pres_pens, freq_pens, min_ps = sp
-        self._count_sample(live)
-        sampled_dev, self._key_data = self._sample(
-            logits,
-            self._key_data,
-            temps,
-            top_ps,
-            top_ks,
-            rep_pens,
-            self._seen,
-            pres_pens,
-            freq_pens,
-            self._gen_counts,
-            self._logit_bias,
-            min_ps,
-            act_d,  # a released slot keeps its last request's filters
-        )
-        self._seen, self._gen_counts = self._mark_seen(
-            self._seen, self._gen_counts, self._slot_iota, sampled_dev
-        )
-        if any(self.want_logprobs[i] for i in live):
-            lp, tids, tlps = (
-                a.tolist()
-                for a in jax.device_get(self._logprobs(logits, sampled_dev))
-            )
-            for i in live:
-                if self.want_logprobs[i]:
-                    # tolist() above already yields python floats/ints
-                    self._last_logprobs[i] = (
-                        lp[i],
-                        list(zip(tids[i], tlps[i])),
-                    )
-        adv = self._advance_state(
-            tok_d, pos_d, rem_d, act_d, eos_d, sampled_dev
-        )
-        out = self._emit(
-            live, self._routed(jax.device_get(self._and_stats(sampled_dev)))
-        )
-        self._turbo_state = (*adv, eos_d)  # see the greedy branch
-        # _emit's invalidation also dropped the sampling-params mirror,
-        # but the per-token advance never touches those lists — restore
-        # so the next sampled token reuses the same device arrays
-        self._sampling_state = sp
-        return out
 
     def _advance_slot(self, i: int, tok: int) -> bool:
         """Publish ONE sampled token for slot ``i`` — the single copy

@@ -92,6 +92,67 @@ def new_serve_registry() -> Registry:
         "one observation per SSE chunk",
         buckets=SHORT_LATENCY_BUCKETS_S,
     )
+    # the decode cycle's host part by name (PERF.md §3): an emitting
+    # engine.step() call is enqueue + wait + finish (shared clock reads,
+    # so the three sums add up to the calls' wall time) ...
+    r.histogram(
+        "dtpu_serve_step_enqueue_seconds",
+        "Emitting engine step's own host time up to and between its "
+        "blocking fetches: fault hooks, path choice, decode-state "
+        "uploads, the jit calls (the call's wall time minus the two "
+        "below), one observation per call",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_step_wait_seconds",
+        "Emitting engine step's time inside its blocking device-to-host "
+        "fetches (the device runs or the transfer does, the worker is "
+        "parked), summed per call",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_step_finish_seconds",
+        "Emitting engine step's host time from the return of its last "
+        "fetch to the return of step(): routing counts, token "
+        "bookkeeping, counters, histograms, the flight record",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_prefill_host_seconds",
+        "One prefill_wave() call's wall time minus its time inside the "
+        "blocking fetches (a non-final chunk does not sync: all of it "
+        "is enqueue)",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    # ... and dtpu_serve_host_gap_seconds is loop_return + tick_host +
+    # loop_yield + worker_start + a few lines of the scheduler's loop
+    # (counted only where the gap is: a park drops what was noted)
+    r.histogram(
+        "dtpu_serve_loop_return_seconds",
+        "Return of an engine call on its worker thread to the "
+        "scheduler's coroutine running again on the event loop",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_loop_yield_seconds",
+        "The scheduler's yield to the event loop after a token "
+        "hand-over: the stream handlers' turn with no engine call in "
+        "flight",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_worker_start_seconds",
+        "Scheduler handing an engine call to a worker thread to the "
+        "call's first line running there",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
+    r.histogram(
+        "dtpu_serve_first_delta_lag_seconds",
+        "Scheduler's token hand-over to a streaming request to the end "
+        "of the handler's next delta write, one observation per "
+        "request per hand-over (not per token)",
+        buckets=SHORT_LATENCY_BUCKETS_S,
+    )
     # prefill dispatch accounting: the packed multi-slot prefill packs
     # up to prefill_pack concurrent prompt chunks into one forward —
     # dispatches per burst is the TTFT-under-load lever these observe

@@ -114,6 +114,9 @@ class _Request:
         self.bucket = None  # qos.TokenBucket this request's admission charged
         self.refunded = False
         self.started = False  # at least one token queued to the client
+        # when the scheduler handed over the oldest token the stream
+        # handler has not yet answered with a delta (None: none waits)
+        self.handed_at: Optional[float] = None
         # distributed tracing: `span` is the request's serve-side root
         # (parented to the router's dispatch leg via X-DTPU-Trace);
         # `phase` is the currently-open engine phase child —
@@ -180,6 +183,10 @@ class Scheduler:
         # this tick's own host seconds so far
         self._engine_returned: Optional[float] = None
         self._tick_host_s = 0.0
+        # the gap's named parts noted since the last engine call
+        # returned, as (family, seconds): observed when the next call
+        # counts its gap, dropped by a park
+        self._gap_parts: list = []
         # serving metrics live in the ENGINE's obs registry (one source
         # of truth); /metrics renders the registry for the shim relay →
         # server prometheus plane and for the benchmark's readers.
@@ -319,25 +326,40 @@ class Scheduler:
 
     # ---- host-phase accounting ----
 
-    def _engine_call(self, fn):
+    async def _engine_call(self, fn):
         """``fn`` (``engine.step`` / ``engine.prefill_wave``) on a worker
         thread. The time since the previous engine call returned — the
         device had no work queued while requests held slots — goes to
         ``dtpu_serve_host_gap_seconds``; both clock reads are taken on
-        the worker thread, so the gap includes the thread hops."""
+        the worker thread, so the gap includes the thread hops, and
+        this is where each hop is read from both sides:
+        ``dtpu_serve_worker_start_seconds`` on the way out,
+        ``dtpu_serve_loop_return_seconds`` on the way back (noted, and
+        observed with the gap's other parts by the call that counts
+        the gap)."""
+        family = self.engine.metrics.family
+        prev = self._engine_returned
+        for name, seconds in self._gap_parts:  # of the gap this call ends
+            family(name).observe(seconds)
+        self._gap_parts.clear()
+        t_hop = time.perf_counter()
 
         def run():
             t0 = time.perf_counter()
-            if self._engine_returned is not None:
-                self.engine.metrics.family(
-                    "dtpu_serve_host_gap_seconds"
-                ).observe(t0 - self._engine_returned)
+            if prev is not None:
+                family("dtpu_serve_host_gap_seconds").observe(t0 - prev)
+                family("dtpu_serve_worker_start_seconds").observe(t0 - t_hop)
             try:
                 return fn()
             finally:
                 self._engine_returned = time.perf_counter()
 
-        return asyncio.to_thread(run)
+        out = await asyncio.to_thread(run)
+        self._gap_parts.append((
+            "dtpu_serve_loop_return_seconds",
+            time.perf_counter() - self._engine_returned,
+        ))
+        return out
 
     @contextlib.contextmanager
     def _host_code(self):
@@ -485,9 +507,12 @@ class Scheduler:
                 ).observe(self._tick_host_s)
                 self._tick_host_s = 0.0
 
-    def _handle_first_token(self, slot: int, req: _Request, first: int) -> bool:
-        """Deliver a finished prefill's first token; True when the slot
-        stays active for the decode loop."""
+    def _handle_first_token(
+        self, slot: int, req: _Request, first: int, now: float
+    ) -> bool:
+        """Deliver a finished prefill's first token (handed over at
+        ``now``, the wave's one clock read); True when the slot stays
+        active for the decode loop."""
         req.phase.end()  # serve.prefill: slot admission → first token
         req.phase = tracing.NOOP_SPAN
         if req.gen.logprobs is not None:
@@ -497,6 +522,8 @@ class Scheduler:
         if first != req.gen.eos_id:
             req.started = True  # charge is earned once a token ships
             self._note_served_token()
+            if req.handed_at is None:
+                req.handed_at = now
             req.queue.put_nowait(first)
             if self._hit_stop(req, first):
                 self.engine.release(slot)
@@ -589,6 +616,7 @@ class Scheduler:
                     req.queue.put_nowait(None)
                 return
             with self._host_code():
+                now = time.perf_counter()
                 for slot, first in firsts.items():
                     # prompt complete; first token sampled
                     req = self.by_prefill.pop(slot, None)
@@ -596,7 +624,7 @@ class Scheduler:
                         # cancel() landed while the wave ran on the
                         # worker thread
                         self.engine.release(slot)
-                    elif self._handle_first_token(slot, req, first):
+                    elif self._handle_first_token(slot, req, first, now):
                         self.by_slot[slot] = req
         if not self.by_slot:
             if self.by_prefill:
@@ -606,6 +634,7 @@ class Scheduler:
             # by_slot/by_prefill here implies an empty queue — wait()
             # parks until the next push (and a park is not a host gap).
             self._engine_returned = None
+            self._gap_parts.clear()
             await self.pending.wait()
             return
         out = await self._guarded_step()
@@ -613,7 +642,13 @@ class Scheduler:
             return  # watchdog tripped: bookkeeping already done
         with self._host_code():
             self._hand_over(out)
-        await asyncio.sleep(0)
+        # the stream handlers' turn, with no engine call in flight
+        t0 = time.perf_counter()
+        with obs_profiling.span("dtpu.loop.yield"):
+            await asyncio.sleep(0)
+        self._gap_parts.append(
+            ("dtpu_serve_loop_yield_seconds", time.perf_counter() - t0)
+        )
 
     def _admit_pending(self) -> None:
         """The tick's admission half, host bookkeeping only."""
@@ -696,6 +731,7 @@ class Scheduler:
 
     def _hand_over(self, out: dict) -> None:
         """One engine step's tokens → their requests' queues."""
+        now = time.perf_counter()  # ONE read: the call's slots share it
         for slot, toks in out.items():
             req = self.by_slot.get(slot)
             if req is None:
@@ -715,6 +751,8 @@ class Scheduler:
                         req.logprob_entries.append(entry)
                 req.started = True
                 self._note_served_token()
+                if req.handed_at is None:
+                    req.handed_at = now
                 req.queue.put_nowait(tok)
                 if self._hit_stop(req, tok):
                     self.engine.release(slot)
@@ -1570,6 +1608,7 @@ def build_app(
 
             m_detok = engine.metrics.family("dtpu_serve_detokenize_seconds")
             m_write = engine.metrics.family("dtpu_serve_stream_write_seconds")
+            m_lag = engine.metrics.family("dtpu_serve_first_delta_lag_seconds")
             # the per-token timings are only noted on the token path:
             # the handlers run in the gap between two engine calls,
             # with the device idle. The histograms take them when the
@@ -1578,15 +1617,18 @@ def build_app(
             # is already on the device.
             detok_s: list[float] = []
             write_s: list[float] = []
+            # hand-over → the end of the delta that answers it, once a
+            # request a hand-over (the scheduler's stamp, cleared here)
+            lag_s: list[float] = []
             loop = asyncio.get_running_loop()
 
             def observe_noted() -> None:
-                for v in detok_s:
-                    m_detok.observe(v)
-                for v in write_s:
-                    m_write.observe(v)
-                detok_s.clear()
-                write_s.clear()
+                for hist, noted in (
+                    (m_detok, detok_s), (m_write, write_s), (m_lag, lag_s)
+                ):
+                    for v in noted:
+                        hist.observe(v)
+                    noted.clear()
 
             def detokenize(hold: bool) -> str:
                 """All ids so far → deliverable text (``hold``: minus a
@@ -1657,6 +1699,9 @@ def build_app(
                         continue
                     sent = out
                     await emit(delta)
+                    if req.handed_at is not None:
+                        lag_s.append(time.perf_counter() - req.handed_at)
+                        req.handed_at = None
                 # generation over: flush held-back text that never
                 # completed into a stop string (minus any true stop cut)
                 if ids and not tools:
