@@ -135,9 +135,10 @@ def new_serve_registry() -> Registry:
     )
     r.histogram(
         "dtpu_serve_loop_yield_seconds",
-        "The scheduler's yield to the event loop after a token "
-        "hand-over: the stream handlers' turn with no engine call in "
-        "flight",
+        "Return of a token hand-over to the first line of the "
+        "scheduler's next tick: event-loop time given away with no "
+        "engine call in flight (about zero: the stream handlers' turn "
+        "lies inside the next call's await)",
         buckets=SHORT_LATENCY_BUCKETS_S,
     )
     r.histogram(
@@ -153,6 +154,19 @@ def new_serve_registry() -> Registry:
         "request per hand-over (not per token)",
         buckets=SHORT_LATENCY_BUCKETS_S,
     )
+    # where the stream handlers' turn falls: inside an engine call's
+    # await (the device computes) or outside one. inc(0): the series
+    # exist from boot, so the share of the two is defined
+    r.counter(
+        "dtpu_serve_stream_tokens_total",
+        "Tokens the streaming chat handlers took off their queues "
+        "(detokenized and, where a delta came of it, written)",
+    ).inc(0)
+    r.counter(
+        "dtpu_serve_stream_tokens_overlapped_total",
+        "Of those, the tokens taken while an engine call was in "
+        "flight on the worker thread",
+    ).inc(0)
     # prefill dispatch accounting: the packed multi-slot prefill packs
     # up to prefill_pack concurrent prompt chunks into one forward —
     # dispatches per burst is the TTFT-under-load lever these observe

@@ -43,6 +43,7 @@ Request-lifecycle hardening (serving.md §9):
 import argparse
 import asyncio
 import contextlib
+import contextvars
 import json
 import os
 import re
@@ -187,6 +188,15 @@ class Scheduler:
         # returned, as (family, seconds): observed when the next call
         # counts its gap, dropped by a park
         self._gap_parts: list = []
+        # when the last token hand-over returned: what the loop gives
+        # away from there to the next tick's first line, with no engine
+        # call in flight, is dtpu_serve_loop_yield_seconds
+        self._handed_over_at: Optional[float] = None
+        # engine calls in flight (one; two while a watchdog-abandoned
+        # step's thread is still out). The stream handlers read it once
+        # a token: their turn lies inside a call's await, while the
+        # device computes, and the overlapped-token counter says so
+        self.calls_in_flight = 0
         # serving metrics live in the ENGINE's obs registry (one source
         # of truth); /metrics renders the registry for the shim relay →
         # server prometheus plane and for the benchmark's readers.
@@ -216,22 +226,20 @@ class Scheduler:
         self.pending.push(req, req.priority)
 
     def cancel(self, req: _Request) -> None:
-        """Client went away: free the slot so decode stops burning steps
-        on an abandoned generation (or its remaining prefill chunks).
-        A request cancelled before its first token refunds its QoS
-        charge (the satellite invariant: abusive-reconnect churn must
-        not burn a victim tenant's budget)."""
+        """Client went away: mark the request, and the next tick's sweep
+        (``_admit_pending``) frees its slot so decode stops burning
+        steps on an abandoned generation (or its remaining prefill
+        chunks). The engine is NOT touched here: a handler runs while
+        an engine call is in flight on the worker thread, and a
+        ``release`` under a running step is overwritten by the device
+        mirror the step restores (the released slot would go on writing
+        its cache rows). A request cancelled before its first token
+        refunds its QoS charge (the satellite invariant:
+        abusive-reconnect churn must not burn a victim tenant's
+        budget)."""
         req.cancelled = True
         self._refund_unstarted(req)
         req.phase.end("cancelled")
-        for slot, r in list(self.by_slot.items()):
-            if r is req:
-                self.engine.release(slot)
-                del self.by_slot[slot]
-        for slot, r in list(self.by_prefill.items()):
-            if r is req:
-                self.engine.release(slot)
-                del self.by_prefill[slot]
 
     def _count_error(self, req: _Request) -> None:
         """One server-side request failure (engine/prefill/admission
@@ -326,10 +334,15 @@ class Scheduler:
 
     # ---- host-phase accounting ----
 
-    async def _engine_call(self, fn):
-        """``fn`` (``engine.step`` / ``engine.prefill_wave``) on a worker
-        thread. The time since the previous engine call returned — the
-        device had no work queued while requests held slots — goes to
+    def _engine_call(self, fn):
+        """Hand ``fn`` (``engine.step`` / ``engine.prefill_wave``) to a
+        worker thread NOW — a plain function, so the call is out before
+        the caller's ``await`` (or the watchdog's task) runs a line —
+        and return the awaitable of its result: whoever the loop runs at
+        that ``await`` (the stream handlers, woken by the last
+        hand-over) then runs beside the call, not before it.
+        The time since the previous engine call returned — the device
+        had no work queued while requests held slots — goes to
         ``dtpu_serve_host_gap_seconds``; both clock reads are taken on
         the worker thread, so the gap includes the thread hops, and
         this is where each hop is read from both sides:
@@ -354,7 +367,19 @@ class Scheduler:
             finally:
                 self._engine_returned = time.perf_counter()
 
-        out = await asyncio.to_thread(run)
+        self.calls_in_flight += 1
+        return self._call_done(asyncio.get_running_loop().run_in_executor(
+            None, contextvars.copy_context().run, run
+        ))
+
+    async def _call_done(self, call: asyncio.Future):
+        """The result of a started engine call. Its ``await`` is where
+        the loop is given away, with the call on its way to the device:
+        the stream handlers take their turn here."""
+        try:
+            out = await call
+        finally:
+            self.calls_in_flight -= 1
         self._gap_parts.append((
             "dtpu_serve_loop_return_seconds",
             time.perf_counter() - self._engine_returned,
@@ -386,6 +411,9 @@ class Scheduler:
         produced no tokens); engine errors propagate as before."""
         if self.watchdog_seconds <= 0:
             return await self._engine_call(self.engine.step)
+        # the call is started here, not by the task: a task's first line
+        # runs a loop iteration later, behind the handlers the hand-over
+        # woke
         task = asyncio.ensure_future(self._engine_call(self.engine.step))
         done, _ = await asyncio.wait({task}, timeout=self.watchdog_seconds)
         if done:
@@ -557,6 +585,12 @@ class Scheduler:
         return any(t in text for t in req.gen.stop)
 
     async def _tick(self) -> None:
+        if self._handed_over_at is not None:
+            self._gap_parts.append((
+                "dtpu_serve_loop_yield_seconds",
+                time.perf_counter() - self._handed_over_at,
+            ))
+            self._handed_over_at = None
         if self._abandoned is not None:
             if not self._abandoned.done():
                 # a dispatch-abandoned step's thread still owns the
@@ -622,7 +656,7 @@ class Scheduler:
                     req = self.by_prefill.pop(slot, None)
                     if req is None or req.cancelled:
                         # cancel() landed while the wave ran on the
-                        # worker thread
+                        # worker thread (the handlers' turn)
                         self.engine.release(slot)
                     elif self._handle_first_token(slot, req, first, now):
                         self.by_slot[slot] = req
@@ -642,13 +676,11 @@ class Scheduler:
             return  # watchdog tripped: bookkeeping already done
         with self._host_code():
             self._hand_over(out)
-        # the stream handlers' turn, with no engine call in flight
-        t0 = time.perf_counter()
-        with obs_profiling.span("dtpu.loop.yield"):
-            await asyncio.sleep(0)
-        self._gap_parts.append(
-            ("dtpu_serve_loop_yield_seconds", time.perf_counter() - t0)
-        )
+        # NO yield here: the tokens are on the requests' queues, and the
+        # next tick's admission and engine call need nothing from the
+        # handlers. Their turn (detokenize + SSE write, ~0.2 ms a token)
+        # lies inside that call's await, while the device computes
+        self._handed_over_at = time.perf_counter()
 
     def _admit_pending(self) -> None:
         """The tick's admission half, host bookkeeping only."""
@@ -656,6 +688,12 @@ class Scheduler:
         # admission pass below, so the reclaimed slot serves live work
         # in the same tick
         self._abort_expired()
+        # so does the slot of a request cancelled while the last engine
+        # call ran (cancel() only marks it)
+        for table in (self.by_slot, self.by_prefill):
+            for slot in [s for s, r in table.items() if r.cancelled]:
+                self.engine.release(slot)
+                del table[slot]
         # admit pending requests into the free slots (host bookkeeping
         # only — the prompt prefills chunk by chunk below) in ONE heap
         # walk: priority-ordered, a tenant at its in-flight cap skipped
@@ -724,17 +762,12 @@ class Scheduler:
             )
             self.by_prefill[slot] = req
 
-        # cancelled mid-prefill: free the slot before the next wave
-        for slot in [s for s, r in self.by_prefill.items() if r.cancelled]:
-            self.engine.release(slot)
-            del self.by_prefill[slot]
-
     def _hand_over(self, out: dict) -> None:
         """One engine step's tokens → their requests' queues."""
         now = time.perf_counter()  # ONE read: the call's slots share it
         for slot, toks in out.items():
             req = self.by_slot.get(slot)
-            if req is None:
+            if req is None or req.cancelled:  # the next sweep frees it
                 continue
             # one event per engine dispatch: a turbo macro-step or
             # speculative verify counts once with its token yield, so
@@ -1609,17 +1642,24 @@ def build_app(
             m_detok = engine.metrics.family("dtpu_serve_detokenize_seconds")
             m_write = engine.metrics.family("dtpu_serve_stream_write_seconds")
             m_lag = engine.metrics.family("dtpu_serve_first_delta_lag_seconds")
+            m_tokens = engine.metrics.family("dtpu_serve_stream_tokens_total")
+            m_overlapped = engine.metrics.family(
+                "dtpu_serve_stream_tokens_overlapped_total"
+            )
             # the per-token timings are only noted on the token path:
-            # the handlers run in the gap between two engine calls,
-            # with the device idle. The histograms take them when the
-            # handler parks for its next token, in the loop iteration
-            # AFTER the scheduler's own — while the next engine call
-            # is already on the device.
+            # the handlers share the GIL with the worker thread that
+            # enqueues the next engine call. The histograms take them
+            # when the handler parks for its next token, a loop
+            # iteration later.
             detok_s: list[float] = []
             write_s: list[float] = []
             # hand-over → the end of the delta that answers it, once a
             # request a hand-over (the scheduler's stamp, cleared here)
             lag_s: list[float] = []
+            # tokens taken off the queue, and those of them taken while
+            # an engine call was in flight (the turn the scheduler
+            # leaves the handlers: inside the next call's await)
+            taken = [0, 0]
             loop = asyncio.get_running_loop()
 
             def observe_noted() -> None:
@@ -1629,6 +1669,10 @@ def build_app(
                     for v in noted:
                         hist.observe(v)
                     noted.clear()
+                if taken[0]:
+                    m_tokens.inc(taken[0])
+                    m_overlapped.inc(taken[1])
+                    taken[:] = 0, 0
 
             def detokenize(hold: bool) -> str:
                 """All ids so far → deliverable text (``hold``: minus a
@@ -1688,6 +1732,8 @@ def build_app(
                     if tok is None:
                         break
                     ids.append(tok)
+                    taken[0] += 1
+                    taken[1] += sched.calls_in_flight > 0
                     out = detokenize(hold=True)
                     if tools:
                         # stream prose up to the first point that could

@@ -5,7 +5,10 @@ callers (``dtpu_serve_host_gap_seconds``, ``_tick_host_``,
 ``_prefill_host_``; the gap between two calls into ``_loop_return_`` /
 ``_loop_yield_`` / ``_worker_start_``; ``_first_delta_lag_``) and,
 while a profiler capture runs, the same intervals as ``dtpu.*`` spans
-on its ``/host:CPU`` plane."""
+on its ``/host:CPU`` plane. Since PR 41 the scheduler does not yield
+between a hand-over and the next engine call: ``_loop_yield_`` reads
+about zero and there is no ``dtpu.loop.yield`` span
+(``test_stream_overlap.py`` has the overlap itself)."""
 
 import asyncio
 import glob
@@ -222,7 +225,7 @@ class TestHostPhaseHistograms:
 CAPTURE_SPANS = (
     "dtpu.engine.step", "dtpu.engine.prefill", "dtpu.tick.host",
     "dtpu.stream.detokenize", "dtpu.stream.write",
-    "dtpu.engine.wait", "dtpu.engine.finish", "dtpu.loop.yield",
+    "dtpu.engine.wait", "dtpu.engine.finish",
 )
 
 
@@ -264,12 +267,13 @@ def capture(tmp_path_factory):
     ]
     assert host
     spans: dict = {}
-    for line in host[0].lines:
+    for i, line in enumerate(host[0].lines):  # a line a thread
         for e in line.events:
             if e.name.startswith("dtpu."):
-                spans.setdefault(e.name, []).append(
-                    (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
-                )
+                spans.setdefault(e.name, []).append((
+                    e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats, line=i),
+                ))
     ring = {r["seq"]: r for r in flight.get_recorder().records(512)}
     return spans, ring
 
@@ -304,12 +308,23 @@ class TestSpansInACapture:
         assert len(inside) == len(steps)
         assert len(spans["dtpu.engine.finish"]) == 2 * len(steps)
 
-    def test_the_handlers_spans_fall_inside_the_yield(self, capture):
+    def test_the_scheduler_yields_nowhere_but_in_an_engine_call(self, capture):
+        assert "dtpu.loop.yield" not in capture[0]
+
+    def test_the_handlers_spans_lie_on_another_line_than_the_engines(self, capture):
+        """The handlers' turn is the next call's await: a delta is
+        written on the loop thread's line while the call is out on a
+        worker thread's (``test_stream_overlap.py`` parks a call to
+        show the two at once; here the threads race for the GIL)."""
         spans, _ = capture
-        yields = spans["dtpu.loop.yield"]
-        writes = spans["dtpu.stream.write"]
-        assert any(
-            y0 <= w0 and w1 <= y1 for w0, w1, _ in writes for y0, y1, _ in yields
+
+        def lines(*names):
+            return {s["line"] for n in names for _, _, s in spans[n]}
+
+        loop = lines("dtpu.stream.write", "dtpu.stream.detokenize", "dtpu.tick.host")
+        assert len(loop) == 1
+        assert not loop & lines(
+            "dtpu.engine.step", "dtpu.engine.prefill", "dtpu.engine.wait"
         )
 
 
@@ -318,8 +333,8 @@ def test_what_the_added_lines_cost_a_call(capsys, live, tokens):
     """The lines this accounting adds to one decode cycle, without an
     engine or a server: the clock reads, the no-op spans, the noted
     parts and the observes of one ``engine.step`` call (two fetches),
-    one ``Scheduler._engine_call`` + hand-over + yield, and ``live``
-    handlers of ``tokens`` tokens each (a plain step of a thin batch, a
+    one ``Scheduler._engine_call`` + hand-over + the next tick's first
+    lines, and ``live`` handlers of ``tokens`` tokens each (a plain step of a thin batch, a
     macro-step of a full one). Prints µs a cycle (``CHANGES.md`` quotes
     it); asserts no time."""
     from dstack_tpu.obs import profiling
@@ -330,8 +345,15 @@ def test_what_the_added_lines_cost_a_call(capsys, live, tokens):
     class Req:
         handed_at = None
 
+    class Sched:
+        calls_in_flight = 0
+        handed_over_at = None
+
+    sched = Sched()
     reqs = [Req() for _ in range(live)]
     m_lag = family("dtpu_serve_first_delta_lag_seconds")
+    m_tokens = family("dtpu_serve_stream_tokens_total")
+    m_overlapped = family("dtpu_serve_stream_tokens_overlapped_total")
 
     def cycle():
         # engine.step: reset, two fetches, two finish spans, three observes
@@ -360,26 +382,37 @@ def test_what_the_added_lines_cost_a_call(capsys, live, tokens):
             family(name).observe(seconds)
         parts.clear()
         t_hop = clock()
+        sched.calls_in_flight += 1
         family("dtpu_serve_worker_start_seconds").observe(clock() - t_hop)
+        sched.calls_in_flight -= 1
         parts.append(("dtpu_serve_loop_return_seconds", clock() - t_hop))
         family(parts[0][0]).observe(parts[0][1])
-        # _hand_over: one read, a test a token; the yield's two reads
+        # _hand_over: one read, a test a token; its return's stamp and
+        # the next tick's reading of it
         now = clock()
         for r in reqs:
             for _ in range(tokens):
                 if r.handed_at is None:
                     r.handed_at = now
-        t0 = clock()
-        with profiling.span("dtpu.loop.yield"):
-            pass
-        parts.append(("dtpu_serve_loop_yield_seconds", clock() - t0))
-        # the handlers: a test a token, one note and one observe a request
+        sched.handed_over_at = clock()
+        if sched.handed_over_at is not None:
+            parts.append(
+                ("dtpu_serve_loop_yield_seconds", clock() - sched.handed_over_at)
+            )
+            sched.handed_over_at = None
+        # the handlers: two counts and a test a token, one note and one
+        # observe a request, two incs a park
         lag_s = []
         for r in reqs:
+            taken = [0, 0]
             for _ in range(tokens):
+                taken[0] += 1
+                taken[1] += sched.calls_in_flight > 0
                 if r.handed_at is not None:
                     lag_s.append(clock() - r.handed_at)
                     r.handed_at = None
+            m_tokens.inc(taken[0])
+            m_overlapped.inc(taken[1])
         for v in lag_s:
             m_lag.observe(v)
 
