@@ -236,19 +236,54 @@ class LlamaConfig:
     # one returns the expert's input times its gate ("zero-computation"
     # identity experts); the router is n_experts + zero_experts wide
     zero_experts: int = 0
+    # --- linear-attention layers (models/kda.py) ---
+    # a layer of kind "linear" in layer_types mixes its tokens through a
+    # gated delta rule with a decay a channel (KDA) behind a causal
+    # depthwise convolution: n_heads heads of linear_head_dim, no rope,
+    # no keys or values kept: a slot's past is a float32 state
+    # [n_heads, D, D] and the convolution's last linear_conv - 1 rows.
+    # Its weights are a stack of their own, params["linear_layers"]
+    # (a first_k_dense prelude of linear layers keeps "dense_layers").
+    # The log-decay is linear_gate_floor * sigmoid(.), in (floor, 0)
+    linear_head_dim: int = 0
+    linear_conv: int = 4
+    linear_gate_floor: float = -5.0
 
     def __post_init__(self):
         kinds = set(self.layer_types)
+        prelude = set(self.layer_types[: self.first_k_dense])
         if self.layer_types and (
             len(self.layer_types) != self.n_layers
-            or not kinds <= {"full", "window"}
-            or "window" in self.layer_types[: self.first_k_dense]
+            or not kinds <= {"full", "window", "linear"}
+            or not prelude <= {"full"} and prelude != {"linear"}
             or self.sliding_pattern or self.nope_pattern
         ):
             raise ValueError(
-                "layer_types: one of 'full' | 'window' a layer (in place "
-                "of sliding_pattern / nope_pattern), the first_k_dense "
-                "prelude all 'full'"
+                "layer_types: one of 'full' | 'window' | 'linear' a layer "
+                "(in place of sliding_pattern / nope_pattern), the "
+                "first_k_dense prelude all 'full' or all 'linear'"
+            )
+        if "linear" in kinds and not (
+            self.mla and self.linear_head_dim and self.linear_conv > 1
+            and self.sublayers == 1 and self.pre_norm
+            and not self.post_norms and not self.parallel_block
+            # a block of the chunkwise form holds its decay in float32
+            and -self.linear_gate_floor * 16 < 88
+        ):
+            raise ValueError(
+                "linear layers: beside latent attention, with "
+                "linear_head_dim, plainly pre-normed, a gate floor over -5.5"
+            )
+        if self.experts_held and self.router_groups and (
+            self.n_experts % self.router_groups[0]
+            or any(
+                n % (self.n_experts // self.router_groups[0])
+                for n in self.experts_held
+            )
+        ):
+            raise ValueError(
+                "experts_held: a whole number of the router's groups "
+                "(a chip of an expert-parallel layer holds whole groups)"
             )
         if "window" in kinds and not (
             self.sliding_window and self.swa_n_heads
@@ -283,6 +318,18 @@ class LlamaConfig:
     @property
     def mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def prelude_kind(self) -> str:
+        """The kind of the ``first_k_dense`` prelude's layers."""
+        return self.layer_types[0] if self.layer_types else "full"
+
+    def n_kind(self, kind: str, prelude: bool = True) -> int:
+        """Layers of ``kind`` (``prelude``: the prelude's counted)."""
+        if not self.layer_types:
+            n = self.n_layers if kind == "full" else 0
+            return n - (0 if prelude or kind != "full" else self.first_k_dense)
+        return self.layer_types[0 if prelude else self.first_k_dense:].count(kind)
 
     @property
     def window_config(self) -> "LlamaConfig":
@@ -394,12 +441,17 @@ class LlamaConfig:
         """Attention parameters over all layers (a window layer has
         its own shape)."""
         n_win = self.layer_types.count("window")
+        n_lin = self.layer_types.count("linear")
         total = (
-            (self.n_layers - n_win) * self.sublayers
+            (self.n_layers - n_win - n_lin) * self.sublayers
             * self._attn_params_per_layer()
         )
         if n_win:
             total += n_win * self.window_config._attn_params_per_layer()
+        if n_lin:
+            from dstack_tpu.models import kda
+
+            total += n_lin * kda.n_params(self)
         return total
 
     def _param_count(self, experts: int, small: bool = True) -> int:
@@ -639,6 +691,19 @@ SCMOE_TINY = LlamaConfig(  # for tests: layers of two sublayers, the experts acr
     n_experts=8, zero_experts=4, experts_per_token=3, capacity_factor=4.0,
     router_bias=True, routed_scale=6.0,
 )
+LINEAR_TINY = LlamaConfig(  # for tests: linear-attention layers beside latent ones
+    vocab_size=512, hidden_size=128, n_layers=7, n_heads=4, n_kv_heads=4,
+    head_dim=16, intermediate_size=64, max_seq_len=256, dtype=jnp.float32,
+    remat=False, rope_interleaved=True,
+    kv_lora_rank=64, qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=24,
+    attn_gate=True, linear_head_dim=16,
+    layer_types=("linear",) + ("linear", "linear", "full") * 2,
+    first_k_dense=1, dense_intermediate=192,
+    n_experts=8, experts_per_token=2, capacity_factor=4.0,
+    router_score="sigmoid", router_bias=True, router_groups=(4, 2),
+    routed_scale=2.5, router_renorm=True, experts_held=(2, 2),
+    moe_shared_expert=True, moe_shared_intermediate=64,
+)
 
 _GPT_OSS_COMMON = dict(
     vocab_size=201088, hidden_size=2880, n_heads=64, n_kv_heads=8,
@@ -678,6 +743,7 @@ CONFIGS = {
     "deepseek-v3": DEEPSEEK_V3,
     "mla-tiny": MLA_TINY,
     "scmoe-tiny": SCMOE_TINY,
+    "linear-tiny": LINEAR_TINY,
     "glm-4-9b": GLM_4_9B,
     "olmo-2-7b": OLMO2_7B,
     "command-r-35b": COMMAND_R_35B,
@@ -819,9 +885,53 @@ def param_specs(config: LlamaConfig) -> dict:
         specs["window_layers"] = {
             k: v for k, v in layer.items() if "idx" not in k
         }
+    if "linear" in config.layer_types:
+        # a linear mixer's leaves in place of the attention's (skinny
+        # or elementwise ones replicated, the projections over heads)
+        from dstack_tpu.models import kda
+
+        lin = {
+            k: L + (
+                ("heads", "embed_fsdp") if k == "wo"
+                else ("embed_fsdp", "heads") if len(shape) == 3 and k != "lin_conv"
+                else (None,) * (len(shape) - 1)
+            )
+            for k, (shape, _) in kda.leaf_shapes(config, 1).items()
+        }
+        swap = lambda tree: {
+            **{k: v for k, v in tree.items() if k not in attn}, **lin
+        }
+        if config.n_kind("linear", prelude=False):
+            specs["linear_layers"] = swap(layer)
+        if config.first_k_dense and config.prelude_kind == "linear":
+            specs["dense_layers"] = swap(specs["dense_layers"])
     if not config.tie_embeddings:
         specs["lm_head"] = ("embed_fsdp", "vocab")
     return specs
+
+
+def _init_linear(
+    c: LlamaConfig, key: jax.Array, L: int, std: float, depth: int
+) -> dict:
+    """A stack of ``L`` linear mixers (models/kda.py states the leaves)."""
+    from dstack_tpu.models import kda
+
+    out = {}
+    for i, (name, (shape, init)) in enumerate(sorted(kda.leaf_shapes(c, L).items())):
+        k = jax.random.fold_in(key, 41 + i)
+        if init == "ones":
+            out[name] = jnp.ones(shape, c.dtype)
+        elif init == "small":
+            out[name] = jax.random.normal(k, shape, jnp.float32) * std
+        else:
+            scale = {
+                "normal": std, "out": std / math.sqrt(2 * depth),
+                "conv": c.linear_conv**-0.5,
+            }[init]
+            out[name] = (
+                jax.random.normal(k, shape, jnp.float32) * scale
+            ).astype(c.dtype)
+    return out
 
 
 def _init_attn(
@@ -902,6 +1012,7 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
     dt = c.dtype
     depth = depth or c.n_layers
     n_win = c.layer_types.count("window")
+    n_lin = c.n_kind("linear", prelude=False)
 
     def normal(key, shape, scale=std):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
@@ -917,7 +1028,7 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
         # Gemma-style norms scale by (1 + w): identity init is w = 0
         return (jnp.zeros if c.norm_offset else jnp.ones)(shape, dt)
 
-    L = c.n_layers - c.first_k_dense - n_win
+    L = c.n_layers - c.first_k_dense - n_win - n_lin
     if c.n_experts:
         E, EH = c.n_experts + c.zero_experts, c.n_experts_held
         mlp = {
@@ -1039,6 +1150,12 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
         if c.post_norms:
             dense["attn_post_norm"] = norm_init((K, c.hidden_size))
             dense["mlp_post_norm"] = norm_init((K, c.hidden_size))
+        if c.prelude_kind == "linear":  # a mixer in the attention's place
+            dense = {
+                "attn_norm": dense["attn_norm"],
+                **_init_linear(c, kd[0], K, std, depth),
+                **{k: dense[k] for k in ("mlp_norm", "w_gate", "w_up", "w_down")},
+            }
         params["dense_layers"] = dense
     if n_win:
         # the window layers: the same leaves at their own attention
@@ -1050,6 +1167,22 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
         params["window_layers"] = init_params(
             wc, jax.random.fold_in(key, 3), depth
         )["layers"]
+    if n_lin:
+        # the linear layers: the expert layer's MLP leaves under a
+        # mixer's, a stack of their own (only the stack is kept)
+        # (the attention the stack is drawn with is dropped: one head of
+        # the least widths, the MLP leaves' draws do not read them)
+        lc = dataclasses.replace(
+            c, n_layers=n_lin, layer_types=(), first_k_dense=0, vocab_size=8,
+            tie_embeddings=True, n_heads=1, kv_lora_rank=1, qk_nope_head_dim=1,
+            qk_rope_head_dim=2, v_head_dim=1,
+        )
+        full = init_params(lc, jax.random.fold_in(key, 4), depth)["layers"]
+        attn = _init_attn(lc, key, 1, std, depth)
+        params["linear_layers"] = {
+            **{k: v for k, v in full.items() if k not in attn},
+            **_init_linear(c, jax.random.fold_in(key, 5), n_lin, std, depth),
+        }
     if not c.tie_embeddings:
         params["lm_head"] = normal(jax.random.fold_in(key, 99), (c.hidden_size, c.vocab_size))
     return params
@@ -1203,14 +1336,19 @@ def layer_nope(config: "LlamaConfig") -> list[bool]:
     return [(i + 1) % c.nope_pattern == 0 for i in range(c.n_layers)]
 
 
+#: a layer kind → the stack of ``params`` its layers past the prelude are in
+STACK_OF = {"full": "layers", "window": "window_layers", "linear": "linear_layers"}
+
+
 class LayerRun(NamedTuple):
     """Consecutive layers of one group, ``params[key][lo:hi]``."""
 
-    key: str  # "dense_layers" | "layers" | "window_layers"
+    key: str  # "dense_layers" | "layers" | "window_layers" | "linear_layers"
     config: "LlamaConfig"  # the group's attention shape
     window: int  # 0 = full attention
     lo: int
     hi: int
+    kind: str = "full"  # "full" | "window" | "linear": what its layers keep
 
 
 def layer_runs(config: "LlamaConfig") -> list:
@@ -1223,23 +1361,35 @@ def layer_runs(config: "LlamaConfig") -> list:
     c = config
     runs = []
     if c.first_k_dense:
-        runs.append(LayerRun("dense_layers", c, 0, 0, c.first_k_dense))
+        runs.append(
+            LayerRun("dense_layers", c, 0, 0, c.first_k_dense, c.prelude_kind)
+        )
     kinds = c.layer_types[c.first_k_dense:] or ("full",) * (
         c.n_layers - c.first_k_dense
     )
-    seen = {"full": 0, "window": 0}
+    seen = {"full": 0, "window": 0, "linear": 0}
     for kind in kinds:
         at = seen[kind]
         seen[kind] += 1
         last = runs[-1] if runs else None
-        key = "window_layers" if kind == "window" else "layers"
+        key = STACK_OF[kind]
         if last is not None and last.key == key and last.hi == at:
             runs[-1] = last._replace(hi=at + 1)
         elif kind == "window":
-            runs.append(LayerRun(key, c.window_config, c.sliding_window, at, at + 1))
+            runs.append(
+                LayerRun(key, c.window_config, c.sliding_window, at, at + 1, kind)
+            )
         else:
-            runs.append(LayerRun(key, c, 0, at, at + 1))
+            runs.append(LayerRun(key, c, 0, at, at + 1, kind))
     return runs
+
+
+def run_row(c: "LlamaConfig", run: LayerRun) -> int:
+    """The run's first layer → its row in its kind's cache buffers: a
+    kind's layers in the order the model walks them, the prelude's
+    first."""
+    ahead = run.key != "dense_layers" and run.kind == c.prelude_kind
+    return run.lo + (c.first_k_dense if ahead else 0)
 
 
 def run_slice(stack: dict, run: LayerRun) -> dict:
@@ -1723,6 +1873,21 @@ def _attention_block(
     return constrain(out, rules, "batch", "seq", None, mesh=mesh)
 
 
+def _linear_block(
+    x: jax.Array, layer: dict, config: LlamaConfig, mesh: Optional[Mesh],
+    rules: ShardingRules,
+) -> jax.Array:
+    """A linear layer's mixer over whole sequences, from a state of
+    zeros (models/kda.py): the training and parity path's."""
+    from dstack_tpu.models import kda
+
+    c = config
+    h = model_norm(x, layer["attn_norm"], c)
+    y, _, _ = kda.mix(h, layer, c, *kda.zeros(c, x.shape[0], x.dtype))
+    out = _proj(layer, "wo", y, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
+    return constrain(out, rules, "batch", "seq", None, mesh=mesh)
+
+
 def _mlp_block(
     x: jax.Array,
     layer: dict,
@@ -1947,6 +2112,10 @@ def forward(
                     rules=rules, attn_impl=attn_impl, window=w, nope=np_,
                     positions=pos,
                 )
+                if "lin_wqkv" in layer:  # a linear mixer in its place
+                    attend = functools.partial(
+                        _linear_block, config=c, mesh=mesh, rules=rules
+                    )
                 if c.sublayers > 1:
                     x, aux_i = _shortcut_layer(
                         x, layer, c.sublayers, attend,

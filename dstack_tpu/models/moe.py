@@ -99,6 +99,13 @@ def _group_limit(
     are zeroed (HF's ``masked_fill(~mask, 0)`` — exact parity incl. its
     quirk that a zeroed slot can outrank a genuinely negative score).
     Group score: max member (V2 softmax) or top-2 sum (V3 sigmoid)."""
+    gs, gmask = _eligible_groups(sel, groups, score)
+    return (gs * gmask[..., None]).reshape(sel.shape)
+
+
+def _eligible_groups(sel: jax.Array, groups: tuple, score: str) -> tuple:
+    """→ (``sel`` by group [B, T, n_group, E / n_group], the mask of the
+    ``topk_group`` best groups [B, T, n_group] in ``sel``'s dtype)."""
     n_group, topk_group = groups
     e = sel.shape[-1]
     gs = sel.reshape(*sel.shape[:-1], n_group, e // n_group)
@@ -108,8 +115,7 @@ def _group_limit(
     else:  # V2 group_limited_greedy: best member
         g_score = gs.max(axis=-1)
     _, gidx = jax.lax.top_k(g_score, topk_group)  # [B, T, topk_group]
-    gmask = jax.nn.one_hot(gidx, n_group, dtype=sel.dtype).sum(axis=-2)
-    return (gs * gmask[..., None]).reshape(sel.shape)
+    return gs, jax.nn.one_hot(gidx, n_group, dtype=sel.dtype).sum(axis=-2)
 
 
 def select(
@@ -163,11 +169,18 @@ def select(
     return logits, probs, expert_idx, gate_vals
 
 
-def _routing_aux(logits, probs, expert_idx, gate_vals, held, valid, zero):
+def _routing_aux(
+    logits, probs, expert_idx, gate_vals, held, valid, zero,
+    groups=(), score="softmax", bias=None,
+):
     """What a routing reports beside its sum, the same for both forms
     of the expert FFNs (``expert_idx`` over the router's whole width):
     the Switch losses and, for a chip's share and for identity experts,
-    the counts and the gate sum :func:`router` documents."""
+    the counts and the gate sum :func:`router` documents. Under
+    group-limited routing a chip's share is whole groups, and ``aux
+    ["group_hit"]`` counts the (``valid``) tokens one of whose eligible
+    groups is held here: the tokens an expert-parallel layer would
+    send this chip."""
     # Switch aux losses (f32): load balance + router z-loss
     e = logits.shape[-1]
     top1 = jax.nn.one_hot(expert_idx[..., 0], e, dtype=jnp.float32)
@@ -181,6 +194,19 @@ def _routing_aux(logits, probs, expert_idx, gate_vals, held, valid, zero):
         if valid is not None:
             landed = landed & valid[..., None]
         aux["held_picks"] = jnp.sum(landed).astype(jnp.int32)
+    if held and groups:
+        # the selection's own scores and group choice, made again (the
+        # compiler folds the two)
+        scores = jax.nn.sigmoid(logits) if score == "sigmoid" else probs
+        sel = scores if bias is None else scores + bias
+        size = logits.shape[-1] // groups[0]
+        mine = _eligible_groups(sel, groups, score)[1][
+            ..., held[0] // size : (held[0] + held[1]) // size
+        ]
+        hit = jnp.sum(mine, axis=-1) > 0
+        if valid is not None:
+            hit = hit & valid
+        aux["group_hit"] = jnp.sum(hit).astype(jnp.int32)
     if zero:
         is_zero = expert_idx >= e - zero
         aux["zero_gate"] = jnp.sum(jnp.where(is_zero, gate_vals, 0.0), axis=-1)
@@ -276,7 +302,10 @@ def router(
 
     if here:
         expert_idx = expert_idx + here[0]
-    aux = _routing_aux(logits, probs, expert_idx, gate_vals, held, valid, zero)
+    aux = _routing_aux(
+        logits, probs, expert_idx, gate_vals, held, valid, zero,
+        groups=groups, score=score, bias=bias,
+    )
     return dispatch, combine, aux
 
 
@@ -488,7 +517,10 @@ def moe_mlp(
         logits, probs, expert_idx, gate_vals = select(
             x, layer["w_router"], experts_per_token, **routing
         )
-        aux = _routing_aux(logits, probs, expert_idx, gate_vals, held, valid, zero)
+        aux = _routing_aux(
+            logits, probs, expert_idx, gate_vals, held, valid, zero,
+            groups=groups, score=score, bias=routing["bias"],
+        )
         with jax.named_scope("dtpu.moe_held" if held else "dtpu.moe"):
             out, read = _picked_experts(
                 x, layer, expert_idx, gate_vals,

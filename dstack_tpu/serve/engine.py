@@ -384,9 +384,14 @@ def _cache_shapes(
     new in PR 38: ``tests/benchmark`` unpacks the first by its length). A model of
     one kind of layer has ``ckv``, or ``k`` and ``v``, alone; a latent
     layer of several attention sublayers has a ``ckv`` row a sublayer,
-    layer ``l``'s sublayer ``i`` at row ``l * sublayers + i``."""
+    layer ``l``'s sublayer ``i`` at row ``l * sublayers + i``. Linear
+    layers (``models/kda.py``) hold ``state`` [Ll, B, heads, D, D] in
+    float32 and ``conv`` [Ll, B, K-1, 3 * heads * D], neither with a
+    token axis; under group-limited routing ``moe_stats`` ends with the
+    tokens one of whose eligible groups is held here."""
     n_win = c.layer_types.count("window")
-    n_full = c.n_layers - n_win
+    n_lin = c.layer_types.count("linear")
+    n_full = c.n_layers - n_win - n_lin
     ring = ring_rows(c, max_seq, chunk) if n_win else 0
     if c.mla:
         rope = c.qk_rope_head_dim
@@ -408,6 +413,14 @@ def _cache_shapes(
             shapes["k_s"] = shapes["v_s"] = shapes["k"][:-1]
         if n_win:
             shapes["win_k"] = shapes["win_v"] = kv(n_win, ring)
+    if n_lin:
+        # a linear layer keeps no rows: a slot's whole past is its state
+        # (float32) and the convolution's tail, whatever max_seq is
+        heads = (c.n_heads, c.linear_head_dim)
+        shapes["state"] = (n_lin, max_batch, *heads, c.linear_head_dim)
+        shapes["conv"] = (
+            n_lin, max_batch, c.linear_conv - 1, 3 * heads[0] * heads[1]
+        )
     if c.experts_held:
         shapes["moe_stats"] = (_moe_counts(c) - 2,)
         shapes["moe_reads"] = (2,)
@@ -422,11 +435,22 @@ _mla_cache_shapes = _cache_shapes
 #: the cache's leaves that are counts and no slot's state
 _COUNTS = ("moe_stats", "moe_reads")
 
+#: the cache's leaves that hold a slot's past whole and not by position:
+#: no prefix of theirs can be copied (a state at a shared length exists
+#: only if it was kept, and none is: PERF.md §7)
+_STATES = ("state", "conv")
+
+#: the token axis of a cache leaf that holds rows by position: what
+#: ``copy_cache_prefix`` can copy a prefix of (a window ring is copied
+#: whole). MLA latent and index keys [L,B,T,R]; k/v [L,B,H,T,D]; the
+#: int8 scales k_s/v_s [L,B,H,T]
+_T_AXIS = {"ckv": 2, "idx": 2, "k": 3, "v": 3, "k_s": 3, "v_s": 3}
+
 
 def _moe_counts(c: LlamaConfig) -> int:
     """Counts a routed layer call of a chip's share of the experts
     yields (:func:`_mlp_out`): ``moe_stats``' and then ``moe_reads``'."""
-    return 5 if c.zero_experts else 4
+    return 4 + bool(c.zero_experts) + bool(c.router_groups)
 
 
 def _moe_stats(cache: dict):
@@ -687,7 +711,8 @@ def init_cache(
         # stack up to ~0.4% multiplicative error on every dequantized
         # vector on top of the int8 error, for ~1.5% byte savings
         dt = (
-            jnp.int32 if n in _COUNTS else jnp.float32 if n.endswith("_s")
+            jnp.int32 if n in _COUNTS
+            else jnp.float32 if n.endswith("_s") or n == "state"
             else jnp.int8 if kv_quant else config.dtype
         )
         if mesh is None:
@@ -774,7 +799,8 @@ def _mlp_out(
     ``valid`` ([B, T] bool, the real tokens) → (output, int32 counts in
     :func:`_moe_stats`' order: the router picks that landed on an
     expert held here, the tokens routed, [the picks of identity experts
-    where the router has them,] the experts whose weights the call
+    where the router has them,] [the tokens with a held group among their
+    eligible ones under group-limited routing,] the experts whose weights the call
     read, the experts held), for a chip's share of the experts
     (``experts_held``)."""
     from dstack_tpu.models.llama import act_fn
@@ -803,6 +829,7 @@ def _mlp_out(
             picks = jnp.stack(
                 [aux["held_picks"], jnp.sum(valid).astype(jnp.int32)]
                 + ([aux["zero_picks"]] if c.zero_experts else [])
+                + ([aux["group_hit"]] if c.router_groups else [])
                 + [aux["experts_read"], aux["experts_held"]]
             )
     else:
@@ -1079,7 +1106,120 @@ def _latent_out(x, cache: dict, o, layer: dict, c: LlamaConfig, valid):
     return x + mo, _count_picks(cache, picks)
 
 
-def _latent_layer(attend, c: LlamaConfig, valid):
+def _linear_rows(cache: dict, li, slots=None, fresh=None):
+    """Layer ``li``'s (state, tail) of a linear layer: every slot's, or
+    the rows of ``slots`` [G] (one slice a row, as :func:`_cread_rows`);
+    zeros where ``fresh`` [G]: a request that starts at position 0
+    starts from nothing, whatever its slot's last request left."""
+    if slots is None:
+        state, tail = _clayer(cache["state"], li), _clayer(cache["conv"], li)
+    else:
+        state = _cread_rows(cache["state"], li, slots, None)
+        tail = _cread_rows(cache["conv"], li, slots, None)
+    if fresh is not None:
+        state = jnp.where(fresh[:, None, None, None], 0.0, state)
+        tail = jnp.where(fresh[:, None, None], jnp.zeros((), tail.dtype), tail)
+    return state, tail
+
+
+def _linear_store(cache: dict, li, slots, live, new) -> dict:
+    """``new`` (state, tail) written in place over layer ``li``'s rows
+    of the stacked leaves: every slot's, or row b into slot
+    ``slots[b]``. A row ``live`` [B] marks dead (a finished slot, a
+    wave's pad row, which carries slot 0) puts back what is there AT
+    ITS TURN: the rows go one after the other, so a pad row behind the
+    real row of the same slot keeps that row's write."""
+    out = dict(cache)
+    keep = lambda n, o, on: jnp.where(on.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
+    for name, rows in zip(_STATES, new):
+        buf = cache[name]
+        if slots is None:
+            rows = keep(rows, _clayer(buf, li), live)
+            buf = jax.lax.dynamic_update_index_in_dim(buf, rows, li, 0)
+        else:
+            li32 = jnp.asarray(li, jnp.int32)
+            zeros = (jnp.zeros((), jnp.int32),) * (buf.ndim - 2)
+            for b in range(rows.shape[0]):
+                at = (li32, slots[b]) + zeros
+                row = rows[b][None, None]
+                cur = jax.lax.dynamic_slice(buf, at, row.shape)
+                buf = jax.lax.dynamic_update_slice(buf, keep(row, cur, live[b]), at)
+        out[name] = buf
+    return out
+
+
+#: what a verify step keeps of each drafted position of a linear layer
+#: until the count of accepted drafts is known (:func:`_linear_commit`)
+_PENDING = ("pend_k", "pend_v", "pend_g", "pend_b", "pend_pre")
+
+
+def _linear_mixer(c: LlamaConfig, live, slots=None, fresh=None, real=None,
+                  counts=None, commit: bool = True):
+    """→ ``mix(x, layer, cache, li) -> (y [B, S, P] for wo, cache)``: a
+    linear layer's mixer (``models/kda.py``) over row ``li`` of the
+    cache's ``state`` and ``conv``, read and written in place
+    (:func:`_linear_rows`, :func:`_linear_store`). ``real`` [B, S]: the
+    tokens that move the state, ``counts`` [B] of them a row (None:
+    all). ``commit`` false is the verify step's: the state stays, and
+    each position's inputs go to the :data:`_PENDING` leaves."""
+    from dstack_tpu.models import kda
+
+    def mix(x, layer, cache, li):
+        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+        state, tail = _linear_rows(cache, li, slots, fresh)
+        if commit:
+            y, state, tail = kda.mix(h, layer, c, state, tail, real, counts)
+            return y, _linear_store(cache, li, slots, live, (state, tail))
+        y, _, inputs = kda.mix_parts(h, layer, c, state, tail)
+        cache = {**cache, **{
+            n: jax.lax.dynamic_update_index_in_dim(cache[n], a, li, 0)
+            for n, a in zip(_PENDING, inputs)
+        }}
+        return y, cache
+
+    return mix
+
+
+def _linear_pending(cache: dict, c: LlamaConfig, s: int) -> dict:
+    """``cache`` with zeroed :data:`_PENDING` leaves for ``s`` positions."""
+    n, b, nh, d, _ = cache["state"].shape
+    f32 = jnp.float32
+    return {
+        **cache,
+        **{k: jnp.zeros((n, b, s, nh, d), f32) for k in _PENDING[:3]},
+        "pend_b": jnp.zeros((n, b, s, nh), f32),
+        "pend_pre": jnp.zeros((n, b, s, cache["conv"].shape[-1]), cache["conv"].dtype),
+    }
+
+
+def _linear_commit(cache: dict, n_tokens, write_mask, c: LlamaConfig) -> dict:
+    """The verify step's second half for the linear layers: each live
+    slot's state and tail advanced by its first ``n_tokens`` [B]
+    positions (the last token and the accepted drafts) and by no
+    rejected one → the cache without the :data:`_PENDING` leaves."""
+    from dstack_tpu.models import kda
+
+    pend = [cache[k] for k in _PENDING]
+    cache = {k: v for k, v in cache.items() if k not in _PENDING}
+    real = jnp.arange(pend[0].shape[2])[None, :] < n_tokens[:, None]  # [B, S]
+
+    def one(cache, xs):
+        li, k, v, g, beta, pre = xs
+        state, tail = _linear_rows(cache, li)
+        with jax.named_scope("dtpu.linear.state"):
+            _, state = kda.rule(
+                jnp.zeros_like(k), k, v,
+                jnp.where(real[..., None, None], g, 0.0),
+                jnp.where(real[..., None], beta, 0.0), state,
+            )
+        tail = kda.next_tail(pre, tail, n_tokens)
+        return _linear_store(cache, li, None, write_mask, (state, tail)), None
+
+    cache, _ = jax.lax.scan(one, cache, (jnp.arange(pend[0].shape[0]), *pend))
+    return cache
+
+
+def _latent_layer(attend, c: LlamaConfig, valid, mix=None):
     """A latent program's attention, ``attend(x, layer, cache, row, run)
     -> (o [B, S, o_dim], cache)`` over row ``row`` of its cache buffers,
     → the ``one_layer(x, layer, cache, li, run)`` that
@@ -1093,6 +1233,9 @@ def _latent_layer(attend, c: LlamaConfig, valid):
     n = c.sublayers
 
     def one_layer(x, layer, cache, li, run):
+        if run.kind == "linear":  # ``mix``: the program's :func:`_linear_mixer`
+            y, cache = mix(x, layer, cache, li)
+            return _latent_out(x, cache, y, layer, c, valid)
         if n == 1:
             o, cache = attend(x, layer, cache, li, run)
             return _latent_out(x, cache, o, layer, c, valid)
@@ -1175,7 +1318,18 @@ def _mla_layers_inplace(params: dict, cache: dict, x: jax.Array, one_layer, c):
     in place and reads its rows from them → (x, cache). Every serving
     program's form, prefill and decode: handed through a scan as
     xs → ys the cache is held twice and copied whole (PERF.md §6, PR 25
-    and PR 29)."""
+    and PR 29). A model with linear layers is walked by its periods
+    (:func:`_walk_layer_groups`: (linear x 5, full) is one scan body
+    however deep the model)."""
+    if "linear" in c.layer_types:
+        (x, cache), _ = _walk_layer_groups(
+            params, (x, cache),
+            lambda carry, layer, li, run: (
+                one_layer(carry[0], layer, carry[1], li, run), None
+            ),
+            c,
+        )
+        return x, cache
     k_dense = c.first_k_dense
     for run in llama.layer_runs(c):
         if run.key == "dense_layers":
@@ -1247,8 +1401,14 @@ def _prefill_chunk_mla(
         o = _attend_causal(q_abs, row, start, gc, c)
         return _latent_values(o, w_kb_v, h, layer, gc), cache
 
+    mix = None
+    if "state" in cache:  # a chunk at position 0 starts from no state
+        mix = _linear_mixer(
+            c, jnp.ones((1,), bool), si[None], jnp.full((1,), start == 0),
+            (jnp.arange(cl) <= last_ix)[None], (last_ix + 1)[None],
+        )
     x, cache = _mla_layers_inplace(
-        params, cache, x, _latent_layer(attend, c, valid), c
+        params, cache, x, _latent_layer(attend, c, valid, mix), c
     )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     last = jnp.take_along_axis(
@@ -1367,8 +1527,11 @@ def _decode_step_mla(
             o = _latent_values(o_lat, w_kb_v, h, layer, gc)
         return o, cache
 
+    mix = None
+    if "state" in cache:  # a dead slot's state and tail stay
+        mix = _linear_mixer(c, write_mask, real=write_mask[:, None])
     x, cache = _mla_layers_inplace(
-        params, cache, x, _latent_layer(attend, c, valid), c
+        params, cache, x, _latent_layer(attend, c, valid, mix), c
     )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     return _head_logits(params, x[:, 0], c), cache
@@ -1381,6 +1544,7 @@ def _verify_step_mla(
     positions: jax.Array,  # [B]
     c: LlamaConfig,
     write_mask: jax.Array,
+    draft_len=None,  # [B]: drafts a row holds (a model with linear layers)
 ) -> tuple[jax.Array, dict]:
     """Absorbed-form multi-token decode (speculative verification)."""
     from dstack_tpu.models.llama import dual_rope_freqs
@@ -1425,11 +1589,37 @@ def _verify_step_mla(
             o = _latent_values(o_lat, w_kb_v, h, layer, gc)
         return o, cache
 
+    mix = None
+    if "state" in cache:
+        # a rejected draft must not have moved a state: the layers read
+        # theirs and keep every position's inputs, and the states are
+        # advanced once the logits say how many drafts stand
+        mix = _linear_mixer(c, write_mask, commit=False)
+        cache = _linear_pending(cache, c, sdraft)
     x, cache = _mla_layers_inplace(
-        params, cache, x, _latent_layer(attend, c, valid), c
+        params, cache, x, _latent_layer(attend, c, valid, mix), c
     )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
-    return _head_logits(params, x, c, eq="bse,ev->bsv"), cache
+    logits = _head_logits(params, x, c, eq="bse,ev->bsv")
+    if mix is not None:
+        cache = _linear_commit(
+            cache, _tokens_standing(logits, tokens, draft_len), write_mask, c
+        )
+    return logits, cache
+
+
+def _tokens_standing(logits, tokens, draft_len):
+    """Of a verify step's positions a slot, how many stand → [B] int32
+    in 1..S: the last token and the drafts that the greedy pick of the
+    position before each confirms, up to the first that it does not:
+    the engine's own rule (``InferenceEngine._accept_drafts``), taken
+    here because a state, unlike a cache row, cannot be masked later.
+    ``draft_len`` [B]: the drafts a row really holds (None: S - 1)."""
+    s = tokens.shape[1]
+    agree = jnp.argmax(logits[:, :-1], axis=-1).astype(jnp.int32) == tokens[:, 1:]
+    if draft_len is not None:
+        agree = agree & (jnp.arange(s - 1)[None, :] < draft_len[:, None])
+    return 1 + jnp.sum(jnp.cumprod(agree.astype(jnp.int32), axis=1), axis=1)
 
 
 def prefill(
@@ -1498,7 +1688,8 @@ def _scan_layers_kv(params: dict, cache: dict, x: jax.Array, one_layer, c):
 
 def _walk_layer_groups(params: dict, carry, one_layer, c: LlamaConfig):
     """Drive ``one_layer(carry, layer, li, run) -> (carry, y)`` over a
-    grouped-query model of layer GROUPS, in the order of
+    model of layer GROUPS (a grouped-query one; a latent one with linear
+    layers, its carry ``(x, cache)``), in the order of
     ``llama.layer_periods``: the prelude, then ONE ``lax.scan`` over the
     periods whose body is one period (each of its runs a scan over its
     share of its group's stack), then what is left over, so that the
@@ -1509,10 +1700,7 @@ def _walk_layer_groups(params: dict, carry, one_layer, c: LlamaConfig):
     stack of the period: what a program that only READS the cache in
     its scans writes after them, one block write a buffer)."""
     plan = llama.layer_periods(c)
-    k_dense = c.first_k_dense
-
-    def row(run):  # the run's first layer → its row in its kind's buffers
-        return run.lo + (k_dense if run.key == "layers" else 0)
+    row = partial(llama.run_row, c)  # the run's first layer → its cache row
 
     def scan_run(carry, run, ahead=0):
         # the layers are indexed out of the whole stack (what lax.scan
@@ -1900,8 +2088,16 @@ def _prefill_packed_mla(
             o = _latent_values(o, w_kb_v, h, layer, gc)
         return o, cache
 
+    mix = None
+    if "state" in cache:
+        # padded positions and pad rows leave state and tail untouched;
+        # a row at position 0 starts from no state
+        mix = _linear_mixer(
+            c, last_ix >= 0, si, (starts == 0) & (last_ix >= 0), valid,
+            last_ix + 1,
+        )
     x, cache = _mla_layers_inplace(
-        params, cache, x, _latent_layer(attend, c, valid), c
+        params, cache, x, _latent_layer(attend, c, valid, mix), c
     )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     last = jnp.take_along_axis(
@@ -2345,6 +2541,7 @@ def verify_step(
     write_mask: jax.Array,  # [B] bool
     decode_kernel: str = "einsum",
     mesh=None,
+    draft_len=None,  # [B] int32: the drafts a row holds (linear layers)
 ) -> tuple[jax.Array, dict]:
     """Multi-token decode for speculative verification → (logits
     [B, S, V], cache).
@@ -2366,7 +2563,7 @@ def verify_step(
     c = config
     if c.mla:
         return _verify_step_mla(
-            params, cache, tokens, positions, c, write_mask
+            params, cache, tokens, positions, c, write_mask, draft_len
         )
     if c.layer_types:
         return _verify_step_groups(
@@ -2681,20 +2878,22 @@ def copy_cache_prefix(cache: dict, src, dst, *, p: int) -> dict:
     a prefix with an already-cached sequence skips prefilling it).
     ``p`` is static (jitted per chunk-aligned length); src/dst are
     traced scalars so one compile serves every slot pair."""
-    # token axis per cache tensor: MLA latent and index keys [L,B,T,R]
-    # → 2; k/v [L,B,H,T,D] → 3; int8 scales k_s/v_s [L,B,H,T] → 3 (last)
-    t_axis = {"ckv": 2, "idx": 2, "k": 3, "v": 3, "k_s": 3, "v_s": 3}
     out = {}
     for name, a in cache.items():
         if name in _COUNTS:  # counts, not a slot's state
             out[name] = a
             continue
+        if name not in _T_AXIS and not _is_ring(name):
+            raise ValueError(
+                f"cache leaf {name!r} holds no rows by position: no prefix "
+                f"of it can be copied (known: {sorted(_T_AXIS)}, rings)"
+            )
         rows = jax.lax.dynamic_index_in_dim(a, src, axis=1, keepdims=True)
         # a window ring is copied whole: its rows are not in position
         # order (the engine only offers a source whose ring still holds
         # the window before ``p``, InferenceEngine._find_prefix_source)
         if not _is_ring(name):
-            rows = jax.lax.slice_in_dim(rows, 0, p, axis=t_axis[name])
+            rows = jax.lax.slice_in_dim(rows, 0, p, axis=_T_AXIS[name])
         idx = [jnp.asarray(0, jnp.int32)] * a.ndim
         idx[1] = dst
         out[name] = jax.lax.dynamic_update_slice(a, rows, tuple(idx))
@@ -2810,7 +3009,7 @@ class InferenceEngine:
         # full-attention layers with an indexer that can bite (host-side
         # counters of what it selects, from positions alone)
         self._indexer_layers = (
-            config.n_layers - config.layer_types.count("window")
+            config.n_kind("full")
             if config.mla and _indexed(config, max_seq) else 0
         )
         # full-attention layers, which reserve max_seq rows a slot, and
@@ -2818,9 +3017,7 @@ class InferenceEngine:
         # the blocks the live contexts hold (the latent family under the
         # causal mask alone, :func:`_attend_live`; 0: whole rows)
         # (a latent layer of several attention sublayers: a row each)
-        self._full_layers = config.sublayers * (
-            config.n_layers - config.layer_types.count("window")
-        )
+        self._full_layers = config.sublayers * config.n_kind("full")
         self._key_block = (
             math.gcd(max_seq, _KEY_BLOCK)
             if config.mla and not _indexed(config, max_seq) else 0
@@ -2839,6 +3036,13 @@ class InferenceEngine:
         self.metrics.family("dtpu_serve_kv_cache_bytes").set(total)
         self.metrics.family("dtpu_serve_kv_window_pool_percent").set(
             100.0 * sum(b for n, b in size.items() if _is_ring(n)) / total
+        )
+        # linear layers: a state and a convolution tail a slot, sized by
+        # the heads and not by max_seq; nothing of theirs is addressed by
+        # position, so no prefix of a slot can serve another request
+        self._linear_layers = config.layer_types.count("linear")
+        self.metrics.family("dtpu_serve_state_cache_percent").set(
+            100.0 * sum(size.get(n, 0) for n in _STATES) / total
         )
         self._auto_seed = seed
         # per-slot host state
@@ -2918,7 +3122,10 @@ class InferenceEngine:
         # prefix device-copies those rows and skips their prefill
         # chunks. Chunk alignment keeps the (C, start) compile grid
         # unchanged — a reused prefix resumes mid-grid, no new kernels.
-        self.prefix_cache = prefix_cache
+        # a state at a shared prefix's end exists only if it was kept
+        # there, and none is (PERF.md §7): a model with linear layers
+        # prefills every prompt whole, and its prefix counters stay 0
+        self.prefix_cache = prefix_cache and not self._linear_layers
         self._prefix_registry: dict[int, list] = {}  # slot → prompt ids
         self._copy_fns: dict = {}  # p → jitted copy_cache_prefix
         self.prefix_hits = 0
@@ -3173,6 +3380,10 @@ class InferenceEngine:
             reuse_len, src = 0, None
         self._prefix_registry.pop(slot, None)  # rows about to be overwritten
         start = 0
+        if self._linear_layers:
+            # the slot's last request's state goes: the first chunk, at
+            # position 0, starts from zeros on the device
+            self.metrics.family("dtpu_serve_state_resets_total").inc(1)
         if src is not None and reuse_len > 0:
             self.cache = self.get_copy_fn(reuse_len)(
                 self.cache, jnp.asarray(src, jnp.int32),
@@ -3717,12 +3928,21 @@ class InferenceEngine:
             row = [self.last_token[i]] + d
             row = row + [0] * (sdraft - len(row))
             rows.append(row[:sdraft])
+        # linear layers advance their state in the call, by the drafts
+        # that stand: the program has to know how many a row holds
+        held = {}
+        if self._linear_layers:
+            # dtpu: noqa[DTPU002] the drafts' lengths are this call's own host data, uploaded with its rows (B int32)
+            held["draft_len"] = jnp.asarray(
+                [len(drafts.get(i, [])) for i in range(self.max_batch)], jnp.int32
+            )
         logits, self.cache = self._verify(
             self.params,
             self.cache,
             jnp.asarray(rows, jnp.int32),
             jnp.asarray(self.lengths, jnp.int32),
             write_mask=jnp.asarray(self.active, bool),
+            **held,
         )
         # the shared jitted argmax (an op-by-op jnp.argmax here paid
         # uncompiled dispatch overhead every speculative step); ONE
@@ -3952,10 +4172,13 @@ class InferenceEngine:
         x, *now = got
         now = np.concatenate(now).astype(np.int64)
         # int32 on the device: it wraps, the difference does not
-        picks, routed, *zero, read, held = (
+        picks, routed, *more, read, held = (
             (now - self._moe_stats_seen) % (1 << 32)
         ).tolist()
         self._moe_stats_seen = now
+        zero = more[:1] if self.config.zero_experts else []
+        if self.config.router_groups:
+            self.metrics.family("dtpu_serve_moe_tokens_group_hit_total").inc(more[-1])
         self.metrics.family("dtpu_serve_moe_picks_held_total").inc(picks)
         self.metrics.family("dtpu_serve_moe_tokens_routed_total").inc(routed)
         self.metrics.family("dtpu_serve_moe_experts_read_total").inc(read)

@@ -415,4 +415,24 @@ def new_serve_registry() -> Registry:
         "(sized by the window, not by max_seq); 0 for a model of one "
         "kind of layer",
     )
+    # linear-attention layers: a state a slot beside the rows
+    r.gauge(
+        "dtpu_serve_state_cache_percent",
+        "Of the cache's bytes, the share held by the linear layers' "
+        "recurrent states and convolution tails (sized by the heads, "
+        "not by max_seq); 0 for a model without linear layers",
+    ).set(0)
+    r.counter(
+        "dtpu_serve_state_resets_total",
+        "Requests that started on a model with linear layers: each "
+        "starts its slot's states from zeros at position 0",
+    ).inc(0)
+    # group-limited routing with a chip's share of whole groups
+    r.counter(
+        "dtpu_serve_moe_tokens_group_hit_total",
+        "Routed token-layers (as dtpu_serve_moe_tokens_routed_total) "
+        "one of whose eligible expert groups is held here: the tokens "
+        "an expert-parallel layer would send this chip; summed on the "
+        "device with the held picks, 0 without router_groups",
+    ).inc(0)
     return r

@@ -513,6 +513,7 @@ def _serving_program(case, sds, place):
         "layer_groups": (None, 8192, None),
         "gqa_groups": (_layer_groups_config("laguna-s-2.1-13l-ep8"), 8192, None),
         "scmoe_zero": (_layer_groups_config("longcat-flash-chat-4l-ep32"), 8192, None),
+        "linear_state": (_layer_groups_config("ling-3.0-flash-vl-13l-ep8"), 8192, None),
     }[model]
     config = config or _layer_groups_config()
     params = place(jax.eval_shape(
@@ -746,6 +747,66 @@ def test_prefill_program_holds_no_second_cache(topo, _as_tpu, case):
     whole around the loop (temp 2.15 / 1.07 GB; not a case here)."""
     room, known = _PREFILL[case]
     _holds_no_second_cache(topo, case, room=room, known=known)
+
+
+# the thinking cell (PR 42): 16 × 8192, eleven linear layers' states
+# [11, 16, 32, 128, 128] in float32 (0.369 GB) and tails beside two latent
+# layers' rows (0.302 GB). case → ``temp`` on PR 42's tree, GB
+_LINEAR_STATE = {
+    "decode_step-linear_state": 0.287,
+    "decode_loop-linear_state": 0.373,
+    "verify_step-linear_state": 0.213,
+    "prefill_chunk_step@0-linear_state": 0.281,
+    "prefill_chunk_step@768-linear_state": 0.281,
+    "prefill_packed_step@2-linear_state": 1.140,
+    "prefill_packed_step@4-linear_state": 0.616,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LINEAR_STATE))
+def test_linear_state_program_updates_the_state_in_place(topo, _as_tpu, case):
+    """Every serving program of a model with linear layers reads a
+    layer's slice of the stacked ``state`` where it lies and writes it
+    back there: no copy of the 369 MB stack, no layer's 33.5 MB slice
+    outside a fusion (a copy a layer would be 4 GB a token step), the
+    same for the latent rows and for the expert stacks of both layer
+    kinds; ``temp`` within a tenth of PR 42's reading (what it holds is
+    a layer's projection weights re-laid out, as in every dense scan
+    here, and a packed wave's f32 scores: G 2 takes them at once, 2 x
+    32 x 256 x 8192 x 4 B twice, G 4 in key blocks). The convolution's
+    tail [11, 16, 3, 12288] (13 MB) IS re-laid out around the layer
+    loop, three rows being no tile: 1.2 MB a layer, left as it is."""
+
+    sharding = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    fn, args, cache = _serving_program(case, sds, place)
+    compiled = _compile(fn, *args, donate_argnums=(1,))
+    sized = lambda leaves: (
+        {leaf.shape for leaf in leaves},
+        {sh for leaf in leaves for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])},
+    )
+    experts = [
+        a for stack in ("layers", "linear_layers") for n, a in args[0][stack].items()
+        if n in ("w_gate", "w_up", "w_down") and a.ndim == 4
+    ]
+    hlo = compiled.as_text()
+    assert not _cache_sized_moves(hlo, *sized(experts))
+    # (the prelude layer's row is a constant: its in-place update fusion
+    # addresses the stack by a ``slice`` where the scanned layers' have a
+    # ``dynamic-slice``, which the checker takes for a copy riding in it)
+    moves = [
+        m for m in _cache_sized_moves(hlo, *sized([cache["state"], cache["ckv"]]))
+        if "select_dynamic-update-slice_fusion" not in m
+    ]
+    # the verify step's blocks-of-tokens form carries the state through a
+    # scan, whose first carry is the layer's slice taken out (33.5 MB a
+    # layer a call, where the call moves gigabytes): known, and no more
+    known = 2 if case.startswith("verify_step") else 0
+    assert len(moves) <= known and not any("whole" in m for m in moves), moves
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.1e9 * _LINEAR_STATE[case], f"temp {temp / 1e9:.3f} GB"
+    _fits(compiled)
 
 
 # ---------------------------------------------------------------------------

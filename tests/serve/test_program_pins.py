@@ -74,6 +74,13 @@ CELLS = {
     # pins above and below stood. An unmasked latent model: a lone row
     # prefills by the serial chunk, so the seven programs of ``NAMES``
     "longcat-flash-chat-4l-ep32": (16, 8192, NAMES),
+    # taken on the tree of PR 42, which added the configuration, the
+    # linear-attention mixer (``models/kda.py``) and the latent family's
+    # walk by periods with a state and a convolution tail a slot beside
+    # the latent rows; the 89 pins above and below stood. An unmasked
+    # latent model: the seven programs of ``NAMES``; its verify step
+    # takes the drafts a row holds (``draft_len``), as the engine calls it
+    "ling-3.0-flash-vl-13l-ep8": (16, 8192, NAMES),
 }
 #: The three configurations that hold a SHARE of their experts (longdoc,
 #: mixed, agent: 6 + 6 + 7 digests) and the tiny family ``gqa-groups``
@@ -112,6 +119,15 @@ CELL_PINS = {
         "prefill_chunk_step@256": "d7b69b55ff596b5ca140e6bd3a77faeb7562f516b4528e53ba223ed1f6919afd",
         "prefill_packed_step@2": "bc4328d7ebb9e49e8e35b52632652771c38aba5f5c0aec1f9bd8e9028ca75e89",
         "prefill_packed_step@4": "1ed3346f1ccd9a788784dcb497e7899e9205362ff3e8cee967545b94345003d7",
+    },
+    "ling-3.0-flash-vl-13l-ep8": {
+        "decode_step": "462679763f48dff2390b5168533b5e2369b2710a7cf20bd6d8bf501c87a75931",
+        "decode_loop": "e53d89a286b66903b52620f80c0e22bc2cdc30627df608fcf237b7edf10c1b73",
+        "verify_step": "f7ddd6d6ca2aff38c17898b3989dcc09eae9491eb8a7622db0a3756e11de6590",
+        "prefill_chunk_step@0": "e1a732ad57bc2f5f5d12c48d37d7f540098210b2eb56a9a6d4d9bf7b2e0097a5",
+        "prefill_chunk_step@256": "80d97c4da1c3e1be6813c67f8598b5f08594e50cfd016c81fb2237403e458026",
+        "prefill_packed_step@2": "eeff4be09d59197cf36c431f66e9bfb59fafd4334f80040bfe3e318dec76f6ce",
+        "prefill_packed_step@4": "58669b78efa6c435462dc49bbb024e5b374ad5ea176776db733f768b39cb4d5a",
     },
     "minitron-4b": {
         "decode_step": "31cd7802fdfa5729183b1aa6346316af5f0a7b1d5a845041033888da8aa84ab7",
@@ -198,7 +214,10 @@ FAMILIES = {
 #: ``scmoe-tiny`` (PR 37): a latent layer of two attention sublayers and
 #: two dense FFNs, the expert branch across them, identity experts among
 #: the router's outputs, every real expert held (no counts in the cache)
-TINY = tuple(sorted(FAMILIES)) + ("mla-tiny", "moe-tiny", "scmoe-tiny")
+#: ``linear-tiny`` (PR 42): linear-attention layers beside latent ones in
+#: two periods behind a linear dense prelude, group-limited routing with
+#: one group of four held
+TINY = tuple(sorted(FAMILIES)) + ("mla-tiny", "moe-tiny", "scmoe-tiny", "linear-tiny")
 TINY_NAMES = (
     "decode_step", "verify_step", "prefill_chunk_step@16", "prefill_packed_step@2",
 )
@@ -282,6 +301,12 @@ TINY_PINS = {
         "prefill_chunk_step@16": "8297db34a0663aaa48b4ab0c26a0716f947a6ac0aa34c8e0dae6685ce5a035a7",
         "prefill_packed_step@2": "70e7b0ca3f571e66510cce28dc91707c976da446fbe0139e4b0e435aba622c75",
     },
+    "linear-tiny": {
+        "decode_step": "e9850ca57d5c2dedc0d26233a99c1962f601e52810e03285afd45230a9005abc",
+        "verify_step": "9650fb4357b2d327717113cfd2594484ddc11960d52d823b986a02db270d5381",
+        "prefill_chunk_step@16": "d7dadee9a22dedb94a4c1a69d0188752e69709d79cb1ff87de560838958f126d",
+        "prefill_packed_step@2": "7d8692bad7b323f39e503c39473bfcc467490c828584bcc409672ddfa3a77e83",
+    },
     "moe-tiny": {
         "decode_step": "a38072b4a98bfd0c163d6cec124ee7e5712705874e59bee3e100c69fdb1838bf",
         "verify_step": "4a1635c4f19ad8db74c2ee9a026759ac91302635b86006703c8e2222d991f4d4",
@@ -329,6 +354,8 @@ def _lower(c, name: str, B: int, T: int, chunk: int = 256, S: int = 5):
         fn = partial(E.verify_step, config=c)
         args = (params, cache, i32(B, S), i32(B))
         kw = {"write_mask": flag}
+        if "linear" in c.layer_types:  # the states advance by the drafts that stand
+            kw["draft_len"] = i32(B)
     elif name.startswith("prefill_chunk_step"):
         fn = partial(E.prefill_chunk_step, config=c, start=int(name.split("@")[1]))
         args = (params, cache, i32(1, chunk), i32(), i32())
