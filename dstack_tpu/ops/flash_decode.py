@@ -1,35 +1,53 @@
 """Flash decode for serving: a pallas kernel for batched one-token GQA
-attention over the slot KV cache.
+attention over the slot KV cache, which reads the keys each slot holds.
 
-The serving engine's decode attention is an einsum over the FULL cache
-row ``[B, Hkv, Tmax, D]`` with a ``kj <= position`` mask
-(serve/engine.py::decode_step) — every step streams ``Tmax`` keys per
-slot from HBM regardless of how much of the row is actually written.
-Decode is HBM-bandwidth-bound, so that full-width read is the cost
-that grows linearly with ``max_seq`` and slot count (the bench comment
-on batch 32/64 regressions).
+The einsum form of the engine's decode attention goes over the FULL
+cache row ``[B, Hkv, Tmax, D]`` under a ``kj <= position`` mask
+(serve/engine.py::_decode_layer): every step streams ``Tmax`` keys a
+slot from HBM, whatever the slot holds and whether or not it is live.
+Decode is HBM-bandwidth-bound, so that read is the cost that grows with
+``max_seq`` and with the slots reserved, not with the traffic served.
 
 This kernel makes the read *ragged*: per-slot ``positions`` ride the
-scalar-prefetch lane, and the KV block index map clamps block indices
-past a slot's length to the last live block — pallas elides the
-repeated DMA (same trick as the causal clamp in ops/flash.py), so the
-unwritten tail of every cache row costs neither bandwidth nor compute.
-A short request in a long-context batch reads only its own prefix.
+scalar-prefetch lane, and the KV block index map sends the block
+indices past a slot's length to a block that is fetched anyway —
+pallas elides the repeated DMA (same trick as the causal clamp in
+ops/flash.py), so the unwritten tail of every cache row costs neither
+bandwidth nor compute. That block is the first of the next slot that
+holds keys: its DMA then runs under grid steps that compute nothing.
+A slot that holds nothing (finished, empty, mid-prefill: the engine
+hands it length 0) requests the same, so it moves no byte at all.
 
-Supported in-kernel (mirroring decode_step's einsum semantics):
+What it reads is the STACKED cache leaf ``[L, B, Hkv, T, D]`` in place:
+the layer's row rides the scalar-prefetch lane too and is one more
+coordinate of the block index. A kernel fed a layer's slice of a
+scanned stack makes the compiler copy that slice out a layer a token.
+A grid step takes all KV heads of one block of keys (``block_keys``:
+about 2 MiB of K and V), since a step costs about half a microsecond
+whatever it moves (PERF.md §6, PR 43: 2.9 µs a live step of 2 MiB,
+724 GB/s over whole rows, 0.44-0.55 µs a step that moves nothing).
+
+Supported in-kernel (mirroring the einsum's semantics):
 - GQA grouping: q arrives ``[B, Hkv, G, D]``, the cache is streamed
   once at KV width (no G× read amplification).
+- the token's own key, not yet in the cache (``k_new`` / ``v_new``):
+  the engine's decode scan only reads the cache and writes all layers'
+  rows after it, so the new key starts the running softmax and the
+  cache is masked at ``kj < position``.
 - int8 KV: the cache blocks load as int8 with their per-(token, head)
-  f32 scales and dequantize in VMEM — HBM traffic stays int8, which is
-  the entire point of ``kv_quant="int8"``.
+  f32 scales, which multiply the scores and the probabilities in VMEM
+  — HBM traffic stays int8, the point of ``kv_quant="int8"``.
 - sliding window as a TRACED value (per-layer windows ride the
   lax.scan over layers): masked in-kernel, and leading blocks wholly
   below the window are clamp-skipped like the tail.
 - tanh softcap (static), attention sinks (gpt-oss: a learned logit in
   the softmax denominator only, applied at the finish step).
+- speculative verify (``rows_per_slot`` = S rows a slot, their keys
+  already written).
 
-Not supported (the engine falls back to the einsum path): MLA latent
-caches and Llama4 chunked-attention layers.
+Not supported (the engine keeps the einsum, :func:`reads_live_keys`):
+MLA latent caches, a window layer's ring (read in row order), Llama4
+chunked-attention layers.
 
 The reference framework has no serving kernels to mirror (it is an
 orchestrator, SURVEY.md §6); the GPU-world analog of this kernel is
@@ -44,18 +62,49 @@ import jax.numpy as jnp
 
 NEG_INF = -1e30
 
+#: K and V bytes one grid step moves, at most (see :func:`block_keys`)
+BLOCK_BYTES = 2 << 20
+
+
+def block_keys(n_kv_heads: int, head_dim: int, rows: int, itemsize: int = 2) -> int:
+    """Keys a grid step reads of every KV head → the ONE block rule: as
+    many as make K and V of the step :data:`BLOCK_BYTES` (a step's fixed
+    cost is then a tenth of its DMA, and a short context is rounded up
+    by a few hundred keys at most), a multiple of 128 that divides the
+    cache row."""
+    most = BLOCK_BYTES // (2 * n_kv_heads * head_dim * itemsize)
+    bk = max(128, min(rows, most) // 128 * 128)
+    while rows % bk:
+        bk -= 128
+    return bk
+
+
+def _live_blocks(pos, win, held, block_k: int, num_k: int):
+    """(first, last) key block a slot at query position ``pos`` reads,
+    its cache holding the keys below ``held``, under window ``win`` (0 =
+    full; its lower bound is row 0's, the loosest that covers every
+    row): the ONE reckoning of the kernel's compute range and of the
+    index map that fetches it."""
+    last = jnp.clip((held - 1) // block_k, 0, num_k - 1)
+    first = jnp.where(
+        win > 0, jnp.clip((pos - (win - 1)) // block_k, 0, num_k - 1), 0
+    )
+    return first, last
+
 
 def _decode_kernel(
-    pos_ref,  # SMEM [B] int32: attend to kj <= pos[b]
-    win_ref,  # SMEM [1] int32: sliding window (0 = full)
-    q_ref,  # [1, 1, G, D]
-    k_ref,  # [1, 1, BK, D] compute dtype or int8
+    pos_ref,  # SMEM [B] int32: the slot's query position
+    tail_ref,  # SMEM [B] int32: the slot whose block this slot's steps past its keys request (index map only)
+    meta_ref,  # SMEM [2] int32: the layer's row of the stack, the sliding window (0 = full)
+    q_ref,  # [1, Hkv, R, D]
+    k_ref,  # [1, 1, Hkv, BK, D] compute dtype or int8
     v_ref,
-    *rest,  # optional (ks_ref, vs_ref [1, 1, 1, BK] f32), optional (sink_ref [1, G, 1] f32), then o_ref + scratch
+    *rest,  # optional (kn_ref, vn_ref [1, Hkv, 1, D]), optional (ks_ref, vs_ref [1, 1, Hkv, BK] f32), optional (sink_ref [Hkv, R, 1] f32), then o_ref + scratch
     scale: float,
     softcap: float,
     block_k: int,
     num_k: int,
+    new_row: bool,
     quantized: bool,
     sinks: bool,
     rows_per_slot: int,
@@ -63,91 +112,114 @@ def _decode_kernel(
     from jax.experimental import pallas as pl
 
     it = iter(rest)
+    kn_ref = next(it) if new_row else None
+    vn_ref = next(it) if new_row else None
     ks_ref = next(it) if quantized else None
     vs_ref = next(it) if quantized else None
     sink_ref = next(it) if sinks else None
     o_ref = next(it)
-    acc_sc = next(it)  # VMEM [G, D] f32
-    m_sc = next(it)  # VMEM [G, 128] f32
-    l_sc = next(it)  # VMEM [G, 128] f32
+    acc_sc = next(it)  # VMEM [Hkv, R, D] f32
+    m_sc = next(it)  # VMEM [Hkv, R, 128] f32
+    l_sc = next(it)  # VMEM [Hkv, R, 128] f32
 
     b = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
     pos = pos_ref[b]
-    win = win_ref[0]
+    win = meta_ref[1]
+    nrows = q_ref.shape[2]
+
+    def capped(s):
+        return softcap * jnp.tanh(s / softcap) if softcap else s
+
+    # every KV head at once, as batched products and whole-array
+    # updates: eight copies of the body, a head each, cost a serving
+    # program's boot a second and a half a kernel it holds (trace and
+    # lowering, paid on a compile-cache hit too), once a program
 
     @pl.when(ki == 0)
     def _init():
-        acc_sc[...] = jnp.zeros_like(acc_sc)
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
+        if not new_row:
+            acc_sc[...] = jnp.zeros_like(acc_sc)
+            m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[...] = jnp.zeros_like(l_sc)
+            return
+        # the token's own key is always visible to it: the running
+        # softmax starts from it (max = its score, sum = 1, values = v)
+        s_new = capped(jnp.sum(
+            q_ref[0].astype(jnp.float32) * kn_ref[0].astype(jnp.float32),
+            axis=-1, keepdims=True,
+        ) * scale)  # [Hkv, R, 1]
+        m_sc[...] = jnp.broadcast_to(s_new, m_sc.shape)
+        l_sc[...] = jnp.ones(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.broadcast_to(vn_ref[0].astype(jnp.float32), acc_sc.shape)
 
-    # live block range for this slot (must agree with _kv_ix's clamp:
-    # clamped-away blocks re-request a live block and skip compute).
-    # Speculative verify (rows_per_slot = S > 1) extends the readable
-    # range to the last drafted position; the window's lower bound
-    # stays at row 0's (the loosest that covers every row).
-    last = jnp.clip(
-        (pos + rows_per_slot - 1) // block_k, 0, num_k - 1
+    # live block range for this slot (the index map's: steps outside it
+    # request a block that is fetched anyway and skip compute). The
+    # cache holds keys below ``held``: up to the last drafted position
+    # where the rows' keys are written (speculative verify,
+    # rows_per_slot = S), below the position where the new key comes
+    # beside it.
+    held = pos + rows_per_slot - (1 if new_row else 0)
+    first, last = _live_blocks(pos, win, held, block_k, num_k)
+    live = jnp.logical_and(
+        jnp.logical_and(ki >= first, ki <= last), held > 0
     )
-    first = jnp.where(
-        win > 0, jnp.clip((pos - (win - 1)) // block_k, 0, num_k - 1), 0
-    )
-    live = jnp.logical_and(ki >= first, ki <= last)
 
     def compute():
-        q = q_ref[0, 0]  # [G, D]
-        k = k_ref[0, 0]  # [BK, D]
-        v = v_ref[0, 0]
-        if quantized:
-            # per-token scales broadcast over D; HBM read was int8
-            k = (k.astype(jnp.float32) * ks_ref[0, 0, 0][:, None]).astype(q.dtype)
-            v = (v.astype(jnp.float32) * vs_ref[0, 0, 0][:, None]).astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [G, BK] f32
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        nrows = q_ref.shape[2]
         cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (nrows, block_k), 1
+            jnp.int32, (1, nrows, block_k), 2
         )
         # rows are [G, S] flattened row-major: row r verifies the
         # token at pos + (r % S), so it sees keys up to there
         qpos = pos + jax.lax.broadcasted_iota(
-            jnp.int32, (nrows, block_k), 0
+            jnp.int32, (1, nrows, block_k), 1
         ) % rows_per_slot
-        keep = cols <= qpos
+        keep = cols < qpos if new_row else cols <= qpos
         keep = jnp.logical_and(
             keep, jnp.logical_or(win == 0, qpos - cols < win)
         )
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_sc[:, :1]  # [G, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        q = q_ref[0]  # [Hkv, R, D]
+        k = k_ref[0, 0]  # [Hkv, BK, D]
+        v = v_ref[0, 0]
+        if quantized:
+            # int8 values are exact in the compute dtype; the
+            # per-token scales multiply the scores and the
+            # probabilities (a row of lanes a head), not K and V
+            k, v = k.astype(q.dtype), v.astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        )  # [Hkv, R, BK] f32
+        if quantized:
+            s = s * ks_ref[0, 0][:, None, :]
+        s = jnp.where(keep, capped(s * scale), NEG_INF)
+        m_prev = m_sc[...][:, :, :1]  # [Hkv, R, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
         p = jnp.exp(jnp.where(s <= NEG_INF / 2, NEG_INF, s) - m_safe)
         alpha = jnp.where(
             m_prev <= NEG_INF / 2, jnp.zeros_like(m_prev), jnp.exp(m_prev - m_safe)
         )
-        l_sc[:, :1] = l_sc[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l_sc[...][:, :, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0, 0][:, None, :]
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
-        m_sc[:, :1] = m_new
+        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
 
     pl.when(live)(compute)
 
     @pl.when(ki == num_k - 1)
     def _finish():
-        m = m_sc[:, :1]
-        l = l_sc[:, :1]
+        m = m_sc[...][:, :, :1]
+        l = l_sc[...][:, :, :1]
         acc = acc_sc[...]
         if sinks:
             # the sink joins the DENOMINATOR only (ops/attention.py::
             # sink_softmax): rescale running stats to max(m, sink)
-            snk = sink_ref[0]  # [G, 1] f32
+            snk = sink_ref[...]  # [Hkv, R, 1] f32
             m_f = jnp.maximum(m, snk)
             alpha = jnp.where(
                 m <= NEG_INF / 2, jnp.zeros_like(m), jnp.exp(m - m_f)
@@ -155,29 +227,40 @@ def _decode_kernel(
             l = l * alpha + jnp.exp(snk - m_f)
             acc = acc * alpha
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
 
 
 def flash_decode(
     q: jax.Array,  # [B, Hkv, G, D] compute dtype
-    k: jax.Array,  # [B, Hkv, T, D] compute dtype, or int8 with k_scale
+    k: jax.Array,  # [L, B, Hkv, T, D] (or one layer's [B, Hkv, T, D]) compute dtype, or int8 with k_scale
     v: jax.Array,
-    positions: jax.Array,  # [B] int32: attend to kj <= positions[b]
+    positions: jax.Array,  # [B] int32: each slot's query position
     *,
     scale: float,
+    layer=0,  # (traced) int32: the row of the stacked ``k`` / ``v`` to read
+    k_new: Optional[jax.Array] = None,  # [B, Hkv, 1, D]: the token's own key, not in the cache
+    v_new: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,  # traced int32 scalar; None/0 = full
     softcap: float = 0.0,
     sinks: Optional[jax.Array] = None,  # [Hkv, G] sink logits
-    k_scale: Optional[jax.Array] = None,  # [B, Hkv, T] f32 (int8 cache)
+    k_scale: Optional[jax.Array] = None,  # [L, B, Hkv, T] / [B, Hkv, T] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
-    block_k: int = 512,
+    block_k: Optional[int] = None,  # None: :func:`block_keys`
     interpret: bool = False,
     rows_per_slot: int = 1,
 ) -> jax.Array:
     """One-token-per-slot GQA attention over the cache → [B, Hkv, G, D].
 
-    Ragged: each slot reads only the KV blocks covering
-    ``positions[b]`` (and, with a window, only blocks inside it).
+    Ragged: each slot reads only the KV blocks that hold its keys (and,
+    with a window, only blocks inside it), out of row ``layer`` of the
+    stacked leaf where it lies.
+
+    Without ``k_new`` the cache holds the token's own key: a slot
+    attends to keys ``<= positions[b]``. With it the cache holds the
+    keys ``< positions[b]`` and the token's own comes as an operand (a
+    decode scan that writes its rows after the layers); a slot at
+    position 0 then reads nothing: what the engine hands a slot that is
+    not live.
 
     ``rows_per_slot=S`` serves speculative verify: ``q``'s row axis is
     ``[G, S]`` flattened row-major, row ``g*S + s`` attends to keys
@@ -185,65 +268,110 @@ def flash_decode(
     into the cache before calling). ``sinks`` must then be pre-expanded
     to ``[Hkv, G*S]``.
     """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, hkv, g, d = q.shape
-    t = k.shape[2]
+    if k.ndim == 4:  # one layer's slice: a stack of one
+        k, v = k[None], v[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    hkv, d, t = q.shape[1], q.shape[3], k.shape[3]
     if t % 128:
         raise ValueError(
             f"flash_decode: cache length {t} must be a multiple of 128 "
             "(gate callers with flash_decode_supported)"
         )
-    quantized = k_scale is not None
-    bk = min(block_k, t)
+    if k_new is not None and rows_per_slot != 1:
+        raise ValueError("flash_decode: k_new goes with one row a slot")
+    bk = min(block_k or block_keys(hkv, d, t, k.dtype.itemsize), t)
     while t % bk:
         bk -= 128
+    meta = jnp.stack([
+        jnp.asarray(layer, jnp.int32).reshape(()),
+        jnp.asarray(0 if window is None else window, jnp.int32).reshape(()),
+    ])
+    return _flash_decode(
+        q, k, v, positions.astype(jnp.int32), meta, k_new, v_new, sinks,
+        k_scale, v_scale, scale=scale, softcap=softcap, block_k=bk,
+        interpret=interpret, rows_per_slot=rows_per_slot,
+    )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("scale", "softcap", "block_k", "interpret", "rows_per_slot"),
+)
+def _flash_decode(
+    q, k, v, pos_arr, meta, k_new, v_new, sinks, k_scale, v_scale, *,
+    scale, softcap, block_k, interpret, rows_per_slot,
+):
+    """:func:`flash_decode` behind its own ``jit``: a serving program
+    that attends through the kernel in more than one layer scan (a model
+    of layer groups: a prelude and a period) then lowers ONE copy of it,
+    and a boot pays the kernel's lowering (a third of a second on a
+    serving host, compile-cache hit or not) once a program."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hkv, g, d = q.shape
+    t, bk = k.shape[3], block_k
     num_k = t // bk
+    quantized = k_scale is not None
+    new_row = k_new is not None
+    held_off = rows_per_slot - (1 if new_row else 0)
+    # what a slot's steps past its last block (all steps of a slot that
+    # holds no key) request: the FIRST block of the next slot that holds
+    # some, so that its DMA runs under the steps that compute nothing
+    # and is not waited for when that slot's turn comes; after the last
+    # such slot, the block that one ended on (slot 0's before any): no
+    # DMA at all
+    slot = jnp.arange(b, dtype=jnp.int32)
+    has = pos_arr + held_off > 0
+    after = jnp.append(jax.lax.cummin(jnp.where(has, slot, b), reverse=True)[1:], b)
+    before = jnp.maximum(jax.lax.cummax(jnp.where(has, slot, -1)), 0)
+    tail = jnp.where(after < b, after, before).astype(jnp.int32)
 
-    if window is None:
-        window = jnp.zeros((), jnp.int32)
-    win_arr = jnp.asarray(window, jnp.int32).reshape(1)
-    pos_arr = positions.astype(jnp.int32)
+    def _kv_ix(bi, ki, pos_ref, tail_ref, meta_ref):
+        # the kernel's `live` range: leading out-of-window blocks clamp
+        # to the first live block (one DMA, re-requested at no cost),
+        # blocks past the last go to ``tail``'s (a later slot's first
+        # block, else an earlier one's last)
+        def blocks(at):
+            return _live_blocks(
+                pos_ref[at], meta_ref[1], pos_ref[at] + held_off, bk, num_k
+            )
 
-    def _kv_ix(bi, h, ki, pos_ref, win_ref):
-        # must agree with the kernel's `live` range: tail blocks clamp
-        # to the last live block, leading out-of-window blocks to the
-        # first — re-requested blocks cost no DMA
-        last = jnp.clip(
-            (pos_ref[bi] + rows_per_slot - 1) // bk, 0, num_k - 1
-        )
-        ix = jnp.minimum(ki, last)
-        first = jnp.where(
-            win_ref[0] > 0,
-            jnp.clip((pos_ref[bi] - (win_ref[0] - 1)) // bk, 0, num_k - 1),
+        first, last = blocks(bi)
+        own = jnp.logical_and(pos_ref[bi] + held_off > 0, ki <= last)
+        at = tail_ref[bi]
+        t_first, t_last = blocks(at)
+        return (
+            meta_ref[0], jnp.where(own, bi, at), 0,
+            jnp.where(own, jnp.maximum(ki, first), jnp.where(at > bi, t_first, t_last)),
             0,
         )
-        return (bi, h, jnp.maximum(ix, first), 0)
+
+    def q_ix(bi, ki, *_):
+        return (bi, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda bi, h, ki, p, w: (bi, h, 0, 0)),
-        pl.BlockSpec((1, 1, bk, d), _kv_ix),
-        pl.BlockSpec((1, 1, bk, d), _kv_ix),
+        pl.BlockSpec((1, hkv, g, d), q_ix),
+        pl.BlockSpec((1, 1, hkv, bk, d), _kv_ix),
+        pl.BlockSpec((1, 1, hkv, bk, d), _kv_ix),
     ]
     args = [q, k, v]
+    if new_row:
+        in_specs += [pl.BlockSpec((1, hkv, 1, d), q_ix)] * 2
+        args += [k_new.reshape(b, hkv, 1, d), v_new.reshape(b, hkv, 1, d)]
     # Mosaic wants a block's last two dims to be multiples of (8, 128)
-    # or to span the array: scales ride as [B, Hkv, 1, T] (a unit row
-    # over the token lanes), sinks as [Hkv, G, 1] (a column per head)
+    # or to span the array: the scales ride as they lie, [.., Hkv, T]
+    # (every head's row of token lanes), the sinks as [Hkv, G, 1] (a
+    # column a head)
     if quantized:
-        def sc_ix(bi, h, ki, p, w):
-            bi, h, ki, _ = _kv_ix(bi, h, ki, p, w)
-            return (bi, h, 0, ki)
+        def sc_ix(bi, ki, *refs):
+            return _kv_ix(bi, ki, *refs)[:4]
 
-        in_specs += [pl.BlockSpec((1, 1, 1, bk), sc_ix)] * 2
-        args += [
-            s.astype(jnp.float32).reshape(b, hkv, 1, t)
-            for s in (k_scale, v_scale)
-        ]
+        in_specs += [pl.BlockSpec((1, 1, hkv, bk), sc_ix)] * 2
+        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     if sinks is not None:
-        in_specs.append(
-            pl.BlockSpec((1, g, 1), lambda bi, h, ki, p, w: (h, 0, 0))
-        )
+        in_specs.append(pl.BlockSpec((hkv, g, 1), lambda bi, ki, *_: (0, 0, 0)))
         args.append(sinks.astype(jnp.float32).reshape(hkv, g, 1))
 
     kernel = functools.partial(
@@ -252,21 +380,20 @@ def flash_decode(
         softcap=softcap,
         block_k=bk,
         num_k=num_k,
+        new_row=new_row,
         quantized=quantized,
         sinks=sinks is not None,
         rows_per_slot=rows_per_slot,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, num_k),
+        num_scalar_prefetch=3,
+        grid=(b, num_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda bi, h, ki, p, w: (bi, h, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, hkv, g, d), q_ix),
         scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
+            pltpu.VMEM((hkv, g, 128), jnp.float32),
+            pltpu.VMEM((hkv, g, 128), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -274,21 +401,62 @@ def flash_decode(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(pos_arr, win_arr, *args)
+        name="flash_decode",
+    )(pos_arr, tail, meta, *args)
 
 
 def flash_decode_supported(config, max_seq: int) -> bool:
-    """Whether the engine may route decode attention through the
-    kernel for this model/cache shape (the caller still falls back
-    per-call when ``interpret`` isn't wanted off-TPU)."""
+    """Whether the kernel computes what this model's full-attention
+    layers attend to over a cache row of ``max_seq`` keys: grouped-query
+    K/V rows (no latent), the causal frontier with at most a sliding
+    window (no Llama4 chunks), widths and rows it can tile. What a
+    caller that ASKS for the kernel needs (``decode_kernel="flash"``);
+    what the engine takes by itself is :func:`reads_live_keys`."""
     return (
         not config.mla
-        and not config.layer_types  # a window layer's cache is a ring
         and not config.attention_chunk_size
         and config.head_dim % 64 == 0
         and max_seq % 128 == 0
         and config.n_heads % config.n_kv_heads == 0
+    )
+
+
+def reads_live_keys(
+    config, rows: int, *, ring: bool = False, mesh=None,
+    decode_kernel: Optional[str] = None,
+) -> bool:
+    """THE rule, at trace time, for one kind of layer of a grouped-query
+    model: does its decode attention read the key blocks each live slot
+    holds (this kernel) or every reserved row under a mask (the
+    einsum)? ``config``: the layer's attention shape (its run's, in a
+    model of groups); ``rows``: the rows a slot of its cache buffer
+    holds; ``ring``: the buffer is a window layer's ring, whose rows lie
+    in ring order and are few (the einsum stays); ``decode_kernel``:
+    what the caller asked for, ``"einsum"`` | ``"flash"`` (the kernel
+    wherever it computes the layer, in interpret mode off the TPU: the
+    tests' way in), or None: by what the program can see —
+
+    - the TPU backend (the interpret mode is no serving path);
+    - ``head_dim`` a multiple of 128: the cache leaf then lies with its
+      width on the lanes, as the kernel's blocks want it (a narrower
+      leaf lies with its TOKENS there, and a kernel makes the compiler
+      re-lay it out whole: ``engine._tokens_on_lanes``);
+    - no mesh, or one whose ``tp`` axis divides the KV heads (the
+      engine's ``shard_map`` wrap: a shard's heads, no collective).
+
+    bf16 and the int8 pair both compile for the chip at a cell's shapes
+    (``tests/compute/test_tpu_compile.py``)."""
+    if decode_kernel == "einsum" or ring:
+        return False
+    if not flash_decode_supported(config, rows):
+        return False
+    if decode_kernel == "flash":
+        return True
+    return (
+        jax.default_backend() == "tpu"
+        and config.head_dim % 128 == 0
+        and (mesh is None or config.n_kv_heads % mesh.shape.get("tp", 1) == 0)
     )

@@ -38,6 +38,12 @@ from dstack_tpu.models.llama import (
     qk_norm_apply,
     rms_norm,
 )
+from dstack_tpu.ops.flash_decode import (
+    block_keys,
+    flash_decode,
+    flash_decode_supported,
+    reads_live_keys,
+)
 from dstack_tpu.utils.logging import get_logger
 
 logger = get_logger("serve.engine")
@@ -2193,19 +2199,22 @@ def prefill_packed_step(
 
 def _flash_attend(
     q_rows,  # [B, Hkv, R, D] — R = grp * rows_per_slot, row-major [G, S]
-    ck, cv,  # per-layer cache slices: arrays or (int8, scale) tuples
+    ck, cv,  # the STACKED cache leaves: arrays or (int8, scale) tuples
+    li,  # the layer's row of them
     positions,  # [B] int32
     window,  # traced int32 scalar (0 = full)
     *,
     config, scale, grp, rows_per_slot, sinks_leaf, mesh,
+    new=None,  # (k, v) [B, Hkv, 1, D] as attended: the token's own, not in the cache
 ):
-    """Shared flash_decode dispatch for decode_step (rows_per_slot=1)
-    and verify_step (S>1): quant-tuple unpack, per-row sink expansion,
+    """Shared flash_decode dispatch for decode_step (rows_per_slot=1,
+    the token's own K/V beside the cache as ``new``) and verify_step
+    (S>1, its rows written): quant-tuple unpack, per-row sink expansion,
     optional-arg threading, interpret detection, and the shard_map wrap
     under a mesh — ONE copy, so a kernel-signature or sharding-spec
-    change cannot silently diverge decode from verify."""
-    from dstack_tpu.ops.flash_decode import flash_decode
-
+    change cannot silently diverge decode from verify. The kernel reads
+    row ``li`` of the stacked leaves where they lie: no layer's slice
+    is an operand."""
     c = config
     kq, ks = (ck if isinstance(ck, tuple) else (ck, None))
     vq, vs = (cv if isinstance(cv, tuple) else (cv, None))
@@ -2219,39 +2228,45 @@ def _flash_attend(
     interp = jax.default_backend() != "tpu"
     softcap = float(c.attn_softcap or 0.0)
 
-    def _fd(q_, kq_, vq_, pos_, win_, *opt):
+    def _fd(q_, kq_, vq_, li_, pos_, win_, *opt):
         it = iter(opt)
+        kn_ = next(it) if new is not None else None
+        vn_ = next(it) if new is not None else None
         ks_ = next(it) if ks is not None else None
         vs_ = next(it) if ks is not None else None
         sk_ = next(it) if sinks_arr is not None else None
         return flash_decode(
-            q_, kq_, vq_, pos_, scale=scale, window=win_,
-            softcap=softcap, sinks=sk_, k_scale=ks_, v_scale=vs_,
+            q_, kq_, vq_, pos_, scale=scale, layer=li_, k_new=kn_, v_new=vn_,
+            window=win_, softcap=softcap, sinks=sk_, k_scale=ks_, v_scale=vs_,
             interpret=interp, rows_per_slot=rows_per_slot,
         )
 
-    opt_args = []
+    opt_args = list(new or ())
     if ks is not None:
         opt_args += [ks, vs]
     if sinks_arr is not None:
         opt_args.append(sinks_arr)
+    li = jnp.asarray(li, jnp.int32)
     if mesh is None:
-        return _fd(q_rows, kq, vq, positions, window, *opt_args)
+        return _fd(q_rows, kq, vq, li, positions, window, *opt_args)
     # per-shard kernel over the tp axis (KV heads local to each shard;
     # attention is per-head → no collectives). Axes the specs don't
     # mention (dp/fsdp/ep) replicate.
     from jax.sharding import PartitionSpec as P
 
     h4 = P(None, "tp", None, None)
-    in_specs = [h4, h4, h4, P(None), P()]
+    h5 = P(None, None, "tp", None, None)
+    in_specs = [h4, h5, h5, P(), P(None), P()]
+    if new is not None:
+        in_specs += [h4] * 2
     if ks is not None:
-        in_specs += [P(None, "tp", None)] * 2
+        in_specs += [P(None, None, "tp", None)] * 2
     if sinks_arr is not None:
         in_specs.append(P("tp", None))
     return jax.shard_map(
         _fd, mesh=mesh, in_specs=tuple(in_specs), out_specs=h4,
         check_vma=False,
-    )(q_rows, kq, vq, positions, window, *opt_args)
+    )(q_rows, kq, vq, li, positions, window, *opt_args)
 
 
 def decode_step(
@@ -2261,7 +2276,7 @@ def decode_step(
     positions: jax.Array,  # [B] int32: where to write (== current length)
     config: LlamaConfig,
     write_mask: jax.Array = None,  # [B] bool: rows allowed to write K/V
-    decode_kernel: str = "einsum",  # "einsum" | "flash" (ops/flash_decode)
+    decode_kernel: Optional[str] = None,  # None: by the rule | "einsum" | "flash"
     mesh=None,  # static: shard_map the flash kernel over this mesh
 ) -> tuple[jax.Array, dict]:
     """One token for every slot → (logits [B, V], cache).
@@ -2271,11 +2286,13 @@ def decode_step(
     K/V into their slot — a decode step interleaved between prefill
     chunks would otherwise corrupt the prompt being written.
 
-    ``decode_kernel="flash"`` routes the cache attention through the
+    A grouped-query layer over a plain row buffer attends through the
     ragged pallas kernel (:func:`dstack_tpu.ops.flash_decode.flash_decode`)
-    — each slot reads only the cache blocks covering its own length
-    instead of the full ``Tmax`` row. The caller gates eligibility
-    (:func:`~dstack_tpu.ops.flash_decode.flash_decode_supported`).
+    — each live slot reads only the cache blocks that hold its own keys
+    instead of the full ``Tmax`` row, a slot that may not write none —
+    where :func:`~dstack_tpu.ops.flash_decode.reads_live_keys` says so
+    (on the TPU, by the model's shape), else through the masked einsum;
+    ``decode_kernel`` is a caller's or a test's way to ask for one form.
     With a ``mesh``, the kernel runs per-shard under ``shard_map``
     (q/cache sharded over KV heads on ``tp``, everything else
     replicated — attention is per-head, so no collectives are needed
@@ -2298,7 +2315,7 @@ def decode_step(
         )
     if c.layer_types:
         return _decode_step_groups(
-            params, cache, tokens, positions, c, write_mask
+            params, cache, tokens, positions, c, write_mask, decode_kernel, mesh
         )
     x = _embed_lookup(params, tokens, c)[:, None, :]
     (cos, sin), (cos_l, sin_l) = dual_rope_freqs(c, positions)  # [B, D/2]
@@ -2346,7 +2363,7 @@ def decode_step(
 def _decode_layer(
     x, layer: dict, li, c: LlamaConfig, ck, cv, positions, write_mask,
     rope, nope, temp, window, ring=None, stats=None,
-    decode_kernel: str = "einsum", mesh=None,
+    decode_kernel: Optional[str] = None, mesh=None,
 ):
     """One dense layer of a decode step, the one copy → (x, this token's
     (k, v) rows as stored[, stats]). ``c``: the layer's attention shape
@@ -2357,38 +2374,49 @@ def _decode_layer(
     of groups, whose window layers' buffers are rings (``ring`` = the
     positions, and the new row lies at ``positions % rows``); ``stats``
     as :func:`_dense_out` takes them (the live slots are the valid
-    tokens)."""
+    tokens). Which form attends: :func:`~dstack_tpu.ops.flash_decode.
+    reads_live_keys` (``decode_kernel``: what a caller asked for)."""
     b = x.shape[0]
     q, k, v = _dense_in(x, layer, c, rope, nope, temp)
-    # the scan only READS the stacked cache: this token's K/V is
-    # selected into the layer's slice on its way into the attention
-    # (masked rows keep theirs) and goes out as ys; all layers' rows
+    # the scan only READS the stacked cache: this token's K/V goes
+    # beside it into the attention and out as ys; all layers' rows
     # are written after the scan, in place, 2 × B small blocks a
     # step where writing inside the scan costs that a layer
-    at = positions if ring is None else _ring_row(positions, ck)
     k_new, v_new = _cstored(k, ck), _cstored(v, cv)
-    ckl = _cwith_row(_clayer(ck, li), at, write_mask, k_new)
-    cvl = _cwith_row(_clayer(cv, li), at, write_mask, v_new)
-    ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
-    cvf = _cfull(cvl, v.dtype)
-    # attend over the cache prefix (mask: j <= position, and within
-    # the layer's sliding window when set). Grouped-query einsum:
-    # q regrouped [B, Hkv, G, D] against the [B, Hkv, T, D] cache —
-    # decode is HBM-bandwidth-bound on the KV read, so the cache is
-    # streamed ONCE at KV width instead of materializing a G×-wider
-    # repeat (4× read amplification for 32q/8kv models).
+    live_keys = reads_live_keys(
+        c, jax.tree.leaves(ck)[0].shape[3], ring=ring is not None, mesh=mesh,
+        decode_kernel=decode_kernel,
+    )
+    if not live_keys:
+        # the einsum's operands: this token's K/V selected into the
+        # layer's slice on its way into the attention (masked rows keep
+        # theirs)
+        at = positions if ring is None else _ring_row(positions, ck)
+        ckl = _cwith_row(_clayer(ck, li), at, write_mask, k_new)
+        cvl = _cwith_row(_clayer(cv, li), at, write_mask, v_new)
+        ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
+        cvf = _cfull(cvl, v.dtype)
+    # Grouped-query: q regrouped [B, Hkv, G, D] against the
+    # [B, Hkv, T, D] cache — decode is HBM-bandwidth-bound on the KV
+    # read, so the cache is streamed ONCE at KV width instead of
+    # materializing a G×-wider repeat (4× read amplification for
+    # 32q/8kv models).
     grp = c.n_heads // c.n_kv_heads
     qg = q[:, :, 0, :].reshape(b, c.n_kv_heads, grp, c.head_dim)
-    if decode_kernel == "flash":
-        # ragged pallas read: blocks past each slot's position are
-        # DMA-elided (caller gated out MLA/chunked-attention/shape
-        # misfits via flash_decode_supported)
+    if live_keys:
+        # ragged pallas read out of the stacked leaf: each slot's key
+        # blocks up to its length, none for a slot that may not write
+        # (its output is discarded), the token's own key (as stored:
+        # what the einsum selects in) folded into the softmax
         o = _flash_attend(
-            qg, ckl, cvl, positions, window,
+            qg, ck, cv, li, jnp.where(write_mask, positions, 0), window,
             config=c, scale=c.attention_scale, grp=grp, rows_per_slot=1,
             sinks_leaf=layer.get("sinks"), mesh=mesh,
+            new=(_cfull(k_new, k.dtype), _cfull(v_new, v.dtype)),
         )
     else:
+        # attend over the cache prefix (mask: j <= position, and within
+        # the layer's sliding window when set)
         with _attn_scope(window):
             s = jnp.einsum(
                 "bhgd,bhkd->bhgk", qg, ckf, preferred_element_type=jnp.float32
@@ -2419,13 +2447,15 @@ def _ring_row(positions, buf):
 
 
 def _decode_step_groups(
-    params: dict, cache: dict, tokens, positions, c: LlamaConfig, write_mask
+    params: dict, cache: dict, tokens, positions, c: LlamaConfig, write_mask,
+    decode_kernel: Optional[str] = None, mesh=None,
 ) -> tuple[jax.Array, dict]:
     """:func:`decode_step` of a grouped-query model of layer GROUPS: the
     same layer (:func:`_decode_layer`) at each run's attention shape
-    against its kind's buffers, a window layer's a ring; every scan
-    reads the cache, and each buffer takes its runs' new rows in one
-    block write a run after them (PR 25's form)."""
+    against its kind's buffers, a window layer's a ring (the einsum over
+    its few rows; a full layer's row buffer by the rule, like a model
+    of one kind); every scan reads the cache, and each buffer takes its
+    runs' new rows in one block write a run after them (PR 25's form)."""
     from dstack_tpu.models.llama import dual_rope_freqs
 
     x = _embed_lookup(params, tokens, c)[:, None, :]
@@ -2440,6 +2470,7 @@ def _decode_step_groups(
             write_mask,
             lambda t: _apply_rope_batch(t, cos, sin, interleaved=c.rope_interleaved),
             False, None, run.window, positions if run.window else None, stats,
+            decode_kernel, mesh,
         )
         return (out if stats is not None else (out, None)), rows
 
@@ -2492,7 +2523,7 @@ def decode_loop(
     *,
     steps: int,  # static: decode steps per macro-step
     max_seq: int,  # static: cache row length
-    decode_kernel: str = "einsum",
+    decode_kernel: Optional[str] = None,
     mesh=None,
 ) -> tuple[jax.Array, dict, jax.Array, jax.Array, jax.Array, jax.Array]:
     """``steps`` greedy decode steps entirely on device → (emitted
@@ -2539,7 +2570,7 @@ def verify_step(
     positions: jax.Array,  # [B] int32: row's current length (pos of tokens[:,0])
     config: LlamaConfig,
     write_mask: jax.Array,  # [B] bool
-    decode_kernel: str = "einsum",
+    decode_kernel: Optional[str] = None,  # "flash": the kernel, where asked for
     mesh=None,
     draft_len=None,  # [B] int32: the drafts a row holds (linear layers)
 ) -> tuple[jax.Array, dict]:
@@ -2617,13 +2648,15 @@ def verify_step(
 def _verify_layer(
     x, layer: dict, li, c: LlamaConfig, ck, cv, positions, pos_grid, write_mask,
     rope, nope, temp, window, ring=None, stats=None,
-    decode_kernel: str = "einsum", mesh=None,
+    decode_kernel: Optional[str] = None, mesh=None,
 ):
     """One dense layer of a verify step, the one copy → (x, ck, cv[,
     stats]): the S tokens' K/V written at their per-row positions into
     the stacked buffers ``ck`` / ``cv`` in place (a ring's one token at
     a time, :func:`_cwrite_ring`), then attended over. Arguments as
-    :func:`_decode_layer` takes them."""
+    :func:`_decode_layer` takes them; the einsum unless the caller asks
+    for the kernel (no cell drafts: the kernel's verify form has not
+    run on the chip)."""
     b, sdraft = pos_grid.shape
     q, k, v = _dense_in(x, layer, c, rope, nope, temp)
     if ring is None:
@@ -2645,7 +2678,7 @@ def _verify_layer(
         # SAME dispatch — sink column included — as decode)
         qr = qg.reshape(b, c.n_kv_heads, grp * sdraft, c.head_dim)
         o = _flash_attend(
-            qr, ckl, cvl, positions, window,
+            qr, ck, cv, li, positions, window,
             config=c, scale=c.attention_scale, grp=grp, rows_per_slot=sdraft,
             sinks_leaf=layer.get("sinks"), mesh=mesh,
         ).reshape(b, c.n_kv_heads, grp, sdraft, c.head_dim)
@@ -3012,16 +3045,47 @@ class InferenceEngine:
             config.n_kind("full")
             if config.mla and _indexed(config, max_seq) else 0
         )
+        # ragged pallas decode attention (ops/flash_decode): the program
+        # takes it by itself where ``reads_live_keys`` holds (None);
+        # "einsum" / "flash" are a caller's way to ask for one form, the
+        # kernel only of a supported model/cache shape. Works under a
+        # tp mesh too — decode_step shard_maps the kernel per KV-head
+        # shard (GSPMD can't partition a pallas call on its own)
+        if decode_kernel not in (None, "einsum", "flash"):
+            raise ValueError(
+                f"decode_kernel={decode_kernel!r}: expected 'einsum' or "
+                "'flash' (a typo here would silently measure the wrong "
+                "path)"
+            )
+        if decode_kernel == "flash" and not flash_decode_supported(config, max_seq):
+            raise ValueError(
+                "decode_kernel='flash' unsupported for this model/"
+                "max_seq (MLA, chunked attention, head_dim % 64, "
+                "or max_seq % 128)"
+            )
+        self.decode_kernel = decode_kernel
         # full-attention layers, which reserve max_seq rows a slot, and
         # the keys a block of their decode attention where it reads only
-        # the blocks the live contexts hold (the latent family under the
-        # causal mask alone, :func:`_attend_live`; 0: whole rows)
+        # the blocks the live contexts hold (0: whole rows): the latent
+        # family under the causal mask alone up to the LONGEST live
+        # context (:func:`_attend_live`), the grouped-query family each
+        # live slot's own (``_slot_keys``: the kernel, by the rule the
+        # program itself takes at trace time)
         # (a latent layer of several attention sublayers: a row each)
         self._full_layers = config.sublayers * config.n_kind("full")
-        self._key_block = (
-            math.gcd(max_seq, _KEY_BLOCK)
-            if config.mla and not _indexed(config, max_seq) else 0
+        self._slot_keys = bool(self._full_layers) and reads_live_keys(
+            config, max_seq, mesh=mesh, decode_kernel=decode_kernel
         )
+        if self._slot_keys:  # the kernel's own block: a shard's heads, the cache's bytes
+            self._key_block = block_keys(
+                config.n_kv_heads // (mesh.shape.get("tp", 1) if mesh else 1),
+                config.head_dim, max_seq,
+                1 if kv_quant else jnp.dtype(config.dtype).itemsize,
+            )
+        elif config.mla and not _indexed(config, max_seq):
+            self._key_block = math.gcd(max_seq, _KEY_BLOCK)
+        else:
+            self._key_block = 0
         # window layers, of either family: the rows of their ring (0:
         # none), the share of the cache's bytes they take, and host-side
         # counters of the keys their mask lets a decoded token see
@@ -3160,27 +3224,6 @@ class InferenceEngine:
         # host→device uploads every macro-step otherwise pays.
         self._turbo_state = None  # (tok, pos, rem, act, eos) on device
 
-        # ragged pallas decode attention (ops/flash_decode): opt-in via
-        # decode_kernel="flash"; requires a supported model/cache shape.
-        # Works under a tp mesh too — decode_step shard_maps the kernel
-        # per KV-head shard (GSPMD can't partition a pallas call on its
-        # own)
-        if decode_kernel not in (None, "einsum", "flash"):
-            raise ValueError(
-                f"decode_kernel={decode_kernel!r}: expected 'einsum' or "
-                "'flash' (a typo here would silently measure the wrong "
-                "path)"
-            )
-        if decode_kernel == "flash":
-            from dstack_tpu.ops.flash_decode import flash_decode_supported
-
-            if not flash_decode_supported(config, max_seq):
-                raise ValueError(
-                    "decode_kernel='flash' unsupported for this model/"
-                    "max_seq (MLA, chunked attention, head_dim % 64, "
-                    "or max_seq % 128)"
-                )
-        self.decode_kernel = decode_kernel or "einsum"
         self._mesh = mesh  # shard_map target for the pallas kernels
 
         # donate caches: decode must update the KV buffers in place, not
@@ -4212,27 +4255,50 @@ class InferenceEngine:
         full layers' decode attention read in this call's token steps →
         ``dtpu_serve_decode_keys_read_total``, beside the rows the
         slots reserve → ``dtpu_serve_decode_keys_reserved_total``. A
-        program that reads whole rows counts read = reserved."""
-        if self._last_step_phase == "spec":  # one call: S rows a slot
-            longest = [
-                max(self.lengths[i] - len(t) for i, t in out.items())
-                + self.spec_draft + 1
-            ]
-        else:  # token step k: the slots that emitted a k-th token
-            longest = [
-                max(
-                    self.lengths[i] - len(t) + k + 1
-                    for i, t in out.items() if len(t) > k
-                )
-                for k in range(max(len(t) for t in out.values()))
-            ]
-        kb = self._key_block or self.max_seq  # whole rows: one block
-        rows = sum(kb * min(-(-n // kb), self.max_seq // kb) for n in longest)
-        per = self.max_batch * self._full_layers
+        program that reads whole rows counts read = reserved; the latent
+        family reads every slot's blocks up to the longest live context,
+        the grouped-query kernel each emitting slot's own blocks and
+        nothing of the other slots."""
+        steps = max(len(t) for t in out.values())
+        kb, spec = self._key_block, self._last_step_phase == "spec"
+        if spec:  # one call: S rows a slot
+            steps = 1
+        reserved = self.max_seq * steps * self.max_batch
+        if not kb or (spec and self._slot_keys):  # whole rows (verify keeps the einsum)
+            rows = reserved
+        elif self._slot_keys:
+            # slot i's k-th token found lengths[i] - len(t) + k keys in
+            # the cache (its own came beside them) and read whole blocks
+            # of them: one pass over the slots, no list a token step
+            def blocks_below(n):  # sum of ceil(j / kb) over 0 <= j < n
+                full, rest = divmod(max(n - 1, 0), kb)
+                return kb * full * (full + 1) // 2 + rest * (full + 1)
+
+            rows = kb * sum(
+                blocks_below(self.lengths[i]) - blocks_below(self.lengths[i] - len(t))
+                for i, t in out.items()
+            )
+        else:
+            if spec:
+                longest = [
+                    max(self.lengths[i] - len(t) for i, t in out.items())
+                    + self.spec_draft + 1
+                ]
+            else:  # token step k: the slots that emitted a k-th token
+                longest = [
+                    max(
+                        self.lengths[i] - len(t) + k + 1
+                        for i, t in out.items() if len(t) > k
+                    )
+                    for k in range(steps)
+                ]
+            rows = self.max_batch * sum(
+                kb * min(-(-n // kb), self.max_seq // kb) for n in longest
+            )
         m = self.metrics
-        m.family("dtpu_serve_decode_keys_read_total").inc(rows * per)
+        m.family("dtpu_serve_decode_keys_read_total").inc(rows * self._full_layers)
         m.family("dtpu_serve_decode_keys_reserved_total").inc(
-            self.max_seq * len(longest) * per
+            reserved * self._full_layers
         )
 
     def _all_greedy(self, live: list) -> bool:

@@ -328,9 +328,10 @@ def new_serve_registry() -> Registry:
         "dtpu_serve_decode_keys_read_total",
         "Key rows the full-attention layers' decode attention read: "
         "slots x key blocks up to the longest live context x layers a "
-        "token step where the program follows the contexts, slots x "
-        "max_seq x layers where it reads whole rows (host-side, from "
-        "positions)",
+        "token step where a latent program follows the contexts, each "
+        "emitting slot's own key blocks x layers where a grouped-query "
+        "one does (ops/flash_decode), slots x max_seq x layers where it "
+        "reads whole rows (host-side, from positions)",
     ).inc(0)
     r.counter(
         "dtpu_serve_decode_keys_reserved_total",

@@ -2097,10 +2097,14 @@ def main(argv=None) -> int:
     )
     p.add_argument(
         "--decode-kernel", default=None, choices=["einsum", "flash"],
-        help="decode attention path: masked einsum over the full cache "
-             "row (default) or the ragged pallas kernel "
-             "(ops/flash_decode — each slot reads only its own prefix; "
-             "non-MLA models; runs per-shard under tensor parallelism)",
+        help="ask for one decode attention form: the masked einsum over "
+             "the full cache row, or the ragged pallas kernel "
+             "(ops/flash_decode — each live slot reads only the key "
+             "blocks it holds; non-MLA models; runs per-shard under "
+             "tensor parallelism). Default: the server chooses by the "
+             "model's shape (ops/flash_decode.reads_live_keys: on the "
+             "TPU the kernel for grouped-query layers of head_dim % 128 "
+             "over a plain row buffer, else the einsum)",
     )
     p.add_argument(
         "--no-warmup", action="store_true",

@@ -138,6 +138,74 @@ class TestFlashDecodeParity:
         )
 
 
+# what the engine's decode scan hands the kernel since PR 43: the
+# STACKED leaf read in place at a traced layer row, the token's own key
+# beside the cache (which is masked at kj < position), length 0 for a
+# slot that is not live. case → (kwargs, int8)
+STACKED = {
+    "plain": ({}, False),
+    "window-softcap": ({"window": 48, "softcap": 30.0}, False),
+    "sinks": ({"sinks": True}, False),
+    "int8": ({}, True),
+    "int8-window-sinks": ({"window": 200, "sinks": True}, True),
+}
+
+
+class TestStackedLeafNewRow:
+    @pytest.mark.parametrize("case", sorted(STACKED))
+    def test_reads_its_layer_and_its_slots_blocks(self, case):
+        from dstack_tpu.serve.engine import kv_dequant
+
+        kw, int8 = STACKED[case]
+        L, li, b, hkv, g, t, d, bk = 3, 1, 5, 2, 3, 384, 64, 128
+        ks = jax.random.split(jax.random.key(9), 6)
+        q = jax.random.normal(ks[0], (b, hkv, g, d), jnp.float32)
+        k = jax.random.normal(ks[1], (L, b, hkv, t, d), jnp.float32)
+        v = jax.random.normal(ks[2], (L, b, hkv, t, d), jnp.float32)
+        k_new = jax.random.normal(ks[3], (b, hkv, 1, d), jnp.float32)
+        v_new = jax.random.normal(ks[4], (b, hkv, 1, d), jnp.float32)
+        # not live (length 0), one key, a block's edge - 1, the edge, the row's end
+        positions = jnp.asarray([0, 1, bk - 1, bk, t - 1], jnp.int32)
+        sinks = (
+            jax.random.normal(ks[5], (hkv, g), jnp.float32) if kw.get("sinks") else None
+        )
+        opt = dict(
+            window=jnp.asarray(kw.get("window", 0), jnp.int32),
+            softcap=kw.get("softcap", 0.0), sinks=sinks,
+        )
+        if int8:
+            (k, k_s), (v, v_s) = kv_quantize(k), kv_quantize(v)
+            opt.update(k_scale=k_s, v_scale=v_s)
+            kf, vf = kv_dequant(k, k_s, q.dtype), kv_dequant(v, v_s, q.dtype)
+        else:
+            kf, vf = k, v
+        # the einsum's operand: the layer's slice with the new row selected in
+        hit = (jnp.arange(t)[None, :] == positions[:, None])[:, None, :, None]
+        ref = _ref_decode_attention(
+            q, jnp.where(hit, k_new, kf[li]), jnp.where(hit, v_new, vf[li]),
+            positions, 0.125, window=kw.get("window", 0),
+            softcap=kw.get("softcap", 0.0), sinks=sinks,
+        )
+        if not int8:
+            # nothing else is read: the other layers and every block past
+            # a slot's last are NaN
+            last = jnp.maximum(positions - 1, 0) // bk
+            dead = (jnp.arange(t)[None, :] // bk > last[:, None])[:, None, :, None]
+            k = jnp.where(dead, jnp.nan, k).at[0].set(jnp.nan).at[2].set(jnp.nan)
+            v = jnp.where(dead, jnp.nan, v).at[0].set(jnp.nan).at[2].set(jnp.nan)
+        out = jax.jit(
+            lambda layer, kn, vn, *a: flash_decode(
+                *a, scale=0.125, layer=layer, k_new=kn, v_new=vn, block_k=bk,
+                interpret=True, **opt,
+            )
+        )(jnp.asarray(li), k_new, v_new, q, k, v, positions)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        if sinks is None:  # a slot that holds nothing attends to its own key alone
+            np.testing.assert_allclose(
+                out[0], jnp.broadcast_to(v_new[0], out[0].shape), atol=1e-6
+            )
+
+
 class TestVerifyRows:
     def test_rows_per_slot_matches_per_row_masks(self):
         """rows_per_slot=S: row g*S+s attends to keys <= pos+s — the

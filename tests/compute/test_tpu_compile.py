@@ -191,6 +191,39 @@ def _decode(rows_per_slot=1, int8=False, sinks=False):
     return build
 
 
+def _decode_stacked(layers, g, t, int8=False, sinks=False):
+    """What the engine's decode scan hands the kernel where
+    ``reads_live_keys`` holds: the stacked leaf [L, 16, 8, T, 128] read
+    in place at a traced row, the token's own K/V beside it, the block
+    rule's own block (the chat and the mixed cell's shapes)."""
+
+    def build(sds):
+        d, kv = 128, jnp.int8 if int8 else BF16
+        args = [
+            sds((_B, _HKV, g, d), BF16), sds((layers, _B, _HKV, t, d), kv),
+            sds((layers, _B, _HKV, t, d), kv), sds((_B,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32),
+            sds((_B, _HKV, 1, d), BF16), sds((_B, _HKV, 1, d), BF16),
+        ]
+        names = ["layer", "window", "k_new", "v_new"]
+        if int8:
+            args += [sds((layers, _B, _HKV, t), jnp.float32)] * 2
+            names += ["k_scale", "v_scale"]
+        if sinks:
+            args.append(sds((_HKV, g), jnp.float32))
+            names.append("sinks")
+
+        def fn(q, k, v, pos, *opt):
+            return flash_decode(
+                q, k, v, pos, scale=d**-0.5, softcap=30.0 if sinks else 0.0,
+                **dict(zip(names, opt)),
+            )
+
+        return fn, tuple(args)
+
+    return build
+
+
 KERNELS = {
     "flash_fwd": _flash_fwd,
     "flash_bwd": _flash_bwd(),
@@ -204,6 +237,9 @@ KERNELS = {
     "decode_int8_kv": _decode(int8=True),
     "decode_sinks": _decode(sinks=True),
     "decode_verify_int8_sinks": _decode(rows_per_slot=5, int8=True, sinks=True),
+    "decode_stacked_new_row_chat": _decode_stacked(32, 3, 1536),
+    "decode_stacked_new_row_mixed": _decode_stacked(4, 6, 8192),
+    "decode_stacked_new_row_int8_sinks": _decode_stacked(32, 3, 1536, int8=True, sinks=True),
 }
 
 
@@ -256,8 +292,9 @@ def test_decode_step_llama_1b_fits(topo):
 
 
 def test_decode_step_flash_kernel_llama_1b(topo, _as_tpu):
-    """The opt-in ragged decode (ROADMAP A3's A/B) inside the whole
-    step, int8 KV: the variant the block-spec repair unblocks."""
+    """The ragged decode asked for (``decode_kernel="flash"``) where the
+    rule would not take it (head_dim 64) inside the whole step, int8 KV:
+    the variant the block-spec repair unblocks."""
     config = llama.LLAMA_32_1B
     sharding = SingleDeviceSharding(topo.devices[0])
     params, _, sds = _abstract_engine_state(config, sharding)
@@ -580,7 +617,7 @@ def _holds_no_second_cache(
     if temp_below:
         assert temp < temp_below, f"temp {temp / 1e9:.3f} GB"
         _fits(compiled)
-        return
+        return compiled
     moves = _cache_sized_moves(
         compiled.as_text(),
         *sized([leaf for leaf in jax.tree.leaves(cache) if leaf.ndim > 1]),
@@ -593,6 +630,7 @@ def _holds_no_second_cache(
         f"temp {temp / 1e9:.2f} GB beside a cache of {cache_bytes / 1e9:.2f} GB"
     )
     _fits(compiled)
+    return compiled
 
 
 #: what the compiler holds beside the mixed cell's cache that is no
@@ -637,8 +675,22 @@ _DECODE_EXPERTS = {
 }
 
 
+#: the decode programs whose full layers are grouped-query layers of
+#: head_dim 128 over a plain row buffer: on the chip they attend through
+#: ``ops/flash_decode`` (``reads_live_keys``), which reads the stacked
+#: leaf where it lies. Every other case holds no kernel: latent layers,
+#: head_dim 64, and ``verify_step`` (the einsum, no cell drafts)
+#: → ``temp`` of the einsum form (PR 42's tree), which the kernel form
+#: may not exceed (PR 43's reading: 0.0027 / 0.635 / 0.757 GB)
+_READS_LIVE_KEYS = {
+    "decode_step-minitron_4b": 0.003e9,
+    "decode_step-gqa_groups": 0.636e9,
+    "decode_loop-gqa_groups": 0.758e9,
+}
+
+
 @pytest.mark.parametrize("case", sorted(_DECODE) + sorted(_DECODE_EXPERTS))
-def test_decode_program_holds_no_second_cache(topo, case):
+def test_decode_program_holds_no_second_cache(topo, _as_tpu, case):
     """Parent readings (PR 24's tree), ``temp`` / cache: decode_step
     3.32 / 3.22 GB, decode_loop 3.06 / 1.36 GB, verify_step 3.26 / 3.22
     GB, int8 0.79 / 0.57 GB. The latent's per-layer slice, copied once
@@ -673,9 +725,13 @@ def test_decode_program_holds_no_second_cache(topo, case):
     stack and the layer's row (``moe.Row``) the loop takes
     ``stack[layer, expert]`` in one slice inside each product's fusion."""
     if case in _DECODE_EXPERTS:
-        _holds_no_second_cache(topo, case, temp_below=_DECODE_EXPERTS[case])
+        compiled = _holds_no_second_cache(topo, case, temp_below=_DECODE_EXPERTS[case])
     else:
-        _holds_no_second_cache(topo, case, room=_DECODE[case])
+        compiled = _holds_no_second_cache(topo, case, room=_DECODE[case])
+    assert _has_kernel(compiled) == (case in _READS_LIVE_KEYS)
+    if case in _READS_LIVE_KEYS:  # no higher than the einsum form's reading
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp <= _READS_LIVE_KEYS[case], f"temp {temp / 1e9:.3f} GB"
 
 
 def _scores(g, heads, max_seq, c=256):
@@ -853,6 +909,41 @@ def test_prefill_chunk_step_tp4_uses_kernel(topo, _as_tpu):
         ),
         params, cache, sds((1, 256), jnp.int32), sds((), jnp.int32),
         sds((), jnp.int32), donate_argnums=(1,),
+    )
+    assert _has_kernel(compiled)
+    _fits(compiled)
+
+
+def test_decode_step_tp4_reads_live_keys(topo, _as_tpu):
+    """``openai_server --tp 4`` of a grouped-query model of head_dim 128
+    (the chat cell's, depth cut: the layers are one scan body): the rule
+    takes the ragged decode kernel by itself, per shard of two KV heads
+    under ``shard_map``, over the stacked leaf sharded on its heads."""
+    from dstack_tpu.parallel.sharding import default_rules, tree_shardings
+
+    config = dataclasses.replace(llama.MINITRON_4B, n_layers=2)
+    mesh = _described_mesh(topo, tp=4)
+    params, cache, _ = _abstract_engine_state(config, None, max_seq=1536)
+    params = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        params,
+        tree_shardings(llama.param_specs(config), mesh, default_rules()),
+    )
+    kv_heads = NamedSharding(mesh, P(None, None, "tp", None, None))
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=kv_heads),
+        cache,
+    )
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(mesh, P())
+        )
+
+    compiled = _compile(
+        lambda p, ca, t, pos, m: eng.decode_step(p, ca, t, pos, config, m, mesh=mesh),
+        params, cache, sds((16,), jnp.int32), sds((16,), jnp.int32),
+        sds((16,), jnp.bool_), donate_argnums=(1,),
     )
     assert _has_kernel(compiled)
     _fits(compiled)
