@@ -7,7 +7,8 @@ torch ``.bin`` shards) and get back ``(LlamaConfig, params)`` ready for
 finetune driver.
 
 Supported ``model_type``s: ``llama``, ``qwen2``, ``qwen3``,
-``qwen3_moe``, ``mistral``, ``gemma``, ``gemma2``, ``gemma3``/
+``qwen3_moe``, ``mistral``, ``gemma``, ``gemma2``, ``lfm2`` (and
+``lfm2_moe``'s config keys), ``gemma3``/
 ``gemma3_text`` (multimodal checkpoints load their text tower),
 ``mixtral``, ``phi3`` (fused qkv/gate_up projections are split on
 load; a Phi-3 export round-trips as the equivalent mistral/llama
@@ -61,6 +62,8 @@ def config_from_hf(hf: dict, dtype: Any = jnp.bfloat16) -> LlamaConfig:
         # weights and the language_model prefix)
         hf = {**hf["text_config"], "model_type": f"{mt}_text"}
         mt = f"{mt}_text"
+    if mt in ("lfm2", "lfm2_moe"):
+        return _lfm2_config(hf, dtype, mt)
     hidden = hf["hidden_size"]
     n_heads = hf["num_attention_heads"]
     head_dim = hf.get("head_dim") or hidden // n_heads
@@ -412,6 +415,68 @@ def _deepseek_config(hf: dict, common: dict, mt: str) -> LlamaConfig:
     )
 
 
+def _lfm2_config(hf: dict, dtype: Any, mt: str) -> LlamaConfig:
+    """LFM2 (``lfm2``, and ``lfm2_moe``'s config keys) → LlamaConfig:
+    gated short-convolution layers (``layer_types`` kind ``conv``,
+    models/shortconv.py) beside grouped-query layers with a per-head
+    q/k norm before a half-split rope; ``lfm2`` a dense SwiGLU on every
+    layer (HF ``Lfm2MLP``: its width adjusted as there), ``lfm2_moe``
+    ``num_dense_layers`` dense layers and then sigmoid-routed experts
+    with a selection-only bias (``use_expert_bias``), gates normed over
+    the picks (``norm_topk_prob``)."""
+    if hf.get("conv_bias"):
+        raise ValueError(f"{mt} conv_bias=true is not supported")
+    n_layers = hf["num_hidden_layers"]
+    kinds = hf.get("layer_types") or [
+        "full_attention"
+        if i in (hf.get("full_attn_idxs") or range(n_layers)) else "conv"
+        for i in range(n_layers)
+    ]
+    if not set(kinds) <= {"full_attention", "conv"}:
+        raise ValueError(f"{mt} layer_types {sorted(set(kinds))} are not supported")
+    inter = hf.get("block_ff_dim", hf["intermediate_size"])
+    if mt == "lfm2" and hf.get("block_auto_adjust_ff_dim", True):
+        inter = int(2 * inter / 3)
+        if hf.get("block_ffn_dim_multiplier", 1.0) is not None:
+            inter = int(hf.get("block_ffn_dim_multiplier", 1.0) * inter)
+            of = hf.get("block_multiple_of", 256)
+            inter = of * ((inter + of - 1) // of)
+    rope = hf.get("rope_parameters") or {}
+    common = dict(
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        n_layers=n_layers,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"],
+        intermediate_size=inter,
+        rope_theta=float(hf.get("theta") or hf.get("rope_theta") or rope.get("rope_theta", 1e6)),
+        norm_eps=hf.get("norm_eps", 1e-5),
+        max_seq_len=hf.get("max_position_embeddings", 128000),
+        tie_embeddings=hf.get("tie_embedding", hf.get("tie_word_embeddings", True)),
+        qk_norm=True,
+        layer_types=tuple("full" if k == "full_attention" else "conv" for k in kinds),
+        conv_taps=hf.get("conv_L_cache", 3),
+        dtype=dtype,
+    )
+    if mt == "lfm2":
+        return LlamaConfig(**common)
+    k_dense = hf.get("num_dense_layers", 0)
+    n, k = hf["num_experts"], hf["num_experts_per_tok"]
+    return LlamaConfig(
+        **{**common, "intermediate_size": hf["moe_intermediate_size"]},
+        first_k_dense=k_dense,
+        dense_intermediate=inter,
+        n_experts=n,
+        experts_per_token=k,
+        capacity_factor=n / k,  # dropless
+        router_score="sigmoid",
+        router_bias=bool(hf.get("use_expert_bias", False)),
+        router_renorm=bool(hf.get("norm_topk_prob", True)),
+        routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+    )
+
+
 def _llama4_config(hf: dict, common: dict) -> LlamaConfig:
     """Llama4 text tower → LlamaConfig (interleaved rope, periodic NoPE
     layers, chunked attention, qk L2 norm, temperature tuning,
@@ -593,6 +658,8 @@ def convert_state_dict(
     dt = c.dtype
     if model_type in ("deepseek_v2", "deepseek_v3"):
         return _convert_deepseek(sd, c)
+    if model_type in ("lfm2", "lfm2_moe"):
+        return _convert_lfm2(sd, c)
     if model_type == "phi3":
         sd = _split_phi3(dict(sd), c)
     if model_type in ("glm", "glm4"):
@@ -852,6 +919,69 @@ def _convert_deepseek(sd: dict, c: LlamaConfig) -> dict:
         params["dense_layers"] = {
             **attn_and_norms(dense_rows), **dense_mlp(dense_rows)
         }
+    if not c.tie_embeddings:
+        params["lm_head"] = np.asarray(get("lm_head.weight").T, dt)
+    return params
+
+
+def _convert_lfm2(sd: dict, c: LlamaConfig) -> dict:
+    """LFM2 state dict → our pytree (HF ``Lfm2ForCausalLM`` names): a
+    stack a kind of layer (``layers``: the grouped-query ones,
+    ``conv_layers``: the gated short convolutions), ``operator_norm`` /
+    ``ffn_norm`` the two pre-norms, the dense FFN ``w1`` / ``w3`` /
+    ``w2`` = gate / up / down, ``embedding_norm`` the last norm. The
+    expert block's names (``lfm2_moe``) are on no machine here: they
+    wait for a checkpoint or that module."""
+    if c.n_experts:
+        raise NotImplementedError(
+            "lfm2_moe checkpoints: the expert block's state-dict names "
+            "are not known here (config keys only)"
+        )
+    dt = c.dtype
+
+    def get(name):
+        if name not in sd:
+            raise KeyError(f"missing weight {name!r} (have e.g. {sorted(sd)[:5]})")
+        return _to_np(sd[name])
+
+    def stack(ids, suffix, transpose=False):
+        mats = [get(f"model.layers.{i}.{suffix}") for i in ids]
+        return np.asarray(np.stack([m.T if transpose else m for m in mats]), dt)
+
+    def common(ids):
+        return {
+            "attn_norm": stack(ids, "operator_norm.weight"),
+            "mlp_norm": stack(ids, "ffn_norm.weight"),
+            "w_gate": stack(ids, "feed_forward.w1.weight", transpose=True),
+            "w_up": stack(ids, "feed_forward.w3.weight", transpose=True),
+            "w_down": stack(ids, "feed_forward.w2.weight", transpose=True),
+        }
+
+    full = [i for i, k in enumerate(c.layer_types) if k == "full"]
+    conv = [i for i, k in enumerate(c.layer_types) if k == "conv"]
+    A = "self_attn."
+    params = {
+        "embed": np.asarray(get("model.embed_tokens.weight"), dt),
+        "final_norm": np.asarray(get("model.embedding_norm.weight"), dt),
+        "layers": {
+            **common(full),
+            "wq": stack(full, A + "q_proj.weight", transpose=True),
+            "wk": stack(full, A + "k_proj.weight", transpose=True),
+            "wv": stack(full, A + "v_proj.weight", transpose=True),
+            "wo": stack(full, A + "out_proj.weight", transpose=True),
+            "q_norm": stack(full, A + "q_layernorm.weight"),
+            "k_norm": stack(full, A + "k_layernorm.weight"),
+        },
+        "conv_layers": {
+            **common(conv),
+            "conv_win": stack(conv, "conv.in_proj.weight", transpose=True),
+            # Conv1d [H, 1, K] → taps [K, H] (tap K-1 on the current row)
+            "conv_w": np.asarray(np.stack([
+                get(f"model.layers.{i}.conv.conv.weight")[:, 0, :].T for i in conv
+            ]), dt),
+            "wo": stack(conv, "conv.out_proj.weight", transpose=True),
+        },
+    }
     if not c.tie_embeddings:
         params["lm_head"] = np.asarray(get("lm_head.weight").T, dt)
     return params

@@ -33,6 +33,22 @@ from dstack_tpu.parallel.sharding import (
 )
 
 
+#: a layer kind → the stack of ``params`` its layers past the prelude are in
+STACK_OF = {
+    "full": "layers", "window": "window_layers", "linear": "linear_layers",
+    "conv": "conv_layers",
+}
+
+
+def mixer_of(kind: str):
+    """The module of a kind of layer that mixes its tokens without
+    attention (``leaf_shapes``, ``n_params``, ``mix``, ``zeros``): a
+    slot's past on such a layer is held whole, not by position."""
+    from dstack_tpu.models import kda, shortconv
+
+    return {"linear": kda, "conv": shortconv}[kind]
+
+
 @dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 128256
@@ -187,8 +203,9 @@ class LlamaConfig:
     first_k_dense: int = 0
     dense_intermediate: int = 0
     # --- layer groups: two attention shapes in one model ---
-    # per-layer kind over all n_layers, "full" | "window" (() = every
-    # layer full; the first_k_dense prelude layers must be full). A
+    # per-layer kind over all n_layers, "full" | "window" | "linear" |
+    # "conv" (() = every layer full; the first_k_dense prelude layers
+    # are all full, all linear or all conv). A
     # window layer is attention of ANOTHER shape (the swa_* sizes: of
     # an MLA model the latent's, of a grouped-query model the query
     # head count over the same KV heads), sees key j from query i iff
@@ -248,20 +265,29 @@ class LlamaConfig:
     linear_head_dim: int = 0
     linear_conv: int = 4
     linear_gate_floor: float = -5.0
+    # --- gated short-convolution layers (models/shortconv.py) ---
+    # a layer of kind "conv" in layer_types mixes its tokens through a
+    # causal depthwise convolution of conv_taps taps over the hidden
+    # itself, between two elementwise gates (LFM2), beside grouped-query
+    # attention: no keys, values or state kept, a slot's past is the
+    # convolution's last conv_taps - 1 rows of hidden_size. Its weights
+    # are a stack of their own, params["conv_layers"] (a first_k_dense
+    # prelude of conv layers keeps "dense_layers")
+    conv_taps: int = 3
 
     def __post_init__(self):
         kinds = set(self.layer_types)
         prelude = set(self.layer_types[: self.first_k_dense])
         if self.layer_types and (
             len(self.layer_types) != self.n_layers
-            or not kinds <= {"full", "window", "linear"}
-            or not prelude <= {"full"} and prelude != {"linear"}
+            or not kinds <= set(STACK_OF)
+            or prelude not in (set(), {"full"}, {"linear"}, {"conv"})
             or self.sliding_pattern or self.nope_pattern
         ):
             raise ValueError(
-                "layer_types: one of 'full' | 'window' | 'linear' a layer "
-                "(in place of sliding_pattern / nope_pattern), the "
-                "first_k_dense prelude all 'full' or all 'linear'"
+                "layer_types: one of 'full' | 'window' | 'linear' | 'conv' a "
+                "layer (in place of sliding_pattern / nope_pattern), the "
+                "first_k_dense prelude all 'full', all 'linear' or all 'conv'"
             )
         if "linear" in kinds and not (
             self.mla and self.linear_head_dim and self.linear_conv > 1
@@ -273,6 +299,15 @@ class LlamaConfig:
             raise ValueError(
                 "linear layers: beside latent attention, with "
                 "linear_head_dim, plainly pre-normed, a gate floor over -5.5"
+            )
+        if "conv" in kinds and not (
+            not self.mla and self.conv_taps > 1
+            and self.sublayers == 1 and self.pre_norm
+            and not self.post_norms and not self.parallel_block
+        ):
+            raise ValueError(
+                "conv layers: beside grouped-query attention, plainly "
+                "pre-normed, one sublayer, two taps or more"
             )
         if self.experts_held and self.router_groups and (
             self.n_experts % self.router_groups[0]
@@ -424,11 +459,17 @@ class LlamaConfig:
                     + h * self.index_n_heads
                 )
             return q + kv + self.o_dim * h + extra
+        qk_norm = (
+            self.q_dim + self.kv_dim
+            if self.qk_norm_flat or self.qk_norm and self.norm_type == "layernorm"
+            else 2 * self.head_dim if self.qk_norm else 0
+        )
         return (
             h * self.q_dim + 2 * h * self.kv_dim + self.q_dim * h
             + (self.q_dim + 2 * self.kv_dim if self.qkv_bias else 0)
             + (h if self.proj_bias else 0)  # bo
             + (h * self.n_heads if self.attn_gate else 0)
+            + qk_norm
         )
 
     def _shared_expert_params(self) -> int:
@@ -441,17 +482,12 @@ class LlamaConfig:
         """Attention parameters over all layers (a window layer has
         its own shape)."""
         n_win = self.layer_types.count("window")
-        n_lin = self.layer_types.count("linear")
-        total = (
-            (self.n_layers - n_win - n_lin) * self.sublayers
-            * self._attn_params_per_layer()
-        )
+        total = self.n_kind("full") * self.sublayers * self._attn_params_per_layer()
         if n_win:
             total += n_win * self.window_config._attn_params_per_layer()
-        if n_lin:
-            from dstack_tpu.models import kda
-
-            total += n_lin * kda.n_params(self)
+        for kind in ("linear", "conv"):
+            if kind in self.layer_types:
+                total += self.n_kind(kind) * mixer_of(kind).n_params(self)
         return total
 
     def _param_count(self, experts: int, small: bool = True) -> int:
@@ -704,6 +740,16 @@ LINEAR_TINY = LlamaConfig(  # for tests: linear-attention layers beside latent o
     routed_scale=2.5, router_renorm=True, experts_held=(2, 2),
     moe_shared_expert=True, moe_shared_intermediate=64,
 )
+CONV_TINY = LlamaConfig(  # for tests: gated short-convolution layers beside grouped-query ones
+    vocab_size=512, hidden_size=128, n_layers=12, n_heads=4, n_kv_heads=2,
+    head_dim=32, intermediate_size=64, max_seq_len=256, dtype=jnp.float32,
+    remat=False, qk_norm=True, tie_embeddings=True, norm_eps=1e-5,
+    rope_theta=1e6, layer_types=("conv", "conv", "full", "conv") * 3,
+    first_k_dense=2, dense_intermediate=192,
+    n_experts=8, experts_per_token=2, capacity_factor=4.0,
+    router_score="sigmoid", router_bias=True, router_renorm=True,
+    experts_held=(2, 4),
+)
 
 _GPT_OSS_COMMON = dict(
     vocab_size=201088, hidden_size=2880, n_heads=64, n_kv_heads=8,
@@ -744,6 +790,7 @@ CONFIGS = {
     "mla-tiny": MLA_TINY,
     "scmoe-tiny": SCMOE_TINY,
     "linear-tiny": LINEAR_TINY,
+    "conv-tiny": CONV_TINY,
     "glm-4-9b": GLM_4_9B,
     "olmo-2-7b": OLMO2_7B,
     "command-r-35b": COMMAND_R_35B,
@@ -885,39 +932,43 @@ def param_specs(config: LlamaConfig) -> dict:
         specs["window_layers"] = {
             k: v for k, v in layer.items() if "idx" not in k
         }
-    if "linear" in config.layer_types:
-        # a linear mixer's leaves in place of the attention's (skinny
-        # or elementwise ones replicated, the projections over heads)
-        from dstack_tpu.models import kda
-
-        lin = {
+    for kind in ("linear", "conv"):
+        if kind not in config.layer_types:
+            continue
+        # a mixer's leaves in place of the attention's (skinny or
+        # elementwise ones replicated, a linear mixer's projections over
+        # heads; a conv mixer's are split in three, not by heads)
+        mixer = {
             k: L + (
-                ("heads", "embed_fsdp") if k == "wo"
-                else ("embed_fsdp", "heads") if len(shape) == 3 and k != "lin_conv"
-                else (None,) * (len(shape) - 1)
+                (None,) * (len(shape) - 1) if init == "conv" or len(shape) < 3
+                else ("heads", "embed_fsdp") if k == "wo" and kind == "linear"
+                else ("embed_fsdp", "heads") if kind == "linear"
+                else (None, "embed_fsdp") if k == "wo" else ("embed_fsdp", None)
             )
-            for k, (shape, _) in kda.leaf_shapes(config, 1).items()
+            for k, (shape, init) in mixer_of(kind).leaf_shapes(config, 1).items()
         }
         swap = lambda tree: {
-            **{k: v for k, v in tree.items() if k not in attn}, **lin
+            **{k: v for k, v in tree.items()
+               if k not in attn and k not in ("q_norm", "k_norm")},
+            **mixer,
         }
-        if config.n_kind("linear", prelude=False):
-            specs["linear_layers"] = swap(layer)
-        if config.first_k_dense and config.prelude_kind == "linear":
+        if config.n_kind(kind, prelude=False):
+            specs[STACK_OF[kind]] = swap(layer)
+        if config.first_k_dense and config.prelude_kind == kind:
             specs["dense_layers"] = swap(specs["dense_layers"])
     if not config.tie_embeddings:
         specs["lm_head"] = ("embed_fsdp", "vocab")
     return specs
 
 
-def _init_linear(
-    c: LlamaConfig, key: jax.Array, L: int, std: float, depth: int
+def _init_mixer(
+    c: LlamaConfig, kind: str, key: jax.Array, L: int, std: float, depth: int
 ) -> dict:
-    """A stack of ``L`` linear mixers (models/kda.py states the leaves)."""
-    from dstack_tpu.models import kda
-
+    """A stack of ``L`` mixers of ``kind`` (its module states the
+    leaves: models/kda.py, models/shortconv.py)."""
     out = {}
-    for i, (name, (shape, init)) in enumerate(sorted(kda.leaf_shapes(c, L).items())):
+    shapes = mixer_of(kind).leaf_shapes(c, L)
+    for i, (name, (shape, init)) in enumerate(sorted(shapes.items())):
         k = jax.random.fold_in(key, 41 + i)
         if init == "ones":
             out[name] = jnp.ones(shape, c.dtype)
@@ -926,7 +977,7 @@ def _init_linear(
         else:
             scale = {
                 "normal": std, "out": std / math.sqrt(2 * depth),
-                "conv": c.linear_conv**-0.5,
+                "conv": shape[1]**-0.5,  # its taps
             }[init]
             out[name] = (
                 jax.random.normal(k, shape, jnp.float32) * scale
@@ -1012,7 +1063,6 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
     dt = c.dtype
     depth = depth or c.n_layers
     n_win = c.layer_types.count("window")
-    n_lin = c.n_kind("linear", prelude=False)
 
     def normal(key, shape, scale=std):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
@@ -1028,7 +1078,7 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
         # Gemma-style norms scale by (1 + w): identity init is w = 0
         return (jnp.zeros if c.norm_offset else jnp.ones)(shape, dt)
 
-    L = c.n_layers - c.first_k_dense - n_win - n_lin
+    L = c.n_kind("full", prelude=False)
     if c.n_experts:
         E, EH = c.n_experts + c.zero_experts, c.n_experts_held
         mlp = {
@@ -1150,10 +1200,10 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
         if c.post_norms:
             dense["attn_post_norm"] = norm_init((K, c.hidden_size))
             dense["mlp_post_norm"] = norm_init((K, c.hidden_size))
-        if c.prelude_kind == "linear":  # a mixer in the attention's place
+        if c.prelude_kind != "full":  # a mixer in the attention's place
             dense = {
                 "attn_norm": dense["attn_norm"],
-                **_init_linear(c, kd[0], K, std, depth),
+                **_init_mixer(c, c.prelude_kind, kd[0], K, std, depth),
                 **{k: dense[k] for k in ("mlp_norm", "w_gate", "w_up", "w_down")},
             }
         params["dense_layers"] = dense
@@ -1167,21 +1217,24 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
         params["window_layers"] = init_params(
             wc, jax.random.fold_in(key, 3), depth
         )["layers"]
-    if n_lin:
-        # the linear layers: the expert layer's MLP leaves under a
-        # mixer's, a stack of their own (only the stack is kept)
-        # (the attention the stack is drawn with is dropped: one head of
-        # the least widths, the MLP leaves' draws do not read them)
-        lc = dataclasses.replace(
-            c, n_layers=n_lin, layer_types=(), first_k_dense=0, vocab_size=8,
-            tie_embeddings=True, n_heads=1, kv_lora_rank=1, qk_nope_head_dim=1,
-            qk_rope_head_dim=2, v_head_dim=1,
+    for kind, folds in (("linear", (4, 5)), ("conv", (6, 8))):
+        n = c.n_kind(kind, prelude=False)
+        if not n:
+            continue
+        # the linear | conv layers: the expert layer's MLP leaves under a
+        # mixer's, a stack of their own (only the stack is kept; the
+        # attention it is drawn with is dropped: one head of the least
+        # widths, the MLP leaves' draws do not read them)
+        mc = dataclasses.replace(
+            c, n_layers=n, layer_types=(), first_k_dense=0, vocab_size=8,
+            tie_embeddings=True, n_heads=1, n_kv_heads=1, head_dim=2,
+            kv_lora_rank=0, qk_norm=False,
         )
-        full = init_params(lc, jax.random.fold_in(key, 4), depth)["layers"]
-        attn = _init_attn(lc, key, 1, std, depth)
-        params["linear_layers"] = {
+        full = init_params(mc, jax.random.fold_in(key, folds[0]), depth)["layers"]
+        attn = _init_attn(mc, key, 1, std, depth)
+        params[STACK_OF[kind]] = {
             **{k: v for k, v in full.items() if k not in attn},
-            **_init_linear(c, jax.random.fold_in(key, 5), n_lin, std, depth),
+            **_init_mixer(c, kind, jax.random.fold_in(key, folds[1]), n, std, depth),
         }
     if not c.tie_embeddings:
         params["lm_head"] = normal(jax.random.fold_in(key, 99), (c.hidden_size, c.vocab_size))
@@ -1336,19 +1389,15 @@ def layer_nope(config: "LlamaConfig") -> list[bool]:
     return [(i + 1) % c.nope_pattern == 0 for i in range(c.n_layers)]
 
 
-#: a layer kind → the stack of ``params`` its layers past the prelude are in
-STACK_OF = {"full": "layers", "window": "window_layers", "linear": "linear_layers"}
-
-
 class LayerRun(NamedTuple):
     """Consecutive layers of one group, ``params[key][lo:hi]``."""
 
-    key: str  # "dense_layers" | "layers" | "window_layers" | "linear_layers"
+    key: str  # "dense_layers" | a stack of :data:`STACK_OF`
     config: "LlamaConfig"  # the group's attention shape
     window: int  # 0 = full attention
     lo: int
     hi: int
-    kind: str = "full"  # "full" | "window" | "linear": what its layers keep
+    kind: str = "full"  # "full" | "window" | "linear" | "conv": what its layers keep
 
 
 def layer_runs(config: "LlamaConfig") -> list:
@@ -1367,7 +1416,7 @@ def layer_runs(config: "LlamaConfig") -> list:
     kinds = c.layer_types[c.first_k_dense:] or ("full",) * (
         c.n_layers - c.first_k_dense
     )
-    seen = {"full": 0, "window": 0, "linear": 0}
+    seen = dict.fromkeys(STACK_OF, 0)
     for kind in kinds:
         at = seen[kind]
         seen[kind] += 1
@@ -1873,17 +1922,16 @@ def _attention_block(
     return constrain(out, rules, "batch", "seq", None, mesh=mesh)
 
 
-def _linear_block(
+def _mixer_block(
     x: jax.Array, layer: dict, config: LlamaConfig, mesh: Optional[Mesh],
-    rules: ShardingRules,
+    rules: ShardingRules, kind: str,
 ) -> jax.Array:
-    """A linear layer's mixer over whole sequences, from a state of
-    zeros (models/kda.py): the training and parity path's."""
-    from dstack_tpu.models import kda
-
-    c = config
+    """A linear or conv layer's mixer over whole sequences, from a past
+    of zeros (models/kda.py, models/shortconv.py): the training and
+    parity path's."""
+    c, mixer = config, mixer_of(kind)
     h = model_norm(x, layer["attn_norm"], c)
-    y, _, _ = kda.mix(h, layer, c, *kda.zeros(c, x.shape[0], x.dtype))
+    y = mixer.mix(h, layer, c, *mixer.zeros(c, x.shape[0], x.dtype))[0]
     out = _proj(layer, "wo", y, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
     return constrain(out, rules, "batch", "seq", None, mesh=mesh)
 
@@ -2098,8 +2146,9 @@ def forward(
     rules = rules or default_rules()
     x, ropes, pos = _embed_tokens(params, tokens, c, mesh, rules, positions)
 
-    def make_group_fn(wins: tuple, nps: tuple, stacked: bool, ac=c):
-        # ``ac``: the attention shape of the layers scanned (a group's)
+    def make_group_fn(wins: tuple, nps: tuple, stacked: bool, ac=c, kind="full"):
+        # ``ac``: the attention shape of the layers scanned (a group's),
+        # ``kind``: what mixes their tokens (a group's: ``LayerRun.kind``)
         def group_fn(x, group):
             aux = jnp.zeros((), jnp.float32)
             for i, (w, np_) in enumerate(zip(wins, nps)):
@@ -2112,9 +2161,9 @@ def forward(
                     rules=rules, attn_impl=attn_impl, window=w, nope=np_,
                     positions=pos,
                 )
-                if "lin_wqkv" in layer:  # a linear mixer in its place
+                if kind in ("linear", "conv"):  # a mixer in the attention's place
                     attend = functools.partial(
-                        _linear_block, config=c, mesh=mesh, rules=rules
+                        _mixer_block, config=c, mesh=mesh, rules=rules, kind=kind
                     )
                 if c.sublayers > 1:
                     x, aux_i = _shortcut_layer(
@@ -2159,7 +2208,7 @@ def forward(
         aux = jnp.zeros((), jnp.float32)
         for run in layer_runs(c):
             x, auxs = jax.lax.scan(
-                make_group_fn((run.window,), (False,), False, run.config),
+                make_group_fn((run.window,), (False,), False, run.config, run.kind),
                 x, run_slice(params[run.key], run),
             )
             aux = aux + jnp.sum(auxs)

@@ -131,9 +131,10 @@ def _cache_unpack(ck, cv) -> dict:
     return {"k": ck, "v": cv}
 
 
-def _cwrite_chunk(ckv, layer, slot, start: int, new, axis: int = 1):
+def _cwrite_chunk(ckv, layer, slot, start, new, axis: int = 1):
     """Write one slot's prefill chunk into layer ``layer`` of a STACKED
-    cache leaf at the static token offset ``start``, in place: ``new``
+    cache leaf at the token offset ``start`` (static under the serial
+    chunk; a lone row's of a model of layer groups traced), in place: ``new``
     [1, *slot] with C in the place of T, as stored (:func:`_cstored`);
     ``axis`` as in :func:`_cwrite_rows`. The engine keeps ``start`` a
     multiple of the chunk and a chunk is whole tiles, so the
@@ -392,12 +393,14 @@ def _cache_shapes(
     layer of several attention sublayers has a ``ckv`` row a sublayer,
     layer ``l``'s sublayer ``i`` at row ``l * sublayers + i``. Linear
     layers (``models/kda.py``) hold ``state`` [Ll, B, heads, D, D] in
-    float32 and ``conv`` [Ll, B, K-1, 3 * heads * D], neither with a
-    token axis; under group-limited routing ``moe_stats`` ends with the
-    tokens one of whose eligible groups is held here."""
+    float32 and ``conv`` [Ll, B, K-1, 3 * heads * D], conv layers
+    (``models/shortconv.py``) ``conv`` [Lc, B, K-1, hidden] alone, none
+    with a token axis; under group-limited routing ``moe_stats`` ends
+    with the tokens one of whose eligible groups is held here."""
     n_win = c.layer_types.count("window")
     n_lin = c.layer_types.count("linear")
-    n_full = c.n_layers - n_win - n_lin
+    n_conv = c.layer_types.count("conv")
+    n_full = c.n_kind("full")
     ring = ring_rows(c, max_seq, chunk) if n_win else 0
     if c.mla:
         rope = c.qk_rope_head_dim
@@ -427,6 +430,9 @@ def _cache_shapes(
         shapes["conv"] = (
             n_lin, max_batch, c.linear_conv - 1, 3 * heads[0] * heads[1]
         )
+    if n_conv:
+        # nor does a conv layer: its convolution's tail over the hidden
+        shapes["conv"] = (n_conv, max_batch, c.conv_taps - 1, c.hidden_size)
     if c.experts_held:
         shapes["moe_stats"] = (_moe_counts(c) - 2,)
         shapes["moe_reads"] = (2,)
@@ -727,7 +733,7 @@ def init_cache(
         # materialize the full cache on one chip first. K/V shard over
         # their heads; a latent has none (the q heads shard instead)
         spec = [None] * len(s)
-        if not config.mla and len(s) > 2:
+        if not config.mla and len(s) > 2 and n not in _STATES:
             spec[2] = "tp"
         # dtpu: noqa[DTPU003] loop over the fixed cache buffer names at engine construction — bounded and once
         return jax.jit(
@@ -1112,32 +1118,44 @@ def _latent_out(x, cache: dict, o, layer: dict, c: LlamaConfig, valid):
     return x + mo, _count_picks(cache, picks)
 
 
-def _linear_rows(cache: dict, li, slots=None, fresh=None):
-    """Layer ``li``'s (state, tail) of a linear layer: every slot's, or
-    the rows of ``slots`` [G] (one slice a row, as :func:`_cread_rows`);
-    zeros where ``fresh`` [G]: a request that starts at position 0
-    starts from nothing, whatever its slot's last request left."""
-    if slots is None:
-        state, tail = _clayer(cache["state"], li), _clayer(cache["conv"], li)
-    else:
-        state = _cread_rows(cache["state"], li, slots, None)
-        tail = _cread_rows(cache["conv"], li, slots, None)
+def _state_rows(cache: dict, li, slots=None, fresh=None) -> tuple:
+    """Layer ``li``'s rows of the :data:`_STATES` leaves the cache holds
+    (a linear layer's (state, tail), a conv layer's (tail,)): every
+    slot's, or the rows of ``slots`` [G] (one slice a row, as
+    :func:`_cread_rows`); zeros where ``fresh`` [G]: a request that
+    starts at position 0 starts from nothing, whatever its slot's last
+    request left."""
+    names = [n for n in _STATES if n in cache]
+    rows = [
+        _clayer(cache[n], li) if slots is None
+        else _cread_rows(cache[n], li, slots, None)
+        for n in names
+    ]
     if fresh is not None:
-        state = jnp.where(fresh[:, None, None, None], 0.0, state)
-        tail = jnp.where(fresh[:, None, None], jnp.zeros((), tail.dtype), tail)
-    return state, tail
+        # (the state's zero a Python scalar, the tail's an array's: the
+        # two lower apart by one no-op convert, and stay as PR 42 wrote
+        # them so that its programs' pins stand)
+        rows = [
+            jnp.where(
+                fresh[(slice(None),) + (None,) * (r.ndim - 1)],
+                0.0 if n == "state" else jnp.zeros((), r.dtype), r,
+            )
+            for n, r in zip(names, rows)
+        ]
+    return tuple(rows)
 
 
-def _linear_store(cache: dict, li, slots, live, new) -> dict:
-    """``new`` (state, tail) written in place over layer ``li``'s rows
-    of the stacked leaves: every slot's, or row b into slot
-    ``slots[b]``. A row ``live`` [B] marks dead (a finished slot, a
-    wave's pad row, which carries slot 0) puts back what is there AT
-    ITS TURN: the rows go one after the other, so a pad row behind the
-    real row of the same slot keeps that row's write."""
+def _state_store(cache: dict, li, slots, live, new) -> dict:
+    """``new`` (:func:`_state_rows`' leaves, in its order) written in
+    place over layer ``li``'s rows of the stacked leaves: every slot's,
+    or row b into slot ``slots[b]``. A row ``live`` [B] marks dead (a
+    finished slot, a wave's pad row, which carries slot 0) puts back
+    what is there AT ITS TURN: the rows go one after the other, so a
+    pad row behind the real row of the same slot keeps that row's
+    write."""
     out = dict(cache)
     keep = lambda n, o, on: jnp.where(on.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
-    for name, rows in zip(_STATES, new):
+    for name, rows in zip([n for n in _STATES if n in cache], new):
         buf = cache[name]
         if slots is None:
             rows = keep(rows, _clayer(buf, li), live)
@@ -1154,64 +1172,89 @@ def _linear_store(cache: dict, li, slots, live, new) -> dict:
     return out
 
 
-#: what a verify step keeps of each drafted position of a linear layer
-#: until the count of accepted drafts is known (:func:`_linear_commit`)
-_PENDING = ("pend_k", "pend_v", "pend_g", "pend_b", "pend_pre")
+#: what a verify step keeps of each drafted position of a layer that
+#: holds a state until the count of accepted drafts is known
+#: (:func:`_state_commit`), by the layers' kind: a linear layer's k, v,
+#: g, beta and the convolution's new rows; a conv layer's new rows
+_PENDING = {
+    "linear": ("pend_k", "pend_v", "pend_g", "pend_b", "pend_pre"),
+    "conv": ("pend_u",),
+}
 
 
-def _linear_mixer(c: LlamaConfig, live, slots=None, fresh=None, real=None,
-                  counts=None, commit: bool = True):
+def _state_kind(c: LlamaConfig) -> str:
+    """The kind of the model's layers that hold a slot's past whole
+    (``"linear"`` | ``"conv"``; a model has one such kind at most)."""
+    return "linear" if "linear" in c.layer_types else "conv"
+
+
+def _state_mixer(c: LlamaConfig, live, slots=None, fresh=None, real=None,
+                 counts=None, commit: bool = True):
     """→ ``mix(x, layer, cache, li) -> (y [B, S, P] for wo, cache)``: a
-    linear layer's mixer (``models/kda.py``) over row ``li`` of the
-    cache's ``state`` and ``conv``, read and written in place
-    (:func:`_linear_rows`, :func:`_linear_store`). ``real`` [B, S]: the
+    linear or conv layer's mixer (``models/kda.py``,
+    ``models/shortconv.py``) over row ``li`` of the cache's
+    :data:`_STATES` leaves, read and written in place
+    (:func:`_state_rows`, :func:`_state_store`). ``real`` [B, S]: the
     tokens that move the state, ``counts`` [B] of them a row (None:
     all). ``commit`` false is the verify step's: the state stays, and
     each position's inputs go to the :data:`_PENDING` leaves."""
-    from dstack_tpu.models import kda
+    kind = _state_kind(c)
+    mixer = llama.mixer_of(kind)
 
     def mix(x, layer, cache, li):
         h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-        state, tail = _linear_rows(cache, li, slots, fresh)
+        rows = _state_rows(cache, li, slots, fresh)
         if commit:
-            y, state, tail = kda.mix(h, layer, c, state, tail, real, counts)
-            return y, _linear_store(cache, li, slots, live, (state, tail))
-        y, _, inputs = kda.mix_parts(h, layer, c, state, tail)
+            y, *rows = mixer.mix(h, layer, c, *rows, real, counts)
+            return y, _state_store(cache, li, slots, live, rows)
+        y, *_, inputs = mixer.mix_parts(h, layer, c, *rows)
         cache = {**cache, **{
             n: jax.lax.dynamic_update_index_in_dim(cache[n], a, li, 0)
-            for n, a in zip(_PENDING, inputs)
+            for n, a in zip(_PENDING[kind], inputs)
         }}
         return y, cache
 
     return mix
 
 
-def _linear_pending(cache: dict, c: LlamaConfig, s: int) -> dict:
+def _state_pending(cache: dict, c: LlamaConfig, s: int) -> dict:
     """``cache`` with zeroed :data:`_PENDING` leaves for ``s`` positions."""
-    n, b, nh, d, _ = cache["state"].shape
+    n, b, _, width = cache["conv"].shape
+    new_rows = lambda: jnp.zeros((n, b, s, width), cache["conv"].dtype)
+    if "state" not in cache:
+        return {**cache, "pend_u": new_rows()}
+    nh, d = cache["state"].shape[2:4]
     f32 = jnp.float32
     return {
         **cache,
-        **{k: jnp.zeros((n, b, s, nh, d), f32) for k in _PENDING[:3]},
+        **{k: jnp.zeros((n, b, s, nh, d), f32) for k in _PENDING["linear"][:3]},
         "pend_b": jnp.zeros((n, b, s, nh), f32),
-        "pend_pre": jnp.zeros((n, b, s, cache["conv"].shape[-1]), cache["conv"].dtype),
+        "pend_pre": new_rows(),
     }
 
 
-def _linear_commit(cache: dict, n_tokens, write_mask, c: LlamaConfig) -> dict:
-    """The verify step's second half for the linear layers: each live
-    slot's state and tail advanced by its first ``n_tokens`` [B]
-    positions (the last token and the accepted drafts) and by no
+def _state_commit(cache: dict, n_tokens, write_mask, c: LlamaConfig) -> dict:
+    """The verify step's second half for the layers that hold a state:
+    each live slot's state and tail advanced by its first ``n_tokens``
+    [B] positions (the last token and the accepted drafts) and by no
     rejected one → the cache without the :data:`_PENDING` leaves."""
     from dstack_tpu.models import kda
 
-    pend = [cache[k] for k in _PENDING]
-    cache = {k: v for k, v in cache.items() if k not in _PENDING}
+    names = _PENDING[_state_kind(c)]
+    pend = [cache[k] for k in names]
+    cache = {k: v for k, v in cache.items() if k not in names}
+    if "state" not in cache:  # a tail alone: every layer's at once
+        with jax.named_scope("dtpu.conv.tail"):
+            tail = jax.vmap(kda.next_tail, in_axes=(0, 0, None))(
+                pend[0], cache["conv"], n_tokens
+            )
+        keep = write_mask[None, :, None, None]
+        return {**cache, "conv": jnp.where(keep, tail, cache["conv"])}
     real = jnp.arange(pend[0].shape[2])[None, :] < n_tokens[:, None]  # [B, S]
 
     def one(cache, xs):
         li, k, v, g, beta, pre = xs
-        state, tail = _linear_rows(cache, li)
+        state, tail = _state_rows(cache, li)
         with jax.named_scope("dtpu.linear.state"):
             _, state = kda.rule(
                 jnp.zeros_like(k), k, v,
@@ -1219,7 +1262,7 @@ def _linear_commit(cache: dict, n_tokens, write_mask, c: LlamaConfig) -> dict:
                 jnp.where(real[..., None], beta, 0.0), state,
             )
         tail = kda.next_tail(pre, tail, n_tokens)
-        return _linear_store(cache, li, None, write_mask, (state, tail)), None
+        return _state_store(cache, li, None, write_mask, (state, tail)), None
 
     cache, _ = jax.lax.scan(one, cache, (jnp.arange(pend[0].shape[0]), *pend))
     return cache
@@ -1239,7 +1282,7 @@ def _latent_layer(attend, c: LlamaConfig, valid, mix=None):
     n = c.sublayers
 
     def one_layer(x, layer, cache, li, run):
-        if run.kind == "linear":  # ``mix``: the program's :func:`_linear_mixer`
+        if run.kind == "linear":  # ``mix``: the program's :func:`_state_mixer`
             y, cache = mix(x, layer, cache, li)
             return _latent_out(x, cache, y, layer, c, valid)
         if n == 1:
@@ -1409,7 +1452,7 @@ def _prefill_chunk_mla(
 
     mix = None
     if "state" in cache:  # a chunk at position 0 starts from no state
-        mix = _linear_mixer(
+        mix = _state_mixer(
             c, jnp.ones((1,), bool), si[None], jnp.full((1,), start == 0),
             (jnp.arange(cl) <= last_ix)[None], (last_ix + 1)[None],
         )
@@ -1535,7 +1578,7 @@ def _decode_step_mla(
 
     mix = None
     if "state" in cache:  # a dead slot's state and tail stay
-        mix = _linear_mixer(c, write_mask, real=write_mask[:, None])
+        mix = _state_mixer(c, write_mask, real=write_mask[:, None])
     x, cache = _mla_layers_inplace(
         params, cache, x, _latent_layer(attend, c, valid, mix), c
     )
@@ -1600,15 +1643,15 @@ def _verify_step_mla(
         # a rejected draft must not have moved a state: the layers read
         # theirs and keep every position's inputs, and the states are
         # advanced once the logits say how many drafts stand
-        mix = _linear_mixer(c, write_mask, commit=False)
-        cache = _linear_pending(cache, c, sdraft)
+        mix = _state_mixer(c, write_mask, commit=False)
+        cache = _state_pending(cache, c, sdraft)
     x, cache = _mla_layers_inplace(
         params, cache, x, _latent_layer(attend, c, valid, mix), c
     )
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     logits = _head_logits(params, x, c, eq="bse,ev->bsv")
     if mix is not None:
-        cache = _linear_commit(
+        cache = _state_commit(
             cache, _tokens_standing(logits, tokens, draft_len), write_mask, c
         )
     return logits, cache
@@ -1840,6 +1883,18 @@ def _attend_rows(
     return o.transpose(0, 3, 1, 2, 4).reshape(b, s, nh * d)
 
 
+def _group_out(x, cache: dict, o, layer: dict, gc: LlamaConfig, valid):
+    """The common tail of a layer of a grouped-query model of groups in
+    the programs that carry the cache (prefill, verify): ``o`` (the
+    attention's output, or a conv layer's mixed rows) through
+    :func:`_dense_out`, the routing counts into the cache → (x, cache)."""
+    stats = _moe_stats(cache)
+    if stats is None:
+        return _dense_out(x, o, layer, gc), cache
+    x, stats = _dense_out(x, o, layer, gc, stats, valid)
+    return x, _with_moe_stats(cache, stats)
+
+
 def _prefill_packed_groups(
     params: dict,
     cache: dict,
@@ -1872,10 +1927,34 @@ def _prefill_packed_groups(
         positions=starts, write_mask=last_ix >= 0, slots=si, counts=last_ix + 1,
         axis=1, unroll=_tokens_on_lanes(c.head_dim), opaque_loop=True,
     )
+    # a lone row on a full layer's leaf with its tokens on the lanes
+    # (head_dim 64): the block form of ONE row, bare or in a loop, makes
+    # the compiler re-lay the whole leaf out around the layer scans
+    # (device-free: ``temp`` 1.6 GB beside three layers' K/V); the whole
+    # chunk as one ``dynamic_update_slice`` at the row's start, the
+    # serial chunk's form, holds in place. What it writes past the row's
+    # real tokens is the masked future; it needs the chunk to lie inside
+    # the row, as the engine's chunk starts do where ``max_seq`` is whole
+    # chunks
+    lone_chunk = (
+        g == 1 and "k" in cache and _tokens_on_lanes(c.head_dim)
+        and cache["k"].shape[3] % cl == 0
+    )
+    mix = None
+    if "conv" in cache:
+        # padded positions and pad rows leave a tail untouched; a row at
+        # position 0 starts from none
+        mix = _state_mixer(
+            c, last_ix >= 0, si, (starts == 0) & (last_ix >= 0), valid,
+            last_ix + 1,
+        )
 
     def one_layer(carry, layer, li, run):
         x, cache = carry
         gc = run.config
+        if run.kind == "conv":
+            y, cache = mix(x, layer, cache, li)
+            return _group_out(x, cache, y, layer, gc, valid), None
         cos, sin = llama.layer_rope(ropes, c, run.window)
         q, k, v = _dense_in(
             x, layer, gc,
@@ -1885,7 +1964,10 @@ def _prefill_packed_groups(
         write = _cwrite_ring if run.window else _cwrite_rows
         rows = []  # the wave's rows of the run's buffers, [G, Hkv, T, D] each
         for name, new in zip(_group_kv(run), (k, v)):
-            buf = write(cache[name], li, new=new, **put)
+            if lone_chunk and not run.window:
+                buf = _cwrite_chunk(cache[name], li, si[0], starts[0], new)
+            else:
+                buf = write(cache[name], li, new=new, **put)
             cache = {**cache, name: buf}
             rows.append(_cread_rows(buf, li, si, new.dtype))
         t = rows[0].shape[2]
@@ -1895,11 +1977,7 @@ def _prefill_packed_groups(
             else:
                 mask = jnp.arange(t)[None, None, :] <= pos_grid[:, :, None]
             o = _attend_rows(q, *rows, mask, gc, jnp.minimum(newest + 1, t))
-        stats = _moe_stats(cache)
-        if stats is None:
-            return (_dense_out(x, o, layer, gc), cache), None
-        x, stats = _dense_out(x, o, layer, gc, stats, valid)
-        return (x, _with_moe_stats(cache, stats)), None
+        return _group_out(x, cache, o, layer, gc, valid), None
 
     (x, cache), _ = _walk_layer_groups(params, (x, cache), one_layer, c)
     x = model_norm(x, params["final_norm"], c)
@@ -2098,7 +2176,7 @@ def _prefill_packed_mla(
     if "state" in cache:
         # padded positions and pad rows leave state and tail untouched;
         # a row at position 0 starts from no state
-        mix = _linear_mixer(
+        mix = _state_mixer(
             c, last_ix >= 0, si, (starts == 0) & (last_ix >= 0), valid,
             last_ix + 1,
         )
@@ -2455,14 +2533,23 @@ def _decode_step_groups(
     against its kind's buffers, a window layer's a ring (the einsum over
     its few rows; a full layer's row buffer by the rule, like a model
     of one kind); every scan reads the cache, and each buffer takes its
-    runs' new rows in one block write a run after them (PR 25's form)."""
+    runs' new rows in one block write a run after them (PR 25's form).
+    A conv layer (``models/shortconv.py``) reads its tail and hands the
+    tail after the token out the same way (a dead slot's as it was)."""
+    from dstack_tpu.models import shortconv
     from dstack_tpu.models.llama import dual_rope_freqs
 
     x = _embed_lookup(params, tokens, c)[:, None, :]
     ropes = dual_rope_freqs(c, positions)  # ([B, D/2] each) full, window
+    live = write_mask[:, None]
 
     def one_layer(carry, layer, li, run):
         x, stats = carry
+        if run.kind == "conv":
+            h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+            y, tail = shortconv.mix(h, layer, c, *_state_rows(cache, li), live)
+            out = _dense_out(x, y, layer, c, stats, live)
+            return (out if stats is not None else (out, None)), (tail,)
         cos, sin = llama.layer_rope(ropes, c, run.window)
         nk, nv = _group_kv(run)
         out, rows = _decode_layer(
@@ -2479,9 +2566,18 @@ def _decode_step_groups(
     )
     cache = _with_moe_stats(dict(cache), stats)
     for run, first, rows in written:
+        if run.kind == "conv":  # the run's tails, whole
+            with jax.named_scope("dtpu.conv.tail"):
+                cache["conv"] = jax.lax.dynamic_update_slice_in_dim(
+                    cache["conv"], rows[0], first, 0
+                )
+            continue
         for name, new in zip(_group_kv(run), rows):
             at = _ring_row(positions, cache[name]) if run.window else positions
-            cache[name] = _cwrite_rows(cache[name], first, at, write_mask, new)
+            cache[name] = _cwrite_rows(
+                cache[name], first, at, write_mask, new,
+                unroll=_tokens_on_lanes(c.head_dim),
+            )
     x = model_norm(x, params["final_norm"], c)
     return _head_logits(params, x[:, 0], c), cache
 
@@ -2572,7 +2668,7 @@ def verify_step(
     write_mask: jax.Array,  # [B] bool
     decode_kernel: Optional[str] = None,  # "flash": the kernel, where asked for
     mesh=None,
-    draft_len=None,  # [B] int32: the drafts a row holds (linear layers)
+    draft_len=None,  # [B] int32: the drafts a row holds (layers that hold a state)
 ) -> tuple[jax.Array, dict]:
     """Multi-token decode for speculative verification → (logits
     [B, S, V], cache).
@@ -2598,7 +2694,7 @@ def verify_step(
         )
     if c.layer_types:
         return _verify_step_groups(
-            params, cache, tokens, positions, c, write_mask
+            params, cache, tokens, positions, c, write_mask, draft_len
         )
     b, sdraft = tokens.shape
     x = _embed_lookup(params, tokens, c)  # [B, S, H]
@@ -2648,7 +2744,7 @@ def verify_step(
 def _verify_layer(
     x, layer: dict, li, c: LlamaConfig, ck, cv, positions, pos_grid, write_mask,
     rope, nope, temp, window, ring=None, stats=None,
-    decode_kernel: Optional[str] = None, mesh=None,
+    decode_kernel: Optional[str] = None, mesh=None, unroll: bool = False,
 ):
     """One dense layer of a verify step, the one copy → (x, ck, cv[,
     stats]): the S tokens' K/V written at their per-row positions into
@@ -2660,8 +2756,8 @@ def _verify_layer(
     b, sdraft = pos_grid.shape
     q, k, v = _dense_in(x, layer, c, rope, nope, temp)
     if ring is None:
-        ck = _cwrite_rows(ck, li, positions, write_mask, _cstored(k, ck))
-        cv = _cwrite_rows(cv, li, positions, write_mask, _cstored(v, cv))
+        ck = _cwrite_rows(ck, li, positions, write_mask, _cstored(k, ck), unroll=unroll)
+        cv = _cwrite_rows(cv, li, positions, write_mask, _cstored(v, cv), unroll=unroll)
     else:
         ck = _cwrite_ring(ck, li, positions, write_mask, k, axis=1, unroll=False)
         cv = _cwrite_ring(cv, li, positions, write_mask, v, axis=1, unroll=False)
@@ -2697,11 +2793,15 @@ def _verify_layer(
 
 
 def _verify_step_groups(
-    params: dict, cache: dict, tokens, positions, c: LlamaConfig, write_mask
+    params: dict, cache: dict, tokens, positions, c: LlamaConfig, write_mask,
+    draft_len=None,  # [B]: drafts a row holds (a model with conv layers)
 ) -> tuple[jax.Array, dict]:
     """:func:`verify_step` of a grouped-query model of layer GROUPS:
     :func:`_verify_layer` at each run's attention shape, the buffers the
-    carry of every scan."""
+    carry of every scan. A conv layer's tail, unlike a cache row, cannot
+    be masked later: the layers read theirs and keep every position's
+    new rows, and the tails are advanced once the logits say how many
+    drafts stand (as the latent family's states, :func:`_state_commit`)."""
     from dstack_tpu.models.llama import dual_rope_freqs
 
     b, sdraft = tokens.shape
@@ -2711,9 +2811,17 @@ def _verify_step_groups(
         lambda a: a.reshape(b, sdraft, a.shape[-1]),
         dual_rope_freqs(c, pos_grid.reshape(-1)),
     )
+    mix = None
+    if "conv" in cache:
+        mix = _state_mixer(c, write_mask, commit=False)
+        cache = _state_pending(cache, c, sdraft)
 
     def one_layer(carry, layer, li, run):
         x, cache = carry
+        if run.kind == "conv":
+            y, cache = mix(x, layer, cache, li)
+            valid = jnp.broadcast_to(write_mask[:, None], (b, sdraft))
+            return _group_out(x, cache, y, layer, c, valid), None
         cos, sin = llama.layer_rope(ropes, c, run.window)
         nk, nv = _group_kv(run)
         stats = _moe_stats(cache)
@@ -2723,6 +2831,7 @@ def _verify_step_groups(
             lambda t: _rope_rows(t, cos, sin, interleaved=c.rope_interleaved),
             False, None, run.window,
             positions + (sdraft - 1) if run.window else None, stats,
+            unroll=_tokens_on_lanes(c.head_dim),
         )
         if stats is not None:
             cache = _with_moe_stats(cache, rest.pop(0))
@@ -2730,7 +2839,12 @@ def _verify_step_groups(
 
     (x, cache), _ = _walk_layer_groups(params, (x, cache), one_layer, c)
     x = model_norm(x, params["final_norm"], c)
-    return _head_logits(params, x, c, eq="bse,ev->bsv"), cache
+    logits = _head_logits(params, x, c, eq="bse,ev->bsv")
+    if mix is not None:
+        cache = _state_commit(
+            cache, _tokens_standing(logits, tokens, draft_len), write_mask, c
+        )
+    return logits, cache
 
 
 def sample(
@@ -3033,8 +3147,13 @@ class InferenceEngine:
         # lone row's too, goes through the packed program at the full
         # chunk width, so its prefill grid is one program a G bucket
         # (a start a program and a bucket a tail would be ~35 programs
-        # of ~10 s each at every cold boot)
-        self._packed_only = bool(_masked(config, max_seq))
+        # of ~10 s each at every cold boot). So has a grouped-query model
+        # of layer groups without a window: its serial chunk IS a packed
+        # wave of one row (:func:`_prefill_packed_groups`), and a program
+        # a start would hold the same text sixteen times over
+        self._packed_only = bool(
+            _masked(config, max_seq) or config.layer_types and not config.mla
+        )
         # a chip's share of the experts counts its routing on the device
         # (cache["moe_stats"], ["moe_reads"]); the last reading, to
         # publish differences
@@ -3101,10 +3220,13 @@ class InferenceEngine:
         self.metrics.family("dtpu_serve_kv_window_pool_percent").set(
             100.0 * sum(b for n, b in size.items() if _is_ring(n)) / total
         )
-        # linear layers: a state and a convolution tail a slot, sized by
-        # the heads and not by max_seq; nothing of theirs is addressed by
-        # position, so no prefix of a slot can serve another request
-        self._linear_layers = config.layer_types.count("linear")
+        # linear and conv layers: a state and / or a convolution tail a
+        # slot, sized by the widths and not by max_seq; nothing of
+        # theirs is addressed by position, so no prefix of a slot can
+        # serve another request
+        self._state_layers = sum(
+            config.layer_types.count(k) for k in ("linear", "conv")
+        )
         self.metrics.family("dtpu_serve_state_cache_percent").set(
             100.0 * sum(size.get(n, 0) for n in _STATES) / total
         )
@@ -3187,9 +3309,9 @@ class InferenceEngine:
         # chunks. Chunk alignment keeps the (C, start) compile grid
         # unchanged — a reused prefix resumes mid-grid, no new kernels.
         # a state at a shared prefix's end exists only if it was kept
-        # there, and none is (PERF.md §7): a model with linear layers
-        # prefills every prompt whole, and its prefix counters stay 0
-        self.prefix_cache = prefix_cache and not self._linear_layers
+        # there, and none is (PERF.md §7): a model with linear or conv
+        # layers prefills every prompt whole, and its prefix counters stay 0
+        self.prefix_cache = prefix_cache and not self._state_layers
         self._prefix_registry: dict[int, list] = {}  # slot → prompt ids
         self._copy_fns: dict = {}  # p → jitted copy_cache_prefix
         self.prefix_hits = 0
@@ -3423,7 +3545,7 @@ class InferenceEngine:
             reuse_len, src = 0, None
         self._prefix_registry.pop(slot, None)  # rows about to be overwritten
         start = 0
-        if self._linear_layers:
+        if self._state_layers:
             # the slot's last request's state goes: the first chunk, at
             # position 0, starts from zeros on the device
             self.metrics.family("dtpu_serve_state_resets_total").inc(1)
@@ -3971,10 +4093,10 @@ class InferenceEngine:
             row = [self.last_token[i]] + d
             row = row + [0] * (sdraft - len(row))
             rows.append(row[:sdraft])
-        # linear layers advance their state in the call, by the drafts
+        # layers that hold a state advance it in the call, by the drafts
         # that stand: the program has to know how many a row holds
         held = {}
-        if self._linear_layers:
+        if self._state_layers:
             # dtpu: noqa[DTPU002] the drafts' lengths are this call's own host data, uploaded with its rows (B int32)
             held["draft_len"] = jnp.asarray(
                 [len(drafts.get(i, [])) for i in range(self.max_batch)], jnp.int32

@@ -416,17 +416,21 @@ def new_serve_registry() -> Registry:
         "(sized by the window, not by max_seq); 0 for a model of one "
         "kind of layer",
     )
-    # linear-attention layers: a state a slot beside the rows
+    # layers that hold a slot's past whole (linear-attention layers: a
+    # recurrent state and a convolution tail; gated short-convolution
+    # layers: a tail) beside the rows
     r.gauge(
         "dtpu_serve_state_cache_percent",
-        "Of the cache's bytes, the share held by the linear layers' "
-        "recurrent states and convolution tails (sized by the heads, "
-        "not by max_seq); 0 for a model without linear layers",
+        "Of the cache's bytes, the share without a token axis: the "
+        "linear layers' recurrent states and the linear and conv "
+        "layers' convolution tails (sized by the widths, not by "
+        "max_seq); 0 for a model without such layers",
     ).set(0)
     r.counter(
         "dtpu_serve_state_resets_total",
-        "Requests that started on a model with linear layers: each "
-        "starts its slot's states from zeros at position 0",
+        "Slots started from zeros: requests that started on a model "
+        "with linear or conv layers, each at position 0 from no state "
+        "and no tail",
     ).inc(0)
     # group-limited routing with a chip's share of whole groups
     r.counter(

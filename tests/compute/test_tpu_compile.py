@@ -551,6 +551,7 @@ def _serving_program(case, sds, place):
         "gqa_groups": (_layer_groups_config("laguna-s-2.1-13l-ep8"), 8192, None),
         "scmoe_zero": (_layer_groups_config("longcat-flash-chat-4l-ep32"), 8192, None),
         "linear_state": (_layer_groups_config("ling-3.0-flash-vl-13l-ep8"), 8192, None),
+        "conv_gqa": (_layer_groups_config("lfm2-24b-a2b-ep8"), 8192, None),
     }[model]
     config = config or _layer_groups_config()
     params = place(jax.eval_shape(
@@ -863,6 +864,59 @@ def test_linear_state_program_updates_the_state_in_place(topo, _as_tpu, case):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.1e9 * _LINEAR_STATE[case], f"temp {temp / 1e9:.3f} GB"
     _fits(compiled)
+
+
+# the rag cell (PR 44): 16 × 8192, ten full layers' K/V [10, 16, 8, 8192, 64]
+# (2 x 1.342 GB, head_dim 64: their tokens on the lanes) beside thirty conv
+# layers' tails [30, 16, 2, 2048] (3.9 MB), 7.29 GB of weights, forty layers
+# walked by periods. case → ``temp`` on PR 44's tree, GB
+_CONV_GQA = {
+    "decode_step-conv_gqa": 0.026,
+    "decode_loop-conv_gqa": 0.152,
+    "verify_step-conv_gqa": 0.299,
+    "prefill_packed_step@1-conv_gqa": 0.019,
+    "prefill_packed_step@2-conv_gqa": 0.009,
+    "prefill_packed_step@4-conv_gqa": 0.057,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CONV_GQA))
+def test_conv_gqa_program_holds_no_second_cache(topo, _as_tpu, case):
+    """Every serving program of the whole-depth conv | grouped-query
+    model fits the chip beside its 10 GB of arguments and moves neither
+    a K/V leaf nor a layer's slice of one, nor an expert stack. At
+    head_dim 64 the K/V leaves lie with their tokens on the lanes, and
+    three forms the wider heads take re-laid BOTH leaves out whole
+    (device-free, this tree before its three cures: decode_step ``temp``
+    2.69 GB, decode_loop 5.50, verify_step 5.39, a lone row's wave 5.39:
+    15.4 GB in all, and 5.4 GB of copies a token step): the slots'
+    block writes in a loop (now unrolled, decode and verify), and ONE
+    row's block write in a wave (now the whole chunk as one
+    ``dynamic_update_slice``, the serial chunk's form). The walk by
+    periods holds the programs at seven loops or fewer however deep the
+    model (21 runs unrolled would be 21 layer scans)."""
+    sharding = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    fn, args, cache = _serving_program(case, sds, place)
+    compiled = _compile(fn, *args, donate_argnums=(1,))
+    sized = lambda leaves: (
+        {leaf.shape for leaf in leaves},
+        {sh for leaf in leaves for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])},
+    )
+    experts = [
+        a for stack in ("layers", "conv_layers") for n, a in args[0][stack].items()
+        if n in ("w_gate", "w_up", "w_down") and a.ndim == 4
+    ]
+    hlo = compiled.as_text()
+    assert not _cache_sized_moves(hlo, *sized(experts))
+    assert not _cache_sized_moves(hlo, *sized([cache["k"]]))
+    assert not _has_kernel(compiled)  # head_dim 64: the einsum over reserved rows
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.1e9 * _CONV_GQA[case] + 5e6, f"temp {temp / 1e9:.3f} GB"
+    assert _fits(compiled) < 10.4e9
+    # layer scans: prelude, period (and its two runs), tail's two, writes
+    assert hlo.count(" while(") <= 8
 
 
 # ---------------------------------------------------------------------------

@@ -81,6 +81,14 @@ CELLS = {
     # latent model: the seven programs of ``NAMES``; its verify step
     # takes the drafts a row holds (``draft_len``), as the engine calls it
     "ling-3.0-flash-vl-13l-ep8": (16, 8192, NAMES),
+    # taken on the tree of PR 44, which added the configuration, the
+    # gated short-convolution mixer (``models/shortconv.py``) and the
+    # grouped-query walk's tail a slot beside K/V rows that the full
+    # layers alone hold; the 100 pins above and below stood but three of
+    # ``gqa-groups`` (below). A grouped-query model of layer groups
+    # prefills by the packed program alone (``engine._packed_only``, with
+    # or without a window since PR 44): the six programs of ``_GROUPS``
+    "lfm2-24b-a2b-ep8": (16, 8192, _GROUPS),
 }
 #: The three configurations that hold a SHARE of their experts (longdoc,
 #: mixed, agent: 6 + 6 + 7 digests) and the tiny family ``gqa-groups``
@@ -128,6 +136,14 @@ CELL_PINS = {
         "prefill_chunk_step@256": "80d97c4da1c3e1be6813c67f8598b5f08594e50cfd016c81fb2237403e458026",
         "prefill_packed_step@2": "eeff4be09d59197cf36c431f66e9bfb59fafd4334f80040bfe3e318dec76f6ce",
         "prefill_packed_step@4": "58669b78efa6c435462dc49bbb024e5b374ad5ea176776db733f768b39cb4d5a",
+    },
+    "lfm2-24b-a2b-ep8": {
+        "decode_step": "0108a966b4079ac3d5ad143f591254076147c8221b05da62134de39901916747",
+        "decode_loop": "b3d616e305274bdacda4b7cf03b36659011323e13fea403c0d7c72d2f3490a04",
+        "verify_step": "831769435377710277fbe4b0f23043ff4d1f2060c38c2d2bda9a9286ee306a5a",
+        "prefill_packed_step@1": "3dd39d9e6936c07c7ec7b3948341a70250fbe7220133261eef9a9d66e093e87a",
+        "prefill_packed_step@2": "f0294c4a5685eb1ca4a64a50fc6cb3a304a98dc89fb7e9cfe10160bddb6d4a6a",
+        "prefill_packed_step@4": "21b94636920f6d61685f53211baf4fa765e0d544786fcbd440c97c6590fc89ee",
     },
     "minitron-4b": {
         "decode_step": "31cd7802fdfa5729183b1aa6346316af5f0a7b1d5a845041033888da8aa84ab7",
@@ -217,7 +233,18 @@ FAMILIES = {
 #: ``linear-tiny`` (PR 42): linear-attention layers beside latent ones in
 #: two periods behind a linear dense prelude, group-limited routing with
 #: one group of four held
-TINY = tuple(sorted(FAMILIES)) + ("mla-tiny", "moe-tiny", "scmoe-tiny", "linear-tiny")
+#: ``conv-tiny`` (PR 44): gated short-convolution layers beside
+#: grouped-query ones in two periods behind a conv dense prelude, q/k
+#: norms, a tied head, half of the experts held. With it ``gqa-groups``'
+#: ``decode_step``, ``verify_step`` and ``prefill_chunk_step@16`` were
+#: taken again on purpose, and no other: at a head_dim that leaves its
+#: K/V leaves with their tokens on the lanes (32 there, 64 in the rag
+#: cell) a model of layer groups writes a step's rows unrolled and a
+#: lone row's chunk as one ``dynamic_update_slice`` (the mixed cell's
+#: head_dim is 128: its six pins stand)
+TINY = tuple(sorted(FAMILIES)) + (
+    "mla-tiny", "moe-tiny", "scmoe-tiny", "linear-tiny", "conv-tiny",
+)
 TINY_NAMES = (
     "decode_step", "verify_step", "prefill_chunk_step@16", "prefill_packed_step@2",
 )
@@ -284,9 +311,9 @@ TINY_PINS = {
         "prefill_packed_step@2": "7b42bbab4e82ee37d5d454b1bdfae798046ee7d0a48557ff405556a4dbf5eec6",
     },
     "gqa-groups": {
-        "decode_step": "f69b69ed7b130d5e8cb2060e04c811d480354bf32b41752e8813e65f8739b61e",
-        "verify_step": "be7107a253287db64984c8aa486d6ff2a21804017078636a9b5005612169923f",
-        "prefill_chunk_step@16": "4ffd6999e5ed466307336b9b283ac510922f37ea7897045a7370c75b6c3a25c3",
+        "decode_step": "446225fb2a83b956e11b7fb1c25a8e7f24c79f0bf38708540b7572c7802f7f50",
+        "verify_step": "0112d66685934c9ee871d89f1bba439975dc79f11eaa0fff3da8ae45654fd03c",
+        "prefill_chunk_step@16": "e5977842577cac93e7d58dcb42946111b5943d96a295aaec7ef28d45d02f65af",
         "prefill_packed_step@2": "a72fe2af95da0bc050c70b77d610a4f5321fb91dd07ddd87716f12849996568e",
     },
     "mla-tiny": {
@@ -306,6 +333,12 @@ TINY_PINS = {
         "verify_step": "9650fb4357b2d327717113cfd2594484ddc11960d52d823b986a02db270d5381",
         "prefill_chunk_step@16": "d7dadee9a22dedb94a4c1a69d0188752e69709d79cb1ff87de560838958f126d",
         "prefill_packed_step@2": "7d8692bad7b323f39e503c39473bfcc467490c828584bcc409672ddfa3a77e83",
+    },
+    "conv-tiny": {
+        "decode_step": "0b0e57c1041e611e243990cb86f81009e80cef5c27cbaee14d87cb0e1e03d571",
+        "verify_step": "5b9d5ac4f0b6f2529426c9532064d623f78b66b75571b7801d488cf9cda870c1",
+        "prefill_chunk_step@16": "96fdde80fbe29401460aa89cf3bb15981600b8f6809e4875e8a6d069d6ff2d83",
+        "prefill_packed_step@2": "3c17d38e5daf5b3d4302665a6ab3d4e474f8214d8c8632e4f728c16e2684bedc",
     },
     "moe-tiny": {
         "decode_step": "a38072b4a98bfd0c163d6cec124ee7e5712705874e59bee3e100c69fdb1838bf",
@@ -354,7 +387,7 @@ def _lower(c, name: str, B: int, T: int, chunk: int = 256, S: int = 5):
         fn = partial(E.verify_step, config=c)
         args = (params, cache, i32(B, S), i32(B))
         kw = {"write_mask": flag}
-        if "linear" in c.layer_types:  # the states advance by the drafts that stand
+        if {"linear", "conv"} & set(c.layer_types):  # the states advance by the drafts that stand
             kw["draft_len"] = i32(B)
     elif name.startswith("prefill_chunk_step"):
         fn = partial(E.prefill_chunk_step, config=c, start=int(name.split("@")[1]))
