@@ -27,6 +27,20 @@ about 2 MiB of K and V), since a step costs about half a microsecond
 whatever it moves (PERF.md §6, PR 43: 2.9 µs a live step of 2 MiB,
 724 GB/s over whole rows, 0.44-0.55 µs a step that moves nothing).
 
+The blocks have TWO forms, taken by how the leaf lies in device memory
+(:func:`tokens_on_lanes`, no flag). A head that fills the 128 lanes
+(``D`` % 128 == 0) lies as it is declared and a block is ``[Hkv, BK,
+D]``: scores ``q · kᵀ``, values ``p · v``. A narrower head (``D`` 64)
+lies ``[.., D, T]``, its TOKENS on the lanes, and a Mosaic operand is
+taken in the row-major order of its logical shape: fed that leaf as
+declared, the first form makes the compiler re-lay both leaves out
+whole, a token step (device-free: ``temp`` 2.1-5.5 GB). So the kernel
+is handed ``swapaxes(leaf, -1, -2)``, the order the leaf already has
+(a bitcast in the compiled program), and a block is ``[Hkv, D, BK]``
+with the keys on the lanes: scores ``q · k``, values ``p · vᵀ`` (the
+shape ``q · kᵀ`` has in the first form), the same running softmax,
+masks, index map and scales (which lie ``[.., Hkv, T]`` either way).
+
 Supported in-kernel (mirroring the einsum's semantics):
 - GQA grouping: q arrives ``[B, Hkv, G, D]``, the cache is streamed
   once at KV width (no G× read amplification).
@@ -79,6 +93,17 @@ def block_keys(n_kv_heads: int, head_dim: int, rows: int, itemsize: int = 2) -> 
     return bk
 
 
+def tokens_on_lanes(width: int) -> bool:
+    """Whether a cache leaf [..., T, width] lies in device memory with
+    its TOKENS on the 128 lanes: the TPU compiler's own choice for a
+    minor axis that does not fill them (head_dim 64, the latent's 576,
+    a window latent's 1088), since it wastes no lane. What holds in
+    place on such a leaf is the other form of what holds on a leaf
+    with its width on the lanes (head_dim 128): this kernel's blocks,
+    and the engine's writes (``serve/engine.py``)."""
+    return width % 128 != 0
+
+
 def _live_blocks(pos, win, held, block_k: int, num_k: int):
     """(first, last) key block a slot at query position ``pos`` reads,
     its cache holding the keys below ``held``, under window ``win`` (0 =
@@ -97,7 +122,7 @@ def _decode_kernel(
     tail_ref,  # SMEM [B] int32: the slot whose block this slot's steps past its keys request (index map only)
     meta_ref,  # SMEM [2] int32: the layer's row of the stack, the sliding window (0 = full)
     q_ref,  # [1, Hkv, R, D]
-    k_ref,  # [1, 1, Hkv, BK, D] compute dtype or int8
+    k_ref,  # [1, 1, Hkv, BK, D] compute dtype or int8 ([1, 1, Hkv, D, BK]: ``keys_minor``)
     v_ref,
     *rest,  # optional (kn_ref, vn_ref [1, Hkv, 1, D]), optional (ks_ref, vs_ref [1, 1, Hkv, BK] f32), optional (sink_ref [Hkv, R, 1] f32), then o_ref + scratch
     scale: float,
@@ -108,6 +133,7 @@ def _decode_kernel(
     quantized: bool,
     sinks: bool,
     rows_per_slot: int,
+    keys_minor: bool,
 ):
     from jax.experimental import pallas as pl
 
@@ -179,15 +205,18 @@ def _decode_kernel(
             keep, jnp.logical_or(win == 0, qpos - cols < win)
         )
         q = q_ref[0]  # [Hkv, R, D]
-        k = k_ref[0, 0]  # [Hkv, BK, D]
+        k = k_ref[0, 0]  # [Hkv, BK, D], or [Hkv, D, BK] with the keys on the lanes
         v = v_ref[0, 0]
+        # the axis of a block the keys lie on: q · kᵀ and p · v where it
+        # is the rows, q · k and p · vᵀ where it is the lanes
+        k_d, v_t = (1, 2) if keys_minor else (2, 1)
         if quantized:
             # int8 values are exact in the compute dtype; the
             # per-token scales multiply the scores and the
             # probabilities (a row of lanes a head), not K and V
             k, v = k.astype(q.dtype), v.astype(q.dtype)
         s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+            q, k, (((2,), (k_d,)), ((0,), (0,))), preferred_element_type=jnp.float32
         )  # [Hkv, R, BK] f32
         if quantized:
             s = s * ks_ref[0, 0][:, None, :]
@@ -203,7 +232,7 @@ def _decode_kernel(
         if quantized:
             p = p * vs_ref[0, 0][:, None, :]
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            p.astype(v.dtype), v, (((2,), (v_t,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
@@ -313,6 +342,12 @@ def _flash_decode(
     b, hkv, g, d = q.shape
     t, bk = k.shape[3], block_k
     num_k = t // bk
+    # a leaf whose width does not fill the lanes lies [.., D, T] in
+    # device memory: asked for in that order, the transposition is a
+    # bitcast and the kernel's blocks are slices of the leaf where it is
+    keys_minor = tokens_on_lanes(d)
+    if keys_minor:
+        k, v = jnp.swapaxes(k, 3, 4), jnp.swapaxes(v, 3, 4)
     quantized = k_scale is not None
     new_row = k_new is not None
     held_off = rows_per_slot - (1 if new_row else 0)
@@ -328,8 +363,9 @@ def _flash_decode(
     before = jnp.maximum(jax.lax.cummax(jnp.where(has, slot, -1)), 0)
     tail = jnp.where(after < b, after, before).astype(jnp.int32)
 
-    def _kv_ix(bi, ki, pos_ref, tail_ref, meta_ref):
-        # the kernel's `live` range: leading out-of-window blocks clamp
+    def key_ix(bi, ki, pos_ref, tail_ref, meta_ref):
+        # (layer, slot, head, key block) a grid step asks for, by the
+        # kernel's `live` range: leading out-of-window blocks clamp
         # to the first live block (one DMA, re-requested at no cost),
         # blocks past the last go to ``tail``'s (a later slot's first
         # block, else an earlier one's last)
@@ -345,16 +381,20 @@ def _flash_decode(
         return (
             meta_ref[0], jnp.where(own, bi, at), 0,
             jnp.where(own, jnp.maximum(ki, first), jnp.where(at > bi, t_first, t_last)),
-            0,
         )
+
+    def _kv_ix(*at):
+        layer, slot, head, blk = key_ix(*at)
+        return (layer, slot, head) + ((0, blk) if keys_minor else (blk, 0))
 
     def q_ix(bi, ki, *_):
         return (bi, 0, 0, 0)
 
+    kv_block = (1, 1, hkv, d, bk) if keys_minor else (1, 1, hkv, bk, d)
     in_specs = [
         pl.BlockSpec((1, hkv, g, d), q_ix),
-        pl.BlockSpec((1, 1, hkv, bk, d), _kv_ix),
-        pl.BlockSpec((1, 1, hkv, bk, d), _kv_ix),
+        pl.BlockSpec(kv_block, _kv_ix),
+        pl.BlockSpec(kv_block, _kv_ix),
     ]
     args = [q, k, v]
     if new_row:
@@ -365,10 +405,7 @@ def _flash_decode(
     # (every head's row of token lanes), the sinks as [Hkv, G, 1] (a
     # column a head)
     if quantized:
-        def sc_ix(bi, ki, *refs):
-            return _kv_ix(bi, ki, *refs)[:4]
-
-        in_specs += [pl.BlockSpec((1, 1, hkv, bk), sc_ix)] * 2
+        in_specs += [pl.BlockSpec((1, 1, hkv, bk), key_ix)] * 2
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     if sinks is not None:
         in_specs.append(pl.BlockSpec((hkv, g, 1), lambda bi, ki, *_: (0, 0, 0)))
@@ -384,6 +421,7 @@ def _flash_decode(
         quantized=quantized,
         sinks=sinks is not None,
         rows_per_slot=rows_per_slot,
+        keys_minor=keys_minor,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -425,8 +463,8 @@ def flash_decode_supported(config, max_seq: int) -> bool:
 
 
 def reads_live_keys(
-    config, rows: int, *, ring: bool = False, mesh=None,
-    decode_kernel: Optional[str] = None,
+    config, rows: int, *, ring: bool = False, quantized: bool = False,
+    mesh=None, decode_kernel: Optional[str] = None,
 ) -> bool:
     """THE rule, at trace time, for one kind of layer of a grouped-query
     model: does its decode attention read the key blocks each live slot
@@ -434,20 +472,29 @@ def reads_live_keys(
     einsum)? ``config``: the layer's attention shape (its run's, in a
     model of groups); ``rows``: the rows a slot of its cache buffer
     holds; ``ring``: the buffer is a window layer's ring, whose rows lie
-    in ring order and are few (the einsum stays); ``decode_kernel``:
+    in ring order and are few (the einsum stays); ``quantized``: the
+    buffer is an (int8, scale) pair; ``decode_kernel``:
     what the caller asked for, ``"einsum"`` | ``"flash"`` (the kernel
     wherever it computes the layer, in interpret mode off the TPU: the
     tests' way in), or None: by what the program can see —
 
     - the TPU backend (the interpret mode is no serving path);
-    - ``head_dim`` a multiple of 128: the cache leaf then lies with its
-      width on the lanes, as the kernel's blocks want it (a narrower
-      leaf lies with its TOKENS there, and a kernel makes the compiler
-      re-lay it out whole: ``engine._tokens_on_lanes``);
+    - a plain bf16 leaf: inside a decode program the compiler stages
+      BOTH whole scale leaves of an int8 pair in fast memory once a
+      layer (device-free, Llama-3.2-1B at 16 x 2048, either width of
+      head: two ``ConcatBitcast`` of [L, B, Hkv, T] f32 in the layer
+      scan's body, as many bytes a step as the int8 rows themselves),
+      so the pair keeps the einsum, whose dequant fuses into the dot;
     - no mesh, or one whose ``tp`` axis divides the KV heads (the
       engine's ``shard_map`` wrap: a shard's heads, no collective).
 
-    bf16 and the int8 pair both compile for the chip at a cell's shapes
+    The head's width chooses no longer between the forms, only the
+    kernel's blocks (since PR 45): ``head_dim`` % 128 fills the lanes,
+    ``head_dim`` 64 leaves the leaf with its TOKENS there and the
+    kernel reads it in that order (:func:`tokens_on_lanes`); any other
+    width :func:`flash_decode_supported` refuses. The kernel alone
+    compiles for the chip in both block forms, bf16 and int8, at a
+    cell's shapes, and the bf16 decode programs move no leaf around it
     (``tests/compute/test_tpu_compile.py``)."""
     if decode_kernel == "einsum" or ring:
         return False
@@ -457,6 +504,6 @@ def reads_live_keys(
         return True
     return (
         jax.default_backend() == "tpu"
-        and config.head_dim % 128 == 0
+        and not quantized
         and (mesh is None or config.n_kv_heads % mesh.shape.get("tp", 1) == 0)
     )

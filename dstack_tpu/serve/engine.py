@@ -43,6 +43,7 @@ from dstack_tpu.ops.flash_decode import (
     flash_decode,
     flash_decode_supported,
     reads_live_keys,
+    tokens_on_lanes as _tokens_on_lanes,
 )
 from dstack_tpu.utils.logging import get_logger
 
@@ -169,16 +170,6 @@ def _cread_rows(ckv, layer, slots, dtype):
     if isinstance(ckv, tuple):
         return kv_dequant(rows(ckv[0]), rows(ckv[1]), dtype)
     return rows(ckv)
-
-
-def _tokens_on_lanes(width: int) -> bool:
-    """Whether a cache leaf [..., T, width] lies in device memory with
-    its TOKENS on the 128 lanes: the TPU compiler's own choice for a
-    minor axis that does not fill them (head_dim 64, the latent's 576,
-    a window latent's 1088), since it wastes no lane. What holds in
-    place on such a leaf is the other form of what holds on a leaf
-    with its width on the lanes (head_dim 128), in two places below."""
-    return width % 128 != 0
 
 
 def _own_rows(rows):
@@ -2462,8 +2453,8 @@ def _decode_layer(
     # step where writing inside the scan costs that a layer
     k_new, v_new = _cstored(k, ck), _cstored(v, cv)
     live_keys = reads_live_keys(
-        c, jax.tree.leaves(ck)[0].shape[3], ring=ring is not None, mesh=mesh,
-        decode_kernel=decode_kernel,
+        c, jax.tree.leaves(ck)[0].shape[3], ring=ring is not None,
+        quantized=isinstance(ck, tuple), mesh=mesh, decode_kernel=decode_kernel,
     )
     if not live_keys:
         # the einsum's operands: this token's K/V selected into the
@@ -3193,7 +3184,8 @@ class InferenceEngine:
         # (a latent layer of several attention sublayers: a row each)
         self._full_layers = config.sublayers * config.n_kind("full")
         self._slot_keys = bool(self._full_layers) and reads_live_keys(
-            config, max_seq, mesh=mesh, decode_kernel=decode_kernel
+            config, max_seq, quantized=bool(kv_quant), mesh=mesh,
+            decode_kernel=decode_kernel,
         )
         if self._slot_keys:  # the kernel's own block: a shard's heads, the cache's bytes
             self._key_block = block_keys(

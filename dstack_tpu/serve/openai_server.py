@@ -2103,8 +2103,8 @@ def main(argv=None) -> int:
              "blocks it holds; non-MLA models; runs per-shard under "
              "tensor parallelism). Default: the server chooses by the "
              "model's shape (ops/flash_decode.reads_live_keys: on the "
-             "TPU the kernel for grouped-query layers of head_dim % 128 "
-             "over a plain row buffer, else the einsum)",
+             "TPU the kernel for grouped-query bf16 layers over a plain "
+             "row buffer, else the einsum)",
     )
     p.add_argument(
         "--no-warmup", action="store_true",

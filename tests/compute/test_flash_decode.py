@@ -4,7 +4,10 @@ The kernel must reproduce serve/engine.py::decode_step's masked-einsum
 attention exactly (same masks, same softmax, same GQA regrouping) for
 every feature combination it claims: ragged positions, int8 KV with
 per-token scales, traced sliding windows, softcap, sinks. Interpret
-mode on CPU — the kernel itself is the unit under test.
+mode on CPU — the kernel itself is the unit under test, in both of its
+block forms: the kernel takes the form by the head's width (``HEAD_DIM``:
+128 fills the lanes and a block is [keys, head]; 64 leaves the cache
+leaf with its tokens on the lanes and a block is [head, keys]).
 """
 
 import jax
@@ -16,6 +19,7 @@ from dstack_tpu.ops.flash_decode import flash_decode, flash_decode_supported
 from dstack_tpu.serve.engine import kv_quantize
 
 NEG_INF = -1e30
+HEAD_DIM = [64, 128]
 
 
 def _ref_decode_attention(
@@ -51,9 +55,10 @@ def _rand(key, b=2, hkv=2, g=4, t=256, d=64, dtype=jnp.float32):
     return q, k, v
 
 
+@pytest.mark.parametrize("d", HEAD_DIM)
 class TestFlashDecodeParity:
-    def test_ragged_positions(self):
-        q, k, v = _rand(jax.random.key(0))
+    def test_ragged_positions(self, d):
+        q, k, v = _rand(jax.random.key(0), d=d)
         # mixed lengths incl. a fresh slot (pos 0) and a full row
         positions = jnp.asarray([3, 255], jnp.int32)
         out = flash_decode(
@@ -62,8 +67,8 @@ class TestFlashDecodeParity:
         ref = _ref_decode_attention(q, k, v, positions, 0.125)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_window_and_softcap(self):
-        q, k, v = _rand(jax.random.key(1))
+    def test_window_and_softcap(self, d):
+        q, k, v = _rand(jax.random.key(1), d=d)
         positions = jnp.asarray([129, 200], jnp.int32)
         win = jnp.asarray(64, jnp.int32)  # traced, like the layer scan
         out = flash_decode(
@@ -75,8 +80,8 @@ class TestFlashDecodeParity:
         )
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_window_zero_matches_full(self):
-        q, k, v = _rand(jax.random.key(2))
+    def test_window_zero_matches_full(self, d):
+        q, k, v = _rand(jax.random.key(2), d=d)
         positions = jnp.asarray([100, 250], jnp.int32)
         out = flash_decode(
             q, k, v, positions, scale=0.125,
@@ -85,8 +90,8 @@ class TestFlashDecodeParity:
         ref = _ref_decode_attention(q, k, v, positions, 0.125)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_int8_kv(self):
-        q, k, v = _rand(jax.random.key(3))
+    def test_int8_kv(self, d):
+        q, k, v = _rand(jax.random.key(3), d=d)
         kq8, ks = kv_quantize(k)
         vq8, vs = kv_quantize(v)
         positions = jnp.asarray([17, 255], jnp.int32)
@@ -103,8 +108,8 @@ class TestFlashDecodeParity:
         )
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_sinks(self):
-        q, k, v = _rand(jax.random.key(4))
+    def test_sinks(self, d):
+        q, k, v = _rand(jax.random.key(4), d=d)
         positions = jnp.asarray([63, 128], jnp.int32)
         sinks = jax.random.normal(jax.random.key(5), (2, 4), jnp.float32)
         out = flash_decode(
@@ -116,8 +121,8 @@ class TestFlashDecodeParity:
         )
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_mha_group_of_one(self):
-        q, k, v = _rand(jax.random.key(6), hkv=4, g=1)
+    def test_mha_group_of_one(self, d):
+        q, k, v = _rand(jax.random.key(6), hkv=4, g=1, d=d)
         positions = jnp.asarray([0, 200], jnp.int32)
         out = flash_decode(
             q, k, v, positions, scale=0.125, block_k=128, interpret=True
@@ -125,8 +130,8 @@ class TestFlashDecodeParity:
         ref = _ref_decode_attention(q, k, v, positions, 0.125)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    def test_bf16_inputs(self):
-        q, k, v = _rand(jax.random.key(7), dtype=jnp.bfloat16)
+    def test_bf16_inputs(self, d):
+        q, k, v = _rand(jax.random.key(7), d=d, dtype=jnp.bfloat16)
         positions = jnp.asarray([50, 180], jnp.int32)
         out = flash_decode(
             q, k, v, positions, scale=0.125, block_k=128, interpret=True
@@ -141,7 +146,9 @@ class TestFlashDecodeParity:
 # what the engine's decode scan hands the kernel since PR 43: the
 # STACKED leaf read in place at a traced layer row, the token's own key
 # beside the cache (which is masked at kj < position), length 0 for a
-# slot that is not live. case → (kwargs, int8)
+# slot that is not live; since PR 45 at both widths, the narrow one
+# with the rag cell's grouping (4 query heads a KV head).
+# case → (kwargs, int8)
 STACKED = {
     "plain": ({}, False),
     "window-softcap": ({"window": 48, "softcap": 30.0}, False),
@@ -152,20 +159,22 @@ STACKED = {
 
 
 class TestStackedLeafNewRow:
+    @pytest.mark.parametrize("d", HEAD_DIM)
     @pytest.mark.parametrize("case", sorted(STACKED))
-    def test_reads_its_layer_and_its_slots_blocks(self, case):
+    def test_reads_its_layer_and_its_slots_blocks(self, case, d):
         from dstack_tpu.serve.engine import kv_dequant
 
         kw, int8 = STACKED[case]
-        L, li, b, hkv, g, t, d, bk = 3, 1, 5, 2, 3, 384, 64, 128
+        L, li, b, hkv, g, t, bk = 3, 1, 6, 2, 4 if d == 64 else 3, 384, 128
         ks = jax.random.split(jax.random.key(9), 6)
         q = jax.random.normal(ks[0], (b, hkv, g, d), jnp.float32)
         k = jax.random.normal(ks[1], (L, b, hkv, t, d), jnp.float32)
         v = jax.random.normal(ks[2], (L, b, hkv, t, d), jnp.float32)
         k_new = jax.random.normal(ks[3], (b, hkv, 1, d), jnp.float32)
         v_new = jax.random.normal(ks[4], (b, hkv, 1, d), jnp.float32)
-        # not live (length 0), one key, a block's edge - 1, the edge, the row's end
-        positions = jnp.asarray([0, 1, bk - 1, bk, t - 1], jnp.int32)
+        # not live (length 0), one key, a block's edge and either side
+        # of it, the row's end
+        positions = jnp.asarray([0, 1, bk - 1, bk, bk + 1, t - 1], jnp.int32)
         sinks = (
             jax.random.normal(ks[5], (hkv, g), jnp.float32) if kw.get("sinks") else None
         )
@@ -207,11 +216,12 @@ class TestStackedLeafNewRow:
 
 
 class TestVerifyRows:
-    def test_rows_per_slot_matches_per_row_masks(self):
+    @pytest.mark.parametrize("d", HEAD_DIM)
+    def test_rows_per_slot_matches_per_row_masks(self, d):
         """rows_per_slot=S: row g*S+s attends to keys <= pos+s — the
         speculative-verify shape, checked against a per-row einsum."""
         S, g = 3, 2
-        b, hkv, t, d = 2, 2, 256, 64
+        b, hkv, t = 2, 2, 256
         kq, kk, kv = jax.random.split(jax.random.key(8), 3)
         q = jax.random.normal(kq, (b, hkv, g * S, d), jnp.float32)
         k = jax.random.normal(kk, (b, hkv, t, d), jnp.float32)

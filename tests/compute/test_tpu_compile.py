@@ -191,14 +191,15 @@ def _decode(rows_per_slot=1, int8=False, sinks=False):
     return build
 
 
-def _decode_stacked(layers, g, t, int8=False, sinks=False):
+def _decode_stacked(layers, g, t, int8=False, sinks=False, d=128):
     """What the engine's decode scan hands the kernel where
-    ``reads_live_keys`` holds: the stacked leaf [L, 16, 8, T, 128] read
+    ``reads_live_keys`` holds: the stacked leaf [L, 16, 8, T, d] read
     in place at a traced row, the token's own K/V beside it, the block
-    rule's own block (the chat and the mixed cell's shapes)."""
+    rule's own block (the chat, the mixed and, at ``d`` 64, with the
+    keys on a block's lanes, the rag cell's shapes)."""
 
     def build(sds):
-        d, kv = 128, jnp.int8 if int8 else BF16
+        kv = jnp.int8 if int8 else BF16
         args = [
             sds((_B, _HKV, g, d), BF16), sds((layers, _B, _HKV, t, d), kv),
             sds((layers, _B, _HKV, t, d), kv), sds((_B,), jnp.int32),
@@ -240,6 +241,8 @@ KERNELS = {
     "decode_stacked_new_row_chat": _decode_stacked(32, 3, 1536),
     "decode_stacked_new_row_mixed": _decode_stacked(4, 6, 8192),
     "decode_stacked_new_row_int8_sinks": _decode_stacked(32, 3, 1536, int8=True, sinks=True),
+    "decode_stacked_new_row_rag": _decode_stacked(10, 4, 8192, d=64),
+    "decode_stacked_new_row_rag_int8_sinks": _decode_stacked(10, 4, 8192, int8=True, sinks=True, d=64),
 }
 
 
@@ -584,6 +587,25 @@ def _serving_program(case, sds, place):
     return fn, (params, cache, i32(b), i32(b), mask), cache
 
 
+def _compiled_program(topo, case):
+    """(compiled, args, cache) of ``case`` (:func:`_serving_program`),
+    compiled the way the engine jits it: the cache donated."""
+    sharding = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+    fn, args, cache = _serving_program(case, sds, place)
+    return _compile(fn, *args, donate_argnums=(1,)), args, cache
+
+
+def _leaf_shapes(leaves) -> tuple:
+    """(stacked, layer) shapes of ``leaves``, as :func:`_cache_sized_moves`
+    takes them."""
+    return (
+        {leaf.shape for leaf in leaves},
+        {sh for leaf in leaves for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])},
+    )
+
+
 def _holds_no_second_cache(
     topo, case, room: float = 0.0, known: int = 0, temp_below: float = 0.0
 ):
@@ -599,20 +621,12 @@ def _holds_no_second_cache(
     ``temp_below`` (bytes): hold ``temp`` under that and the expert
     stacks alone, for a model whose masked attention copies a layer's
     rows by design (the longdoc cell)."""
-    sharding = SingleDeviceSharding(topo.devices[0])
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-    fn, args, cache = _serving_program(case, sds, place)
-    compiled = _compile(fn, *args, donate_argnums=(1,))
-    sized = lambda leaves: (
-        {leaf.shape for leaf in leaves},
-        {sh for leaf in leaves for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])},
-    )
+    compiled, args, cache = _compiled_program(topo, case)
     experts = [
         a for n, a in args[0]["layers"].items()
         if n in ("w_gate", "w_up", "w_down") and a.ndim == 4
     ]
-    moves = _cache_sized_moves(compiled.as_text(), *sized(experts))
+    moves = _cache_sized_moves(compiled.as_text(), *_leaf_shapes(experts))
     assert not moves, moves
     temp = compiled.memory_analysis().temp_size_in_bytes
     if temp_below:
@@ -621,7 +635,7 @@ def _holds_no_second_cache(
         return compiled
     moves = _cache_sized_moves(
         compiled.as_text(),
-        *sized([leaf for leaf in jax.tree.leaves(cache) if leaf.ndim > 1]),
+        *_leaf_shapes([leaf for leaf in jax.tree.leaves(cache) if leaf.ndim > 1]),
     )
     assert len(moves) <= known and not any("slice" in m for m in moves), moves
     cache_bytes = sum(
@@ -676,11 +690,16 @@ _DECODE_EXPERTS = {
 }
 
 
-#: the decode programs whose full layers are grouped-query layers of
-#: head_dim 128 over a plain row buffer: on the chip they attend through
-#: ``ops/flash_decode`` (``reads_live_keys``), which reads the stacked
-#: leaf where it lies. Every other case holds no kernel: latent layers,
-#: head_dim 64, and ``verify_step`` (the einsum, no cell drafts)
+#: the decode programs whose full layers are grouped-query layers over a
+#: plain row buffer: on the chip they attend through ``ops/flash_decode``
+#: (``reads_live_keys``), which reads the stacked leaf where it lies,
+#: head_dim 128 in blocks [keys, head], head_dim 64 (a leaf with its
+#: tokens on the lanes; since PR 45) in blocks [head, keys]. Every other
+#: case holds no kernel: latent layers, ``verify_step`` (the einsum, no
+#: cell drafts) and the int8 pair (with the kernel in the program the
+#: compiler stages both whole scale leaves in fast memory a layer: two
+#: ``ConcatBitcast`` of [16, 16, 8, 2048] f32 in the scan's body, which
+#: this test reads as whole-leaf moves; at head_dim 128 alike)
 #: → ``temp`` of the einsum form (PR 42's tree), which the kernel form
 #: may not exceed (PR 43's reading: 0.0027 / 0.635 / 0.757 GB)
 _READS_LIVE_KEYS = {
@@ -834,26 +853,18 @@ def test_linear_state_program_updates_the_state_in_place(topo, _as_tpu, case):
     tail [11, 16, 3, 12288] (13 MB) IS re-laid out around the layer
     loop, three rows being no tile: 1.2 MB a layer, left as it is."""
 
-    sharding = SingleDeviceSharding(topo.devices[0])
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-    fn, args, cache = _serving_program(case, sds, place)
-    compiled = _compile(fn, *args, donate_argnums=(1,))
-    sized = lambda leaves: (
-        {leaf.shape for leaf in leaves},
-        {sh for leaf in leaves for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])},
-    )
+    compiled, args, cache = _compiled_program(topo, case)
     experts = [
         a for stack in ("layers", "linear_layers") for n, a in args[0][stack].items()
         if n in ("w_gate", "w_up", "w_down") and a.ndim == 4
     ]
     hlo = compiled.as_text()
-    assert not _cache_sized_moves(hlo, *sized(experts))
+    assert not _cache_sized_moves(hlo, *_leaf_shapes(experts))
     # (the prelude layer's row is a constant: its in-place update fusion
     # addresses the stack by a ``slice`` where the scanned layers' have a
     # ``dynamic-slice``, which the checker takes for a copy riding in it)
     moves = [
-        m for m in _cache_sized_moves(hlo, *sized([cache["state"], cache["ckv"]]))
+        m for m in _cache_sized_moves(hlo, *_leaf_shapes([cache["state"], cache["ckv"]]))
         if "select_dynamic-update-slice_fusion" not in m
     ]
     # the verify step's blocks-of-tokens form carries the state through a
@@ -869,10 +880,11 @@ def test_linear_state_program_updates_the_state_in_place(topo, _as_tpu, case):
 # the rag cell (PR 44): 16 × 8192, ten full layers' K/V [10, 16, 8, 8192, 64]
 # (2 x 1.342 GB, head_dim 64: their tokens on the lanes) beside thirty conv
 # layers' tails [30, 16, 2, 2048] (3.9 MB), 7.29 GB of weights, forty layers
-# walked by periods. case → ``temp`` on PR 44's tree, GB
+# walked by periods. case → ``temp`` on PR 45's tree, GB (the two decode
+# programs on PR 44's, with the einsum: 0.026 / 0.152)
 _CONV_GQA = {
-    "decode_step-conv_gqa": 0.026,
-    "decode_loop-conv_gqa": 0.152,
+    "decode_step-conv_gqa": 0.022,
+    "decode_loop-conv_gqa": 0.145,
     "verify_step-conv_gqa": 0.299,
     "prefill_packed_step@1-conv_gqa": 0.019,
     "prefill_packed_step@2-conv_gqa": 0.009,
@@ -894,29 +906,56 @@ def test_conv_gqa_program_holds_no_second_cache(topo, _as_tpu, case):
     row's block write in a wave (now the whole chunk as one
     ``dynamic_update_slice``, the serial chunk's form). The walk by
     periods holds the programs at seven loops or fewer however deep the
-    model (21 runs unrolled would be 21 layer scans)."""
-    sharding = SingleDeviceSharding(topo.devices[0])
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    place = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-    fn, args, cache = _serving_program(case, sds, place)
-    compiled = _compile(fn, *args, donate_argnums=(1,))
-    sized = lambda leaves: (
-        {leaf.shape for leaf in leaves},
-        {sh for leaf in leaves for sh in (leaf.shape[1:], (1,) + leaf.shape[1:])},
-    )
+    model (21 runs unrolled would be 21 layer scans). Since PR 45 the
+    two decode programs attend through ``ops/flash_decode`` in its
+    keys-on-lanes block form: handed ``swapaxes(leaf, -1, -2)``, the
+    order the leaf already lies in, the Mosaic call takes a ``bitcast``
+    of the donated buffer (the block form of head_dim 128 fed this leaf
+    made the compiler re-lay both leaves out whole, ``temp`` 2.1-5.5
+    GB); ``verify_step`` and the prefill waves keep the einsum."""
+    compiled, args, cache = _compiled_program(topo, case)
     experts = [
         a for stack in ("layers", "conv_layers") for n, a in args[0][stack].items()
         if n in ("w_gate", "w_up", "w_down") and a.ndim == 4
     ]
     hlo = compiled.as_text()
-    assert not _cache_sized_moves(hlo, *sized(experts))
-    assert not _cache_sized_moves(hlo, *sized([cache["k"]]))
-    assert not _has_kernel(compiled)  # head_dim 64: the einsum over reserved rows
+    assert not _cache_sized_moves(hlo, *_leaf_shapes(experts))
+    assert not _cache_sized_moves(hlo, *_leaf_shapes([cache["k"]]))
+    assert _has_kernel(compiled) == case.startswith("decode_")
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.1e9 * _CONV_GQA[case] + 5e6, f"temp {temp / 1e9:.3f} GB"
     assert _fits(compiled) < 10.4e9
     # layer scans: prelude, period (and its two runs), tail's two, writes
     assert hlo.count(" while(") <= 8
+
+
+# the plain family at head_dim 64 (Llama-3.2-1B, no cell), which the rule
+# reaches since PR 45. case → (``temp`` on PR 44's tree with the einsum,
+# GB; whole-leaf copies the compiler made there and still makes: the
+# slots' block writes in ``decode_loop``'s token loop, cured in the walk
+# of layer groups only, PERF.md §7)
+_PLAIN_64 = {
+    "decode_step-llama_1b": (0.0007, 0),
+    "decode_loop-llama_1b": (2.3185, 6),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLAIN_64))
+def test_plain_head_dim_64_decode_reads_live_keys_at_the_einsums_temp(
+    topo, _as_tpu, case
+):
+    """The kernel's keys-on-lanes form in the scan of a model of ONE
+    kind of layer: it is there, it moves no leaf the einsum form did
+    not move, and ``temp`` stays at the parent's reading (+ 1 MB: the
+    kernel's own operands in padded tiles)."""
+    compiled, _, cache = _compiled_program(topo, case)
+    assert _has_kernel(compiled)
+    parent_temp, parent_moves = _PLAIN_64[case]
+    moves = _cache_sized_moves(compiled.as_text(), *_leaf_shapes([cache["k"]]))
+    assert len(moves) <= parent_moves, moves
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1e9 * parent_temp + 1e6, f"temp {temp / 1e9:.4f} GB"
+    _fits(compiled)
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,12 @@ at tiny widths with the kernel in interpret mode (asked for by
 takes the kernel on the TPU only): it computes what the masked einsum
 computes, logits and the whole cache, with slots at every edge of a
 block in one batch and dead rows among them; and the engine's two
-counters say what was read, slot by slot. What the TPU compiler makes
-of it is ``tests/compute/test_tpu_compile.py``'s to check.
+counters say what was read, slot by slot. The kernel has a block form
+a way the leaf lies (``flash_decode.tokens_on_lanes``): the models here
+are head_dim 64 (a block is [head, keys]: the rag cell's form, PR 45)
+but ``gqa-128`` (a block is [keys, head]: chat's and mixed's form).
+What the TPU compiler makes of either is
+``tests/compute/test_tpu_compile.py``'s to check.
 """
 
 import dataclasses
@@ -34,6 +38,7 @@ DENSE = dataclasses.replace(
 # case → (config, kv_quant)
 MODELS = {
     "gqa": (DENSE, None),
+    "gqa-128": (dataclasses.replace(DENSE, head_dim=128), None),
     "window-softcap": (
         dataclasses.replace(
             DENSE, sliding_window=32, sliding_pattern=2, attn_softcap=30.0
@@ -47,6 +52,15 @@ MODELS = {
     "groups": (
         dataclasses.replace(
             DENSE, layer_types=("full", "window"), sliding_window=8, swa_n_heads=6,
+        ),
+        None,
+    ),
+    # the rag cell's family: conv layers (a tail a slot, no rows) beside
+    # full layers that alone hold K/V, walked by periods, held experts
+    "conv-groups": (
+        dataclasses.replace(
+            llama.CONV_TINY, head_dim=64, n_layers=4,
+            layer_types=("conv", "conv", "full", "conv"),
         ),
         None,
     ),
@@ -135,19 +149,31 @@ def test_kernel_path_is_the_einsum_path(case, program, monkeypatch):
 
 
 def test_the_rule_takes_the_kernel_for_what_it_can_see(monkeypatch):
-    """By itself: on the TPU, a grouped-query layer of head_dim % 128
-    over a plain row buffer; never a ring, a latent, Llama4's chunks; a
-    caller's word goes first."""
-    wide = dataclasses.replace(DENSE, head_dim=128)
+    """By itself: on the TPU, a grouped-query layer over a plain row
+    buffer, its head filling the lanes (128) or leaving the leaf with
+    its tokens there (64: the kernel's other block form, PR 45); never
+    a ring, a latent, Llama4's chunks, a head that is no half of the
+    lanes; a caller's word goes first."""
+    wide = MODELS["gqa-128"][0]
     assert not fd.reads_live_keys(wide, 1536)  # the CPU: the einsum
+    assert not fd.reads_live_keys(DENSE, 1536)
     assert fd.reads_live_keys(DENSE, 256, decode_kernel="flash")  # asked for
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert fd.reads_live_keys(wide, 1536)
     assert fd.reads_live_keys(dataclasses.replace(MODELS["groups"][0], head_dim=128), 8192)
+    assert not fd.reads_live_keys(wide, 1536, quantized=True)  # an int8 pair, either width
+    assert not fd.reads_live_keys(DENSE, 1536, quantized=True)
+    assert fd.reads_live_keys(DENSE, 256, quantized=True, decode_kernel="flash")
+    # head_dim 64: the plain family, layer groups, beside conv layers
+    assert fd.reads_live_keys(DENSE, 1536) and fd.reads_live_keys(llama.LLAMA_32_1B, 2048)
+    assert fd.reads_live_keys(MODELS["groups"][0], 8192)
+    assert fd.reads_live_keys(MODELS["conv-groups"][0], 8192)
+    assert not fd.reads_live_keys(MODELS["conv-groups"][0], 768, ring=True)
+    assert not fd.reads_live_keys(llama.LLAMA_TINY, 1536)  # head_dim 32
+    assert fd.tokens_on_lanes(64) and fd.tokens_on_lanes(576) and not fd.tokens_on_lanes(128)
     assert not fd.reads_live_keys(wide, 1536, decode_kernel="einsum")
     assert not fd.reads_live_keys(wide, 768, ring=True)
     assert not fd.reads_live_keys(wide, 1500)  # rows the blocks do not divide
-    assert not fd.reads_live_keys(DENSE, 1536)  # head_dim 64: tokens on the lanes
     assert not fd.reads_live_keys(dataclasses.replace(wide, attention_chunk_size=64), 1536)
     assert not fd.reads_live_keys(llama.MLA_TINY, 1536)
     assert not fd.reads_live_keys(wide, 256, ring=True, decode_kernel="flash")
@@ -155,16 +181,20 @@ def test_the_rule_takes_the_kernel_for_what_it_can_see(monkeypatch):
     assert fd.block_keys(8, 128, 1536) == 512 and fd.block_keys(8, 128, 8192) == 512
     assert fd.block_keys(8, 128, 1536, itemsize=1) == 768
     assert fd.block_keys(2, 128, 1536) == 1536 and fd.block_keys(8, 128, 640) == 128
+    assert fd.block_keys(8, 64, 8192) == 1024  # the rag cell's: the same bytes a step
 
 
-def test_engine_counts_each_slots_own_blocks(monkeypatch):
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_engine_counts_each_slots_own_blocks(head_dim, monkeypatch):
     """One long and three short live slots and twelve empty ones: a
     token step reads the sum of the four's own blocks, nothing of the
-    twelve, where a whole-row program reads sixteen rows."""
-    _block_of_128(monkeypatch, DENSE)
-    params = llama.init_params(DENSE, jax.random.key(1))
+    twelve, where a whole-row program reads sixteen rows: the same
+    count in either block form of the kernel."""
+    config = dataclasses.replace(DENSE, head_dim=head_dim)
+    _block_of_128(monkeypatch, config)
+    params = llama.init_params(config, jax.random.key(1))
     e = InferenceEngine(
-        DENSE, params, max_batch=16, max_seq=TMAX, spec_draft=0, turbo_steps=0,
+        config, params, max_batch=16, max_seq=TMAX, spec_draft=0, turbo_steps=0,
         decode_kernel="flash",
     )
     assert e._slot_keys and e._key_block == KB and e._full_layers == 2
@@ -184,5 +214,5 @@ def test_engine_counts_each_slots_own_blocks(monkeypatch):
     e._count_decode_keys({slots[3]: [1, 2, 3], slots[1]: [4]})
     assert value("dtpu_serve_decode_keys_read_total") - before == (3 * 2 + 1) * KB * 2
     # the einsum reads every reserved row
-    whole = InferenceEngine(DENSE, params, max_batch=16, max_seq=TMAX, spec_draft=0)
+    whole = InferenceEngine(config, params, max_batch=16, max_seq=TMAX, spec_draft=0)
     assert not whole._slot_keys and whole._key_block == 0
