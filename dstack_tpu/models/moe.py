@@ -29,10 +29,16 @@ same sum Σ_j gate_j · FFN_{e_j}(x) as the combine einsum.
 by a flag: serving (``rules is None``), a shape at which the capacity
 form is dropless too (``cap == T``: so the two agree whatever
 ``capacity_factor`` is), the plain weight forms (stacks in the model's
-dtype, SwiGLU, the gate on the combine side), and an expected share of
-the stack's experts picked by some token, ``1 - (1 - k/E)^N`` over the
-router's width ``E`` and the call's ``N`` tokens, under
-:data:`PICKED_SHARE`.
+dtype, SwiGLU, the gate on the combine side), and then the bytes each
+form streams a call. The capacity form streams the stack: ``count``
+experts of ``expert_bytes`` (one expert's three matrices). The picked
+form makes a trip for each expert some token picked, ``count`` times
+:func:`picked_share` of them expected (``1 - (1 - k/E)^N`` over the
+router's width ``E`` and the call's ``N`` tokens), and a trip streams
+its expert and pays :data:`TRIP_BYTES` beside it. The picked form is
+taken where that is the smaller number: at few tokens over a wide
+router, and the sooner the larger an expert is beside a trip's fixed
+cost; a toy expert never takes it.
 
 Aux losses follow Switch Transformer: load-balance (E · Σ_e f_e·p_e) and
 router z-loss; the router runs in f32 for softmax stability.
@@ -309,14 +315,21 @@ def router(
     return dispatch, combine, aux
 
 
-#: the picked form is taken where the expected share of a stack's experts
-#: that some token of the call picks is under this. Read on a v5e (PERF.md
-#: §6, PR 38) at shares 0.22, 0.40 and 0.47: a trip of the loop streams
-#: one expert's three matrices at about the rate the batched einsums
-#: stream the stack's, so the picked form wins by about the share itself.
-#: Over it stands 0.79 (64 experts all held, ~51 trips a layer for a
-#: fifth of the bytes at best), which keeps the capacity form untried.
-PICKED_SHARE = 0.6
+#: what a trip of the picked form's loop costs beside its expert's own
+#: bytes (the loop's condition, ``order[i]``, the gate column's slice, two
+#: fusions that each start their stream from nothing, and the call's sort
+#: and gate table spread over its trips), written as the bytes the chip
+#: streams in that time. Read on a v5e (PERF.md §6, PR 46) from one routed
+#: layer call of 16 x 1 tokens in a layer scan, both forms, as
+#: ``loop's time / trips x the capacity form's bytes a µs - expert_bytes``:
+#: 4.2 MB at 8 held of a 64-wide top-4 router (18.9 MB an expert: 168.7 µs
+#: against 213.3) and 5.1 MB at 64 of 64, top-6 (17.3 MB: 1528.7 µs
+#: against 1492.7, the loop BEHIND); the constant is the slower reading,
+#: so that 50.7 trips for a fifth of the bytes keep the batched einsums.
+#: Eleven more readings at the other cells' shapes and at 2-80 tokens
+#: (3.8-13.8 MB, the high ones where a call makes few trips and the loop
+#: is far ahead) fall on the side of the rule this value puts them.
+TRIP_BYTES = 5_100_000
 
 
 def picked_share(n_tokens: int, router_width: int, experts_per_token: int) -> float:
@@ -326,6 +339,19 @@ def picked_share(n_tokens: int, router_width: int, experts_per_token: int) -> fl
     distinct outputs, of which a stack of ``count`` experts sees
     count/E, over ``count``: the stack's size cancels."""
     return 1.0 - (1.0 - experts_per_token / router_width) ** n_tokens
+
+
+def _experts_and_bytes(layer: dict) -> tuple:
+    """(experts in the layer's stacks, bytes of one expert's three
+    matrices), from the stacks as a layer holds them: [count, ., .],
+    stacked over layers, or a :class:`Row` of that."""
+    stacks = [
+        leaf.stack if isinstance(leaf, Row) else leaf
+        for leaf in (layer[w] for w in EXPERT_STACKS)
+    ]
+    return stacks[0].shape[-3], sum(
+        a.shape[-2] * a.shape[-1] * jnp.dtype(a.dtype).itemsize for a in stacks
+    )
 
 
 def reads_picked_experts(
@@ -349,12 +375,13 @@ def reads_picked_experts(
     dropless = seq_len == expert_capacity(
         seq_len, n_experts, experts_per_token, capacity_factor
     )
-    return (
-        rules is None and plain and dropless
-        and picked_share(
-            batch * seq_len, layer["w_router"].shape[-1], experts_per_token
-        ) < PICKED_SHARE
+    if not (rules is None and plain and dropless):
+        return False
+    count, expert_bytes = _experts_and_bytes(layer)
+    trips = count * picked_share(
+        batch * seq_len, layer["w_router"].shape[-1], experts_per_token
     )
+    return trips * (expert_bytes + TRIP_BYTES) < count * expert_bytes
 
 
 def _picked_experts(

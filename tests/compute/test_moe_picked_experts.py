@@ -1,12 +1,15 @@
 """``moe.moe_mlp``'s picked form (one loop trip a distinct picked expert,
 no other expert's weights read) against its capacity form and against a
-dense every-expert sum, over the router forms of the three cells that
-take it, and the rule that chooses between the two forms.
+dense every-expert sum, over the router forms of three cells, and the
+rule that chooses between the two forms.
 
 Widths are toy; the routers are the cells' own widths (768 = 512 + 256
 identity experts with 16 held; 256 with 32 held; 64, all held), because
-the rule reads them. The capacity form is had from the same call with
-``PICKED_SHARE`` at 0: what the rule decides is the only difference.
+the rule reads them. It reads an expert's bytes too, and a toy expert
+(24 KB) is less than a loop trip's fixed cost: the picked form is had
+with ``TRIP_BYTES`` at 0 (the loop wherever some expert is expected
+unpicked), the capacity form from the same call with it at infinity:
+what the rule decides is the only difference.
 """
 
 import dataclasses
@@ -79,11 +82,12 @@ def _call(form, x, layer, valid=None, rules=None):
 def _both(form, x, layer, monkeypatch, valid=None):
     """(picked form, capacity form) of one call, each (out, aux)."""
     f = FORMS[form]
+    monkeypatch.setattr(moe, "TRIP_BYTES", 0)
     assert moe.reads_picked_experts(
         layer, *x.shape[:2], f["width"] - f["zero"], f["k"], f["width"] / f["k"], None
     ), "the case's shape must engage the picked form"
     picked = jax.jit(lambda: _call(form, x, layer, valid))()
-    monkeypatch.setattr(moe, "PICKED_SHARE", 0.0)
+    monkeypatch.setattr(moe, "TRIP_BYTES", float("inf"))
     capacity = jax.jit(lambda: _call(form, x, layer, valid))()
     monkeypatch.undo()
     return picked, capacity
@@ -134,7 +138,7 @@ def _distinct_here(form, x, layer, valid=None):
     return len(set(here.tolist())), here.size
 
 
-SHAPES = {  # form → {T: batch}: under the constant, some experts unpicked
+SHAPES = {  # form → {T: batch}: few enough tokens that some experts go unpicked
     "softmax-bias-zero-held": {1: 16, 5: 2},
     "sigmoid-renorm-scale-held": {1: 16, 5: 2},
     "softmax-all-held": {1: 2, 5: 1},
@@ -251,6 +255,7 @@ def test_dead_rows_read_nothing_and_count_nothing(t, monkeypatch):
 def test_unpicked_experts_weights_are_not_touched(form, monkeypatch):
     """NaN in every expert no token picked: the output is finite and the
     clean layer's (a read of any of them would poison every row)."""
+    monkeypatch.setattr(moe, "TRIP_BYTES", 0)
     layer, x = _layer(form), _x(SHAPES[form][1], 1)
     f = FORMS[form]
     first, count = f["held"] or (0, f["width"] - f["zero"])
@@ -265,13 +270,14 @@ def test_unpicked_experts_weights_are_not_touched(form, monkeypatch):
     got, _ = jax.jit(lambda: _call(form, x, poisoned))()
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(got, clean)
-    monkeypatch.setattr(moe, "PICKED_SHARE", 0.0)  # the capacity form reads them all
+    monkeypatch.setattr(moe, "TRIP_BYTES", float("inf"))  # the capacity form reads them all
     assert not np.isfinite(np.asarray(jax.jit(lambda: _call(form, x, poisoned))()[0])).all()
 
 
 def test_a_row_of_the_layer_stack_reads_the_same(monkeypatch):
     """The expert stacks handed as ``moe.Row(stack over layers, l)`` (how
     a layer scan hands them) give the layer's own output."""
+    monkeypatch.setattr(moe, "TRIP_BYTES", 0)
     form = "sigmoid-renorm-scale-held"
     layers = [_layer(form, seed=s) for s in range(3)]
     x = _x(16, 1)
@@ -287,7 +293,7 @@ def test_a_row_of_the_layer_stack_reads_the_same(monkeypatch):
         got, aux = jax.jit(lambda: _call(form, x, rows))()
         np.testing.assert_array_equal(got, want)
         assert int(aux["experts_held"]) == 32
-    monkeypatch.setattr(moe, "PICKED_SHARE", 0.0)  # a row in the capacity form is taken
+    monkeypatch.setattr(moe, "TRIP_BYTES", float("inf"))  # a row in the capacity form is taken
     np.testing.assert_allclose(
         jax.jit(lambda: _call(form, x, rows))()[0], want, rtol=1e-5, atol=1e-5
     )
@@ -319,12 +325,26 @@ def _takes_picked(c, b, t, rules=None):
     )
 
 
-CELLS = {  # configuration → (expected share at 16 decode rows, picked form?)
-    "longcat-flash-chat-4l-ep32": (0.223, True),
-    "dots3-note-prev-5l-ep8": (0.398, True),
-    "laguna-s-2.1-13l-ep8": (0.471, True),
-    "deepseek-v2-lite-9l": (0.793, False),
+# configuration → (expected share at 16 decode rows, picked form?; the same
+# of a full verify grid, 16 x 5 tokens). Reasoning's 0.793 of 64 experts of
+# 17.3 MB stands just over its break-even (0.773); agent's grid, 0.716 of 16
+# experts of 75.5 MB, is the one grid under its own (0.937)
+CELLS = {
+    "longcat-flash-chat-4l-ep32": (0.223, True, 0.716, True),
+    "ling-3.0-flash-vl-13l-ep8": (0.223, True, 0.716, False),
+    "dots3-note-prev-5l-ep8": (0.398, True, 0.921, False),
+    "laguna-s-2.1-13l-ep8": (0.471, True, 0.959, False),
+    "lfm2-24b-a2b-ep8": (0.644, True, 0.994, False),
+    "deepseek-v2-lite-9l": (0.793, False, 1.0, False),
 }
+
+
+def _expert_bytes(layer):
+    """The three matrices of one expert of ``layer``'s stacks."""
+    return sum(
+        int(np.prod(layer[w].shape[-2:])) * layer[w].dtype.itemsize
+        for w in moe.EXPERT_STACKS
+    )
 
 
 @pytest.mark.parametrize("name", sorted(CELLS) + ["minitron-4b"])
@@ -336,17 +356,65 @@ def test_the_rule_at_the_cells_decode_shapes(name):
         dataclasses.replace(llama.DEEPSEEK_V2_LITE, n_layers=9)
         if name == "deepseek-v2-lite-9l" else _cell_config(name)
     )
-    share, picked = CELLS[name]
+    share, picked, grid_share, grid_picked = CELLS[name]
     width = c.n_experts + c.zero_experts
     assert moe.picked_share(16, width, c.experts_per_token) == pytest.approx(share, abs=1e-3)
-    assert (share < moe.PICKED_SHARE) == picked
+    assert moe.picked_share(80, width, c.experts_per_token) == pytest.approx(
+        grid_share, abs=1e-3
+    )
+    # the bytes each form streams a call: the whole stack, or a trip an
+    # expected distinct expert, each its expert and a trip's fixed cost
+    layer = _expert_layer_shapes(c)
+    count, one = layer["w_gate"].shape[-3], _expert_bytes(layer)
+    assert (c.experts_held[1] if c.experts_held else c.n_experts) == count
+    assert (share * count * (one + moe.TRIP_BYTES) < count * one) == picked
+    assert (grid_share * count * (one + moe.TRIP_BYTES) < count * one) == grid_picked
     # decode_step and decode_loop route 16 x 1 tokens a layer call
     assert _takes_picked(c, 16, 1) == picked
-    # a 256-token prefill chunk or wave row, and a full verify grid: capacity form
+    # a full verify grid
+    assert _takes_picked(c, 16, 5) == grid_picked
+    # a 256-token prefill chunk or wave row: the capacity form
     assert not _takes_picked(c, 1, 256) and not _takes_picked(c, 4, 256)
-    assert not _takes_picked(c, 16, 5)
     # under sharding rules (training, ep): the dispatch einsums
     assert not _takes_picked(c, 16, 1, rules=default_rules())
+
+
+def _rule_at(expert_bytes, tokens, width=64, k=4, count=8, dtype=jnp.bfloat16):
+    """The rule at an expert of ``expert_bytes`` (three square-ish bf16
+    matrices) and ``tokens`` x 1 tokens over a ``width``-wide top-``k``
+    router, ``count`` experts held."""
+    side = int(round((expert_bytes / 3 / jnp.dtype(dtype).itemsize) ** 0.5))
+    sds = jax.ShapeDtypeStruct
+    layer = {
+        "w_router": sds((side, width), jnp.float32),
+        "w_gate": sds((count, side, side), dtype),
+        "w_up": sds((count, side, side), dtype),
+        "w_down": sds((count, side, side), dtype),
+    }
+    return moe.reads_picked_experts(layer, tokens, 1, width, k, width / k, None)
+
+
+@pytest.mark.parametrize("count", [8, 64])
+@pytest.mark.parametrize("tokens", [1, 4, 16, 32])
+def test_the_rule_follows_an_experts_bytes_and_the_share(tokens, count):
+    """At a fixed share the loop is refused as an expert's bytes shrink
+    toward a trip's fixed cost and taken as they grow past it; at a
+    fixed expert it is taken as the share falls toward 0 and refused as
+    it rises toward 1: monotone in both, whatever the stack's count
+    (it cancels), and the break-even is where the bytes say."""
+    share = moe.picked_share(tokens, 64, 4)
+    sizes = [moe.TRIP_BYTES * 2.0**p for p in range(-8, 9)]
+    taken = [_rule_at(b, tokens, count=count) for b in sizes]
+    assert taken == sorted(taken)  # once taken, taken at every larger expert
+    assert not taken[0]  # an expert of 1/256 of a trip's cost: never the loop
+    # the break-even: share x (bytes + TRIP_BYTES) = bytes
+    even = moe.TRIP_BYTES * share / (1 - share)
+    for size, took in zip(sizes, taken):
+        assert took == (size > even), (size, even)
+    # at one expert (a cell's 18.9 MB) over more and more tokens
+    by_tokens = [_rule_at(18.9e6, n, count=count) for n in (1, 2, 4, 8, 16, 32, 64, 256)]
+    assert by_tokens == sorted(by_tokens, reverse=True)
+    assert by_tokens[0] and not by_tokens[-1]
 
 
 def test_rules_given_runs_the_capacity_form():
@@ -361,7 +429,8 @@ def test_rules_given_runs_the_capacity_form():
 @pytest.mark.parametrize(
     "why", ["int8", "expert-biases", "oai_glu", "sigmoid_input", "capacity-drops"]
 )
-def test_forms_the_loop_does_not_cover_keep_the_capacity_form(why):
+def test_forms_the_loop_does_not_cover_keep_the_capacity_form(why, monkeypatch):
+    monkeypatch.setattr(moe, "TRIP_BYTES", 0)  # toy widths (the module's docstring)
     form = "softmax-bias-zero-held"
     layer = jax.eval_shape(lambda: _layer(form))
     args = dict(

@@ -5,9 +5,13 @@ both forms of the expert FFNs serve the same logits, and the engine
 publishes how many of the held experts the routed layer calls read.
 
 Toy widths, a router wide enough (60 experts + 4 identity ones, top-2,
-8 held) that 4 slots and a verify grid of 4 x 5 stay under the constant;
-``scmoe-tiny``'s layer otherwise (two latent sublayers, the experts
-across them), so the loop sits where the agent cell's does.
+8 held) that 4 slots and a verify grid of 4 x 5 expect most experts
+unpicked; ``scmoe-tiny``'s layer otherwise (two latent sublayers, the
+experts across them), so the loop sits where the agent cell's does. The
+rule weighs an expert's bytes against a loop trip's fixed cost
+(``moe.TRIP_BYTES``), which a toy expert is far under: every test here
+runs with that cost scaled to the toy expert, a quarter of its bytes as
+it is of a cell's, so that the rule breaks even at a share of 0.8.
 """
 
 import dataclasses
@@ -35,6 +39,13 @@ def params():
     return llama.init_params(C, jax.random.key(3))
 
 
+@pytest.fixture(autouse=True)
+def _toy_trip(params, monkeypatch):
+    layer = params["layers"]
+    expert_bytes = sum(layer[w][0, 0].nbytes for w in moe.EXPERT_STACKS)
+    monkeypatch.setattr(moe, "TRIP_BYTES", expert_bytes // 4)
+
+
 def _takes_picked(b, t):
     layer = jax.eval_shape(lambda: llama.init_params(C, jax.random.key(0)))["layers"]
     return moe.reads_picked_experts(
@@ -45,7 +56,7 @@ def _takes_picked(b, t):
 def test_the_shapes_engage_the_picked_form():
     assert _takes_picked(B, 1) and _takes_picked(B, 5)  # decode, verify
     assert _takes_picked(1, CHUNK)  # a 16-token chunk: dropless, 16 tokens
-    assert not _takes_picked(2, 64)  # 128 tokens: over the constant
+    assert not _takes_picked(2, 64)  # 128 tokens: nearly every expert picked
 
 
 def _prefilled(params, rng, lengths):
@@ -124,7 +135,7 @@ def test_both_forms_serve_the_same_logits_and_count_what_they_read(
     before = np.asarray(cache["moe_reads"])
     with jax.default_matmul_precision("highest"):
         logits, after = run()
-        monkeypatch.setattr(moe, "PICKED_SHARE", 0.0)  # the capacity form
+        monkeypatch.setattr(moe, "TRIP_BYTES", float("inf"))  # the capacity form
         want, after_cap = run()
     live = np.asarray(mask) if program != "prefill_chunk_step" else slice(None)
     np.testing.assert_allclose(

@@ -101,7 +101,23 @@ CELLS = {
 #: of them picked a call: the capacity form), ``CELL_PINS["minitron-4b"]``
 #: and the other 52 tiny digests stand letter for letter: the proof that
 #: splitting ``moe.router`` into ``select`` / ``_routing_aux`` and
-#: ``moe_mlp`` into its two forms left the capacity form's text alone
+#: ``moe_mlp`` into its two forms left the capacity form's text alone.
+#: Three digests were taken again on the tree of PR 46, on purpose, and
+#: no other of the 110: ``moe.reads_picked_experts`` chooses by the bytes
+#: each form streams a call (``moe.TRIP_BYTES`` 5.1 MB a loop trip beside
+#: its expert, read on the chip) where it held the expected share of
+#: experts picked under a constant 0.6. ``lfm2-24b-a2b-ep8``'s
+#: ``decode_step`` and ``decode_loop`` (0.644 of 8 experts of 18.9 MB
+#: expected at 16 tokens: 168.7 µs a layer call against 213.3) now read
+#: the picked experts alone, the change that PR was for; and
+#: ``longcat-flash-chat-4l-ep32``'s ``verify_step``, which its issue did
+#: not foresee: a 16 x 5 grid over the 768-wide top-12 router expects
+#: 0.716 of the 16 held experts picked, and at 75.5 MB an expert the loop
+#: streams fewer bytes (1,245 µs a layer call against 1,705 on the chip).
+#: No cell's traffic reaches a verify step. Every other grid stays: 0.716
+#: of thinking's 64 experts of 11.8 MB (the loop 11 % behind there),
+#: 0.92-0.99 in longdoc, mixed and rag; reasoning's 0.793 of 64 at
+#: 17.3 MB keeps the capacity form at decode (the loop 2.4 % behind)
 CELL_PINS = {
     "dots3-note-prev-5l-ep8": {
         "decode_step": "e0a2a0ea02be32c46fbd6666b4fae69a96b65062217f90cbd1247b465b60cebf",
@@ -122,7 +138,7 @@ CELL_PINS = {
     "longcat-flash-chat-4l-ep32": {
         "decode_step": "58eb77fe356a7e98456547a1d9da51c7cedf84e13c52011984338c4d08766741",
         "decode_loop": "5d6c1e1796dbf5eb78f6a238c6d5a1c8508920f2f3268709795a3f6a7db7c3cf",
-        "verify_step": "69049f7889eb1b60f10926b82f7785952d4a7d17aec1e5895f9599749dd785a4",
+        "verify_step": "cc18ba84f37d31c0d2db54aeff49bd83fef9018f00c85d558ca87b11f7ef51b8",
         "prefill_chunk_step@0": "899a7a025997ee5b0f319e0b6208dc4bc1b422b3e18caf5525c53c68ae956345",
         "prefill_chunk_step@256": "d7b69b55ff596b5ca140e6bd3a77faeb7562f516b4528e53ba223ed1f6919afd",
         "prefill_packed_step@2": "bc4328d7ebb9e49e8e35b52632652771c38aba5f5c0aec1f9bd8e9028ca75e89",
@@ -138,8 +154,8 @@ CELL_PINS = {
         "prefill_packed_step@4": "58669b78efa6c435462dc49bbb024e5b374ad5ea176776db733f768b39cb4d5a",
     },
     "lfm2-24b-a2b-ep8": {
-        "decode_step": "0108a966b4079ac3d5ad143f591254076147c8221b05da62134de39901916747",
-        "decode_loop": "b3d616e305274bdacda4b7cf03b36659011323e13fea403c0d7c72d2f3490a04",
+        "decode_step": "b079f16e34d0b2f2406cddafda048f14b262b1e2ed3c2eb07b9ab8e0c92f5445",
+        "decode_loop": "271432d2b38d3659ca99782dc44b78f54eeca0d2f28b79ab4461cdcd0867edc5",
         "verify_step": "831769435377710277fbe4b0f23043ff4d1f2060c38c2d2bda9a9286ee306a5a",
         "prefill_packed_step@1": "3dd39d9e6936c07c7ec7b3948341a70250fbe7220133261eef9a9d66e093e87a",
         "prefill_packed_step@2": "f0294c4a5685eb1ca4a64a50fc6cb3a304a98dc89fb7e9cfe10160bddb6d4a6a",
