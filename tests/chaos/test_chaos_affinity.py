@@ -25,7 +25,6 @@ import json
 import time
 
 import aiohttp
-import jax
 from aiohttp import web
 from aiohttp.test_utils import TestClient, TestServer
 
@@ -38,6 +37,7 @@ from dstack_tpu.routing.pool import PoolConfig, ReplicaPool, ReplicaState
 from dstack_tpu.serve.engine import InferenceEngine
 from dstack_tpu.serve.openai_server import build_app
 from dstack_tpu.serve.tokenizer import ByteTokenizer
+from tests.shared import init_params
 
 TENANT = "chaos-tenant"
 
@@ -148,7 +148,7 @@ async def _serving_stack(
     """n REAL replicas (same tiny model + params) behind a logging
     router → (client, servers, engines, router)."""
     config = dataclasses.replace(llama.LLAMA_TINY, n_layers=n_layers)
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     servers, engines = [], []
     for _ in range(n):
         engine = InferenceEngine(

@@ -11,7 +11,6 @@ import sys
 import time
 from pathlib import Path
 
-import jax
 import pytest
 
 from dstack_tpu import faults
@@ -20,6 +19,7 @@ from dstack_tpu.obs import flight, tracing
 from dstack_tpu.serve.engine import GenParams, InferenceEngine
 from dstack_tpu.serve.openai_server import build_app
 from dstack_tpu.serve.tokenizer import ByteTokenizer
+from tests.shared import init_params
 
 REPO = Path(__file__).resolve().parents[2]
 
@@ -50,7 +50,7 @@ async def _watchdog_client(watchdog_seconds=0.3):
     from aiohttp.test_utils import TestClient, TestServer
 
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     engine = InferenceEngine(config, params, max_batch=4, max_seq=128)
     if watchdog_seconds:
         # as `openai_server.main` does before it serves: a 0.3 s
@@ -197,7 +197,7 @@ class TestFlightChaosAcceptance:
         bound — flight writes are a few dict ops against a ~ms jit
         dispatch, and CPU CI timing is noisy)."""
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         eng = InferenceEngine(
             config, params, max_batch=2, max_seq=512,
             spec_draft=0, turbo_steps=0,

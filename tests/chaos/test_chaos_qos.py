@@ -12,14 +12,13 @@ and the token bucket's schedule is a pure function of its clock.
 import asyncio
 import time
 
-import jax
-
 from dstack_tpu import faults, qos
 from dstack_tpu.models import llama
 from dstack_tpu.qos import PriorityPending, QoSPolicy, TokenBucket
 from dstack_tpu.serve.engine import InferenceEngine
 from dstack_tpu.serve.openai_server import build_app
 from dstack_tpu.serve.tokenizer import ByteTokenizer
+from tests.shared import init_params
 
 
 class TestTokenBucketDeterminism:
@@ -220,7 +219,7 @@ def _make_client(qos_policy=None, max_batch=4):
     from aiohttp.test_utils import TestClient, TestServer
 
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     engine = InferenceEngine(config, params, max_batch=max_batch, max_seq=128)
     app = build_app(
         engine, ByteTokenizer(), "llama-tiny", qos_policy=qos_policy

@@ -2,20 +2,19 @@
 request(s); the scheduler loop survives and the server keeps serving.
 """
 
-import jax
-
 from dstack_tpu import faults
 from dstack_tpu.models import llama
 from dstack_tpu.serve.engine import InferenceEngine
 from dstack_tpu.serve.openai_server import build_app
 from dstack_tpu.serve.tokenizer import ByteTokenizer
+from tests.shared import init_params
 
 
 async def _client():
     from aiohttp.test_utils import TestClient, TestServer
 
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     engine = InferenceEngine(config, params, max_batch=4, max_seq=128)
     app = build_app(engine, ByteTokenizer(), "llama-tiny")
     client = TestClient(TestServer(app))
@@ -70,7 +69,7 @@ async def _client_with(watchdog_seconds=0.0, qos_policy=None, max_batch=4):
     from aiohttp.test_utils import TestClient, TestServer
 
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     engine = InferenceEngine(config, params, max_batch=max_batch, max_seq=128)
     if watchdog_seconds:
         # as `openai_server.main` does before it serves: a watchdog of
@@ -219,7 +218,7 @@ class TestPreFirstTokenRefund:
         from dstack_tpu.serve.tokenizer import ByteTokenizer
 
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         engine = InferenceEngine(config, params, max_batch=2, max_seq=64)
         sched = Scheduler(engine, ByteTokenizer())
         bucket = qos_mod.TokenBucket(rate=0.001, burst=2.0)

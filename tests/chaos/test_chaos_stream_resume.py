@@ -17,7 +17,6 @@ import asyncio
 import json
 
 import aiohttp
-import jax
 import pytest
 from aiohttp import web
 from aiohttp.test_utils import TestClient, TestServer
@@ -31,6 +30,7 @@ from dstack_tpu.routing.pool import PoolConfig, ReplicaPool
 from dstack_tpu.serve.engine import InferenceEngine
 from dstack_tpu.serve.openai_server import build_app
 from dstack_tpu.serve.tokenizer import ByteTokenizer
+from tests.shared import init_params
 
 
 def _sse_events(raw: bytes) -> list:
@@ -93,7 +93,7 @@ async def _serving_stack(qos_policy=None):
     """Two REAL replicas (same tiny model + params → identical greedy
     streams) behind a router → (router client, [replica servers])."""
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     servers = []
     for _ in range(2):
         engine = InferenceEngine(config, params, max_batch=2, max_seq=128)

@@ -20,7 +20,6 @@ import asyncio
 import json
 
 import aiohttp
-import jax
 import pytest
 from aiohttp import web
 from aiohttp.test_utils import TestClient, TestServer
@@ -33,6 +32,7 @@ from dstack_tpu.routing.pool import PoolConfig, ReplicaPool
 from dstack_tpu.serve.engine import InferenceEngine
 from dstack_tpu.serve.openai_server import build_app
 from dstack_tpu.serve.tokenizer import ByteTokenizer
+from tests.shared import init_params
 
 
 @pytest.fixture(autouse=True)
@@ -98,7 +98,7 @@ class _Router:
 
 async def _serving_stack(qos_policy=None):
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     servers, engines = [], []
     for _ in range(2):
         engine = InferenceEngine(config, params, max_batch=2, max_seq=128)

@@ -17,6 +17,7 @@ import pytest
 
 from dstack_tpu.ops.flash_decode import flash_decode, flash_decode_supported
 from dstack_tpu.serve.engine import kv_quantize
+from tests.shared import init_params
 
 NEG_INF = -1e30
 HEAD_DIM = [64, 128]
@@ -258,7 +259,7 @@ class TestEngineParity:
         from dstack_tpu.serve.engine import GenParams, InferenceEngine
 
         config = self._config()
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         prompts = [
             list(range(1, 40)),
             list(range(7, 20)),  # ragged: different lengths
@@ -297,7 +298,7 @@ class TestEngineParity:
         config = llama.dataclasses.replace(
             llama.LLAMA_TINY_64, n_heads=2, n_kv_heads=2,
         )
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         mesh = make_mesh(MeshConfig(dp=1, fsdp=1, tp=2))
         prompt = [11, 22, 33, 44, 55]
         outs = {}
@@ -320,7 +321,7 @@ class TestEngineParity:
         from dstack_tpu.serve.engine import GenParams, InferenceEngine
 
         config = self._config()
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         phrase = [5, 9, 13, 17]
         prompt = (phrase * 12)[:40]  # repetition → drafts accepted
         outs = {}
@@ -349,7 +350,7 @@ class TestEngineParity:
             hidden_size=256, intermediate_size=512,
             attn_sinks=True, sliding_window=32, sliding_pattern=2,
         )
-        params = llama.init_params(config, jax.random.key(3))
+        params = init_params(config, 3)
         mesh = make_mesh(MeshConfig(dp=1, fsdp=1, tp=2))
         phrase = [5, 9, 13, 17]
         prompt = (phrase * 12)[:44]  # repetition → drafts fire
@@ -381,7 +382,7 @@ class TestEngineParity:
             hidden_size=256, intermediate_size=512,
             attn_sinks=True, sliding_window=32, sliding_pattern=2,
         )
-        params = llama.init_params(config, jax.random.key(2))
+        params = init_params(config, 2)
         mesh = make_mesh(MeshConfig(dp=1, fsdp=1, tp=2))
         prompt = list(range(3, 50))  # long enough to engage the window
         outs = {}
@@ -400,7 +401,7 @@ class TestEngineParity:
         from dstack_tpu.serve.engine import InferenceEngine
 
         config = llama.LLAMA_TINY  # head_dim 32
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         with pytest.raises(ValueError, match="flash"):
             InferenceEngine(
                 config, params, max_batch=2, max_seq=256,

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from dstack_tpu.models import llama
+from tests.shared import init_params
 
 TIGHT = 2e-5
 
@@ -131,7 +132,7 @@ def test_periods_fold_the_runs_and_lose_none(kinds, head, period, count, tail):
 @pytest.mark.parametrize("model", sorted(MODELS))
 def test_the_tree_holds_a_stack_a_kind_and_counts_itself(model):
     c = MODELS[model]
-    params = llama.init_params(c, jax.random.key(0))
+    params = init_params(c, 0)
     n_win = c.layer_types.count("window")
     n_full = c.n_layers - n_win - c.first_k_dense
     assert params["window_layers"]["wq"].shape == (n_win, 64, 6 * 16)
@@ -164,7 +165,7 @@ def test_a_list_of_full_layers_is_the_model_without_one():
     scans: the same weights give the same logits."""
     plain = dataclasses.replace(TINY, n_layers=4, layer_types=(), sliding_window=0)
     listed = dataclasses.replace(plain, layer_types=("full",) * 4)
-    params = llama.init_params(plain, jax.random.key(2))
+    params = init_params(plain, 2)
     tokens = jax.random.randint(jax.random.key(3), (2, 24), 1, 256)
     with jax.default_matmul_precision("highest"):
         a = llama.forward(params, tokens, plain)
@@ -181,7 +182,7 @@ def test_window_layers_of_the_full_shape_are_the_pattern_model():
     fields = {**BASE, "swa_n_heads": 4, "swa_partial_rotary": 0.0, "intermediate_size": 96}
     groups = llama.LlamaConfig(n_layers=6, layer_types=kinds, **fields)
     pattern = llama.LlamaConfig(n_layers=6, sliding_pattern=3, **fields)
-    params = llama.init_params(groups, jax.random.key(4))
+    params = init_params(groups, 4)
     at = {"layers": 0, "window_layers": 0}
     order = []
     for kind in kinds:
@@ -213,7 +214,7 @@ def test_the_forward_trains(model):
     """The training-side forward differentiates through every stack:
     each group's projections, gates and experts get a gradient."""
     c = MODELS[model]
-    params = llama.init_params(c, jax.random.key(6))
+    params = init_params(c, 6)
     tokens = jax.random.randint(jax.random.key(7), (2, 20), 1, 256)
 
     def loss(p):
@@ -236,7 +237,7 @@ def test_the_gate_scales_a_heads_output():
     """A gate driven shut silences the attention sublayer; left out,
     the logits move."""
     c = PLAIN
-    params = llama.init_params(c, jax.random.key(8))
+    params = init_params(c, 8)
     tokens = jax.random.randint(jax.random.key(9), (1, 16), 1, 256)
     shut = jax.tree.map(lambda a: a, params)
     for key in ("layers", "window_layers"):

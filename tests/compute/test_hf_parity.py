@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from dstack_tpu.models import llama
 from dstack_tpu.models.convert_hf import load_checkpoint
+from tests.shared import init_params
 
 B, T = 2, 16
 
@@ -567,7 +568,7 @@ class TestExport:
                 rope_theta=10000.0, max_seq_len=64, dtype=jnp.float32,
                 remat=False,
             )
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         out_dir = tmp_path / "export"
         save_checkpoint(config, params, str(out_dir))
 
@@ -589,7 +590,7 @@ class TestExport:
         config = llama.dataclasses.replace(
             llama.LLAMA_TINY, vocab_size=300, tie_embeddings=False
         )
-        params = llama.init_params(config, jax.random.key(1))
+        params = init_params(config, 1)
         save_checkpoint(config, params, str(tmp_path / "rt"))
         config2, params2 = load_checkpoint(
             str(tmp_path / "rt"), dtype=jnp.float32
@@ -779,7 +780,7 @@ class TestQwen3Moe:
             n_heads=4, n_kv_heads=2, head_dim=16, intermediate_size=96,
             max_seq_len=64, dtype=jnp.float32, remat=False,
         )
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         out = tmp_path / "export"
         save_checkpoint(config, params, str(out))
         hf_model = transformers.AutoModelForCausalLM.from_pretrained(
@@ -1257,7 +1258,7 @@ class TestStarcoder2:
             n_heads=4, n_kv_heads=2, head_dim=16, intermediate_size=96,
             max_seq_len=64, sliding_window=0, dtype=jnp.float32, remat=False,
         )
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         out = tmp_path / "export"
         save_checkpoint(config, params, str(out))
         hf_model = transformers.AutoModelForCausalLM.from_pretrained(
@@ -1320,7 +1321,7 @@ class TestNemotron:
             n_heads=4, n_kv_heads=2, head_dim=16, intermediate_size=96,
             max_seq_len=64, dtype=jnp.float32, remat=False,
         )
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         out = tmp_path / "export"
         save_checkpoint(config, params, str(out))
         hf_model = transformers.AutoModelForCausalLM.from_pretrained(

@@ -16,6 +16,7 @@ from dstack_tpu.train.lora import (
     sharded_lora_init,
 )
 from dstack_tpu.train.step import default_optimizer
+from tests.shared import init_params
 
 CFG = llama.LLAMA_TINY
 LORA = LoRAConfig(rank=4, alpha=8.0)
@@ -33,7 +34,7 @@ def _batch(key, batch=4, seq=32):
 class TestLoRAForward:
     def test_zero_init_is_identity(self):
         """B=0 at init → adapter output must equal the base model."""
-        params = llama.init_params(CFG, jax.random.key(0))
+        params = init_params(CFG, 0)
         lora = init_lora_params(CFG, LORA, jax.random.key(1))
         tokens = jax.random.randint(jax.random.key(2), (2, 16), 0, CFG.vocab_size)
         base = llama.forward(params, tokens, CFG)
@@ -44,7 +45,7 @@ class TestLoRAForward:
 
     def test_bypass_matches_merged_weights(self):
         """s·(x·A)·B bypass ≡ forward with W+s·A·B folded in."""
-        params = llama.init_params(CFG, jax.random.key(0))
+        params = init_params(CFG, 0)
         lora = init_lora_params(CFG, LORA, jax.random.key(1))
         # give B real values so the adapters actually do something
         lora = jax.tree.map(
@@ -62,7 +63,7 @@ class TestLoRAForward:
 
     def test_mlp_target_modules(self):
         lora_conf = LoRAConfig(rank=4, target_modules=("w_gate", "w_up", "w_down"))
-        params = llama.init_params(CFG, jax.random.key(0))
+        params = init_params(CFG, 0)
         lora = init_lora_params(CFG, lora_conf, jax.random.key(1))
         tokens = jnp.zeros((1, 8), jnp.int32)
         out = llama.forward(params, tokens, CFG, lora=lora, lora_scale=lora_conf.scale)

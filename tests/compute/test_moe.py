@@ -10,6 +10,7 @@ from dstack_tpu.models import llama, moe
 from dstack_tpu.parallel.mesh import MeshConfig, make_mesh
 from dstack_tpu.parallel.sharding import default_rules
 from dstack_tpu.train.step import default_optimizer, make_train_step, sharded_init
+from tests.shared import init_params
 
 
 def _moe_layer(key, h=16, f=32, e=4):
@@ -88,7 +89,7 @@ class TestShardedMoE:
 class TestMoELlama:
     def test_forward_and_aux(self):
         config = llama.MOE_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         tokens = jax.random.randint(jax.random.key(1), (2, 32), 0, config.vocab_size)
         logits, aux = llama.forward(params, tokens, config, return_aux=True)
         assert logits.shape == (2, 32, config.vocab_size)

@@ -18,6 +18,7 @@ from dstack_tpu.parallel.pipeline import (
     unmicrobatch,
 )
 from dstack_tpu.train.step import default_optimizer, make_train_step, sharded_init
+from tests.shared import init_params
 
 
 def _simple_stack(key, n_layers=4, h=16):
@@ -104,7 +105,7 @@ class TestPipelinedLlama:
     def test_forward_matches(self):
         mesh = make_mesh(MeshConfig(pp=2, fsdp=2, tp=2))
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         tokens = jax.random.randint(jax.random.key(1), (4, 64), 0, config.vocab_size)
         ref = llama.forward(params, tokens, config)
         out = jax.jit(

@@ -13,6 +13,7 @@ from dstack_tpu.models.quant import (
     quantize_tree,
     quantize_weight,
 )
+from tests.shared import init_params
 
 
 class TestQuantizeWeight:
@@ -41,7 +42,7 @@ class TestQuantizeWeight:
 class TestQuantizedForward:
     def test_logits_close_to_full_precision(self):
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         assert is_quantized(qparams)
         tokens = jax.random.randint(jax.random.key(1), (2, 32), 0, config.vocab_size)
@@ -54,7 +55,7 @@ class TestQuantizedForward:
 
     def test_untied_lm_head_quantized(self):
         config = llama.dataclasses.replace(llama.LLAMA_TINY, tie_embeddings=False)
-        params = llama.init_params(config, jax.random.key(2))
+        params = init_params(config, 2)
         qparams = quantize_tree(params, config)
         assert "lm_head_q" in qparams and "lm_head" not in qparams
         tokens = jax.random.randint(jax.random.key(3), (1, 16), 0, config.vocab_size)
@@ -70,7 +71,7 @@ class TestQuantizedForward:
         config = llama.dataclasses.replace(
             llama.MOE_TINY, capacity_factor=float(llama.MOE_TINY.n_experts)
         )
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         assert "w_gate_q" in qparams["layers"]
         assert qparams["layers"]["w_gate_s"].shape == (
@@ -94,7 +95,7 @@ class TestQuantizedForward:
             moe_shared_intermediate=64,
             capacity_factor=float(llama.MOE_TINY.n_experts),
         )
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         assert "w_shared_gate_q" in qparams["layers"]
         tokens = jax.random.randint(
@@ -112,7 +113,7 @@ class TestQuantizedForward:
         config = llama.dataclasses.replace(
             llama.MOE_TINY, capacity_factor=float(llama.MOE_TINY.n_experts)
         )
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         eng = InferenceEngine(
             config, qparams, max_batch=2, max_seq=64,
@@ -139,7 +140,7 @@ class TestRandomQuantizedParams:
         from dstack_tpu.models.quant import random_quantized_params
 
         real = quantize_tree(
-            llama.init_params(config, jax.random.key(0)), config
+            init_params(config, 0), config
         )
         fast = random_quantized_params(config)
         rl = jax.tree_util.tree_leaves_with_path(real)
@@ -199,7 +200,7 @@ class TestQuantizedServing:
         from dstack_tpu.serve.engine import GenParams, InferenceEngine
 
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         full_eng = InferenceEngine(config, params, max_batch=2, max_seq=64)
         q_eng = InferenceEngine(config, qparams, max_batch=2, max_seq=64)
@@ -217,7 +218,7 @@ class TestQuantizedServing:
         from dstack_tpu.serve.engine import GenParams, InferenceEngine
 
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         mesh = make_mesh(
             MeshConfig(dp=1, fsdp=1, tp=2), devices=jax.devices()[:2]
@@ -231,7 +232,7 @@ class TestQuantizedServing:
 
     def test_spec_tree_matches_quantized_leaves(self):
         config = llama.dataclasses.replace(llama.LLAMA_TINY, tie_embeddings=False)
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         specs = quant_param_specs(llama.param_specs(config))
         # identical tree structure → shardable leaf-for-leaf
@@ -257,7 +258,7 @@ class TestMLAQuantization:
         from dstack_tpu.models.quant import quant_targets
 
         config = llama.MLA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         assert is_quantized(qparams)
         for stack in ("layers", "dense_layers"):
@@ -271,7 +272,7 @@ class TestMLAQuantization:
 
     def test_mla_quantized_forward_close(self):
         config = llama.MLA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         tokens = jax.random.randint(
             jax.random.key(1), (2, 32), 0, config.vocab_size
@@ -286,7 +287,7 @@ class TestMLAQuantization:
         from dstack_tpu.serve.engine import GenParams, InferenceEngine
 
         config = llama.MLA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         eng = InferenceEngine(config, qparams, max_batch=2, max_seq=128)
         out = eng.generate([7, 11, 13, 17], GenParams(max_new_tokens=5))
@@ -300,7 +301,7 @@ class TestMLAQuantization:
         from dstack_tpu.serve.engine import GenParams, InferenceEngine
 
         config = llama.MLA_TINY  # 4 q heads: tp=2 shards them
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         prompt = [7, 11, 13, 17]
         ref = InferenceEngine(
@@ -314,7 +315,7 @@ class TestMLAQuantization:
 
     def test_mla_spec_tree_matches_quantized_leaves(self):
         config = llama.MLA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         qparams = quantize_tree(params, config)
         specs = quant_param_specs(llama.param_specs(config), config)
         p_paths = {

@@ -12,13 +12,14 @@ import numpy as np
 import pytest
 
 from dstack_tpu.models import llama
+from tests.shared import init_params
 
 TINY = llama.CONFIGS["scmoe-tiny"]
 
 
 def test_tree_axes_and_count():
     c = TINY
-    params = llama.init_params(c, jax.random.key(0))
+    params = init_params(c, 0)
     L, H = c.n_layers, c.hidden_size
     layers = params["layers"]
     assert {k for k in layers if k.startswith("sub")} == {"sub0", "sub1"}
@@ -66,7 +67,7 @@ def test_gradients_reach_the_branch_and_both_sublayers():
     """The loss moves with the router, the experts and each sublayer's
     attention and dense FFN; an identity expert has nothing to train."""
     c = TINY
-    params = llama.init_params(c, jax.random.key(1))
+    params = init_params(c, 1)
     tokens = jax.random.randint(jax.random.key(2), (2, 16), 1, c.vocab_size)
 
     def loss(p):

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from dstack_tpu.models import convert_hf, kda, llama, shortconv
+from tests.shared import init_params
 
 TIGHT = 2e-5
 H, K = 24, 3
@@ -127,7 +128,7 @@ def test_leaves_and_count():
         "conv_win": (5, 128, 384), "conv_w": (5, 3, 128), "wo": (5, 128, 128),
     }
     assert shortconv.n_params(c) == 128 * 384 + 3 * 128 + 128 * 128
-    p = llama.init_params(c, jax.random.key(0))
+    p = init_params(c, 0)
     assert sum(a.size for a in jax.tree.leaves(p)) == c.num_params()
     assert set(p) == {"embed", "dense_layers", "layers", "conv_layers", "final_norm"}
     assert "wq" not in p["conv_layers"] and "q_norm" not in p["conv_layers"]

@@ -18,6 +18,7 @@ platform gate is steered in the test (``_as_tpu``).
 """
 
 import dataclasses
+import functools
 import os
 import re
 
@@ -59,21 +60,21 @@ def topo():
 def _no_compile_cache():
     """A described-device executable is written to the persistent cache
     but can never be read back without a chip (each later compile would
-    warn and recompile), so the cache is off around this module — and
-    stays off for the rest of the process. Twice in three whole runs of
-    PR 33's tree (and once in PR 31's) the xdist worker that had
-    compiled this module's programs for the described TPU died with a
-    segmentation fault inside ``compilation_cache.
-    get_executable_and_time`` when a later test of another module read
-    a CPU executable back (``test_prefix_registry``'s ``decode_loop``;
-    the entry was sound: other processes loaded it before and after;
-    not reproduced in one process). A process that has loaded libtpu
-    for a described topology compiles what it needs itself."""
+    warn and recompile), so the cache is off around this module's cases,
+    and on again behind them: the tests a worker runs afterwards (most
+    of ``tests/serve``) find what its neighbours compiled. Until PR 47 it
+    stayed off for the rest of the process, on PR 33's reading that a
+    process which had loaded libtpu died reading a CPU executable back;
+    what it read was an entry a neighbour was still writing (PR 37), and
+    ``tests/conftest.py`` now writes an entry whole or not at all."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
 
 
 @pytest.fixture
@@ -262,6 +263,21 @@ def test_kernel_compiles(topo, name):
 # ---------------------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def _abstract_params(config):
+    """The parameters' shapes, traced once a configuration: the cases of
+    one model share them (a trace of ``init_params`` is not the compile
+    a case is here for)."""
+    return jax.eval_shape(lambda: llama.init_params(config, jax.random.key(0)))
+
+
+@functools.lru_cache(maxsize=None)
+def _abstract_cache(config, max_batch, max_seq, kv_quant=None):
+    return jax.eval_shape(
+        lambda: eng.init_cache(config, max_batch, max_seq, kv_quant=kv_quant)
+    )
+
+
 def _abstract_engine_state(config, sharding, max_batch=16, max_seq=2048):
     """(params, cache, sds) as shapes placed on ``sharding`` (None: the
     caller places them)."""
@@ -272,10 +288,8 @@ def _abstract_engine_state(config, sharding, max_batch=16, max_seq=2048):
             tree,
         )
 
-    params = jax.eval_shape(
-        lambda: llama.init_params(config, jax.random.key(0))
-    )
-    cache = jax.eval_shape(lambda: eng.init_cache(config, max_batch, max_seq))
+    params = _abstract_params(config)
+    cache = _abstract_cache(config, max_batch, max_seq)
     return place(params), place(cache), lambda shape, dt: jax.ShapeDtypeStruct(
         shape, dt, sharding=sharding
     )
@@ -371,6 +385,7 @@ def test_mla_moe_decode_step_deepseek_widths(topo):
     _fits(compiled)
 
 
+@functools.lru_cache(maxsize=None)
 def _layer_groups_config(name="dots3-note-prev-5l-ep8"):
     """A benchmark configuration of layer groups at its published
     widths: ``benchmark/configs/dots3-note-prev-5l-ep8.json`` (two
@@ -547,12 +562,8 @@ def _serving_program(case, sds, place):
         "conv_gqa": (_layer_groups_config("lfm2-24b-a2b-ep8"), 8192, None),
     }[model]
     config = config or _layer_groups_config()
-    params = place(jax.eval_shape(
-        lambda: llama.init_params(config, jax.random.key(0))
-    ))
-    cache = place(jax.eval_shape(
-        lambda: eng.init_cache(config, b, max_seq, kv_quant=kv_quant)
-    ))
+    params = place(_abstract_params(config))
+    cache = place(_abstract_cache(config, b, max_seq, kv_quant))
     if program == "decode_loop":
         fn = lambda p, c, t, pos, rem, act, eos: eng.decode_loop(
             p, c, t, pos, rem, act, eos, config, steps=8, max_seq=max_seq

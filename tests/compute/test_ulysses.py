@@ -10,6 +10,7 @@ from dstack_tpu.models import llama
 from dstack_tpu.ops.attention import _xla_attention
 from dstack_tpu.parallel.mesh import MeshConfig, make_mesh
 from dstack_tpu.parallel.ulysses import ulysses_attention
+from tests.shared import init_params
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 4, reason="needs >= 4 virtual devices"
@@ -105,7 +106,7 @@ class TestUlyssesInModel:
         the single-device forward."""
         mesh = make_mesh(MeshConfig(dp=1, fsdp=2, sp=2, tp=2))
         config = llama.dataclasses.replace(llama.LLAMA_TINY, max_seq_len=128)
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         tokens = jax.random.randint(jax.random.key(1), (2, 128), 0, config.vocab_size)
 
         dense = llama.forward(params, tokens, config)

@@ -16,46 +16,50 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-# Persistent XLA compile cache: CPU compiles dominate the suite on this
-# single-core image (a cold full run cannot finish in any reviewer's
-# patience budget; a warm one can). On by default for tests, at the
-# place every entry point uses (utils/backend.enable_compile_cache:
-# JAX_COMPILATION_CACHE_DIR, else the fixed in-checkout path) — disable
-# with DTPU_TEST_NO_COMPILE_CACHE=1. The cpu_aot_loader logs a noisy
-# machine-feature pseudo-mismatch (prefer-no-scatter/gather) on every
-# cache load even though compile and execute happen on this same
-# machine; those ERROR lines are suppressed ONLY when the cache is on.
+# Persistent XLA compile cache (DTPU_TEST_NO_COMPILE_CACHE=1 turns it off):
+# the suite's seconds are CPU compiles, six xdist workers on 8 cores under
+# the driver's 1,470 s (docs/guides/testing.md). All processes share ONE
+# directory (JAX's key holds the path: a directory a worker shares nothing)
+# and an entry is written whole, a temporary file then ``os.replace``: JAX's
+# plain ``write_bytes`` let a neighbour read half of one and die (PR 37).
 _use_compile_cache = os.environ.get("DTPU_TEST_NO_COMPILE_CACHE") != "1"
 if _use_compile_cache:
+    # every cache load logs a harmless cpu_aot_loader machine-feature ERROR
     os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
 
 import asyncio  # noqa: E402
 import inspect  # noqa: E402
+import threading  # noqa: E402
 
 import pytest  # noqa: E402
 
+
+def share_compile_cache() -> str:
+    """Turn the cache on at the suite's directory, ``tests/`` under the
+    entry points' (the server children that tests start write theirs
+    without a lock), with an atomic ``put``;
+    ``tests/test_compile_cache_contract.py`` pins the ``jax._src`` names
+    this stands on."""
+    from jax._src import compilation_cache, lru_cache
+
+    from dstack_tpu.utils.backend import compile_cache_dir, enable_compile_cache
+
+    class AtomicPutCache(lru_cache.LRUCache):
+        def put(self, key: str, val: bytes) -> None:
+            entry = self.path / f"{key}{lru_cache._CACHE_SUFFIX}"
+            if not entry.exists():
+                tmp = self.path / f"{key}.{os.getpid()}.{threading.get_ident()}.tmp"
+                tmp.write_bytes(val)
+                os.replace(tmp, entry)
+
+    compilation_cache.get_file_cache = lambda path: (
+        AtomicPutCache(path, max_size=-1), path
+    )
+    return enable_compile_cache(os.path.join(compile_cache_dir(), "tests"))
+
+
 if _use_compile_cache:
-    from dstack_tpu.utils.backend import enable_compile_cache  # noqa: E402
-
-    # One directory a xdist WORKER. JAX writes a cache entry with a
-    # plain ``write_bytes`` and reads it back without a lock unless the
-    # cache has a size limit (``jax/_src/lru_cache.py``), so a worker
-    # that finds an entry another worker is still writing reads a
-    # truncated executable: the segmentation faults inside
-    # ``compilation_cache.get_executable_and_time`` of PR 33 (both "while
-    # reading a sound entry", one on a fresh cache) and the one test that
-    # flipped in the driver's run of PR 35
-    # (``test_prefix_registry.py::...::test_slot_overwrite_drops_stale_entry``,
-    # the first of its file to load llama-tiny's ``decode_loop``, which
-    # tests of other files on other workers compile at the same moment).
-    # A worker's own entries are written before it reads them.
-    _worker = os.environ.get("PYTEST_XDIST_WORKER")
-    if _worker and not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        from dstack_tpu.utils.backend import compile_cache_dir  # noqa: E402
-
-        enable_compile_cache(os.path.join(compile_cache_dir(), _worker))
-    else:
-        enable_compile_cache()
+    share_compile_cache()
 
 
 # ---- quick tier ----

@@ -20,7 +20,6 @@ that (``test_a_wrong_tail_shows``).
 """
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +28,7 @@ import pytest
 
 from dstack_tpu.models import llama
 from dstack_tpu.serve import engine as E
+from tests.shared import init_params, jitted
 
 C = llama.CONFIGS["conv-tiny"]
 TIGHT = 2e-5
@@ -39,7 +39,7 @@ N_MOE = C.n_layers - C.first_k_dense
 
 @pytest.fixture(scope="module")
 def params():
-    p = llama.init_params(C, jax.random.key(11))
+    p = init_params(C, 11)
     # a selection bias that bites, as the benchmark draws it
     for stack in ("layers", "conv_layers"):
         bias = p[stack]["router_bias"]
@@ -70,12 +70,12 @@ class _Served:
     def __init__(self, params):
         self.params = params
         self.cache = E.init_cache(C, B, TMAX, chunk=CHUNK)
-        self.decode = jax.jit(partial(E.decode_step, config=C))
+        self.decode = jitted(E.decode_step, config=C)
 
     def serial(self, prompt, slot):
         for start in range(0, len(prompt), CHUNK):
             chunk = prompt[start:start + CHUNK]
-            fn = jax.jit(partial(E.prefill_chunk_step, config=C, start=start))
+            fn = jitted(E.prefill_chunk_step, config=C, start=start)
             logits, self.cache = fn(
                 self.params, self.cache,
                 jnp.asarray([chunk + [0] * (CHUNK - len(chunk))], jnp.int32),
@@ -87,7 +87,7 @@ class _Served:
         """A chunk of every prompt a wave of ``g`` rows: rows at unequal
         starts once the shorter prompts are through, pad rows (slot 0,
         start 0, ``last_ix`` -1, as the engine makes them) behind."""
-        fn = jax.jit(partial(E.prefill_packed_step, config=C))
+        fn = jitted(E.prefill_packed_step, config=C)
         at, out = {s: 0 for s in prompts}, {}
         while at:
             slots = sorted(at)
@@ -197,7 +197,7 @@ def test_macro_step_carries_the_tail_over_its_tokens(params):
     act = np.zeros(B, bool)
     for s, p in prompts.items():
         tok[s], pos[s], act[s] = int(first[s].argmax()), len(p), True
-    loop = jax.jit(partial(E.decode_loop, config=C, steps=8, max_seq=TMAX))
+    loop = jitted(E.decode_loop, config=C, steps=8, max_seq=TMAX)
     toks, sv.cache, *_ = loop(
         params, sv.cache, jnp.asarray(tok), jnp.asarray(pos),
         jnp.full((B,), 50, jnp.int32), jnp.asarray(act), jnp.full((B,), -1, jnp.int32),
@@ -239,7 +239,7 @@ def test_a_rejected_draft_has_not_moved_the_tail(params, stand):
     pos[1], pos[2] = len(seq[1]) - 1, len(seq[2]) - 1
     live = np.asarray([False, True, True, False])
     dead_before = np.asarray(sv.cache["conv"])[:, 0].copy()
-    verify = jax.jit(partial(E.verify_step, config=C))
+    verify = jitted(E.verify_step, config=C)
     logits, sv.cache = verify(
         params, sv.cache, jnp.asarray(rows), jnp.asarray(pos),
         write_mask=jnp.asarray(live), draft_len=jnp.asarray([0, 4, 0, 0], jnp.int32),
@@ -356,7 +356,7 @@ def test_a_shared_prefix_is_served_whole(params):
     assert not eng._copy_fns
     cache = E.init_cache(C, B, TMAX, chunk=CHUNK)
     with pytest.raises(ValueError, match="'conv'"):
-        jax.eval_shape(partial(E.copy_cache_prefix, p=CHUNK), cache, 0, 1)
+        jax.eval_shape(lambda c: E.copy_cache_prefix(c, 0, 1, p=CHUNK), cache)
 
 
 def test_the_engine_drafts_and_keeps_its_tails(params):

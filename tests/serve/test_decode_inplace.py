@@ -20,6 +20,7 @@ import pytest
 
 from dstack_tpu.models import llama
 from dstack_tpu.serve import engine as eng
+from tests.shared import init_params, jitted
 
 TMAX = 40  # not a multiple of any tile: the last block is a clamped one
 PROMPTS = [[5, 99, 321, 7, 250], [41, 18, 3, 77, 400, 10, 20, 30, 40], [9] * 17]
@@ -45,7 +46,7 @@ def model(request):
     assert request.param != "mla-moe-first-k-dense" or (
         config.mla and config.n_experts and config.first_k_dense == 1
     )
-    params = llama.init_params(config, jax.random.key(7))
+    params = init_params(config, 7)
     rng = np.random.default_rng(11)
     seqs = [
         p + [int(t) for t in rng.integers(1, config.vocab_size, N_NEW + 1)]
@@ -60,9 +61,9 @@ def _prefilled(config, kv_quant, params, seqs, lengths):
     cache = eng.init_cache(config, len(seqs), TMAX, kv_quant=kv_quant)
     for b, (seq, n) in enumerate(zip(seqs, lengths)):
         toks = jnp.asarray([seq[:n] + [0] * (32 - n)], jnp.int32)
-        _, cache = eng.prefill(
+        _, cache = jitted(eng.prefill, config=config)(
             params, toks, jnp.asarray([n], jnp.int32),
-            jnp.asarray(b, jnp.int32), config, cache,
+            jnp.asarray(b, jnp.int32), cache=cache,
         )
     return cache
 
@@ -87,8 +88,8 @@ def _decode(config, params, cache, seqs, starts, steps, mask):
     for i in range(steps):
         toks = jnp.asarray([s[p + i] for s, p in zip(seqs, starts)], jnp.int32)
         pos = jnp.asarray([p + i for p in starts], jnp.int32)
-        logits, cache = eng.decode_step(
-            params, cache, toks, pos, config, write_mask=jnp.asarray(mask)
+        logits, cache = jitted(eng.decode_step, config=config)(
+            params, cache, toks, pos, write_mask=jnp.asarray(mask)
         )
         out.append(np.asarray(logits))
     return out, cache
@@ -105,10 +106,11 @@ def test_inplace_decode_matches_forward_and_prefill_cache(model):
     tol = 0.05 if kv_quant else 2e-3
     for b, seq in enumerate(seqs):
         for i in (0, N_NEW // 2, N_NEW - 1):
-            full = llama.forward(
-                params, jnp.asarray([seq[: starts[b] + i + 1]], jnp.int32), config
+            n = starts[b] + i + 1  # causal: what is padded behind moves nothing
+            full = jitted(llama.forward, config=config)(
+                params, jnp.asarray([seq[:n] + [0] * (32 - n)], jnp.int32)
             )
-            ref = np.asarray(full[0, -1])
+            ref = np.asarray(full[0, n - 1])
             assert np.abs(logits[i][b] - ref).max() < tol * max(
                 np.abs(ref).max(), 1.0
             ), (b, i)
@@ -159,8 +161,8 @@ def test_write_at_tmax_is_dropped(model):
     keep = jax.tree.map(np.asarray, before)
     toks = jnp.asarray([s[0] for s in seqs], jnp.int32)
     pos = jnp.full((3,), TMAX, jnp.int32)
-    _, after = eng.decode_step(
-        params, before, toks, pos, config, write_mask=jnp.ones((3,), bool)
+    _, after = jitted(eng.decode_step, config=config)(
+        params, before, toks, pos, write_mask=jnp.ones((3,), bool)
     )
     for name, leaf in after.items():
         assert np.asarray(leaf).tobytes() == keep[name].tobytes()
@@ -176,19 +178,19 @@ def test_decode_loop_equals_n_decode_steps(model):
     act = jnp.ones((3,), bool)
     eos = jnp.full((3,), -1, jnp.int32)
     n = 5
-    emitted, loop_cache, *_ = eng.decode_loop(
-        params, cache, tok, pos, rem, act, eos, config, steps=n, max_seq=TMAX
-    )
+    emitted, loop_cache, *_ = jitted(
+        eng.decode_loop, config=config, steps=n, max_seq=TMAX
+    )(params, cache, tok, pos, rem, act, eos)
     step_cache = _prefilled(config, kv_quant, params, seqs, starts)
     want = []
     for _ in range(n):
-        logits, step_cache = eng.decode_step(
-            params, step_cache, tok, pos, config, write_mask=act
+        logits, step_cache = jitted(eng.decode_step, config=config)(
+            params, step_cache, tok, pos, write_mask=act
         )
         new = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         was = act
-        tok, pos, rem, act = eng.advance_decode_state(
-            tok, pos, rem, act, eos, new, max_seq=TMAX
+        tok, pos, rem, act = jitted(eng.advance_decode_state, max_seq=TMAX)(
+            tok, pos, rem, act, eos, new
         )
         want.append(np.where(np.asarray(was), np.asarray(tok), -1))
     assert np.array_equal(np.asarray(emitted), np.stack(want))
@@ -208,9 +210,9 @@ def test_verify_step_leaves_the_rows_of_s_decode_steps(model):
     toks = jnp.asarray(
         [seq[p : p + s] for seq, p in zip(seqs, starts)], jnp.int32
     )
-    v_logits, v_cache = eng.verify_step(
+    v_logits, v_cache = jitted(eng.verify_step, config=config)(
         params, _prefilled(config, kv_quant, params, seqs, starts), toks,
-        jnp.asarray(starts, jnp.int32), config, jnp.asarray(mask),
+        jnp.asarray(starts, jnp.int32), write_mask=jnp.asarray(mask),
     )
     d_logits, d_cache = _decode(
         config, params, _prefilled(config, kv_quant, params, seqs, starts),

@@ -29,6 +29,7 @@ from dstack_tpu.models import llama
 from dstack_tpu.ops import flash_decode as fd
 from dstack_tpu.serve import engine as eng
 from dstack_tpu.serve.engine import GenParams, InferenceEngine
+from tests.shared import init_params
 
 TMAX, KB = 256, 128
 DENSE = dataclasses.replace(
@@ -83,7 +84,7 @@ def _block_of_128(monkeypatch, config, kv_quant=None):
 
 def _state(case):
     config, kv_quant = MODELS[case]
-    params = llama.init_params(config, jax.random.key(4))
+    params = init_params(config, 4)
     rng = np.random.default_rng(11)
     cache = eng.init_cache(config, len(LIVE), TMAX, kv_quant=kv_quant, chunk=16)
 
@@ -192,7 +193,7 @@ def test_engine_counts_each_slots_own_blocks(head_dim, monkeypatch):
     count in either block form of the kernel."""
     config = dataclasses.replace(DENSE, head_dim=head_dim)
     _block_of_128(monkeypatch, config)
-    params = llama.init_params(config, jax.random.key(1))
+    params = init_params(config, 1)
     e = InferenceEngine(
         config, params, max_batch=16, max_seq=TMAX, spec_draft=0, turbo_steps=0,
         decode_kernel="flash",

@@ -6,7 +6,6 @@ forward (``llama.forward``, no cache); the plain reference is held
 against both in ``tests/benchmark/test_reference_gqa_groups.py``."""
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +15,7 @@ import pytest
 from dstack_tpu.models import llama
 from dstack_tpu.serve import engine as E
 from dstack_tpu.serve.engine import GenParams, InferenceEngine
+from tests.shared import init_params, jitted
 
 TIGHT = 2e-5
 B, TMAX, CHUNK = 4, 96, 16  # a ring of 32 rows (window 8 - 1 + a chunk, in tiles)
@@ -47,15 +47,15 @@ WHOLE = dataclasses.replace(
 @pytest.fixture(scope="module", params=["periods", "odd", "whole"])
 def model(request):
     c = {"periods": TINY, "odd": ODD, "whole": WHOLE}[request.param]
-    return c, llama.init_params(c, jax.random.key(1))
+    return c, init_params(c, 1)
 
 
 def _forward(c, params, tokens):
-    return np.asarray(llama.forward(params, jnp.asarray(tokens)[None], c))[0]
+    return np.asarray(jitted(llama.forward, config=c)(params, jnp.asarray(tokens)[None]))[0]
 
 
 def _packed(c, params, cache, prompts: dict, g: int = 2):
-    fn = jax.jit(partial(E.prefill_packed_step, config=c))
+    fn = jitted(E.prefill_packed_step, config=c)
     at, out = {s: 0 for s in prompts}, {}
     while at:
         slots = sorted(at)
@@ -115,7 +115,7 @@ def test_prefill_then_decode_past_a_wrap_is_the_forward(model):
     cache = E.init_cache(c, B, TMAX, chunk=CHUNK)
     rng = np.random.default_rng(1)
     seqs = {1: rng.integers(1, 512, 5).tolist(), 3: rng.integers(1, 512, 50).tolist()}
-    decode = jax.jit(partial(E.decode_step, config=c))
+    decode = jitted(E.decode_step, config=c)
     with jax.default_matmul_precision("highest"):
         first, cache = _packed(c, params, cache, seqs)
         got = {s: [first[s]] for s in seqs}
@@ -151,7 +151,7 @@ def test_macro_step_and_verify_step_agree_with_the_decode_step(model):
         for s in prompts:
             tok[s], pos[s] = int(first[s].argmax()), len(prompts[s])
         # eight tokens by the decode step, a token at a time
-        decode = jax.jit(partial(E.decode_step, config=c))
+        decode = jitted(E.decode_step, config=c)
         one, t1, p1, steps = jax.tree.map(jnp.copy, cache), tok.copy(), pos.copy(), []
         for _ in range(8):
             logits, one = decode(
@@ -161,7 +161,7 @@ def test_macro_step_and_verify_step_agree_with_the_decode_step(model):
             p1 = p1 + live
             steps.append(t1.copy())
         # the same eight in one program
-        loop = jax.jit(partial(E.decode_loop, config=c, steps=8, max_seq=TMAX))
+        loop = jitted(E.decode_loop, config=c, steps=8, max_seq=TMAX)
         emitted, looped, *_ = loop(
             params, jax.tree.map(jnp.copy, cache), jnp.asarray(tok), jnp.asarray(pos),
             jnp.full((B,), 30, jnp.int32), jnp.asarray(live), jnp.full((B,), -1, jnp.int32),
@@ -177,7 +177,7 @@ def test_macro_step_and_verify_step_agree_with_the_decode_step(model):
         grid[:, 0] = tok
         for j in range(3):
             grid[:, j + 1] = steps[j]
-        vlogits, _ = jax.jit(partial(E.verify_step, config=c))(
+        vlogits, _ = jitted(E.verify_step, config=c)(
             params, cache, jnp.asarray(grid), jnp.asarray(pos), write_mask=jnp.asarray(live)
         )
         picked = np.asarray(vlogits.argmax(-1))
@@ -189,7 +189,7 @@ def test_a_dead_slot_keeps_its_bytes_in_both_buffers():
     """A masked row writes nothing: not into the full layers' rows, not
     into the ring (decode, verify and a wave's pad row alike)."""
     c = TINY
-    params = llama.init_params(c, jax.random.key(1))
+    params = init_params(c, 1)
     rng = np.random.default_rng(3)
     cache = {
         n: jnp.asarray(rng.normal(size=a.shape), a.dtype) if a.ndim > 1 else a
@@ -198,8 +198,8 @@ def test_a_dead_slot_keeps_its_bytes_in_both_buffers():
     live = jnp.asarray([True, False, True, False])
     pos = jnp.asarray([40, 41, 3, 33], jnp.int32)
     tok = jnp.asarray([5, 6, 7, 8], jnp.int32)
-    after, _ = jax.jit(partial(E.decode_step, config=c))(params, cache, tok, pos, write_mask=live)[::-1]
-    very, _ = jax.jit(partial(E.verify_step, config=c))(
+    after, _ = jitted(E.decode_step, config=c)(params, cache, tok, pos, write_mask=live)[::-1]
+    very, _ = jitted(E.verify_step, config=c)(
         params, cache, jnp.tile(tok[:, None], (1, 3)), pos, write_mask=live
     )[::-1]
     _, waved = _packed(c, params, cache, {2: rng.integers(1, 512, 9).tolist()})
@@ -223,7 +223,7 @@ def test_the_engine_counts_and_reuses_a_prefix_while_the_ring_holds_it():
     what it decodes without the reuse; a slot that has run on past the
     ring is not offered as a source."""
     c = TINY
-    params = llama.init_params(c, jax.random.key(1))
+    params = init_params(c, 1)
     rng = np.random.default_rng(5)
     shared = rng.integers(1, 512, 32).tolist()
     first = shared + rng.integers(1, 512, 7).tolist()
@@ -263,7 +263,7 @@ def test_the_engine_counts_and_reuses_a_prefix_while_the_ring_holds_it():
     assert far.prefix_hits == 0
     # a model of one kind of layer: the window's series stay at nothing
     dense = InferenceEngine(
-        llama.LLAMA_TINY, llama.init_params(llama.LLAMA_TINY, jax.random.key(0)),
+        llama.LLAMA_TINY, init_params(llama.LLAMA_TINY, 0),
         max_batch=2, max_seq=64,
     )
     assert dense.metrics.family("dtpu_serve_kv_window_pool_percent").value() == 0
@@ -274,7 +274,7 @@ def test_speculative_and_macro_steps_through_the_engine_decode_the_same():
     """The engine's three decode paths (per-token, macro-step, verify
     with n-gram drafts) over the two caches give one greedy stream."""
     c = TINY
-    params = llama.init_params(c, jax.random.key(1))
+    params = init_params(c, 1)
     prompt = ([7, 8, 9, 10] * 6)[:22]
 
     def run(**kw):

@@ -7,14 +7,18 @@ import numpy as np
 
 from dstack_tpu.models import llama
 from dstack_tpu.serve.engine import GenParams, InferenceEngine, sample
+from tests.shared import init_params, jitted
 
 
 def _reference_greedy(params, config, prompt: list[int], n: int) -> list[int]:
     seq = list(prompt)
     out = []
+    forward = jitted(llama.forward, config=config)
     for _ in range(n):
-        logits = llama.forward(params, jnp.asarray([seq], jnp.int32), config)
-        nxt = int(jnp.argmax(logits[0, -1]))
+        # one program a 32 tokens (causal: padding behind moves nothing)
+        padded = seq + [0] * (-len(seq) % 32)
+        logits = forward(params, jnp.asarray([padded], jnp.int32))
+        nxt = int(jnp.argmax(logits[0, len(seq) - 1]))
         out.append(nxt)
         seq.append(nxt)
     return out
@@ -23,7 +27,7 @@ def _reference_greedy(params, config, prompt: list[int], n: int) -> list[int]:
 class TestDecode:
     def setup_method(self):
         self.config = llama.LLAMA_TINY
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def test_greedy_matches_full_forward(self):
         eng = InferenceEngine(self.config, self.params, max_batch=2, max_seq=64)
@@ -186,7 +190,7 @@ class TestTensorParallelServing:
         from dstack_tpu.parallel.mesh import MeshConfig, make_mesh
 
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         prompt = [11, 22, 33, 44]
         ref = InferenceEngine(config, params, max_batch=2, max_seq=64).generate(
             prompt, GenParams(max_new_tokens=5)
@@ -203,7 +207,7 @@ class TestTensorParallelServing:
         from dstack_tpu.parallel.mesh import MeshConfig, make_mesh
 
         config = llama.LLAMA_TINY  # 2 kv heads
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         mesh = make_mesh(MeshConfig(dp=1, fsdp=1, tp=4))
         with pytest.raises(ValueError):
             InferenceEngine(config, params, mesh=mesh)
@@ -217,7 +221,7 @@ class TestChunkedPrefill:
     config = llama.LLAMA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def test_multi_chunk_matches_reference(self):
         # chunk=32, prompt 80 → 3 chunks (two full + padded tail)
@@ -307,7 +311,7 @@ class TestSpeculativeDecoding:
     config = llama.LLAMA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def test_lossless_vs_disabled(self):
         prompt = [7, 8, 9, 10] * 6  # repetitive: drafts will fire
@@ -374,7 +378,7 @@ class TestTurboDecode:
     config = llama.LLAMA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def _engine(self, turbo: int, **kw):
         kw.setdefault("max_batch", 2)
@@ -532,7 +536,7 @@ class TestPenaltyScopes:
         GENERATED tokens — a long prompt must not pre-ban its own
         vocabulary on the first sampled token."""
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         prompt = [7, 8, 9] * 8
         base = InferenceEngine(config, params, max_batch=1, max_seq=128)
         pen = InferenceEngine(config, params, max_batch=1, max_seq=128)
@@ -560,7 +564,7 @@ class TestSpecWithFamilyDeltas:
             hidden_act="gelu_tanh", sliding_window=16, sliding_pattern=2,
             attn_softcap=30.0, logit_softcap=20.0,
         )
-        params = llama.init_params(config, jax.random.key(3))
+        params = init_params(config, 3)
         prompt = [4, 5, 6] * 8
         on = InferenceEngine(
             config, params, max_batch=1, max_seq=128, spec_draft=4
@@ -574,7 +578,7 @@ class TestSpecWithFamilyDeltas:
 
     def test_lossless_with_qk_norm(self):
         config = llama.dataclasses.replace(llama.LLAMA_TINY, qk_norm=True)
-        params = llama.init_params(config, jax.random.key(4))
+        params = init_params(config, 4)
         prompt = [9, 9, 2] * 6
         on = InferenceEngine(
             config, params, max_batch=1, max_seq=128, spec_draft=3
@@ -596,7 +600,7 @@ class TestMLADecode:
     config = llama.MLA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def test_cache_is_compressed_latent(self):
         from dstack_tpu.serve.engine import init_cache
@@ -684,7 +688,7 @@ class TestPrefixCache:
     config = llama.LLAMA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def _engine(self, **kw):
         kw.setdefault("max_batch", 3)
@@ -749,7 +753,7 @@ class TestPrefixCache:
 
     def test_mla_prefix_cache(self):
         config = llama.MLA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         shared = list(range(30, 70))
         p2 = shared + [3, 4]
         cold = InferenceEngine(
@@ -774,7 +778,7 @@ class TestKVQuant:
     config = llama.LLAMA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def _engine(self, kv_quant, **kw):
         kw.setdefault("max_batch", 2)
@@ -869,7 +873,7 @@ class TestKVQuant:
         import pytest
 
         config = llama.MLA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         with pytest.raises(ValueError, match="MLA"):
             InferenceEngine(config, params, max_batch=2, max_seq=32,
                             kv_quant="int8")
@@ -883,7 +887,7 @@ class TestAdaptiveTurbo:
     config = llama.LLAMA_TINY
 
     def _engine(self, **kw):
-        params = llama.init_params(self.config, jax.random.key(0))
+        params = init_params(self.config, 0)
         kw.setdefault("max_batch", 2)
         kw.setdefault("max_seq", 256)
         kw.setdefault("spec_draft", 0)
@@ -928,7 +932,7 @@ class TestExpertParallelServing:
         from dstack_tpu.parallel.mesh import MeshConfig, make_mesh
 
         config = llama.MOE_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         prompt = [11, 22, 33, 44]
         ref = InferenceEngine(
             config, params, max_batch=2, max_seq=64,
@@ -978,7 +982,7 @@ class TestPackedPrefill:
     config = llama.LLAMA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def _engine(self, **kw):
         kw.setdefault("max_batch", 4)
@@ -1039,7 +1043,7 @@ class TestPackedPrefill:
 
     def test_mla_packed_matches_serial(self):
         config = llama.MLA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         mk = lambda n: InferenceEngine(  # noqa: E731
             config, params, max_batch=4, max_seq=96, prefill_chunk=16,
             prefill_pack=n, spec_draft=0, turbo_steps=0,
@@ -1084,7 +1088,7 @@ class TestDecodeStateMirror:
     config = llama.LLAMA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def _engine(self, **kw):
         kw.setdefault("max_batch", 2)
@@ -1174,7 +1178,7 @@ class TestCompileCacheAccounting:
     def test_variant_count_bounded_across_start_combinations(self):
         import math
 
-        params = llama.init_params(self.config, jax.random.key(0))
+        params = init_params(self.config, 0)
         chunk, pack = 16, 4
         eng = InferenceEngine(
             self.config, params, max_batch=4, max_seq=128,
@@ -1216,7 +1220,7 @@ class TestLogitBiasMinP:
     config = llama.LLAMA_TINY
 
     def setup_method(self):
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
         self.eng = InferenceEngine(
             self.config, self.params, max_batch=2, max_seq=64,
             spec_draft=0, turbo_steps=0,
@@ -1265,7 +1269,7 @@ class TestResumableGeneration:
 
     def setup_method(self):
         self.config = llama.LLAMA_TINY
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def _engine(self):
         return InferenceEngine(
@@ -1336,7 +1340,7 @@ class TestAbandonStep:
 
     def setup_method(self):
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         self.eng = InferenceEngine(config, params, max_batch=2, max_seq=64)
 
     def test_abandon_reports_wedge_phase_and_bumps_epoch(self):
@@ -1388,7 +1392,7 @@ class TestFlightRecorder:
     def setup_method(self):
         from dstack_tpu.obs import flight
 
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
         self._prior = flight.get_recorder()
         self.rec = flight.enable(buffer=256)
 
@@ -1562,7 +1566,7 @@ class TestSteadyStateRecompiles:
         )
 
     def test_second_pass_compiles_nothing(self):
-        params = llama.init_params(self.config, jax.random.key(0))
+        params = init_params(self.config, 0)
         eng = InferenceEngine(
             self.config, params, max_batch=4, max_seq=128,
             prefill_chunk=16, prefill_pack=4, spec_draft=0,
@@ -1610,7 +1614,7 @@ class TestSteadyStateRecompiles:
         detected, not merely priced as a generic recompile."""
         from dstack_tpu.obs import boot
 
-        params = llama.init_params(self.config, jax.random.key(0))
+        params = init_params(self.config, 0)
         eng = InferenceEngine(
             self.config, params, max_batch=4, max_seq=128,
             prefill_chunk=16, prefill_pack=4, spec_draft=0,

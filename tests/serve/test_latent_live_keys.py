@@ -22,6 +22,7 @@ import pytest
 from dstack_tpu.models import llama
 from dstack_tpu.serve import engine as eng
 from dstack_tpu.serve.engine import GenParams, InferenceEngine
+from tests.shared import init_params
 
 TMAX, KB = 2048, 512
 LAYERS, LI, HEADS = 2, 1, 3
@@ -83,7 +84,7 @@ def test_attend_live_is_the_whole_row_softmax(case, s):
 @pytest.fixture(scope="module")
 def tiny():
     config = dataclasses.replace(llama.MLA_TINY, max_seq_len=1024)
-    params = llama.init_params(config, jax.random.key(2))
+    params = init_params(config, 2)
     rng = np.random.default_rng(9)
     cache = eng.init_cache(config, 3, 1024)
     # whatever wrote them, the rows a slot holds are its context
@@ -128,7 +129,7 @@ def test_engine_counts_the_key_rows_read_and_reserved():
     """Both series exist from boot; a latent engine counts whole blocks
     up to the longest live context a token step, a dense one whole rows."""
     config = dataclasses.replace(llama.MLA_TINY, max_seq_len=2048)
-    params = llama.init_params(config, jax.random.key(2))
+    params = init_params(config, 2)
     e = InferenceEngine(
         config, params, max_batch=2, max_seq=2048, prefill_chunk=256, spec_draft=0,
     )
@@ -153,7 +154,7 @@ def test_engine_counts_the_key_rows_read_and_reserved():
     assert value("dtpu_serve_decode_keys_read_total") - before == 2 * KB * 3 * 2
 
     dense = InferenceEngine(
-        llama.LLAMA_TINY, llama.init_params(llama.LLAMA_TINY, jax.random.key(1)),
+        llama.LLAMA_TINY, init_params(llama.LLAMA_TINY, 1),
         max_batch=2, max_seq=64, spec_draft=0,
     )
     assert dense._key_block == 0

@@ -27,6 +27,7 @@ import pytest
 
 from dstack_tpu.models import kda, llama
 from dstack_tpu.serve import engine as E
+from tests.shared import init_params, jitted
 
 C = llama.CONFIGS["linear-tiny"]
 TIGHT = 2e-5
@@ -36,7 +37,7 @@ N_LIN = C.layer_types.count("linear")
 
 @pytest.fixture(scope="module")
 def params():
-    p = llama.init_params(C, jax.random.key(11))
+    p = init_params(C, 11)
     for stack in ("dense_layers", "linear_layers"):
         p[stack]["lin_dt_bias"] = p[stack]["lin_dt_bias"] - 3.0  # slow decays
     return p
@@ -65,12 +66,12 @@ class _Served:
     def __init__(self, params):
         self.params = params
         self.cache = E.init_cache(C, B, TMAX, chunk=CHUNK)
-        self.decode = jax.jit(partial(E.decode_step, config=C))
+        self.decode = jitted(E.decode_step, config=C)
 
     def serial(self, prompt, slot):
         for start in range(0, len(prompt), CHUNK):
             chunk = prompt[start:start + CHUNK]
-            fn = jax.jit(partial(E.prefill_chunk_step, config=C, start=start))
+            fn = jitted(E.prefill_chunk_step, config=C, start=start)
             logits, self.cache = fn(
                 self.params, self.cache,
                 jnp.asarray([chunk + [0] * (CHUNK - len(chunk))], jnp.int32),
@@ -82,7 +83,7 @@ class _Served:
         """A chunk of every prompt a wave of ``g`` rows: rows at unequal
         starts once the shorter prompts are through, pad rows (slot 0,
         start 0, ``last_ix`` -1, as the engine makes them) behind."""
-        fn = jax.jit(partial(E.prefill_packed_step, config=C))
+        fn = jitted(E.prefill_packed_step, config=C)
         at, out = {s: 0 for s in prompts}, {}
         while at:
             slots = sorted(at)
@@ -117,6 +118,7 @@ class _Served:
 # --- the recurrence's two forms -------------------------------------------
 
 
+@partial(jax.jit, static_argnums=(0, 1, 2))  # one program a case, not six draws
 def _rule_inputs(t, seed, floor_share):
     ks = jax.random.split(jax.random.key(seed), 6)
     nh, d = 3, 16
@@ -140,7 +142,7 @@ def test_chunkwise_is_token_by_token(t, floor_share):
     for i in range(t):
         o, s = kda.token_rule(q[:, i], k[:, i], v[:, i], g[:, i], beta[:, i], s)
         outs.append(o)
-    o2, s2 = jax.jit(kda.chunk_rule)(q, k, v, g, beta, s0)
+    o2, s2 = jitted(kda.chunk_rule)(q, k, v, g, beta, s0)
     assert float(jnp.abs(jnp.stack(outs, 1) - o2).max()) < 1e-5
     assert float(jnp.abs(s - s2).max()) < 1e-5
 
@@ -216,7 +218,7 @@ def test_macro_step_carries_the_state_over_its_tokens(params):
     act = np.zeros(B, bool)
     for s, p in prompts.items():
         tok[s], pos[s], act[s] = int(first[s].argmax()), len(p), True
-    loop = jax.jit(partial(E.decode_loop, config=C, steps=8, max_seq=TMAX))
+    loop = jitted(E.decode_loop, config=C, steps=8, max_seq=TMAX)
     toks, sv.cache, *_ = loop(
         params, sv.cache, jnp.asarray(tok), jnp.asarray(pos),
         jnp.full((B,), 50, jnp.int32), jnp.asarray(act), jnp.full((B,), -1, jnp.int32),
@@ -259,7 +261,7 @@ def test_a_rejected_draft_has_not_moved_the_state(params, stand):
     pos[1], pos[2] = len(seq[1]) - 1, len(seq[2]) - 1
     live = np.asarray([False, True, True, False])
     dead_before = np.asarray(sv.cache["state"])[:, 0].copy()
-    verify = jax.jit(partial(E.verify_step, config=C))
+    verify = jitted(E.verify_step, config=C)
     logits, sv.cache = verify(
         params, sv.cache, jnp.asarray(rows), jnp.asarray(pos),
         write_mask=jnp.asarray(live), draft_len=jnp.asarray([0, 4, 0, 0], jnp.int32),
@@ -332,7 +334,7 @@ def test_copying_a_prefix_of_a_state_is_an_error():
     a leaf without one is refused where the program is built, by name."""
     cache = E.init_cache(C, B, TMAX, chunk=CHUNK)
     with pytest.raises(ValueError, match="'state'|'conv'"):
-        jax.eval_shape(partial(E.copy_cache_prefix, p=CHUNK), cache, 0, 1)
+        jax.eval_shape(lambda c: E.copy_cache_prefix(c, 0, 1, p=CHUNK), cache)
 
 
 def test_the_engine_drafts_and_keeps_its_states(params):

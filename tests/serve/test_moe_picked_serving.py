@@ -24,6 +24,7 @@ import pytest
 
 from dstack_tpu.models import llama, moe
 from dstack_tpu.serve import engine as E
+from tests.shared import init_params, jitted
 
 B, TMAX, CHUNK = 4, 64, 16
 HELD = 8
@@ -36,7 +37,7 @@ LAYERS = C.n_layers  # an expert branch a layer
 
 @pytest.fixture(scope="module")
 def params():
-    return llama.init_params(C, jax.random.key(3))
+    return init_params(C, 3)
 
 
 @pytest.fixture(autouse=True)
@@ -64,7 +65,8 @@ def _prefilled(params, rng, lengths):
     last = []
     for slot, n in enumerate(lengths):
         toks = rng.integers(1, C.vocab_size, n).tolist()
-        fn = jax.jit(partial(E.prefill_chunk_step, config=C, start=0))
+        # traced under `_toy_trip`'s patch, like every program of this file
+        fn = jitted(E.prefill_chunk_step, "toy-trip", config=C, start=0)
         logits, cache = fn(
             params, cache, jnp.asarray([toks + [0] * (CHUNK - n)], jnp.int32),
             jnp.asarray(slot, jnp.int32), jnp.asarray(n - 1, jnp.int32),
@@ -85,12 +87,12 @@ def test_decode_loop_of_eight_is_eight_decode_steps(params):
     assert held0 == len(lengths) * LAYERS * HELD and 0 < read0 < held0
     tok, pos = jnp.asarray(tok0, jnp.int32), jnp.asarray(lengths, jnp.int32)
     act = jnp.asarray([True, True, False, True])  # a dead slot rides along
-    loop = jax.jit(partial(E.decode_loop, config=C, steps=8, max_seq=TMAX))
+    loop = jitted(E.decode_loop, "toy-trip", config=C, steps=8, max_seq=TMAX)
     emitted, cache_l, *_ = loop(
         params, cache0, tok, pos, jnp.full((B,), 64, jnp.int32), act,
         jnp.full((B,), -1, jnp.int32),
     )
-    step = jax.jit(partial(E.decode_step, config=C))
+    step = jitted(E.decode_step, "toy-trip", config=C)
     cache_s, t, p, want = cache0, tok, pos, []
     for _ in range(8):
         logits, cache_s = step(params, cache_s, t, p, write_mask=act)

@@ -11,11 +11,12 @@ from dstack_tpu.models import llama
 from dstack_tpu.serve.engine import InferenceEngine
 from dstack_tpu.serve.openai_server import build_app
 from dstack_tpu.serve.tokenizer import ByteTokenizer, load_tokenizer
+from tests.shared import init_params
 
 
 async def _client():
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     engine = InferenceEngine(config, params, max_batch=4, max_seq=128)
     app = build_app(engine, ByteTokenizer(), "llama-tiny")
     client = TestClient(TestServer(app))
@@ -30,7 +31,7 @@ def test_warmup_compiles_and_leaves_engine_clean():
     from dstack_tpu.serve.openai_server import _warmup_engine
 
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     engine = InferenceEngine(
         config, params, max_batch=2, max_seq=128, spec_draft=3, turbo_steps=4
     )
@@ -62,7 +63,7 @@ def test_warmup_covers_greedy_step_beside_pending_prefill():
     from dstack_tpu.serve.openai_server import _warmup_engine
 
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     engine = InferenceEngine(
         config, params, max_batch=4, max_seq=128, spec_draft=0, turbo_steps=4
     )
@@ -107,7 +108,7 @@ class TestOpenAIServer:
         config = llama.LLAMA_TINY
         mesh = make_mesh(MeshConfig(dp=1, fsdp=1, tp=2))
         engine = InferenceEngine(
-            config, llama.init_params(config, jax.random.key(0)),
+            config, init_params(config, 0),
             max_batch=2, max_seq=128, mesh=mesh,
         )
         client = TestClient(TestServer(
@@ -655,7 +656,7 @@ class TestServeMetrics:
         import asyncio
 
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         engine = InferenceEngine(
             config, params, max_batch=4, max_seq=256, prefill_chunk=32,
             prefill_pack=4, spec_draft=0,
@@ -811,7 +812,7 @@ class TestBootEndpoint:
 
     async def _boot_client(self, rec):
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         engine = InferenceEngine(config, params, max_batch=4, max_seq=128)
         app = build_app(engine, ByteTokenizer(), "llama-tiny", boot=rec)
         client = TestClient(TestServer(app))
@@ -868,7 +869,7 @@ class TestBootEndpoint:
         """build_app(boot=None): no boot block in /health and an
         honest disabled /debug/boot (the soak's baseline replicas)."""
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         engine = InferenceEngine(config, params, max_batch=4, max_seq=128)
         app = build_app(engine, ByteTokenizer(), "llama-tiny", boot=None)
         client = TestClient(TestServer(app))
@@ -1004,7 +1005,7 @@ class TestDeepseekServing:
 class TestEmbeddings:
     async def test_embeddings_shapes_and_norm(self):
         config = llama.LLAMA_TINY
-        params = jax.device_put(llama.init_params(config, jax.random.key(0)))
+        params = jax.device_put(init_params(config, 0))
         engine = InferenceEngine(config, params, max_batch=2, max_seq=64)
         app = build_app(engine, ByteTokenizer(), "tiny")
         client = TestClient(TestServer(app))
@@ -1040,7 +1041,7 @@ class TestEmbeddings:
 
     async def test_embeddings_overlong_input_400(self):
         config = llama.LLAMA_TINY
-        params = jax.device_put(llama.init_params(config, jax.random.key(0)))
+        params = jax.device_put(init_params(config, 0))
         engine = InferenceEngine(config, params, max_batch=2, max_seq=32)
         app = build_app(engine, ByteTokenizer(), "tiny")
         client = TestClient(TestServer(app))
@@ -1063,7 +1064,7 @@ class TestResponseFormat:
 
     async def _client(self):
         config = llama.LLAMA_TINY
-        params = jax.device_put(llama.init_params(config, jax.random.key(0)))
+        params = jax.device_put(init_params(config, 0))
         engine = InferenceEngine(config, params, max_batch=2, max_seq=64)
         app = build_app(engine, ByteTokenizer(), "tiny")
         client = TestClient(TestServer(app))
@@ -1157,7 +1158,7 @@ class TestToolCalls:
 
     async def test_chat_accepts_tools_and_tool_messages(self):
         config = llama.LLAMA_TINY
-        params = jax.device_put(llama.init_params(config, jax.random.key(0)))
+        params = jax.device_put(init_params(config, 0))
         engine = InferenceEngine(config, params, max_batch=2, max_seq=64)
         # template that proves tools reach the renderer
         tmpl = ("{% for m in messages %}{{ m['role'] }}:"
@@ -1218,7 +1219,7 @@ class TestToolCalls:
         buffer-everything), tool markup never leaks as a prose delta,
         and the stream still terminates with a valid finish_reason."""
         config = llama.LLAMA_TINY
-        params = jax.device_put(llama.init_params(config, jax.random.key(0)))
+        params = jax.device_put(init_params(config, 0))
         engine = InferenceEngine(config, params, max_batch=2, max_seq=64)
         app = build_app(engine, ByteTokenizer(), "tiny")
         client = TestClient(TestServer(app))
@@ -1245,7 +1246,7 @@ class TestToolCalls:
 
     async def test_tool_choice_none_and_unsupported(self):
         config = llama.LLAMA_TINY
-        params = jax.device_put(llama.init_params(config, jax.random.key(0)))
+        params = jax.device_put(init_params(config, 0))
         engine = InferenceEngine(config, params, max_batch=2, max_seq=64)
         app = build_app(engine, ByteTokenizer(), "tiny")
         client = TestClient(TestServer(app))
@@ -1274,7 +1275,7 @@ class TestToolCalls:
 class TestSamplingValidation:
     async def test_bad_min_p_and_logit_bias_400(self):
         config = llama.LLAMA_TINY
-        params = jax.device_put(llama.init_params(config, jax.random.key(0)))
+        params = jax.device_put(init_params(config, 0))
         engine = InferenceEngine(config, params, max_batch=2, max_seq=64)
         app = build_app(engine, ByteTokenizer(), "tiny")
         client = TestClient(TestServer(app))

@@ -17,7 +17,6 @@ does not own.
 """
 
 import dataclasses
-import functools
 import json
 import os
 
@@ -28,6 +27,7 @@ import pytest
 
 from dstack_tpu.models import llama
 from dstack_tpu.serve import engine as eng
+from tests.shared import init_params, jitted
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -68,7 +68,7 @@ CASES = {
 @pytest.fixture(scope="module", params=sorted(CASES))
 def model(request):
     config, kv_quant = CASES[request.param]()
-    params = llama.init_params(config, jax.random.key(3))
+    params = init_params(config, 3)
     return request.param, config, kv_quant, params
 
 
@@ -276,18 +276,14 @@ def _tokens(config, n, seed):
     return [int(t) for t in rng.integers(1, config.vocab_size, n)]
 
 
-@functools.lru_cache(maxsize=None)
-def _jitted(form, fn, config, **static):
+class _Programs:
     """One compiled program a (form, step, configuration, start), as the
     engine holds them, shared by the tests of a case; traced on first
     use, over whatever forms the module has then (``form`` keeps the
     engine's own and the reference's apart)."""
-    return jax.jit(functools.partial(fn, config=config, **static))
 
-
-class _Programs:
     def __init__(self, config, form="engine"):
-        self._jitted = functools.partial(_jitted, form, config=config)
+        self._jitted = lambda fn, **static: jitted(fn, form, config=config, **static)
 
     def serial(self, params, cache, toks, slot, start, n_real):
         row = toks + [0] * (CHUNK - len(toks))
@@ -419,7 +415,8 @@ def test_first_tokens_logits_are_the_full_forwards(model):
     for start in (0, 16):
         _, cache = run.serial(params, cache, toks[start : start + 16], 3, start, 16)
     logits, _ = run.packed(params, cache, [(toks[32:], 3, 32, 10), ([], 0, 0, 0)])
-    ref = np.asarray(llama.forward(params, jnp.asarray([toks], jnp.int32), config)[0, -1])
+    full = jitted(llama.forward, config=config)(params, jnp.asarray([toks], jnp.int32))
+    ref = np.asarray(full[0, -1])
     tol = 0.05 if kv_quant else 5e-2 if case == "latent-indexer-ring" else 2e-3
     assert np.abs(np.asarray(logits[0]) - ref).max() < tol * max(np.abs(ref).max(), 1.0)
 

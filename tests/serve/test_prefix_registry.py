@@ -10,10 +10,9 @@ a partial-overlap hit must copy ONLY the shared chunk-aligned prefix
 unit tests here, not just implied by the bench numbers.
 """
 
-import jax
-
 from dstack_tpu.models import llama
 from dstack_tpu.serve.engine import GenParams, InferenceEngine
+from tests.shared import init_params
 
 
 def _run_to_completion(eng, slot):
@@ -31,7 +30,7 @@ def _serve(eng, prompt, gen_len=2):
 class TestPrefixRegistryLifecycle:
     def setup_method(self):
         self.config = llama.LLAMA_TINY
-        self.params = llama.init_params(self.config, jax.random.key(0))
+        self.params = init_params(self.config, 0)
 
     def _engine(self, batch=2, chunk=16, max_seq=256):
         return InferenceEngine(

@@ -7,12 +7,12 @@ site; PERF.md §3 has the table this test pins."""
 
 import re
 
-import jax
 import pytest
 
 from dstack_tpu.models import llama
 from dstack_tpu.obs import flight
 from dstack_tpu.serve.engine import GenParams, InferenceEngine
+from tests.shared import init_params
 
 # compile label (flight / dtpu_serve_compiles_total{fn}) → program
 LABEL_PROGRAM = {
@@ -58,7 +58,7 @@ def lowered_names():
     mp.setattr(flight, "watch_jit", spy)
     try:
         config = llama.LLAMA_TINY
-        params = llama.init_params(config, jax.random.key(0))
+        params = init_params(config, 0)
         eng = InferenceEngine(
             config, params, max_batch=4, max_seq=128, prefill_chunk=16,
             spec_draft=0, turbo_steps=4,

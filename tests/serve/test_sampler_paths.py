@@ -14,6 +14,7 @@ import pytest
 
 from dstack_tpu.models import llama
 from dstack_tpu.serve.engine import NEG_INF, GenParams, InferenceEngine, sample
+from tests.shared import init_params
 
 
 def _sample_all_branches(
@@ -205,7 +206,7 @@ def _counts(eng):
 
 def test_a_top_p_request_engages_the_filters_only_while_it_is_live():
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     prompts = [[5, 99, 321, 7], [10, 20, 30, 40, 50]]
     plain = lambda: [  # noqa: E731
         GenParams(max_new_tokens=14, temperature=0.9, seed=11),

@@ -18,7 +18,7 @@ times that (``test_bf16_weights_fail_the_same_comparison``).
 import dataclasses
 import json
 import os
-from functools import partial
+from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +27,7 @@ import pytest
 
 from benchmark import launch, weights
 from benchmark.reference import mla_scmoe_zero as R
+from tests.shared import init_params, jitted
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 DATA = os.path.join(ROOT, "tests", "benchmark", "data", "scmoe_zero")
@@ -37,6 +38,11 @@ B, TMAX, CHUNK = 4, 96, 16
 def _cfg():
     with open(os.path.join(DATA, "configs", "tiny-scmoe-zero.json")) as f:
         return json.load(f)
+
+
+@lru_cache(maxsize=None)
+def _params():
+    return weights.make_params(_cfg(), 7)
 
 
 def _ref_logits(cfg, params, tokens):
@@ -51,19 +57,21 @@ class _Served:
     """The engine's programs on one cache, driven by hand so that each
     program's logits can be read."""
 
-    def __init__(self, cfg, params=None):
+    def __init__(self, cfg, params=None, scores=None):
         from dstack_tpu.serve import engine as E
 
         self.E, self.cfg = E, cfg
         self.c = launch.build_llama_config(cfg["llama_config"])
-        self.params = weights.make_params(cfg, 7) if params is None else params
+        self.params = _params() if params is None else params
         self.cache = E.init_cache(self.c, B, TMAX, chunk=CHUNK)
-        self.decode = jax.jit(partial(E.decode_step, config=self.c))
+        # a program traced under the `scores` fixture's patch is its own
+        self.jitted = lambda fn, **static: jitted(fn, scores, config=self.c, **static)
+        self.decode = self.jitted(E.decode_step)
 
     def serial(self, prompt, slot):
         for start in range(0, len(prompt), CHUNK):
             chunk = prompt[start:start + CHUNK]
-            fn = jax.jit(partial(self.E.prefill_chunk_step, config=self.c, start=start))
+            fn = self.jitted(self.E.prefill_chunk_step, start=start)
             logits, self.cache = fn(
                 self.params, self.cache,
                 jnp.asarray([chunk + [0] * (CHUNK - len(chunk))], jnp.int32),
@@ -74,7 +82,7 @@ class _Served:
     def packed(self, prompts: dict):
         """Every prompt a chunk a wave, rows at unequal starts once the
         shorter prompts are through → {slot: last logits}."""
-        fn = jax.jit(partial(self.E.prefill_packed_step, config=self.c))
+        fn = self.jitted(self.E.prefill_packed_step)
         at, out = {s: 0 for s in prompts}, {}
         while at:
             slots = sorted(at)
@@ -141,7 +149,7 @@ def test_bf16_weights_fail_the_same_comparison():
     """What the tolerance is for: the reference on the weights as given
     against the programs on weights rounded to bfloat16."""
     cfg = _cfg()
-    params = weights.make_params(cfg, 7)
+    params = _params()
     rounded = jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(jnp.float32), params)
     sv = _Served(cfg, rounded)
     toks, got = _serial_then_decode(sv, n_steps=4)
@@ -167,7 +175,7 @@ def test_packed_wave_macro_step_and_verify_step(scores):
     """Two prompts of unequal length in one wave, then the macro-step (8
     tokens = eight steps') and the verify step over both."""
     cfg = _cfg()
-    sv = _Served(cfg)
+    sv = _Served(cfg, scores=scores)
     E, c = sv.E, sv.c
     rng = np.random.default_rng(1)
     prompts = {1: rng.integers(1, 512, 5).tolist(), 3: rng.integers(1, 512, 52).tolist()}
@@ -180,7 +188,7 @@ def test_packed_wave_macro_step_and_verify_step(scores):
             seqs[s].append(int(first[s].argmax()))
         stats0 = np.asarray(sv.cache["moe_stats"])
         assert stats0[1] == (5 + 52) * 2
-        loop = jax.jit(partial(E.decode_loop, config=c, steps=8, max_seq=TMAX))
+        loop = sv.jitted(E.decode_loop, steps=8, max_seq=TMAX)
         live = np.zeros(B, bool)
         live[[1, 3]] = True
         tok, pos = np.zeros(B, np.int32), np.zeros(B, np.int32)
@@ -211,7 +219,7 @@ def test_packed_wave_macro_step_and_verify_step(scores):
         for s in seqs:
             grid[s] = [seqs[s][-1]] + drafts[s]
             pos[s] = len(seqs[s]) - 1
-        vlogits, sv.cache = jax.jit(partial(E.verify_step, config=c))(
+        vlogits, sv.cache = sv.jitted(E.verify_step)(
             sv.params, sv.cache, jnp.asarray(grid), jnp.asarray(pos),
             write_mask=jnp.asarray(live),
         )
@@ -235,10 +243,10 @@ def test_a_prefix_copy_takes_every_sublayers_row():
     prompt = np.random.default_rng(3).integers(1, 512, 40).tolist()
     with jax.default_matmul_precision("highest"):
         sv.serial(prompt[:32], slot=0)
-        sv.cache = jax.jit(partial(sv.E.copy_cache_prefix, p=32))(
+        sv.cache = jitted(sv.E.copy_cache_prefix, p=32)(
             sv.cache, jnp.asarray(0, jnp.int32), jnp.asarray(3, jnp.int32)
         )
-        fn = jax.jit(partial(sv.E.prefill_chunk_step, config=sv.c, start=32))
+        fn = sv.jitted(sv.E.prefill_chunk_step, start=32)
         logits, sv.cache = fn(
             sv.params, sv.cache, jnp.asarray([prompt[32:] + [0] * 8], jnp.int32),
             jnp.asarray(3, jnp.int32), jnp.asarray(7, jnp.int32),
@@ -338,7 +346,7 @@ def test_engine_serves_it_and_counts_sublayers_and_zero_picks():
 
     c = dataclasses.replace(llama.CONFIGS["scmoe-tiny"], experts_held=(0, 4))
     eng = InferenceEngine(
-        c, llama.init_params(c, jax.random.key(0)), max_batch=2, max_seq=128,
+        c, init_params(c, 0), max_batch=2, max_seq=128,
         prefill_chunk=16,
     )
     fam = lambda n: eng.metrics.family(n).value()
@@ -360,6 +368,6 @@ def test_engine_serves_it_and_counts_sublayers_and_zero_picks():
     toks = list(prompt)
     with jax.default_matmul_precision("highest"):
         for _ in range(3):
-            logits = llama.forward(eng.params, jnp.asarray(toks)[None], c)
+            logits = jitted(llama.forward, config=c)(eng.params, jnp.asarray(toks)[None])
             toks.append(int(np.asarray(logits)[0, -1].argmax()))
     assert toks[len(prompt):] == out[:3]

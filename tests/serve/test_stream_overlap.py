@@ -12,7 +12,6 @@ import json
 import threading
 import time
 
-import jax
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
@@ -20,6 +19,7 @@ from dstack_tpu.models import llama
 from dstack_tpu.serve.engine import InferenceEngine
 from dstack_tpu.serve.openai_server import build_app
 from dstack_tpu.serve.tokenizer import ByteTokenizer
+from tests.shared import init_params
 
 GAP_PARTS = ("loop_return", "tick_host", "loop_yield", "worker_start")
 # keep to ASCII ids: every token is a visible delta, and a stream takes
@@ -29,7 +29,7 @@ ASCII = {str(i): -100 for i in range(128, 512)}
 
 async def _client(watchdog_seconds=0.0, max_batch=4):
     config = llama.LLAMA_TINY
-    params = llama.init_params(config, jax.random.key(0))
+    params = init_params(config, 0)
     engine = InferenceEngine(config, params, max_batch=max_batch, max_seq=128)
     app = build_app(
         engine, ByteTokenizer(), "llama-tiny", watchdog_seconds=watchdog_seconds
