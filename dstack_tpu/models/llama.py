@@ -36,17 +36,22 @@ from dstack_tpu.parallel.sharding import (
 #: a layer kind → the stack of ``params`` its layers past the prelude are in
 STACK_OF = {
     "full": "layers", "window": "window_layers", "linear": "linear_layers",
-    "conv": "conv_layers",
+    "conv": "conv_layers", "mamba": "mamba_layers", "gmu": "gmu_layers",
+    "cross": "cross_layers",
 }
+
+#: the kinds whose layers mix their tokens through a module of
+#: :func:`mixer_of` and hold a slot's past whole (a model has one at most)
+STATE_KINDS = ("linear", "conv", "mamba")
 
 
 def mixer_of(kind: str):
     """The module of a kind of layer that mixes its tokens without
     attention (``leaf_shapes``, ``n_params``, ``mix``, ``zeros``): a
     slot's past on such a layer is held whole, not by position."""
-    from dstack_tpu.models import kda, shortconv
+    from dstack_tpu.models import kda, mamba, shortconv
 
-    return {"linear": kda, "conv": shortconv}[kind]
+    return {"linear": kda, "conv": shortconv, "mamba": mamba}[kind]
 
 
 @dataclass(frozen=True)
@@ -274,6 +279,32 @@ class LlamaConfig:
     # are a stack of their own, params["conv_layers"] (a first_k_dense
     # prelude of conv layers keeps "dense_layers")
     conv_taps: int = 3
+    # --- state-space layers (models/mamba.py) and the layers that keep
+    # nothing (a decoder-hybrid-decoder stack, arXiv:2507.06607) ---
+    # a layer of kind "mamba" mixes its tokens through a diagonal
+    # selective recurrence (Mamba-1) over ssm_expand * hidden channels,
+    # beside grouped-query attention: a slot's past is a float32 state
+    # [d_inner, ssm_state] and the convolution's last ssm_conv - 1 rows
+    # of d_inner; params["mamba_layers"]. Its scan output, before the
+    # gate, is what the "gmu" layers after it read at the same position
+    # (a gated memory unit: no state, no rows; params["gmu_layers"]).
+    # A layer of kind "cross" has queries alone and attends over the
+    # rows of the model's ONE full layer, which precedes it (no rows of
+    # its own; params["cross_layers"])
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 0  # 0 = ceil(hidden / 16)
+    # differential attention (arXiv:2410.05258) in every attending layer:
+    # query heads (2p, 2p+1) and KV heads (2g, 2g+1), g = p // 2, are a
+    # pair; softmax(q1 k1) [v1|v2] - lambda softmax(q2 k2) [v1|v2],
+    # RMSNormed over the 2 * head_dim of the pair under a weight stored
+    # as (w - 1), times (1 - lambda_init(layer)). No rotary: a model of
+    # recurrences that carry position sets partial_rotary = 0
+    diff_attn: bool = False
+    # a bias on the attention's output projection alone (``bo``), none
+    # on the MLP (``proj_bias`` ties the two)
+    wo_bias: bool = False
 
     def __post_init__(self):
         kinds = set(self.layer_types)
@@ -281,13 +312,19 @@ class LlamaConfig:
         if self.layer_types and (
             len(self.layer_types) != self.n_layers
             or not kinds <= set(STACK_OF)
-            or prelude not in (set(), {"full"}, {"linear"}, {"conv"})
+            or prelude not in (set(), {"full"}, {"linear"}, {"conv"}, {"mamba"})
             or self.sliding_pattern or self.nope_pattern
         ):
             raise ValueError(
-                "layer_types: one of 'full' | 'window' | 'linear' | 'conv' a "
-                "layer (in place of sliding_pattern / nope_pattern), the "
-                "first_k_dense prelude all 'full', all 'linear' or all 'conv'"
+                "layer_types: one of 'full' | 'window' | 'linear' | 'conv' | "
+                "'mamba' | 'gmu' | 'cross' a layer (in place of "
+                "sliding_pattern / nope_pattern), the first_k_dense prelude "
+                "of one kind: 'full', 'linear', 'conv' or 'mamba'"
+            )
+        if len(kinds & set(STATE_KINDS)) > 1:
+            raise ValueError(
+                "layer_types: one kind of layer that holds a slot's past "
+                "whole ('linear' | 'conv' | 'mamba') a model"
             )
         if "linear" in kinds and not (
             self.mla and self.linear_head_dim and self.linear_conv > 1
@@ -308,6 +345,36 @@ class LlamaConfig:
             raise ValueError(
                 "conv layers: beside grouped-query attention, plainly "
                 "pre-normed, one sublayer, two taps or more"
+            )
+        if "mamba" in kinds and not (
+            not self.mla and self.ssm_conv > 1 and self.ssm_state > 0
+            and self.sublayers == 1 and self.pre_norm
+            and not self.post_norms and not self.parallel_block
+        ):
+            raise ValueError(
+                "mamba layers: beside grouped-query attention, plainly "
+                "pre-normed, one sublayer, two taps or more"
+            )
+        types = self.layer_types
+        if "gmu" in kinds and "mamba" not in types[: types.index("gmu")]:
+            raise ValueError("a gmu layer reads the scan of a mamba layer before it")
+        if "cross" in kinds and (
+            self.mla or not self.diff_attn or types.count("full") != 1
+            or types.index("full") > types.index("cross")
+        ):
+            raise ValueError(
+                "cross layers read the rows of the model's one full "
+                "(differential grouped-query) layer, which precedes them"
+            )
+        if self.diff_attn and (
+            self.mla or self.n_heads % 2 or self.n_kv_heads % 2
+            or (self.n_heads // 2) % (self.n_kv_heads // 2)
+            or self.rope_dim or self.qk_norm or self.qk_norm_flat
+            or self.attn_gate or self.attn_sinks or self.attn_softcap
+        ):
+            raise ValueError(
+                "diff_attn: grouped-query heads in pairs, no rotary "
+                "(partial_rotary = 0), no q/k norm, gate, sinks or softcap"
             )
         if self.experts_held and self.router_groups and (
             self.n_experts % self.router_groups[0]
@@ -390,6 +457,33 @@ class LlamaConfig:
         )
 
     @property
+    def ssm_inner(self) -> int:
+        """Channels of a mamba layer's recurrence (``d_inner``)."""
+        return self.ssm_expand * self.hidden_size
+
+    @property
+    def ssm_rank(self) -> int:
+        """Width of a mamba layer's step-size latent (``dt_rank``)."""
+        return self.ssm_dt_rank or -(-self.hidden_size // 16)
+
+    @property
+    def attend_config(self) -> "LlamaConfig":
+        """The shape the attention itself runs at and the cache holds.
+        Differential attention runs as plain grouped-query attention
+        over KV PAIRS: keys and values of heads (2g, 2g+1) side by side
+        are one head of twice the width (a reshape of what the
+        projection gives), a query head zero-padded into its key's half
+        scores q1 . k1 or q2 . k2 alone against it, and its value is
+        [v1 | v2]: K and V are each read once, at a width that fills
+        the lanes. Any other model: itself."""
+        if not self.diff_attn:
+            return self
+        return dataclasses.replace(
+            self, head_dim=2 * self.head_dim, n_kv_heads=self.n_kv_heads // 2,
+            attn_scale=self.attention_scale, diff_attn=False, layer_types=(),
+        )
+
+    @property
     def n_experts_held(self) -> int:
         return self.experts_held[1] if self.experts_held else self.n_experts
 
@@ -467,9 +561,10 @@ class LlamaConfig:
         return (
             h * self.q_dim + 2 * h * self.kv_dim + self.q_dim * h
             + (self.q_dim + 2 * self.kv_dim if self.qkv_bias else 0)
-            + (h if self.proj_bias else 0)  # bo
+            + (h if self.proj_bias or self.wo_bias else 0)  # bo
             + (h * self.n_heads if self.attn_gate else 0)
             + qk_norm
+            + (6 * self.head_dim if self.diff_attn else 0)  # lambdas, sub-norm
         )
 
     def _shared_expert_params(self) -> int:
@@ -481,13 +576,20 @@ class LlamaConfig:
     def _attn_params_total(self) -> int:
         """Attention parameters over all layers (a window layer has
         its own shape)."""
-        n_win = self.layer_types.count("window")
+        n_win, h_in = self.layer_types.count("window"), self.hidden_size
         total = self.n_kind("full") * self.sublayers * self._attn_params_per_layer()
         if n_win:
             total += n_win * self.window_config._attn_params_per_layer()
-        for kind in ("linear", "conv"):
+        for kind in STATE_KINDS:
             if kind in self.layer_types:
                 total += self.n_kind(kind) * mixer_of(kind).n_params(self)
+        # a gmu layer's two projections; a cross layer's attention
+        # without its keys and values
+        total += self.n_kind("gmu") * 2 * h_in * self.ssm_inner
+        total += self.n_kind("cross") * (
+            self._attn_params_per_layer()
+            - 2 * (h_in + bool(self.qkv_bias)) * self.kv_dim
+        )
         return total
 
     def _param_count(self, experts: int, small: bool = True) -> int:
@@ -750,6 +852,14 @@ CONV_TINY = LlamaConfig(  # for tests: gated short-convolution layers beside gro
     router_score="sigmoid", router_bias=True, router_renorm=True,
     experts_held=(2, 4),
 )
+SSM_TINY = LlamaConfig(  # for tests: state-space, differential window | full, gmu and cross layers
+    vocab_size=512, hidden_size=128, n_layers=12, n_heads=8, n_kv_heads=4,
+    head_dim=16, intermediate_size=192, max_seq_len=256, dtype=jnp.float32,
+    remat=False, tie_embeddings=True, norm_eps=1e-5, norm_type="layernorm1p",
+    partial_rotary=0.0, qkv_bias=True, wo_bias=True, diff_attn=True,
+    sliding_window=24, swa_n_heads=8, ssm_dt_rank=8,
+    layer_types=("mamba", "window") * 3 + ("mamba", "full") + ("gmu", "cross") * 2,
+)
 
 _GPT_OSS_COMMON = dict(
     vocab_size=201088, hidden_size=2880, n_heads=64, n_kv_heads=8,
@@ -791,6 +901,7 @@ CONFIGS = {
     "scmoe-tiny": SCMOE_TINY,
     "linear-tiny": LINEAR_TINY,
     "conv-tiny": CONV_TINY,
+    "ssm-tiny": SSM_TINY,
     "glm-4-9b": GLM_4_9B,
     "olmo-2-7b": OLMO2_7B,
     "command-r-35b": COMMAND_R_35B,
@@ -889,6 +1000,11 @@ def param_specs(config: LlamaConfig) -> dict:
         if not config.n_experts:  # dense-MLP biases only
             layer["b_up"] = L + ("mlp",)
             layer["b_down"] = L + (None,)
+    if config.wo_bias:
+        layer["bo"] = L + (None,)
+    if config.diff_attn:  # four lambda vectors and the pair's sub-norm
+        layer["diff_lam"] = L + (None, None)
+        layer["diff_norm"] = L + (None,)
     if config.attn_sinks:
         layer["sinks"] = L + ("heads",)
     if config.qk_norm:
@@ -932,12 +1048,26 @@ def param_specs(config: LlamaConfig) -> dict:
         specs["window_layers"] = {
             k: v for k, v in layer.items() if "idx" not in k
         }
-    for kind in ("linear", "conv"):
+    # what a layer's attention adds to its norms and MLP (a mixer's, a
+    # gmu's or a cross layer's leaves take its place)
+    attn_side = set(attn) | {
+        "q_norm", "k_norm", "bq", "bk", "bv", "bo", "diff_lam", "diff_norm",
+    }
+    if "gmu" in config.layer_types:
+        specs["gmu_layers"] = {
+            **{k: v for k, v in layer.items() if k not in attn_side},
+            "gmu_w1": L + ("embed_fsdp", None), "wo": L + (None, "embed_fsdp"),
+        }
+    if "cross" in config.layer_types:  # the queries' side of the attention
+        specs["cross_layers"] = {
+            k: v for k, v in layer.items() if k not in ("wk", "wv", "bk", "bv")
+        }
+    for kind in STATE_KINDS:
         if kind not in config.layer_types:
             continue
         # a mixer's leaves in place of the attention's (skinny or
         # elementwise ones replicated, a linear mixer's projections over
-        # heads; a conv mixer's are split in three, not by heads)
+        # heads; a conv or mamba mixer's are not split by heads)
         mixer = {
             k: L + (
                 (None,) * (len(shape) - 1) if init == "conv" or len(shape) < 3
@@ -948,8 +1078,7 @@ def param_specs(config: LlamaConfig) -> dict:
             for k, (shape, init) in mixer_of(kind).leaf_shapes(config, 1).items()
         }
         swap = lambda tree: {
-            **{k: v for k, v in tree.items()
-               if k not in attn and k not in ("q_norm", "k_norm")},
+            **{k: v for k, v in tree.items() if k not in attn_side},
             **mixer,
         }
         if config.n_kind(kind, prelude=False):
@@ -972,6 +1101,8 @@ def _init_mixer(
         k = jax.random.fold_in(key, 41 + i)
         if init == "ones":
             out[name] = jnp.ones(shape, c.dtype)
+        elif init == "zeros":
+            out[name] = jnp.zeros(shape, c.dtype)
         elif init == "small":
             out[name] = jax.random.normal(k, shape, jnp.float32) * std
         else:
@@ -1051,6 +1182,13 @@ def _init_attn(
         attn["w_og"] = normal(
             jax.random.fold_in(key, 22), (L, c.hidden_size, c.n_heads)
         )
+    if c.wo_bias:
+        attn["bo"] = jnp.zeros((L, c.hidden_size), dt)
+    if c.diff_attn:
+        # (lambda_q1, lambda_k1, lambda_q2, lambda_k2); the sub-norm's
+        # weight as (w - 1): zeros are identity
+        attn["diff_lam"] = normal(jax.random.fold_in(key, 26), (L, 4, c.head_dim))
+        attn["diff_norm"] = jnp.zeros((L, 2 * c.head_dim), dt)
     return attn
 
 
@@ -1217,24 +1355,46 @@ def init_params(config: LlamaConfig, key: jax.Array, depth: int = 0) -> dict:
         params["window_layers"] = init_params(
             wc, jax.random.fold_in(key, 3), depth
         )["layers"]
-    for kind, folds in (("linear", (4, 5)), ("conv", (6, 8))):
+    for kind, folds in (
+        ("linear", (4, 5)), ("conv", (6, 8)), ("mamba", (9, 10)),
+        ("gmu", (14, 15)), ("cross", (16, 17)),
+    ):
         n = c.n_kind(kind, prelude=False)
         if not n:
             continue
-        # the linear | conv layers: the expert layer's MLP leaves under a
-        # mixer's, a stack of their own (only the stack is kept; the
-        # attention it is drawn with is dropped: one head of the least
-        # widths, the MLP leaves' draws do not read them)
+        # the layers that do not attend over rows of their own: the
+        # expert layer's MLP leaves under a mixer's (a gmu's two
+        # projections, a cross layer's queries), a stack of their own
+        # (only the stack is kept; the attention it is drawn with is
+        # dropped: one head of the least widths, the MLP leaves' draws do
+        # not read them)
         mc = dataclasses.replace(
             c, n_layers=n, layer_types=(), first_k_dense=0, vocab_size=8,
             tie_embeddings=True, n_heads=1, n_kv_heads=1, head_dim=2,
-            kv_lora_rank=0, qk_norm=False,
+            kv_lora_rank=0, qk_norm=False, qkv_bias=False, wo_bias=False,
+            diff_attn=False,
         )
         full = init_params(mc, jax.random.fold_in(key, folds[0]), depth)["layers"]
         attn = _init_attn(mc, key, 1, std, depth)
+        own = jax.random.fold_in(key, folds[1])
+        if kind == "gmu":
+            di = c.ssm_inner
+            mixer = {
+                "gmu_w1": normal(jax.random.fold_in(own, 0), (n, c.hidden_size, di)),
+                "wo": normal(
+                    jax.random.fold_in(own, 1), (n, di, c.hidden_size),
+                    std / math.sqrt(2 * depth),
+                ),
+            }
+        elif kind == "cross":
+            mixer = {
+                k: v for k, v in _init_attn(c, own, n, std, depth).items()
+                if k not in ("wk", "wv", "bk", "bv")
+            }
+        else:
+            mixer = _init_mixer(c, kind, own, n, std, depth)
         params[STACK_OF[kind]] = {
-            **{k: v for k, v in full.items() if k not in attn},
-            **_init_mixer(c, kind, jax.random.fold_in(key, folds[1]), n, std, depth),
+            **{k: v for k, v in full.items() if k not in attn}, **mixer,
         }
     if not c.tie_embeddings:
         params["lm_head"] = normal(jax.random.fold_in(key, 99), (c.hidden_size, c.vocab_size))
@@ -1397,7 +1557,7 @@ class LayerRun(NamedTuple):
     window: int  # 0 = full attention
     lo: int
     hi: int
-    kind: str = "full"  # "full" | "window" | "linear" | "conv": what its layers keep
+    kind: str = "full"  # a key of :data:`STACK_OF`: what its layers keep
 
 
 def layer_runs(config: "LlamaConfig") -> list:
@@ -1463,31 +1623,61 @@ class LayerPeriods(NamedTuple):
     tail: list
 
 
+def _fold_runs(rest: list, anywhere: bool) -> tuple:
+    """The shortest pattern of (stack, length) that repeats over most of
+    ``rest`` → (runs before it, its period, its count, runs after it);
+    ``anywhere``: it may start past the first run (else at it)."""
+    shape = lambda r: (r.key, r.hi - r.lo)
+    at_best, p_best, n_best = 0, 0, 0
+    for at in range(len(rest) if anywhere else 1):
+        for p in range(1, (len(rest) - at) // 2 + 1):
+            n = 1
+            while at + (n + 1) * p <= len(rest) and all(
+                shape(rest[at + n * p + j]) == shape(rest[at + j]) for j in range(p)
+            ):
+                n += 1
+            if n > 1 and n * p > n_best * p_best:
+                at_best, p_best, n_best = at, p, n
+    end = at_best + p_best * n_best
+    return rest[:at_best], rest[at_best : at_best + p_best], n_best, rest[end:]
+
+
+def _periods_of(head: list, period: list, count: int, tail: list) -> LayerPeriods:
+    per: dict = {}
+    for r in period:
+        per[r.key] = per.get(r.key, 0) + r.hi - r.lo
+    return LayerPeriods(head, period, count, per, tail)
+
+
 def layer_periods(config: "LlamaConfig") -> LayerPeriods:
     """The runs after the prelude as the shortest pattern of (stack,
     length) that repeats over most of them: a program that scans over
     the periods, its body one period, does not grow with depth (13
     layers of full·dense, then window × 3 + full three times, are a
     prelude and three periods of two runs, not seven runs). Where
-    nothing repeats ``count`` is 0 and every run is in ``tail``."""
+    nothing repeats ``count`` is 0 and every run is in ``tail``. (The
+    FIRST fold of :func:`layer_segments`: a model of one pattern.)"""
     runs = layer_runs(config)
     head = [r for r in runs if r.key == "dense_layers"]
-    rest = runs[len(head):]
-    shape = lambda r: (r.key, r.hi - r.lo)
-    p_best, n_best = 0, 0
-    for p in range(1, len(rest) // 2 + 1):
-        n = 1
-        while (n + 1) * p <= len(rest) and all(
-            shape(rest[n * p + j]) == shape(rest[j]) for j in range(p)
-        ):
-            n += 1
-        if n > 1 and n * p > n_best * p_best:
-            p_best, n_best = p, n
-    period = rest[:p_best]
-    per: dict = {}
-    for r in period:
-        per[r.key] = per.get(r.key, 0) + r.hi - r.lo
-    return LayerPeriods(head, period, n_best, per, rest[p_best * n_best:])
+    _, period, count, tail = _fold_runs(runs[len(head):], anywhere=False)
+    return _periods_of(head, period, count, tail)
+
+
+def layer_segments(config: "LlamaConfig") -> list:
+    """:func:`layer_periods`, and its tail folded again wherever a
+    pattern repeats in it → a list of :class:`LayerPeriods`, walked one
+    after the other (each one's ``tail`` empty but the last's): a model
+    of two patterns, (mamba, window) x 8, then mamba, full, then (gmu,
+    cross) x 7, is two folded segments and not one with a tail of 16
+    unrolled runs. A model of one pattern is one segment."""
+    out = [layer_periods(config)]
+    while True:
+        last = out[-1]
+        head, period, count, tail = _fold_runs(last.tail, anywhere=True)
+        if not count:
+            return out
+        out[-1] = last._replace(tail=[])
+        out.append(_periods_of(head, period, count, tail))
 
 
 def l2_norm(x: jax.Array, eps: float) -> jax.Array:
@@ -1791,6 +1981,97 @@ def mla_qkv(
     return q, k, v
 
 
+def diff_lambda_init(c: LlamaConfig, kind: str) -> jax.Array:
+    """lambda_init of the layers of ``kind`` in the order the model walks
+    them (a layer's row in its kind's buffers, the prelude's first) →
+    [n] float32: 0.8 - 0.6 exp(-0.3 l), ``l`` the layer's index in the
+    whole model."""
+    at = [i for i, t in enumerate(c.layer_types) if t == kind]
+    return jnp.asarray([0.8 - 0.6 * math.exp(-0.3 * i) for i in at], jnp.float32)
+
+
+def diff_pack(q: jax.Array, k, v, c: LlamaConfig) -> tuple:
+    """Differential attention's projections q [B, S, q_dim], k, v
+    [B, S, kv_dim] (None: a cross layer's) → what plain grouped-query
+    attention at ``c.attend_config`` takes: q [B, H, S, 2D], query head
+    2p + j (q1 | q2 of pair p) zero-padded into half j of the pair's
+    width, k and v [B, Hkv / 2, S, 2D], KV heads (2g, 2g + 1) side by
+    side. Head 2p + j of the packed queries reads packed KV head
+    (2p + j) // (2 H / Hkv) = p // (H / Hkv): its pair's."""
+    b, s, _ = q.shape
+    d = c.head_dim
+    q = q.reshape(b, s, c.n_heads // 2, 2, d)
+    zero = jnp.zeros_like(q[..., 0, :])
+    q = jnp.stack([
+        jnp.concatenate([q[..., 0, :], zero], axis=-1),
+        jnp.concatenate([zero, q[..., 1, :]], axis=-1),
+    ], axis=-2).reshape(b, s, c.n_heads, 2 * d).transpose(0, 2, 1, 3)
+    if k is None:
+        return q, None, None
+    pair = lambda a: a.reshape(b, s, c.n_kv_heads // 2, 2 * d).transpose(0, 2, 1, 3)
+    return q, pair(k), pair(v)
+
+
+def diff_combine(o: jax.Array, layer: dict, c: LlamaConfig, lam0) -> jax.Array:
+    """The packed heads' outputs ``o`` [B, S, H * 2D] (head 2p + j: a_j
+    of pair p over [v1 | v2]) → [B, S, q_dim] for ``wo``: a1 - lambda a2,
+    RMSNormed over the pair's 2D under (1 + ``diff_norm``), times
+    (1 - lambda_init); ``lam0`` the layer's lambda_init (a scalar)."""
+    with jax.named_scope("dtpu.diff_attn"):
+        b, s, _ = o.shape
+        f32 = jnp.float32
+        a = o.reshape(b, s, c.n_heads // 2, 2, 2 * c.head_dim).astype(f32)
+        lq1, lk1, lq2, lk2 = layer["diff_lam"].astype(f32)
+        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam0
+        dlt = a[..., 0, :] - lam * a[..., 1, :]
+        dlt = dlt * jax.lax.rsqrt(
+            jnp.mean(dlt * dlt, axis=-1, keepdims=True) + c.norm_eps
+        )
+        dlt = dlt * (1.0 + layer["diff_norm"].astype(f32)) * (1.0 - lam0)
+        return dlt.reshape(b, s, c.q_dim).astype(o.dtype)
+
+
+def gmu_mix(h: jax.Array, m: jax.Array, layer: dict) -> jax.Array:
+    """A gated memory unit on the normed hidden ``h`` [B, S, H] and the
+    scan output ``m`` [B, S, d_inner] of the same positions → m *
+    silu(h W_1) for ``wo`` (W_2)."""
+    with jax.named_scope("dtpu.gmu"):
+        gate = jnp.einsum(
+            "bte,ed->btd", h, layer["gmu_w1"].astype(h.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        return (m.astype(jnp.float32) * jax.nn.silu(gate)).astype(h.dtype)
+
+
+def _diff_attention_block(
+    x: jax.Array, layer: dict, c: LlamaConfig, window: int, lam0, kv,
+    mesh: Optional[Mesh], rules: ShardingRules, attn_impl: Optional[str],
+):
+    """A differential-attention layer's sublayer over whole sequences →
+    (out, (k, v) packed [B, Hkv / 2, T, 2D]: what the cross layers after
+    a full layer read). ``kv``: the rows a cross layer reads (its own
+    stack has queries alone), else None."""
+    ac = c.attend_config
+    b, t, _ = x.shape
+    h = model_norm(x, layer["attn_norm"], c)
+    proj = lambda n: _proj(
+        layer, f"w{n}", h, "bte,ed->btd", "bte,er->btr", "btr,rd->btd"
+    ) + (layer[f"b{n}"] if c.qkv_bias else 0)
+    if kv is None:
+        q, k, v = diff_pack(proj("q"), proj("k"), proj("v"), c)
+    else:
+        q, (k, v) = diff_pack(proj("q"), None, None, c)[0], kv
+    o = attention(
+        q, k, v, causal=True, scale=ac.attention_scale, impl=attn_impl,
+        window=window, shard=kernel_shard(mesh, rules, b, k.shape[1]),
+    )
+    o = diff_combine(o.transpose(0, 2, 1, 3).reshape(b, t, ac.q_dim), layer, c, lam0)
+    out = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
+    if c.wo_bias:
+        out = out + layer["bo"]
+    return constrain(out, rules, "batch", "seq", None, mesh=mesh), (k, v)
+
+
 def _attention_block(
     x: jax.Array,
     layer: dict,
@@ -1850,7 +2131,7 @@ def _attention_block(
             q, k = qk_norm_apply(q, k, layer, c)
         q = constrain(q, rules, "batch", "heads", "seq", None, mesh=mesh)
         k = constrain(k, rules, "batch", "kv_heads", "seq", None, mesh=mesh)
-        if not nope:
+        if not nope and c.rope_dim:  # partial_rotary 0: no rotary at all
             q = apply_rope(q, cos, sin, interleaved=c.rope_interleaved)
             k = apply_rope(k, cos, sin, interleaved=c.rope_interleaved)
             if c.qk_l2_norm:  # Llama4: weightless L2 norm AFTER rope
@@ -1913,7 +2194,7 @@ def _attention_block(
     o = head_gate(o, h, layer, c, "bth,bhtv->bhtv")
     o = o.transpose(0, 2, 1, 3).reshape(b, t, c.o_dim)
     out = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-    if c.proj_bias:
+    if c.proj_bias or c.wo_bias:
         out = out + layer["bo"]
     if c.post_norms:
         out = model_norm(out, layer["attn_post_norm"], c)
@@ -1924,16 +2205,25 @@ def _attention_block(
 
 def _mixer_block(
     x: jax.Array, layer: dict, config: LlamaConfig, mesh: Optional[Mesh],
-    rules: ShardingRules, kind: str,
-) -> jax.Array:
-    """A linear or conv layer's mixer over whole sequences, from a past
-    of zeros (models/kda.py, models/shortconv.py): the training and
-    parity path's."""
-    c, mixer = config, mixer_of(kind)
+    rules: ShardingRules, kind: str, m=None,
+):
+    """A linear, conv or mamba layer's mixer over whole sequences, from a
+    past of zeros (models/kda.py, shortconv.py, mamba.py): the training
+    and parity path's. A gmu layer (``kind`` ``"gmu"``) gates ``m``, the
+    scan output of the mamba layer before it; with ``m`` (a model with
+    gmu layers) → (out, the scan output the layers after this one read)."""
+    c = config
     h = model_norm(x, layer["attn_norm"], c)
-    y = mixer.mix(h, layer, c, *mixer.zeros(c, x.shape[0], x.dtype))[0]
+    if kind == "gmu":
+        y = gmu_mix(h, m, layer)
+    else:
+        mixer = mixer_of(kind)
+        y = mixer.mix(h, layer, c, *mixer.zeros(c, x.shape[0], x.dtype))[0]
+        if kind == "mamba":  # (y, its scan's output)
+            y, m = y if m is not None else (y[0], None)
     out = _proj(layer, "wo", y, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-    return constrain(out, rules, "batch", "seq", None, mesh=mesh)
+    out = constrain(out, rules, "batch", "seq", None, mesh=mesh)
+    return out if m is None else (out, m)
 
 
 def _mlp_block(
@@ -2114,6 +2404,67 @@ def _merge_lora(xs: dict, lora: Optional[dict], lora_scale: float, config: Llama
     }
 
 
+def _shared_zeros(c: LlamaConfig, x: jax.Array) -> dict:
+    """What layers of a model hand to layers further up at the same
+    positions, before any has: ``m`` [B, T, d_inner], the latest mamba
+    layer's scan output (the gmu layers read it); ``k`` / ``v``
+    [B, Hkv, T, D] at ``attend_config``, the full layer's rows (the
+    cross layers read them). Empty for a model with neither."""
+    b, t, _ = x.shape
+    shared = {}
+    if "gmu" in c.layer_types:
+        shared["m"] = jnp.zeros((b, t, c.ssm_inner), x.dtype)
+    if "cross" in c.layer_types:
+        ac = c.attend_config
+        shared["k"] = shared["v"] = jnp.zeros(
+            (b, ac.n_kv_heads, t, ac.head_dim), x.dtype
+        )
+    return shared
+
+
+def _make_shared_run_fn(c: LlamaConfig, run, mesh, rules, attn_impl, ropes, pos):
+    """The layer scan's body of one run of a model whose layers hand
+    something on (:func:`_shared_zeros`) or attend differentially: carry
+    ``(x, shared)``, xs ``(layer, its row among its kind's layers)``."""
+    kind = run.kind
+    lam_table = (
+        diff_lambda_init(c, kind)
+        if c.diff_attn and kind in ("full", "window", "cross") else None
+    )
+
+    def run_fn(carry, layer_and_row):
+        (x, shared), (layer, row) = carry, layer_and_row
+        if kind in STATE_KINDS or kind == "gmu":
+            ao = _mixer_block(x, layer, c, mesh, rules, kind, shared.get("m"))
+            if "m" in shared:
+                ao, m = ao
+                shared = {**shared, "m": m}
+        elif c.diff_attn:
+            ao, kv = _diff_attention_block(
+                x, layer, run.config, run.window, lam_table[row],
+                (shared["k"], shared["v"]) if kind == "cross" else None,
+                mesh, rules, attn_impl,
+            )
+            if kind == "full" and "k" in shared:
+                shared = {**shared, "k": kv[0], "v": kv[1]}
+        else:  # plain attention beside mamba and gmu layers
+            cos, sin = layer_rope(ropes, c, run.window)
+            ao = _attention_block(
+                x, layer, run.config, cos, sin, mesh, rules, attn_impl,
+                window=run.window, positions=pos,
+            )
+        x = x + ao
+        o, aux = _mlp_block(x, layer, c, mesh, rules)
+        return (x + o, shared), aux
+
+    if c.remat:
+        run_fn = jax.checkpoint(
+            run_fn,
+            policy=jax.checkpoint_policies.save_only_these_names("flash_residuals"),
+        )
+    return run_fn
+
+
 def forward(
     params: dict,
     tokens: jax.Array,  # [B, T] int32
@@ -2161,7 +2512,7 @@ def forward(
                     rules=rules, attn_impl=attn_impl, window=w, nope=np_,
                     positions=pos,
                 )
-                if kind in ("linear", "conv"):  # a mixer in the attention's place
+                if kind in STATE_KINDS:  # a mixer in the attention's place
                     attend = functools.partial(
                         _mixer_block, config=c, mesh=mesh, rules=rules, kind=kind
                     )
@@ -2206,11 +2557,21 @@ def forward(
         if lora is not None:
             raise NotImplementedError("LoRA over layer groups")
         aux = jnp.zeros((), jnp.float32)
+        shared = _shared_zeros(c, x)
         for run in layer_runs(c):
-            x, auxs = jax.lax.scan(
-                make_group_fn((run.window,), (False,), False, run.config, run.kind),
-                x, run_slice(params[run.key], run),
-            )
+            if shared or c.diff_attn:
+                (x, shared), auxs = jax.lax.scan(
+                    _make_shared_run_fn(c, run, mesh, rules, attn_impl, ropes, pos),
+                    (x, shared), (
+                        run_slice(params[run.key], run),
+                        run_row(c, run) + jnp.arange(run.hi - run.lo),
+                    ),
+                )
+            else:
+                x, auxs = jax.lax.scan(
+                    make_group_fn((run.window,), (False,), False, run.config, run.kind),
+                    x, run_slice(params[run.key], run),
+                )
             aux = aux + jnp.sum(auxs)
         out = _lm_head(params, x, c, mesh, rules, return_hidden)
         return (out, aux) if return_aux else out

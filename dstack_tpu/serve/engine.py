@@ -385,12 +385,17 @@ def _cache_shapes(
     layer ``l``'s sublayer ``i`` at row ``l * sublayers + i``. Linear
     layers (``models/kda.py``) hold ``state`` [Ll, B, heads, D, D] in
     float32 and ``conv`` [Ll, B, K-1, 3 * heads * D], conv layers
-    (``models/shortconv.py``) ``conv`` [Lc, B, K-1, hidden] alone, none
-    with a token axis; under group-limited routing ``moe_stats`` ends
+    (``models/shortconv.py``) ``conv`` [Lc, B, K-1, hidden] alone, mamba
+    layers (``models/mamba.py``) ``state`` [Lm, B, N, d_inner] in float32
+    and ``conv`` [Lm, B, K-1, d_inner], none with a token axis (gmu and
+    cross layers hold nothing; with ``diff_attn`` ``k`` / ``v`` and the
+    rings hold a KV pair as one head, ``attend_config``); under
+    group-limited routing ``moe_stats`` ends
     with the tokens one of whose eligible groups is held here."""
     n_win = c.layer_types.count("window")
     n_lin = c.layer_types.count("linear")
     n_conv = c.layer_types.count("conv")
+    n_mamba = c.layer_types.count("mamba")
     n_full = c.n_kind("full")
     ring = ring_rows(c, max_seq, chunk) if n_win else 0
     if c.mla:
@@ -407,7 +412,10 @@ def _cache_shapes(
                 n_win, max_batch, ring, c.window_config.kv_lora_rank + rope
             )
     else:
-        kv = lambda n, t: (n, max_batch, c.n_kv_heads, t, c.head_dim)
+        # (differential attention: a KV pair side by side is one head of
+        # twice the width, ``attend_config``; the bytes are the same)
+        ac = c.attend_config
+        kv = lambda n, t: (n, max_batch, ac.n_kv_heads, t, ac.head_dim)
         shapes = {"k": kv(n_full, max_seq), "v": kv(n_full, max_seq)}
         if kv_quant:
             shapes["k_s"] = shapes["v_s"] = shapes["k"][:-1]
@@ -424,6 +432,13 @@ def _cache_shapes(
     if n_conv:
         # nor does a conv layer: its convolution's tail over the hidden
         shapes["conv"] = (n_conv, max_batch, c.conv_taps - 1, c.hidden_size)
+    if n_mamba:
+        # a mamba layer (``models/mamba.py``): its recurrence's state,
+        # float32, the channels on the lanes, and its convolution's
+        # tail; gmu and cross layers hold nothing (a cross layer reads
+        # row 0 of ``k`` / ``v``)
+        shapes["state"] = (n_mamba, max_batch, c.ssm_state, c.ssm_inner)
+        shapes["conv"] = (n_mamba, max_batch, c.ssm_conv - 1, c.ssm_inner)
     if c.experts_held:
         shapes["moe_stats"] = (_moe_counts(c) - 2,)
         shapes["moe_reads"] = (2,)
@@ -861,8 +876,10 @@ def _mlp_out(
     return mo if valid is None else (mo, picks)
 
 
-def _qkv(h: jax.Array, layer: dict, c: LlamaConfig) -> tuple:
+def _qkv(h: jax.Array, layer: dict, c: LlamaConfig, cross: bool = False) -> tuple:
     q = _proj(layer, "wq", h, "bte,ed->btd", "bte,er->btr", "btr,rd->btd")
+    if cross:  # a cross layer has queries alone: another layer's rows are its keys
+        return (q + layer["bq"] if c.qkv_bias else q), None, None
     k = _proj(layer, "wk", h, "bte,ed->btd", "bte,er->btr", "btr,rd->btd")
     v = _proj(layer, "wv", h, "bte,ed->btd", "bte,er->btr", "btr,rd->btd")
     if c.qkv_bias:
@@ -873,7 +890,9 @@ def _qkv(h: jax.Array, layer: dict, c: LlamaConfig) -> tuple:
     return q, k, v
 
 
-def _dense_in(x: jax.Array, layer: dict, c: LlamaConfig, rope, nope, temp) -> tuple:
+def _dense_in(
+    x: jax.Array, layer: dict, c: LlamaConfig, rope, nope, temp, cross: bool = False
+) -> tuple:
     """A dense layer's way into attention, the one copy prefill, decode
     and verify share: norm, projections, heads apart, per-head q/k norm,
     rope, Llama4's norm after it → (q [B, H, S, D], k, v [B, Hkv, S, D]).
@@ -882,9 +901,14 @@ def _dense_in(x: jax.Array, layer: dict, c: LlamaConfig, rope, nope, temp) -> tu
     (Llama4): a Python bool where the program unrolls its layers
     (prefill), a traced flag where they ride one scan (decode, verify),
     and then both forms are computed and one selected. ``temp``: q → q
-    under the NoPE query temperature at the caller's positions."""
+    under the NoPE query temperature at the caller's positions.
+    Differential attention (``diff_attn``: no rope) → the same at
+    ``c.attend_config``, packed a pair (``llama.diff_pack``); ``cross``:
+    a layer of queries alone (k, v None)."""
     b, s = x.shape[0], x.shape[1]
     h = model_norm(x, layer["attn_norm"], c) if c.pre_norm else x
+    if c.diff_attn:
+        return llama.diff_pack(*_qkv(h, layer, c, cross), c)
     q, k, v = _qkv(h, layer, c)
     q = q.reshape(b, s, c.n_heads, c.head_dim).transpose(0, 2, 1, 3)
     k = k.reshape(b, s, c.n_kv_heads, c.head_dim).transpose(0, 2, 1, 3)
@@ -921,7 +945,7 @@ def _dense_out(
             o.reshape(b, s, c.n_heads, c.head_dim), h, layer, c, "bsh,bshd->bshd"
         ).reshape(o.shape)
     ao = _proj(layer, "wo", o, "btd,de->bte", "btd,dr->btr", "btr,re->bte")
-    if c.proj_bias:
+    if c.proj_bias or c.wo_bias and "bo" in layer:  # (wo_bias: the attending layers')
         ao = ao + layer["bo"]
     if c.post_norms:
         ao = model_norm(ao, layer["attn_post_norm"], c)
@@ -1170,13 +1194,14 @@ def _state_store(cache: dict, li, slots, live, new) -> dict:
 _PENDING = {
     "linear": ("pend_k", "pend_v", "pend_g", "pend_b", "pend_pre"),
     "conv": ("pend_u",),
+    "mamba": ("pend_x", "pend_dt", "pend_b", "pend_pre"),
 }
 
 
 def _state_kind(c: LlamaConfig) -> str:
     """The kind of the model's layers that hold a slot's past whole
-    (``"linear"`` | ``"conv"``; a model has one such kind at most)."""
-    return "linear" if "linear" in c.layer_types else "conv"
+    (``llama.STATE_KINDS``; a model has one such kind at most)."""
+    return next(k for k in llama.STATE_KINDS if k in c.layer_types)
 
 
 def _state_mixer(c: LlamaConfig, live, slots=None, fresh=None, real=None,
@@ -1188,17 +1213,20 @@ def _state_mixer(c: LlamaConfig, live, slots=None, fresh=None, real=None,
     (:func:`_state_rows`, :func:`_state_store`). ``real`` [B, S]: the
     tokens that move the state, ``counts`` [B] of them a row (None:
     all). ``commit`` false is the verify step's: the state stays, and
-    each position's inputs go to the :data:`_PENDING` leaves."""
+    each position's inputs go to the :data:`_PENDING` leaves. A mamba
+    layer's ``y`` is the pair (y, m): its scan's output beside it."""
     kind = _state_kind(c)
     mixer = llama.mixer_of(kind)
 
     def mix(x, layer, cache, li):
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+        h = model_norm(x, layer["attn_norm"], c)
         rows = _state_rows(cache, li, slots, fresh)
         if commit:
             y, *rows = mixer.mix(h, layer, c, *rows, real, counts)
             return y, _state_store(cache, li, slots, live, rows)
-        y, *_, inputs = mixer.mix_parts(h, layer, c, *rows)
+        y, *rest, inputs = mixer.mix_parts(h, layer, c, *rows)
+        if kind == "mamba":  # (y, the scan's output: the gmu layers')
+            y = (y, rest[0])
         cache = {**cache, **{
             n: jax.lax.dynamic_update_index_in_dim(cache[n], a, li, 0)
             for n, a in zip(_PENDING[kind], inputs)
@@ -1214,8 +1242,16 @@ def _state_pending(cache: dict, c: LlamaConfig, s: int) -> dict:
     new_rows = lambda: jnp.zeros((n, b, s, width), cache["conv"].dtype)
     if "state" not in cache:
         return {**cache, "pend_u": new_rows()}
-    nh, d = cache["state"].shape[2:4]
     f32 = jnp.float32
+    if _state_kind(c) == "mamba":  # state [n, B, N, d_inner]
+        n_state = cache["state"].shape[2]
+        return {
+            **cache,
+            **{k: jnp.zeros((n, b, s, width), f32) for k in ("pend_x", "pend_dt")},
+            "pend_b": jnp.zeros((n, b, s, n_state), f32),
+            "pend_pre": new_rows(),
+        }
+    nh, d = cache["state"].shape[2:4]
     return {
         **cache,
         **{k: jnp.zeros((n, b, s, nh, d), f32) for k in _PENDING["linear"][:3]},
@@ -1224,16 +1260,35 @@ def _state_pending(cache: dict, c: LlamaConfig, s: int) -> dict:
     }
 
 
-def _state_commit(cache: dict, n_tokens, write_mask, c: LlamaConfig) -> dict:
+def _state_commit(
+    cache: dict, n_tokens, write_mask, c: LlamaConfig, params=None
+) -> dict:
     """The verify step's second half for the layers that hold a state:
     each live slot's state and tail advanced by its first ``n_tokens``
     [B] positions (the last token and the accepted drafts) and by no
-    rejected one → the cache without the :data:`_PENDING` leaves."""
-    from dstack_tpu.models import kda
+    rejected one → the cache without the :data:`_PENDING` leaves.
+    ``params``: the model's (a mamba layer's decay is its weight's)."""
+    from dstack_tpu.models import kda, mamba
 
     names = _PENDING[_state_kind(c)]
     pend = [cache[k] for k in names]
     cache = {k: v for k, v in cache.items() if k not in names}
+    if _state_kind(c) == "mamba":
+
+        def one_ssm(cache, xs):
+            li, a_log, *inputs = xs
+            state, tail = _state_rows(cache, li)
+            with jax.named_scope("dtpu.ssm.scan"):
+                new = mamba.advance(
+                    state, tail, {"ssm_a_log": a_log}, inputs, n_tokens
+                )
+            return _state_store(cache, li, None, write_mask, new), None
+
+        cache, _ = jax.lax.scan(one_ssm, cache, (
+            jnp.arange(pend[0].shape[0]),
+            params[llama.STACK_OF["mamba"]]["ssm_a_log"], *pend,
+        ))
+        return cache
     if "state" not in cache:  # a tail alone: every layer's at once
         with jax.named_scope("dtpu.conv.tail"):
             tail = jax.vmap(kda.next_tail, in_axes=(0, 0, None))(
@@ -1726,20 +1781,25 @@ def _scan_layers_kv(params: dict, cache: dict, x: jax.Array, one_layer, c):
     return x, _cache_unpack(ck, cv)
 
 
-def _walk_layer_groups(params: dict, carry, one_layer, c: LlamaConfig):
+def _walk_layer_groups(params: dict, carry, one_layer, c: LlamaConfig, one=None):
     """Drive ``one_layer(carry, layer, li, run) -> (carry, y)`` over a
     model of layer GROUPS (a grouped-query one; a latent one with linear
     layers, its carry ``(x, cache)``), in the order of
-    ``llama.layer_periods``: the prelude, then ONE ``lax.scan`` over the
+    ``llama.layer_segments``: the prelude, then ONE ``lax.scan`` over the
     periods whose body is one period (each of its runs a scan over its
-    share of its group's stack), then what is left over, so that the
-    program holds a layer body a run of the period and does not grow
-    with depth. ``li`` is the layer's row in its kind's cache buffers
+    share of its group's stack), then what is left over (folded again
+    where a second pattern repeats in it), so that the program holds a
+    layer body a run of a period and does not grow with depth. ``li`` is the layer's row in its kind's cache buffers
     (full layers: the prelude first). → (carry, [(run, first row, ys
     stacked in row order)] a run of the prelude and of the tail and a
     stack of the period: what a program that only READS the cache in
-    its scans writes after them, one block write a buffer)."""
-    plan = llama.layer_periods(c)
+    its scans writes after them, one block write a buffer). ``one``: a
+    traced int32 that reads 1 (a decode step's, where some run is ONE
+    layer that reads a buffer the step writes after its scans: a scan of
+    one trip is inlined, the read then stands bare in ``decode_loop``'s
+    token loop, and the compiler copies the whole leaf into the write's
+    loop and out of it, 2 x 0.67 GB a leaf a token at 32 x 8192,
+    device-free; a trip count it cannot read keeps the loop)."""
     row = partial(llama.run_row, c)  # the run's first layer → its cache row
 
     def scan_run(carry, run, ahead=0):
@@ -1759,44 +1819,108 @@ def _walk_layer_groups(params: dict, carry, one_layer, c: LlamaConfig):
             layer = rows(layer, run.lo + ahead + j)
             return one_layer(carry, layer, row(run) + ahead + j, run)
 
+        if one is not None and run.hi - run.lo == 1:
+            ys = jax.tree.map(
+                lambda a: jnp.zeros((1,) + a.shape, a.dtype),
+                jax.eval_shape(lambda cr: layer_fn(cr, 0)[1], carry),
+            )
+
+            def trip(j, carry_ys):
+                carry, y = layer_fn(carry_ys[0], j)
+                return carry, jax.tree.map(lambda a: a[None], y)
+
+            return jax.lax.fori_loop(0, one, trip, (carry, ys))
         return jax.lax.scan(layer_fn, carry, jnp.arange(run.hi - run.lo))
 
     out = []
-    for run in plan.head:
-        carry, ys = scan_run(carry, run)
-        out.append((run, row(run), ys))
-    if plan.count:
+    # a model of one repeating pattern is one segment; of two (a lower
+    # half of one period, an upper half of another) one after the other
+    for plan in llama.layer_segments(c):
+        for run in plan.head:
+            carry, ys = scan_run(carry, run)
+            out.append((run, row(run), ys))
+        if plan.count:
 
-        def period_fn(carry, i):
-            ys = {key: [] for key in plan.per}
+            def period_fn(carry, i, plan=plan):
+                ys = {key: [] for key in plan.per}
+                for run in plan.period:
+                    carry, y = scan_run(carry, run, i * plan.per[run.key])
+                    ys[run.key].append(y)
+                return carry, {
+                    key: y[0] if len(y) == 1 else jax.tree.map(
+                        lambda *a: jnp.concatenate(a), *y
+                    )
+                    for key, y in ys.items()
+                }
+
+            carry, ys = jax.lax.scan(period_fn, carry, jnp.arange(plan.count))
+            seen = set()
             for run in plan.period:
-                carry, y = scan_run(carry, run, i * plan.per[run.key])
-                ys[run.key].append(y)
-            return carry, {
-                key: y[0] if len(y) == 1 else jax.tree.map(
-                    lambda *a: jnp.concatenate(a), *y
-                )
-                for key, y in ys.items()
-            }
-
-        carry, ys = jax.lax.scan(period_fn, carry, jnp.arange(plan.count))
-        seen = set()
-        for run in plan.period:
-            if run.key not in seen:  # once a stack: its first run's row
-                seen.add(run.key)
-                out.append((run, row(run), jax.tree.map(
-                    lambda a: a.reshape((-1,) + a.shape[2:]), ys[run.key]
-                )))
-    for run in plan.tail:
-        carry, ys = scan_run(carry, run)
-        out.append((run, row(run), ys))
+                if run.key not in seen:  # once a stack: its first run's row
+                    seen.add(run.key)
+                    out.append((run, row(run), jax.tree.map(
+                        lambda a: a.reshape((-1,) + a.shape[2:]), ys[run.key]
+                    )))
+        for run in plan.tail:
+            carry, ys = scan_run(carry, run)
+            out.append((run, row(run), ys))
     return carry, out
 
 
 def _group_kv(run) -> tuple[str, str]:
     """The cache buffers a run of a grouped-query model of groups keeps
-    its keys and values in."""
+    its keys and values in (a cross layer's: the full layer's, which it
+    reads)."""
     return ("win_k", "win_v") if run.window else ("k", "v")
+
+
+def _shared_zeros(c: LlamaConfig, x: jax.Array, cache=None) -> dict:
+    """What layers of a model hand to layers further up at the same
+    positions, before any has, a part of every group program's carry:
+    ``m`` [B, S, d_inner], the latest mamba layer's scan output (the gmu
+    layers read it); with ``cache`` (a decode step, which else only
+    reads its buffers in its scans) the full layer's ``k`` / ``v``
+    buffers themselves, which the cross layers read after it has
+    written this token's row. Empty, no leaf of any program, for a
+    model with neither."""
+    shared = {}
+    if "gmu" in c.layer_types:
+        shared["m"] = jnp.zeros(x.shape[:2] + (c.ssm_inner,), x.dtype)
+    if cache is not None and "cross" in c.layer_types:
+        shared["k"], shared["v"] = cache["k"], cache["v"]
+    return shared
+
+
+def _lambda_tables(c: LlamaConfig) -> dict:
+    """kind → lambda_init of its layers by their row (differential
+    attention; empty for any other model)."""
+    if not c.diff_attn:
+        return {}
+    return {
+        k: llama.diff_lambda_init(c, k)
+        for k in ("full", "window", "cross") if k in c.layer_types
+    }
+
+
+def _mixed_layer(x, layer, kind: str, c: LlamaConfig, shared: dict, mix):
+    """The mixer of a layer of a grouped-query model that does not
+    attend → (y for ``wo``, shared, what ``mix`` returned besides): a
+    conv or mamba layer's through ``mix(x, layer) -> (y, rest)`` (the
+    program's way of reading and keeping its state), a mamba layer's
+    scan output kept for the gmu layers; a gmu layer's from that."""
+    if kind == "gmu":
+        h = model_norm(x, layer["attn_norm"], c)
+        return llama.gmu_mix(h, shared["m"], layer), shared, None
+    y, rest = mix(x, layer)
+    if kind == "mamba":
+        y, m = y
+        if "m" in shared:
+            shared = {**shared, "m": m}
+    return y, shared, rest
+
+
+#: the kinds of layer of a grouped-query model of groups that do not attend
+_MIXED = ("conv", "mamba", "gmu")
 
 
 def _attend_rows(
@@ -1914,9 +2038,10 @@ def _prefill_packed_groups(
     # their bytes — the masked-future invariant
     valid = jnp.arange(cl)[None, :] <= last_ix[:, None]  # [G, C]
     newest = starts + jnp.maximum(last_ix, 0)  # [G] the last position written
+    ac = c.attend_config  # the cache's shape (every run's: heads vary, not these)
     put = dict(
         positions=starts, write_mask=last_ix >= 0, slots=si, counts=last_ix + 1,
-        axis=1, unroll=_tokens_on_lanes(c.head_dim), opaque_loop=True,
+        axis=1, unroll=_tokens_on_lanes(ac.head_dim), opaque_loop=True,
     )
     # a lone row on a full layer's leaf with its tokens on the lanes
     # (head_dim 64): the block form of ONE row, bare or in a loop, makes
@@ -1928,33 +2053,42 @@ def _prefill_packed_groups(
     # the row, as the engine's chunk starts do where ``max_seq`` is whole
     # chunks
     lone_chunk = (
-        g == 1 and "k" in cache and _tokens_on_lanes(c.head_dim)
+        g == 1 and "k" in cache and _tokens_on_lanes(ac.head_dim)
         and cache["k"].shape[3] % cl == 0
     )
     mix = None
     if "conv" in cache:
-        # padded positions and pad rows leave a tail untouched; a row at
-        # position 0 starts from none
+        # padded positions and pad rows leave a state and a tail
+        # untouched; a row at position 0 starts from none
         mix = _state_mixer(
             c, last_ix >= 0, si, (starts == 0) & (last_ix >= 0), valid,
             last_ix + 1,
         )
+    lam = _lambda_tables(c)
 
     def one_layer(carry, layer, li, run):
-        x, cache = carry
+        x, cache, shared = carry
         gc = run.config
-        if run.kind == "conv":
-            y, cache = mix(x, layer, cache, li)
-            return _group_out(x, cache, y, layer, gc, valid), None
+        if run.kind in _MIXED:
+            y, shared, new_cache = _mixed_layer(
+                x, layer, run.kind, c, shared,
+                lambda x, layer: mix(x, layer, cache, li),
+            )
+            cache = cache if new_cache is None else new_cache
+            return (*_group_out(x, cache, y, layer, gc, valid), shared), None
+        cross = run.kind == "cross"
         cos, sin = llama.layer_rope(ropes, c, run.window)
         q, k, v = _dense_in(
             x, layer, gc,
             lambda t: _rope_rows(t, cos, sin, interleaved=c.rope_interleaved),
-            False, None,
+            False, None, cross,
         )
         write = _cwrite_ring if run.window else _cwrite_rows
         rows = []  # the wave's rows of the run's buffers, [G, Hkv, T, D] each
         for name, new in zip(_group_kv(run), (k, v)):
+            if cross:  # the one full layer's rows, which it has written
+                rows.append(_cread_rows(cache[name], 0, si, q.dtype))
+                continue
             if lone_chunk and not run.window:
                 buf = _cwrite_chunk(cache[name], li, si[0], starts[0], new)
             else:
@@ -1962,15 +2096,21 @@ def _prefill_packed_groups(
             cache = {**cache, name: buf}
             rows.append(_cread_rows(buf, li, si, new.dtype))
         t = rows[0].shape[2]
-        with _attn_scope(run.window):
+        with _attn_scope(run.window, cross=cross):
             if run.window:
                 mask = _ring_mask(pos_grid, newest, t, run.window)
             else:
                 mask = jnp.arange(t)[None, None, :] <= pos_grid[:, :, None]
-            o = _attend_rows(q, *rows, mask, gc, jnp.minimum(newest + 1, t))
-        return _group_out(x, cache, o, layer, gc, valid), None
+            o = _attend_rows(
+                q, *rows, mask, gc.attend_config, jnp.minimum(newest + 1, t)
+            )
+        if c.diff_attn:
+            o = llama.diff_combine(o, layer, gc, lam[run.kind][li])
+        return (*_group_out(x, cache, o, layer, gc, valid), shared), None
 
-    (x, cache), _ = _walk_layer_groups(params, (x, cache), one_layer, c)
+    (x, cache, _), _ = _walk_layer_groups(
+        params, (x, cache, _shared_zeros(c, x)), one_layer, c
+    )
     x = model_norm(x, params["final_norm"], c)
     last = jnp.take_along_axis(
         x, jnp.maximum(last_ix, 0)[:, None, None].astype(jnp.int32), axis=1
@@ -2432,10 +2572,17 @@ def decode_step(
 def _decode_layer(
     x, layer: dict, li, c: LlamaConfig, ck, cv, positions, write_mask,
     rope, nope, temp, window, ring=None, stats=None,
-    decode_kernel: Optional[str] = None, mesh=None,
+    decode_kernel: Optional[str] = None, mesh=None, lam0=None, held=None,
 ):
     """One dense layer of a decode step, the one copy → (x, this token's
-    (k, v) rows as stored[, stats]). ``c``: the layer's attention shape
+    (k, v) rows as stored[, stats]). (Differential attention attends at
+    ``c.attend_config``, a KV pair one head, and combines the pair's two
+    outputs under the layer's ``lam0``. ``held``: the buffers hold this
+    token's row by the time the layer attends, as a verify step's do
+    (a model whose cross layers read the one full layer's buffers
+    carries them through its walk): ``"write"``, the full layer's, which
+    writes its row first and → (x, ck, cv[, stats]); ``"read"``, a cross
+    layer's, which has queries alone and → (x[, stats]).) ``c``: the layer's attention shape
     (its run's, in a model of groups); ``ck`` / ``cv``: the stacked
     buffers its kind of layer keeps, of which this layer is row ``li``;
     ``rope``, ``nope``, ``temp`` as :func:`_dense_in` takes them;
@@ -2446,14 +2593,19 @@ def _decode_layer(
     tokens). Which form attends: :func:`~dstack_tpu.ops.flash_decode.
     reads_live_keys` (``decode_kernel``: what a caller asked for)."""
     b = x.shape[0]
-    q, k, v = _dense_in(x, layer, c, rope, nope, temp)
+    ac = c.attend_config  # the shape the attention itself runs at
+    q, k, v = _dense_in(x, layer, c, rope, nope, temp, cross=held == "read")
     # the scan only READS the stacked cache: this token's K/V goes
     # beside it into the attention and out as ys; all layers' rows
     # are written after the scan, in place, 2 × B small blocks a
     # step where writing inside the scan costs that a layer
-    k_new, v_new = _cstored(k, ck), _cstored(v, cv)
+    if held != "read":
+        k_new, v_new = _cstored(k, ck), _cstored(v, cv)
+    if held == "write":
+        ck = _cwrite_rows(ck, li, positions, write_mask, k_new)
+        cv = _cwrite_rows(cv, li, positions, write_mask, v_new)
     live_keys = reads_live_keys(
-        c, jax.tree.leaves(ck)[0].shape[3], ring=ring is not None,
+        ac, jax.tree.leaves(ck)[0].shape[3], ring=ring is not None,
         quantized=isinstance(ck, tuple), mesh=mesh, decode_kernel=decode_kernel,
     )
     if not live_keys:
@@ -2461,49 +2613,59 @@ def _decode_layer(
         # layer's slice on its way into the attention (masked rows keep
         # theirs)
         at = positions if ring is None else _ring_row(positions, ck)
-        ckl = _cwith_row(_clayer(ck, li), at, write_mask, k_new)
-        cvl = _cwith_row(_clayer(cv, li), at, write_mask, v_new)
-        ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
-        cvf = _cfull(cvl, v.dtype)
+        if held:
+            ckl, cvl = _clayer(ck, li), _clayer(cv, li)
+        else:
+            ckl = _cwith_row(_clayer(ck, li), at, write_mask, k_new)
+            cvl = _cwith_row(_clayer(cv, li), at, write_mask, v_new)
+        ckf = _cfull(ckl, q.dtype)  # int8 caches dequant INSIDE the dot
+        cvf = _cfull(cvl, q.dtype)
     # Grouped-query: q regrouped [B, Hkv, G, D] against the
     # [B, Hkv, T, D] cache — decode is HBM-bandwidth-bound on the KV
     # read, so the cache is streamed ONCE at KV width instead of
     # materializing a G×-wider repeat (4× read amplification for
     # 32q/8kv models).
-    grp = c.n_heads // c.n_kv_heads
-    qg = q[:, :, 0, :].reshape(b, c.n_kv_heads, grp, c.head_dim)
+    grp = ac.n_heads // ac.n_kv_heads
+    qg = q[:, :, 0, :].reshape(b, ac.n_kv_heads, grp, ac.head_dim)
     if live_keys:
         # ragged pallas read out of the stacked leaf: each slot's key
         # blocks up to its length, none for a slot that may not write
         # (its output is discarded), the token's own key (as stored:
         # what the einsum selects in) folded into the softmax
-        o = _flash_attend(
-            qg, ck, cv, li, jnp.where(write_mask, positions, 0), window,
-            config=c, scale=c.attention_scale, grp=grp, rows_per_slot=1,
-            sinks_leaf=layer.get("sinks"), mesh=mesh,
-            new=(_cfull(k_new, k.dtype), _cfull(v_new, v.dtype)),
-        )
+        with _attn_scope(None, cross=held == "read"):
+            o = _flash_attend(
+                qg, ck, cv, li, jnp.where(write_mask, positions, 0), window,
+                config=ac, scale=ac.attention_scale, grp=grp, rows_per_slot=1,
+                sinks_leaf=layer.get("sinks"), mesh=mesh,
+                new=None if held else (_cfull(k_new, q.dtype), _cfull(v_new, q.dtype)),
+            )
     else:
         # attend over the cache prefix (mask: j <= position, and within
         # the layer's sliding window when set)
-        with _attn_scope(window):
+        with _attn_scope(window, cross=held == "read"):
             s = jnp.einsum(
                 "bhgd,bhkd->bhgk", qg, ckf, preferred_element_type=jnp.float32
-            ) * c.attention_scale
-            p = _dense_probs(s, positions, window, nope, layer, c, ring)
+            ) * ac.attention_scale
+            p = _dense_probs(s, positions, window, nope, layer, ac, ring)
             o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(cvf.dtype), cvf)
     # [B, Hkv, G, D] row-major flatten == query-head order
-    o = o.reshape(b, 1, c.q_dim)
+    o = o.reshape(b, 1, ac.q_dim)
+    if c.diff_attn:
+        o = llama.diff_combine(o, layer, c, lam0)
+    rows = () if held == "read" else (ck, cv) if held else ((k_new, v_new),)
     if stats is None:
-        return _dense_out(x, o, layer, c), (k_new, v_new)
+        return (_dense_out(x, o, layer, c), *rows)
     x, stats = _dense_out(x, o, layer, c, stats, write_mask[:, None])
-    return (x, stats), (k_new, v_new)
+    return ((x, stats), *rows)
 
 
-def _attn_scope(window):
+def _attn_scope(window, cross: bool = False):
     """The named scope a capture finds a layer's attention under, where
     its kind is static (a model of layer groups): ``dtpu.attn_window`` |
-    ``dtpu.attn_full``; none where the window rides a scan as data."""
+    ``dtpu.attn_full``, a cross layer's read of another layer's rows
+    ``dtpu.cross_attn``; none where the window rides a scan as data."""
+    if cross:
+        return jax.named_scope("dtpu.cross_attn")
     if not isinstance(window, int):
         return contextlib.nullcontext()
     return jax.named_scope("dtpu.attn_window" if window else "dtpu.attn_full")
@@ -2526,48 +2688,87 @@ def _decode_step_groups(
     of one kind); every scan reads the cache, and each buffer takes its
     runs' new rows in one block write a run after them (PR 25's form).
     A conv layer (``models/shortconv.py``) reads its tail and hands the
-    tail after the token out the same way (a dead slot's as it was)."""
-    from dstack_tpu.models import shortconv
+    tail after the token out the same way (a dead slot's as it was), a
+    mamba layer its state and tail; a gmu layer reads the last mamba
+    layer's scan output out of the carry; where cross layers read the
+    one full layer's buffers, those ride the carry too and the full
+    layer writes its row before it attends (``_decode_layer``'s ``held``)."""
     from dstack_tpu.models.llama import dual_rope_freqs
 
     x = _embed_lookup(params, tokens, c)[:, None, :]
     ropes = dual_rope_freqs(c, positions)  # ([B, D/2] each) full, window
     live = write_mask[:, None]
+    lam = _lambda_tables(c)
+    states = [n for n in _STATES if n in cache]
+
+    def state_mix(x, layer, li):
+        # a conv or mamba layer reads its rows and hands the rows after
+        # the token out (a dead slot's as they were)
+        h = model_norm(x, layer["attn_norm"], c)
+        y, *rows = llama.mixer_of(_state_kind(c)).mix(
+            h, layer, c, *_state_rows(cache, li), live
+        )
+        return y, tuple(rows)
 
     def one_layer(carry, layer, li, run):
-        x, stats = carry
-        if run.kind == "conv":
-            h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-            y, tail = shortconv.mix(h, layer, c, *_state_rows(cache, li), live)
+        x, stats, shared = carry
+        if run.kind in _MIXED:
+            y, shared, rows = _mixed_layer(
+                x, layer, run.kind, c, shared,
+                lambda x, layer: state_mix(x, layer, li),
+            )
             out = _dense_out(x, y, layer, c, stats, live)
-            return (out if stats is not None else (out, None)), (tail,)
+            return (*(out if stats is not None else (out, None)), shared), rows
         cos, sin = llama.layer_rope(ropes, c, run.window)
         nk, nv = _group_kv(run)
-        out, rows = _decode_layer(
-            x, layer, li, run.config, cache[nk], cache[nv], positions,
-            write_mask,
+        # the buffers the cross layers read ride the carry: the full
+        # layer writes its row into them, then it and they attend over
+        # what is held (written after the scans, a leaf of ONE layer was
+        # copied whole into the write's loop and out of it a token of
+        # ``decode_loop``, 4 x 0.67 GB at 32 x 8192, device-free)
+        held = (
+            None if "k" not in shared or run.window
+            else "read" if run.kind == "cross" else "write"
+        )
+        bufs = (shared[nk], shared[nv]) if held else (cache[nk], cache[nv])
+        out, *rows = _decode_layer(
+            x, layer, 0 if held == "read" else li, run.config, *bufs,
+            positions, write_mask,
             lambda t: _apply_rope_batch(t, cos, sin, interleaved=c.rope_interleaved),
             False, None, run.window, positions if run.window else None, stats,
             decode_kernel, mesh,
+            lam0=lam[run.kind][li] if lam else None, held=held,
         )
-        return (out if stats is not None else (out, None)), rows
+        if held == "write":
+            shared = {**shared, nk: rows[0], nv: rows[1]}
+        return (
+            (*(out if stats is not None else (out, None)), shared),
+            None if held else rows[0],
+        )
 
-    (x, stats), written = _walk_layer_groups(
-        params, (x, _moe_stats(cache)), one_layer, c
+    (x, stats, shared), written = _walk_layer_groups(
+        params, (x, _moe_stats(cache), _shared_zeros(c, x, cache)), one_layer, c,
+        # (a run of ONE mamba layer: see ``one``)
+        one=jnp.minimum(1, 1 + positions[0]) if "cross" in c.layer_types else None,
     )
     cache = _with_moe_stats(dict(cache), stats)
+    cache.update({n: shared[n] for n in ("k", "v") if n in shared})
     for run, first, rows in written:
-        if run.kind == "conv":  # the run's tails, whole
-            with jax.named_scope("dtpu.conv.tail"):
-                cache["conv"] = jax.lax.dynamic_update_slice_in_dim(
-                    cache["conv"], rows[0], first, 0
-                )
+        if rows is None:  # layers that keep nothing, or wrote their own
+            continue
+        if run.kind in llama.STATE_KINDS:  # the run's tails (and states), whole
+            scope = "dtpu.conv.tail" if run.kind == "conv" else "dtpu.ssm.scan"
+            with jax.named_scope(scope):
+                for name, new in zip(states, rows):
+                    cache[name] = jax.lax.dynamic_update_slice_in_dim(
+                        cache[name], new, first, 0
+                    )
             continue
         for name, new in zip(_group_kv(run), rows):
             at = _ring_row(positions, cache[name]) if run.window else positions
             cache[name] = _cwrite_rows(
                 cache[name], first, at, write_mask, new,
-                unroll=_tokens_on_lanes(c.head_dim),
+                unroll=_tokens_on_lanes(c.attend_config.head_dim),
             )
     x = model_norm(x, params["final_norm"], c)
     return _head_logits(params, x[:, 0], c), cache
@@ -2736,47 +2937,54 @@ def _verify_layer(
     x, layer: dict, li, c: LlamaConfig, ck, cv, positions, pos_grid, write_mask,
     rope, nope, temp, window, ring=None, stats=None,
     decode_kernel: Optional[str] = None, mesh=None, unroll: bool = False,
+    lam0=None, cross: bool = False,
 ):
     """One dense layer of a verify step, the one copy → (x, ck, cv[,
-    stats]): the S tokens' K/V written at their per-row positions into
+    stats]) (``lam0``, ``cross``: as :func:`_decode_layer`'s; a cross
+    layer writes nothing: the full layer's rows are written by now): the S tokens' K/V written at their per-row positions into
     the stacked buffers ``ck`` / ``cv`` in place (a ring's one token at
     a time, :func:`_cwrite_ring`), then attended over. Arguments as
     :func:`_decode_layer` takes them; the einsum unless the caller asks
     for the kernel (no cell drafts: the kernel's verify form has not
     run on the chip)."""
     b, sdraft = pos_grid.shape
-    q, k, v = _dense_in(x, layer, c, rope, nope, temp)
-    if ring is None:
+    ac = c.attend_config
+    q, k, v = _dense_in(x, layer, c, rope, nope, temp, cross=cross)
+    if cross:
+        pass
+    elif ring is None:
         ck = _cwrite_rows(ck, li, positions, write_mask, _cstored(k, ck), unroll=unroll)
         cv = _cwrite_rows(cv, li, positions, write_mask, _cstored(v, cv), unroll=unroll)
     else:
         ck = _cwrite_ring(ck, li, positions, write_mask, k, axis=1, unroll=False)
         cv = _cwrite_ring(cv, li, positions, write_mask, v, axis=1, unroll=False)
     ckl, cvl = _clayer(ck, li), _clayer(cv, li)
-    ckf = _cfull(ckl, k.dtype)  # int8 caches dequant INSIDE the dot
-    cvf = _cfull(cvl, v.dtype)
+    ckf = _cfull(ckl, q.dtype)  # int8 caches dequant INSIDE the dot
+    cvf = _cfull(cvl, q.dtype)
     # grouped-query attention against the KV-width cache (see
     # decode_step): q [B, Hkv, G, S, D] · cache [B, Hkv, T, D]
-    grp = c.n_heads // c.n_kv_heads
-    qg = q.reshape(b, c.n_kv_heads, grp, sdraft, c.head_dim)
+    grp = ac.n_heads // ac.n_kv_heads
+    qg = q.reshape(b, ac.n_kv_heads, grp, sdraft, ac.head_dim)
     if decode_kernel == "flash":
         # ragged verify: rows flatten [G, S] row-major; row g*S+s
         # attends keys <= pos+s inside the kernel (verify rides the
         # SAME dispatch — sink column included — as decode)
-        qr = qg.reshape(b, c.n_kv_heads, grp * sdraft, c.head_dim)
+        qr = qg.reshape(b, ac.n_kv_heads, grp * sdraft, ac.head_dim)
         o = _flash_attend(
             qr, ck, cv, li, positions, window,
-            config=c, scale=c.attention_scale, grp=grp, rows_per_slot=sdraft,
+            config=ac, scale=ac.attention_scale, grp=grp, rows_per_slot=sdraft,
             sinks_leaf=layer.get("sinks"), mesh=mesh,
-        ).reshape(b, c.n_kv_heads, grp, sdraft, c.head_dim)
+        ).reshape(b, ac.n_kv_heads, grp, sdraft, ac.head_dim)
     else:
-        with _attn_scope(window):
+        with _attn_scope(window, cross=cross):
             s = jnp.einsum(
                 "bhgsd,bhkd->bhgsk", qg, ckf, preferred_element_type=jnp.float32
-            ) * c.attention_scale
-            p = _dense_probs(s, pos_grid, window, nope, layer, c, ring)
+            ) * ac.attention_scale
+            p = _dense_probs(s, pos_grid, window, nope, layer, ac, ring)
             o = jnp.einsum("bhgsk,bhkd->bhgsd", p.astype(cvf.dtype), cvf)
-    o = o.transpose(0, 3, 1, 2, 4).reshape(b, sdraft, c.q_dim)
+    o = o.transpose(0, 3, 1, 2, 4).reshape(b, sdraft, ac.q_dim)
+    if c.diff_attn:
+        o = llama.diff_combine(o, layer, c, lam0)
     if stats is None:
         return _dense_out(x, o, layer, c), ck, cv
     valid = jnp.broadcast_to(write_mask[:, None], (b, sdraft))
@@ -2806,34 +3014,44 @@ def _verify_step_groups(
     if "conv" in cache:
         mix = _state_mixer(c, write_mask, commit=False)
         cache = _state_pending(cache, c, sdraft)
+    lam = _lambda_tables(c)
 
     def one_layer(carry, layer, li, run):
-        x, cache = carry
-        if run.kind == "conv":
-            y, cache = mix(x, layer, cache, li)
+        x, cache, shared = carry
+        if run.kind in _MIXED:
+            y, shared, new_cache = _mixed_layer(
+                x, layer, run.kind, c, shared,
+                lambda x, layer: mix(x, layer, cache, li),
+            )
+            cache = cache if new_cache is None else new_cache
             valid = jnp.broadcast_to(write_mask[:, None], (b, sdraft))
-            return _group_out(x, cache, y, layer, c, valid), None
+            return (*_group_out(x, cache, y, layer, c, valid), shared), None
         cos, sin = llama.layer_rope(ropes, c, run.window)
         nk, nv = _group_kv(run)
         stats = _moe_stats(cache)
+        cross = run.kind == "cross"
         x, *rest = _verify_layer(
-            x, layer, li, run.config, cache[nk], cache[nv], positions,
-            pos_grid, write_mask,
+            x, layer, 0 if cross else li, run.config, cache[nk], cache[nv],
+            positions, pos_grid, write_mask,
             lambda t: _rope_rows(t, cos, sin, interleaved=c.rope_interleaved),
             False, None, run.window,
             positions + (sdraft - 1) if run.window else None, stats,
-            unroll=_tokens_on_lanes(c.head_dim),
+            unroll=_tokens_on_lanes(c.attend_config.head_dim),
+            lam0=lam[run.kind][li] if lam else None, cross=cross,
         )
         if stats is not None:
             cache = _with_moe_stats(cache, rest.pop(0))
-        return (x, {**cache, nk: rest[0], nv: rest[1]}), None
+        return (x, {**cache, nk: rest[0], nv: rest[1]}, shared), None
 
-    (x, cache), _ = _walk_layer_groups(params, (x, cache), one_layer, c)
+    (x, cache, _), _ = _walk_layer_groups(
+        params, (x, cache, _shared_zeros(c, x)), one_layer, c
+    )
     x = model_norm(x, params["final_norm"], c)
     logits = _head_logits(params, x, c, eq="bse,ev->bsv")
     if mix is not None:
         cache = _state_commit(
-            cache, _tokens_standing(logits, tokens, draft_len), write_mask, c
+            cache, _tokens_standing(logits, tokens, draft_len), write_mask, c,
+            params,
         )
     return logits, cache
 
@@ -3167,7 +3385,9 @@ class InferenceEngine:
                 "'flash' (a typo here would silently measure the wrong "
                 "path)"
             )
-        if decode_kernel == "flash" and not flash_decode_supported(config, max_seq):
+        if decode_kernel == "flash" and not flash_decode_supported(
+            config.attend_config, max_seq
+        ):
             raise ValueError(
                 "decode_kernel='flash' unsupported for this model/"
                 "max_seq (MLA, chunked attention, head_dim % 64, "
@@ -3181,16 +3401,21 @@ class InferenceEngine:
         # context (:func:`_attend_live`), the grouped-query family each
         # live slot's own (``_slot_keys``: the kernel, by the rule the
         # program itself takes at trace time)
-        # (a latent layer of several attention sublayers: a row each)
-        self._full_layers = config.sublayers * config.n_kind("full")
+        # (a latent layer of several attention sublayers: a row each; a
+        # cross layer reads the one full layer's rows over again: the
+        # count is of the layers that READ, what the key counters weigh)
+        self._full_layers = (
+            config.sublayers * config.n_kind("full") + config.n_kind("cross")
+        )
+        ac = config.attend_config
         self._slot_keys = bool(self._full_layers) and reads_live_keys(
-            config, max_seq, quantized=bool(kv_quant), mesh=mesh,
+            ac, max_seq, quantized=bool(kv_quant), mesh=mesh,
             decode_kernel=decode_kernel,
         )
         if self._slot_keys:  # the kernel's own block: a shard's heads, the cache's bytes
             self._key_block = block_keys(
-                config.n_kv_heads // (mesh.shape.get("tp", 1) if mesh else 1),
-                config.head_dim, max_seq,
+                ac.n_kv_heads // (mesh.shape.get("tp", 1) if mesh else 1),
+                ac.head_dim, max_seq,
                 1 if kv_quant else jnp.dtype(config.dtype).itemsize,
             )
         elif config.mla and not _indexed(config, max_seq):
@@ -3212,12 +3437,18 @@ class InferenceEngine:
         self.metrics.family("dtpu_serve_kv_window_pool_percent").set(
             100.0 * sum(b for n, b in size.items() if _is_ring(n)) / total
         )
-        # linear and conv layers: a state and / or a convolution tail a
-        # slot, sized by the widths and not by max_seq; nothing of
+        # linear, conv and mamba layers: a state and / or a convolution
+        # tail a slot, sized by the widths and not by max_seq; nothing of
         # theirs is addressed by position, so no prefix of a slot can
         # serve another request
         self._state_layers = sum(
-            config.layer_types.count(k) for k in ("linear", "conv")
+            config.layer_types.count(k) for k in llama.STATE_KINDS
+        )
+        # layers that keep nothing (gmu, cross): the upper half of a
+        # decoder-hybrid-decoder, whose prefill over a prompt's every
+        # position only its last needs; counted beside the lower half's
+        self._upper_layers = sum(
+            config.layer_types.count(k) for k in ("gmu", "cross")
         )
         self.metrics.family("dtpu_serve_state_cache_percent").set(
             100.0 * sum(size.get(n, 0) for n in _STATES) / total
@@ -3732,6 +3963,13 @@ class InferenceEngine:
             )
             self.metrics.family("dtpu_serve_prefill_dispatches_total").inc(1)
             self.metrics.family("dtpu_serve_prefill_pack_rows").observe(len(rows))
+            if self._upper_layers:
+                # the prompt positions this wave computed, through the
+                # layers that keep something and through those that keep
+                # nothing: the same today (PERF.md §7)
+                real = sum(ix + 1 for ix in last_ix[: len(rows)])
+                self.metrics.family("dtpu_serve_prefill_lower_rows_total").inc(real)
+                self.metrics.family("dtpu_serve_prefill_upper_rows_total").inc(real)
             if flight.enabled():
                 # batch composition straight from the wave's host lists:
                 # the (G, C) bucket, real rows packed, per-row starts

@@ -422,10 +422,25 @@ def new_serve_registry() -> Registry:
     r.gauge(
         "dtpu_serve_state_cache_percent",
         "Of the cache's bytes, the share without a token axis: the "
-        "linear layers' recurrent states and the linear and conv "
-        "layers' convolution tails (sized by the widths, not by "
-        "max_seq); 0 for a model without such layers",
+        "linear and mamba layers' recurrent states and the linear, conv "
+        "and mamba layers' convolution tails (sized by the widths, not "
+        "by max_seq); 0 for a model without such layers",
     ).set(0)
+    # a model whose upper layers keep nothing (gmu and cross layers
+    # over one K/V leaf): the prompt positions its two halves computed
+    # (two counters and no label: the benchmark's scrape sums label sets)
+    r.counter(
+        "dtpu_serve_prefill_lower_rows_total",
+        "Prompt positions that the layers which keep a state, a ring or "
+        "rows computed in prefill, on a model that also has layers "
+        "which keep nothing; 0 for any other model",
+    ).inc(0)
+    r.counter(
+        "dtpu_serve_prefill_upper_rows_total",
+        "Prompt positions that the layers which keep nothing (gmu, "
+        "cross) computed in prefill: a prompt's last position alone "
+        "needs them; today every position goes through them",
+    ).inc(0)
     r.counter(
         "dtpu_serve_state_resets_total",
         "Slots started from zeros: requests that started on a model "

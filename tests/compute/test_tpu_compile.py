@@ -560,7 +560,10 @@ def _serving_program(case, sds, place):
         "scmoe_zero": (_layer_groups_config("longcat-flash-chat-4l-ep32"), 8192, None),
         "linear_state": (_layer_groups_config("ling-3.0-flash-vl-13l-ep8"), 8192, None),
         "conv_gqa": (_layer_groups_config("lfm2-24b-a2b-ep8"), 8192, None),
+        "ssm_yoco": (_layer_groups_config("phi-4-mini-flash-reasoning"), 8192, None),
     }[model]
+    if model == "ssm_yoco":  # the cell runs 32 slots
+        b, mask = 32, sds((32,), jnp.bool_)
     config = config or _layer_groups_config()
     params = place(_abstract_params(config))
     cache = place(_abstract_cache(config, b, max_seq, kv_quant))
@@ -950,6 +953,49 @@ def test_conv_gqa_program_holds_no_second_cache(topo, _as_tpu, case):
     assert loops <= 8
     if case.startswith("decode_"):  # its three | four scans and the four experts' loops
         assert loops == (4 + 4 if case.startswith("decode_loop") else 3 + 4)
+
+
+# the longgen cell (PR 48): 32 x 8192, the whole Phi-4-mini-flash-reasoning:
+# ONE full layer's K/V [1, 32, 10, 8192, 128] (a KV pair side by side is one
+# head: 2 x 0.67 GB) that seven cross layers read too, eight rings
+# [8, 32, 10, 768, 128] (2 x 0.50 GB), nine float32 states [9, 32, 16, 5120]
+# (94 MB, the channels on the lanes) and tails, 7.70 GB of weights, 32 layers
+# walked as two folded segments. case → ``temp`` on PR 48's tree, GB
+_SSM_YOCO = {
+    "decode_loop-ssm_yoco": 0.369,
+    "prefill_packed_step@4-ssm_yoco": 0.295,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SSM_YOCO))
+def test_ssm_yoco_program_holds_no_second_cache(topo, _as_tpu, case):
+    """The macro-step and a wave of four rows of the whole model fit the
+    chip beside 10.2 GB of arguments and copy neither the K/V leaf, nor
+    a ring, nor the states whole. Three forms did, device-free, before
+    their cures: the state declared [d_inner, 16] was re-laid out
+    [16, d_inner] and back a token step (now declared so); a run of ONE
+    mamba layer, its scan of one trip inlined into ``decode_loop``'s
+    token loop, had the states copied into the write after the scans and
+    out of it (now a loop whose trip count the compiler cannot read,
+    ``_walk_layer_groups``' ``one``); and the K/V leaf of ONE layer,
+    read in the scans and written after them, was copied whole into the
+    write's loop and out of it, 4 x 0.67 GB a token (``temp`` 1.77 GB;
+    now the leaf rides the walk's carry, the full layer writes its row
+    and the cross layers read what is held). The macro-step attends
+    through ``ops/flash_decode`` at head_dim 128 (a KV pair one head);
+    the wave keeps the einsum. The upper half is ONE period's two scan
+    bodies, not fourteen unrolled layers: the gmu stands in the text a
+    dozen times (its few fusions), as often as with two periods."""
+    compiled, args, cache = _compiled_program(topo, case)
+    hlo = compiled.as_text()
+    whole = {cache[n].shape for n in ("k", "v", "win_k", "win_v", "state")}
+    assert not _cache_sized_moves(hlo, whole, set())
+    assert _has_kernel(compiled) == case.startswith("decode_")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.1e9 * _SSM_YOCO[case] + 5e6, f"temp {temp / 1e9:.3f} GB"
+    assert 10.1e9 < _fits(compiled) < 10.7e9
+    assert hlo.count(" while(") <= 16  # two segments' scans, the slots' writes
+    assert 0 < sum("dtpu.gmu" in line for line in hlo.splitlines()) <= 14
 
 
 # the plain family at head_dim 64 (Llama-3.2-1B, no cell), which the rule

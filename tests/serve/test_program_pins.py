@@ -89,6 +89,12 @@ CELLS = {
     # prefills by the packed program alone (``engine._packed_only``, with
     # or without a window since PR 44): the six programs of ``_GROUPS``
     "lfm2-24b-a2b-ep8": (16, 8192, _GROUPS),
+    # taken on the tree of PR 48, which added the configuration, the
+    # selective state-space mixer (``models/mamba.py``), differential
+    # attention, the gmu and cross layers and the walk by folded segments
+    # (``llama.layer_segments``); the 110 pins above and below stood. 32
+    # slots; the six programs of ``_GROUPS``
+    "phi-4-mini-flash-reasoning": (32, 8192, _GROUPS),
 }
 #: The three configurations that hold a SHARE of their experts (longdoc,
 #: mixed, agent: 6 + 6 + 7 digests) and the tiny family ``gqa-groups``
@@ -160,6 +166,14 @@ CELL_PINS = {
         "prefill_packed_step@1": "3dd39d9e6936c07c7ec7b3948341a70250fbe7220133261eef9a9d66e093e87a",
         "prefill_packed_step@2": "f0294c4a5685eb1ca4a64a50fc6cb3a304a98dc89fb7e9cfe10160bddb6d4a6a",
         "prefill_packed_step@4": "21b94636920f6d61685f53211baf4fa765e0d544786fcbd440c97c6590fc89ee",
+    },
+    "phi-4-mini-flash-reasoning": {
+        "decode_step": "bd3b547d2c475b278761a7b82eaa41be141eb1c1a449e3b0562a01c476274566",
+        "decode_loop": "19ef8c8afdac26610100b80d2bdfd251f4140e462c48a455a2ffb09266d9111d",
+        "verify_step": "ca0e75beb685e3bcf84cb688492f9de5d7773465fdf98c19d8dcfe12d29ce364",
+        "prefill_packed_step@1": "87159cbaa7453ffee7edc7bcefa9aec98d0d4b18469d8436b59022720a39d603",
+        "prefill_packed_step@2": "81f3d772bf1498c95d351f533a43471ac1fbdb036657b55fbfae655fda6ee3ab",
+        "prefill_packed_step@4": "d8cdad464e245e6df71db816e4cbeee5abe0cb8cd5792674a51eadc36299db0f",
     },
     "minitron-4b": {
         "decode_step": "31cd7802fdfa5729183b1aa6346316af5f0a7b1d5a845041033888da8aa84ab7",
@@ -258,8 +272,11 @@ FAMILIES = {
 #: cell) a model of layer groups writes a step's rows unrolled and a
 #: lone row's chunk as one ``dynamic_update_slice`` (the mixed cell's
 #: head_dim is 128: its six pins stand)
+#: ``ssm-tiny`` (PR 48): mamba, differential window | full, gmu and cross
+#: layers in two folded segments, LayerNorms with biases, no rotary
 TINY = tuple(sorted(FAMILIES)) + (
     "mla-tiny", "moe-tiny", "scmoe-tiny", "linear-tiny", "conv-tiny",
+    "ssm-tiny",
 )
 TINY_NAMES = (
     "decode_step", "verify_step", "prefill_chunk_step@16", "prefill_packed_step@2",
@@ -355,6 +372,12 @@ TINY_PINS = {
         "verify_step": "5b9d5ac4f0b6f2529426c9532064d623f78b66b75571b7801d488cf9cda870c1",
         "prefill_chunk_step@16": "96fdde80fbe29401460aa89cf3bb15981600b8f6809e4875e8a6d069d6ff2d83",
         "prefill_packed_step@2": "3c17d38e5daf5b3d4302665a6ab3d4e474f8214d8c8632e4f728c16e2684bedc",
+    },
+    "ssm-tiny": {
+        "decode_step": "57bea30bd013eab27d8b590d24fbecb7f9a30bbb1b2c3517da36fcf6efd25deb",
+        "verify_step": "95292efc7cdda9d6e87965bfd32444577860b5de8f6df464add4ede3ddb2dd63",
+        "prefill_chunk_step@16": "d464c2945f9a357925548b0bb3e550419ba6ada3525d30576d4c0643c429ee8c",
+        "prefill_packed_step@2": "340999a61ec15fad3aa9517de2f0b687f2ab345bff23cf5ecd0fea7a99376619",
     },
     "moe-tiny": {
         "decode_step": "a38072b4a98bfd0c163d6cec124ee7e5712705874e59bee3e100c69fdb1838bf",
